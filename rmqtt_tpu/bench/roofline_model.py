@@ -29,8 +29,26 @@ import numpy as np
 
 from rmqtt_tpu.ops.partitioned import CHUNK, WORDS_PER_CHUNK
 
-#: default modeled part: v5e HBM bandwidth (GB/s); pass bw_gbps for others
-V5E_HBM_GBPS = 819.0
+#: published peaks, keyed by ``jax.devices()[0].device_kind``. A device that
+#: is not here is an error, not a default: a roofline against the wrong
+#: part's bandwidth is a wrong number with a right-looking name.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {
+        "hbm_gbps": 819.0,
+        "source": "Google Cloud documentation, \"TPU v5e\": 16 GB of HBM "
+                  "at 819 GB/s per chip",
+    },
+}
+
+
+def peak_hbm_gbps(device_kind: str) -> float:
+    try:
+        return DEVICE_PEAKS[device_kind]["hbm_gbps"]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r} in "
+            f"roofline_model.DEVICE_PEAKS (known: {sorted(DEVICE_PEAKS)}); "
+            "add the part with its source before modelling it") from None
 
 
 def tile_bytes_legacy(max_levels: int, tok_wide: bool = False) -> int:
@@ -43,12 +61,14 @@ def tile_bytes_packed(layout) -> int:
     return layout.groups * CHUNK * 4
 
 
-def model_table(table, ncs: Sequence[int], bw_gbps: float = V5E_HBM_GBPS,
+def model_table(table, ncs: Sequence[int], device_kind: str,
                 measured_topics_per_sec: Optional[float] = None) -> dict:
-    """HBM roofline of one table against a MEASURED candidate-count sample
-    ``ncs`` (one entry per topic of the real publish stream). When
-    ``measured_topics_per_sec`` is given, the modeled-vs-measured fraction
-    is included so regressions in either direction are visible per run."""
+    """HBM roofline of one table on ``device_kind`` against a MEASURED
+    candidate-count sample ``ncs`` (one entry per topic of the real publish
+    stream). When ``measured_topics_per_sec`` is given, the
+    modeled-vs-measured fraction is included so regressions in either
+    direction are visible per run."""
+    bw_gbps = peak_hbm_gbps(device_kind)
     ncs = np.asarray(ncs, dtype=np.float64)
     nc_eff = float(ncs.mean()) if ncs.size else 1.0
     layout = table.packed_layout()
@@ -59,6 +79,7 @@ def model_table(table, ncs: Sequence[int], bw_gbps: float = V5E_HBM_GBPS,
     bpt = nc_eff * ptile + out_bytes if ptile is not None else bpt_legacy
     bw = bw_gbps * 1e9
     out = {
+        "device_kind": device_kind,
         "hbm_gbps": bw_gbps,
         "nc_mean": round(nc_eff, 2),
         "nc_p99": int(np.percentile(ncs, 99)) if ncs.size else 0,

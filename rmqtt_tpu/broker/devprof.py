@@ -37,8 +37,7 @@ makes those diagnosable in production:
     + snapshot into one JSON artifact; ``auto_dump()`` fires on retrace
     storms, device-plane failover trips (broker/failover.py), fused-
     verify disagreement (ops/partitioned.py, parallel/sharded.py) and
-    bench/chip-hunter failure exits — exactly the postmortem cfg4/cfg5
-    never got.
+    failed bench configs.
 
 Surfaces follow the house pattern: ``/api/v1/device`` (+ cluster
 ``/device/sum`` via a ``what=device`` DATA query), ``rmqtt_device_*``

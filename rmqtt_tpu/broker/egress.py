@@ -145,7 +145,7 @@ class _WheelEntry:
 class KeepaliveWheel:
     """Hashed timer wheel: one ticking task serves every connection.
 
-    Entries are filed into ``slots[deadline // tick % n_slots]``; each
+    Entries are filed into ``slots[round(deadline / tick) % n_slots]``; each
     tick visits one slot and only touches entries whose deadline cohort
     is due (longer timeouts simply re-file on their wheel round — the
     classic hashed-wheel rounds check, done by deadline comparison).
@@ -169,7 +169,13 @@ class KeepaliveWheel:
     # ------------------------------------------------------------- arming
     def _file(self, entry: _WheelEntry, deadline: float) -> None:
         entry.deadline = deadline
-        entry.slot = int(deadline / self.tick) % self.n_slots
+        # NEAREST slot, not the containing one: slot k is visited at some
+        # now >= k*tick and fires what is due within half a tick, so an
+        # entry is caught on that visit only if its deadline lies below
+        # (k+0.5)*tick. Filed by floor, a deadline in the upper half of its
+        # slot was skipped by an early-in-the-tick visit and waited a whole
+        # wheel round (n_slots ticks) to expire.
+        entry.slot = int(deadline / self.tick + 0.5) % self.n_slots
         self.slots[entry.slot].add(entry)
 
     def arm(self, state, timeout: float) -> _WheelEntry:

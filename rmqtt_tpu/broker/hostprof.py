@@ -205,6 +205,8 @@ class HostProfiler:
         self._last_tick_mono = 0.0
         # gc accounting
         self._gc_t0: Dict[int, int] = {}
+        # GC samples awaiting the lock (see _gc_cb: it must never block)
+        self._gc_pending: deque = deque()
         self.gc_hist: Dict[int, Histogram] = {g: Histogram() for g in _GENS}
         self.gc_pauses: Dict[int, int] = {g: 0 for g in _GENS}
         self.gc_pause_ns: Dict[int, int] = {g: 0 for g in _GENS}
@@ -409,19 +411,20 @@ class HostProfiler:
         dur_ns = time.perf_counter_ns() - t0
         collected = int(info.get("collected", 0) or 0)
         uncollectable = int(info.get("uncollectable", 0) or 0)
-        with self._lock:
-            self.gc_pauses[gen] = self.gc_pauses.get(gen, 0) + 1
-            self.gc_pause_ns[gen] = self.gc_pause_ns.get(gen, 0) + dur_ns
-            self.gc_collected[gen] = self.gc_collected.get(gen, 0) + collected
-            self.gc_uncollectable[gen] = (
-                self.gc_uncollectable.get(gen, 0) + uncollectable)
-            h = self.gc_hist.get(gen)
-            if h is None:
-                h = self.gc_hist[gen] = Histogram()
-            h.record(dur_ns)
-            r = self._rollup()
-            r.gc_pauses += 1
-            r.gc_pause_ns += dur_ns
+        # NEVER block here. A collection starts wherever an allocation
+        # tips the threshold — including inside one of this object's own
+        # ``with self._lock`` sections, on the thread that holds the lock.
+        # A blocking acquire then deadlocks that thread, and every other
+        # thread at its next collection (seen: a broker loading 1M
+        # subscriptions went silent at 0% CPU, API included). Samples queue
+        # lock-free; whoever gets the lock next folds them.
+        self._gc_pending.append((gen, dur_ns, collected, uncollectable))
+        if self._lock.acquire(blocking=False):
+            try:
+                while self._gc_pending:
+                    self._fold_gc(*self._gc_pending.popleft())
+            finally:
+                self._lock.release()
         if self.gc_slow_ms and dur_ns >= self.gc_slow_ms * 1e6:
             in_dispatch = 0
             probe = self.dispatch_probe
@@ -439,6 +442,22 @@ class HostProfiler:
                 # routing batches were in flight?
                 "in_dispatch": in_dispatch,
             })
+
+    def _fold_gc(self, gen: int, dur_ns: int, collected: int,
+                 uncollectable: int) -> None:
+        """One GC sample into the counters; caller holds ``self._lock``."""
+        self.gc_pauses[gen] = self.gc_pauses.get(gen, 0) + 1
+        self.gc_pause_ns[gen] = self.gc_pause_ns.get(gen, 0) + dur_ns
+        self.gc_collected[gen] = self.gc_collected.get(gen, 0) + collected
+        self.gc_uncollectable[gen] = (
+            self.gc_uncollectable.get(gen, 0) + uncollectable)
+        h = self.gc_hist.get(gen)
+        if h is None:
+            h = self.gc_hist[gen] = Histogram()
+        h.record(dur_ns)
+        r = self._rollup()
+        r.gc_pauses += 1
+        r.gc_pause_ns += dur_ns
 
     # ------------------------------------------------- blocking-call watchdog
     def _watchdog_loop(self, stop_evt: threading.Event) -> None:

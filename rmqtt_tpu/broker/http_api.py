@@ -428,6 +428,12 @@ class HttpApi:
             from rmqtt_tpu.broker.devprof import DEVPROF
 
             body_out = {"node": ctx.node_id, **DEVPROF.snapshot()}
+            # what serves matches in this process (router/xla.py): device
+            # identity as JAX reports it, words producer, host mirror;
+            # {} for the host routers, which never touch a device
+            info = getattr(ctx.router, "device_info", None)
+            body_out["backend"] = info() if callable(info) else {}
+            body_out["retained"] = ctx.retain.device_info()
             if q.get("flight", ["0"])[0] not in ("0", "", "false"):
                 body_out["flight"] = DEVPROF.flight()
             return 200, body_out, J

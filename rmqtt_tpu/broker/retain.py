@@ -39,6 +39,7 @@ class RetainStore:
         self._scanner = None
         self._rowid_by_topic: Dict[str, int] = {}
         self._msg_by_rowid: Dict[int, Tuple[str, Message]] = {}
+        self.device_scans = 0  # wildcard lookups answered by the scanner
         # cluster hook: called as on_set(topic, msg_or_None) after a local
         # mutation (broadcast-mode retain_set_broadcast analogue)
         self.on_set = None
@@ -172,11 +173,11 @@ class RetainStore:
                 PartitionedRetainedScanner,
                 RetainedTable,
             )
-            from rmqtt_tpu.utils.tpuprobe import ensure_safe_platform
+            from rmqtt_tpu.utils.jaxenv import device_identity
 
-            # the first scan is the first backend touch on this path: a
-            # wedged accelerator grant would block the event loop forever
-            ensure_safe_platform()
+            # first backend touch when the router is a host router: same
+            # rule as the device router — no unasked-for CPU fallback
+            device_identity()
             self._table = RetainedTable()
             self._scanner = PartitionedRetainedScanner(self._table)
             # backfill current tree contents (incl. $-topics)
@@ -205,7 +206,21 @@ class RetainStore:
             if self._table is not None:
                 self._table.remove(rid)
 
+    def device_info(self) -> Dict[str, object]:
+        """The device scanner's state for ``/api/v1/device``: whether it is
+        configured, how many lookups it answered, and what it uploaded."""
+        sc = self._scanner
+        return {
+            "enabled": self._tpu,
+            "threshold": self._tpu_threshold,
+            "scans": self.device_scans,
+            "rows": len(self._rowid_by_topic),
+            "uploads": sc.uploads if sc is not None else 0,
+            "upload_bytes": sc.upload_bytes if sc is not None else 0,
+        }
+
     def _matches_tpu(self, topic_filter: str) -> List[Tuple[str, Message]]:
         self._ensure_tpu()
         (row,) = self._scanner.scan([topic_filter])
+        self.device_scans += 1
         return [self._msg_by_rowid[rid] for rid in row.tolist() if rid in self._msg_by_rowid]

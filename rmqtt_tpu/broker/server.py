@@ -590,6 +590,11 @@ async def _amain(args) -> None:
     # [log] section (file/console targets + level, logging.rs analogue);
     # replaces the bootstrap basicConfig from main()
     conf.setup_logging(settings.log, verbose=getattr(args, "verbose", False))
+    if settings.broker.router == "xla" or settings.broker.retain_tpu:
+        # before the first jit of the process (utils/jaxenv.py)
+        from rmqtt_tpu.utils.jaxenv import setup_compile_cache
+
+        log.info("jax compilation cache: %s", setup_compile_cache())
     broker = MqttBroker(ServerContext(settings.broker))
     conf.instantiate_plugins(broker.ctx, settings)
     cluster = None
@@ -718,28 +723,33 @@ def _supervise_workers(args, argv: list) -> None:
     import signal
     import subprocess
 
+    from rmqtt_tpu import conf
+
     if args.cluster_mode or args.cluster_listen or args.node_id or args.peer:
         sys.exit("--workers manages node ids and the cluster itself; it "
                  "cannot combine with --cluster-mode/--cluster-listen/"
                  "--node-id/--peer")
-    if args.config:
-        from rmqtt_tpu import conf
-
-        if conf.load(args.config).broker.durability_enable:
-            # every worker would recover + journal into ONE store file:
-            # duplicated sessions per process and colliding journal seqs
-            # (upserts overwrite each other). Same class of guard as
-            # fabric+cluster — fail at launch, not at the first kill -9.
-            sys.exit("[durability] cannot combine with --workers: each "
-                     "worker process would recover and journal into the "
-                     "same store (run the durability plane single-process)")
+    router_cli = {"node": {"router": args.router}} if args.router else {}
+    cfg = conf.load(args.config, cli=router_cli).broker
+    if cfg.durability_enable:
+        # every worker would recover + journal into ONE store file:
+        # duplicated sessions per process and colliding journal seqs
+        # (upserts overwrite each other). Same class of guard as
+        # fabric+cluster — fail at launch, not at the first kill -9.
+        sys.exit("[durability] cannot combine with --workers: each "
+                 "worker process would recover and journal into the "
+                 "same store (run the durability plane single-process)")
     fabric_dir = None
     fabric_tmp = None
-    fabric_on = args.fabric or args.fabric_dir
-    if not fabric_on and args.config:
-        from rmqtt_tpu import conf
-
-        fabric_on = conf.load(args.config).broker.fabric_enable
+    fabric_on = args.fabric or args.fabric_dir or cfg.fabric_enable
+    if cfg.router == "xla" and not fabric_on:
+        # a chip belongs to one process: N peered workers would each build
+        # a device router and all but one would fail to take the chip.
+        # Same class of guard as durability above — fail at launch.
+        sys.exit("--workers N --router xla needs --fabric: one worker owns "
+                 "the device table (and the chip) and the others match on "
+                 "it over the fabric; without --fabric every worker would "
+                 "open the device itself")
     if fabric_on:
         if args.fabric_dir:
             fabric_dir = args.fabric_dir

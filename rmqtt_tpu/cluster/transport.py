@@ -85,6 +85,7 @@ class PeerClient:
         self._pending: Dict[int, asyncio.Future] = {}
         self._corr = itertools.count(1)
         self._lock = asyncio.Lock()
+        self._connect_lock = asyncio.Lock()
 
     @property
     def connected(self) -> bool:
@@ -93,18 +94,25 @@ class PeerClient:
     async def _ensure(self) -> None:
         if self._writer is not None:
             return
-        if not self.breaker.allow():
-            raise PeerUnavailable(f"circuit open to node {self.node_id}")
-        try:
-            reader, writer = await asyncio.wait_for(
-                asyncio.open_connection(self.host, self.port), self.timeout
-            )
-        except (OSError, asyncio.TimeoutError) as e:
-            self.breaker.fail()
-            raise PeerUnavailable(f"connect to node {self.node_id} failed: {e}") from e
-        self._writer = writer
-        self._reader_task = asyncio.get_running_loop().create_task(self._read_loop(reader))
-        self.breaker.ok()
+        # one connect at a time: two callers that both saw no writer (a
+        # heartbeat and an RPC) each opened a connection, the second
+        # replaced the first, and the first's reader — dropped, so closed —
+        # tore down the live one and failed every pending call
+        async with self._connect_lock:
+            if self._writer is not None:
+                return
+            if not self.breaker.allow():
+                raise PeerUnavailable(f"circuit open to node {self.node_id}")
+            try:
+                reader, writer = await asyncio.wait_for(
+                    asyncio.open_connection(self.host, self.port), self.timeout
+                )
+            except (OSError, asyncio.TimeoutError) as e:
+                self.breaker.fail()
+                raise PeerUnavailable(f"connect to node {self.node_id} failed: {e}") from e
+            self._writer = writer
+            self._reader_task = asyncio.get_running_loop().create_task(self._read_loop(reader))
+            self.breaker.ok()
 
     async def _read_loop(self, reader: asyncio.StreamReader) -> None:
         try:

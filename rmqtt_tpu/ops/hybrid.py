@@ -4,10 +4,11 @@ The deployed router keeps two match engines for the same filter set: a
 host-side trie (µs-scale per topic, the reference's own data structure,
 `/root/reference/rmqtt/src/trie.rs:288-408`) and the batched device
 automaton (`ops/partitioned.py`). Which one is faster depends on scale and
-placement: at small tables or over a high-RTT tunnel the trie wins at any
-batch size; at 1M+ wildcard subs the device path wins on bursts (NOTES.md
-measured both regimes). A fixed size threshold can't know which regime it
-is in — so the hybrid measures.
+dispatch cost: at small tables the trie wins at any batch size; at 1M+
+wildcard subs the device path wins on bursts (NOTES.md measured both
+regimes, through a slow link; PERF.md has the attached chip's constants).
+A fixed size threshold can't know which regime it is in — so the hybrid
+measures.
 
 Policy:
 - batches ≤ ``small_max`` always take the trie (per-message latency
@@ -15,7 +16,7 @@ Policy:
   publish costs a full round trip);
 - larger batches go to whichever path's throughput EMA is higher; every
   ``probe_every``-th large batch runs on the slower path to refresh its
-  EMA, so regime changes (table growth, co-located vs tunneled chip) flip
+  EMA, so regime changes (table growth, a busier host or device) flip
   the routing within a bounded number of batches;
 - with no device matcher (or no trie side) the surviving path serves
   everything.
@@ -62,6 +63,9 @@ class AdaptiveHybrid:
         # only DEVICE successes reset the failover breaker's consecutive-
         # failure count; trie-served batches are not device evidence
         self.last_backend: Optional[str] = None
+        # served-share counters per backend: [batches, topics] — what the
+        # device actually served vs the host mirror (device_info() surface)
+        self.served = {"side": [0, 0], "device": [0, 0]}
         # EMA state is touched from both the submit and the completion
         # executor threads (RoutingService pipelining); the GIL keeps it
         # memory-safe but probe cadence / rate attribution would skew —
@@ -88,8 +92,15 @@ class AdaptiveHybrid:
             if self._dev_samples > 1 and dt > 0:
                 self._bump("device", n / dt)
 
+    def _note(self, backend: str, n: int) -> None:
+        self.last_backend = backend
+        with self._lock:
+            c = self.served[backend]
+            c[0] += 1
+            c[1] += n
+
     def _side_match(self, topics: Sequence[str]) -> List[np.ndarray]:
-        self.last_backend = "side"
+        self._note("side", len(topics))
         t0 = time.perf_counter()
         if len(topics) > 1 and hasattr(self.side, "match_batch"):
             # one native call for the whole batch: the per-topic ctypes
@@ -104,7 +115,7 @@ class AdaptiveHybrid:
         return rows
 
     def _device_match(self, topics: Sequence[str]) -> List[np.ndarray]:
-        self.last_backend = "device"
+        self._note("device", len(topics))
         if _FP_DISPATCH.action is not None:
             _FP_DISPATCH.fire_sync()
         t0 = time.perf_counter()
@@ -166,7 +177,7 @@ class AdaptiveHybrid:
             and self._pick() == "device"
         ):
             if hasattr(self.matcher, "match_submit"):
-                self.last_backend = "device"
+                self._note("device", len(topics))
                 if _FP_DISPATCH.action is not None:
                     _FP_DISPATCH.fire_sync()
                 return ("device", self.matcher.match_submit(topics),

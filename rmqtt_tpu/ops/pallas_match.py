@@ -25,17 +25,32 @@ earlier revision on real TPU — interpret mode hides all of them):
   contiguous [BT, WPC] row range at a dynamic sublane offset, so the out
   block is chunk-major [nc*BT, WPC] — the wrapper transposes back to the
   caller's [B, NC*WPC] order inside the same jit;
-- HBM DMA slices must be 128-aligned in the minor dim: the table tile is
-  field-major [L+3, CHUNK=256] (which also keeps the XLA-side HBM array
-  un-padded — see pack_device_rows);
+- a DMA slice must cover whole tiles of the source array's layout, and
+  the layout is XLA's, not the kernel's: the legacy table is field-major
+  [L+3, CHUNK] with the L+3 field rows padded up to the dtype's sublane
+  tile (8 rows of int32, 16 of int16) before the call, and the packed
+  table is viewed as [chunks, groups, CHUNK] so that one chunk is one
+  (groups, 128) tile — sliced as a row of a flat [chunks, groups*CHUNK]
+  array it is a sub-tile (1, 256) window of an (8, 128) tiling, which
+  Mosaic refuses ("Slice shape along dimension 0 must be aligned to
+  tiling (8), but is 1"; likewise "dimension 1 ... but is 11" for the
+  unpadded int16 legacy tile). tests/test_chip_compile.py compiles both
+  entry points for a described v5e so the next such refusal costs no
+  chip time;
 - dynamic-sublane vector loads from VMEM blocks are avoided: per-topic
   values (tokens/tlen/tdollar) ride as [BT, ·] VMEM blocks read at STATIC
   level offsets and lane-broadcast; candidate chunk ids stay SMEM scalars
   (DMA descriptors need scalar indices); the level loop is unrolled.
 
+The table operand is declared ``pl.ANY``: the compiler places it — in VMEM
+when the whole table fits (the 1M-filter packed table is 8 MB), else HBM —
+and the kernel only ever DMAs chunk tiles out of it.
+
 Semantics are identical to the lax path (same [B, NC*WPC] packed words);
-`PartitionedMatcher` verifies that on-device at first use and falls back if
-anything disagrees — an unprofiled kernel must never change routing results.
+`PartitionedMatcher` verifies that on-device at first use and stays on lax
+if the results disagree — an unprofiled kernel must never change routing
+results. A kernel that is selected and does not COMPILE is an error there,
+not a fallback.
 """
 
 from __future__ import annotations
@@ -82,8 +97,9 @@ def _kernel(nc: int, lvl: int, chunk: int, cid_ref, ttok_ref, tlen_ref,
                 start_wave((k + 1) % 2, k + 1)
 
             wait_wave(slot, k)
-            # [BT, L+3, CHUNK] field-major; tiles may ship int16 (half the
-            # DMA bytes) — widen once after load, the mask math stays int32
+            # [BT, L+3 (sublane-padded), CHUNK] field-major; tiles may ship
+            # int16 (half the DMA bytes) — widen once after load, the mask
+            # math stays int32. Pad rows sit past lvl+2 and are never read.
             tiles = scratch[slot].astype(jnp.int32)
             flen = tiles[:, lvl, :]  # [BT, CHUNK]
             plen = tiles[:, lvl + 1, :]
@@ -132,7 +148,7 @@ def _kernel(nc: int, lvl: int, chunk: int, cid_ref, ttok_ref, tlen_ref,
 
     pl.run_scoped(
         body,
-        scratch=pltpu.VMEM((2, BT, lvl + 3, chunk), rows_hbm.dtype),
+        scratch=pltpu.VMEM((2, BT) + rows_hbm.shape[1:], rows_hbm.dtype),
         sems=pltpu.SemaphoreType.DMA((2, BT)),
     )
 
@@ -145,6 +161,11 @@ def match_words_pallas(packed_rows, ttok, tlen, tdollar, chunk_ids,
     nchunks, width, chunk = packed_rows.shape
     lvl = width - 3
     wpc = chunk // 32
+    # whole-tile DMA (module docstring): pad the field rows up to the
+    # dtype's sublane tile — 8 rows of int32, 16 of int16
+    pad = -width % (8 * (4 // packed_rows.dtype.itemsize))
+    if pad:
+        packed_rows = jnp.pad(packed_rows, ((0, 0), (0, pad), (0, 0)))
     kernel = functools.partial(_kernel, nc, lvl, chunk)
     # constant bit-pack selectors: P[c, j] = 2^(c%32 - half*16) when word
     # c//32 == j and c%32 in the half's 16-bit range, else 0 (see _kernel)
@@ -163,7 +184,7 @@ def match_words_pallas(packed_rows, ttok, tlen, tdollar, chunk_ids,
             pl.BlockSpec((BT, 1), lambda i: (i, 0)),
             pl.BlockSpec((chunk, wpc), lambda i: (0, 0)),
             pl.BlockSpec((chunk, wpc), lambda i: (0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),  # packed_rows stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),  # never blocked: DMA source
         ],
         out_specs=pl.BlockSpec((nc * BT, wpc), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b // BT * nc * BT, wpc), jnp.uint32),
@@ -192,7 +213,7 @@ def _kernel_packed(nc: int, layout: PackedLayout, chunk: int, cid_ref,
                    ttok_ref, tlen_ref, tdollar_ref, plo_ref, phi_ref,
                    rows_hbm, out_ref):
     """The wave kernel over BIT-PACKED tiles (pack_device_rows_packed):
-    ``rows_hbm`` is flat ``[up_chunks, groups*CHUNK]`` int32 — four byte
+    ``rows_hbm`` is ``[up_chunks, groups, CHUNK]`` int32 — four byte
     planes per lane — so each wave DMAs ``groups*CHUNK*4`` bytes per topic
     instead of the legacy ``(L+3)*CHUNK*2``: the same ≥2× HBM-traffic
     reduction the roofline models, in the kernel that is measured
@@ -201,7 +222,6 @@ def _kernel_packed(nc: int, layout: PackedLayout, chunk: int, cid_ref,
     is not something this kernel wants to depend on); everything downstream
     of the unpack (mask math in int32, MXU bit-pack via the f32 selector
     matmuls, chunk-major stores) is identical to ``_kernel``."""
-    lanes = layout.groups * chunk
     offs = layout.plane_offsets()
     meta_p = layout.planes - 1
 
@@ -230,12 +250,12 @@ def _kernel_packed(nc: int, layout: PackedLayout, chunk: int, cid_ref,
                 start_wave((k + 1) % 2, k + 1)
 
             wait_wave(slot, k)
-            tiles = scratch[slot]  # [BT, groups*CHUNK] int32
+            tiles = scratch[slot]  # [BT, groups, CHUNK] int32
 
             def plane(p):
-                # byte plane p: static lane slice + static shift/mask
+                # byte plane p: static group row + static shift/mask
                 grp, sh = p // 4, (p % 4) * 8
-                x = tiles[:, grp * chunk : (grp + 1) * chunk]
+                x = tiles[:, grp, :]
                 if sh:
                     x = x >> sh
                 return x & 0xFF
@@ -279,7 +299,7 @@ def _kernel_packed(nc: int, layout: PackedLayout, chunk: int, cid_ref,
 
     pl.run_scoped(
         body,
-        scratch=pltpu.VMEM((2, BT, lanes), jnp.int32),
+        scratch=pltpu.VMEM((2, BT, layout.groups, chunk), jnp.int32),
         sems=pltpu.SemaphoreType.DMA((2, BT)),
     )
 
@@ -292,9 +312,13 @@ def match_words_pallas_packed(packed_rows, ttok, tlen, tdollar, chunk_ids,
     and the lax ``scan_words_packed_impl`` — `PartitionedMatcher` verifies
     that on-device at first use and falls back if anything disagrees."""
     b, nc = chunk_ids.shape
-    lanes = packed_rows.shape[1]
-    chunk = lanes // layout.groups
+    chunk = packed_rows.shape[1] // layout.groups
     wpc = chunk // 32
+    # whole-tile DMA (module docstring): one chunk = one (groups, CHUNK)
+    # tile. The resident array stays flat for the lax scan; the view costs
+    # one relayout copy of the table per call, small next to the B*NC tile
+    # reads it enables
+    packed_rows = packed_rows.reshape(-1, layout.groups, chunk)
     nlvl = layout.nlvl
     kernel = functools.partial(_kernel_packed, nc, layout, chunk)
     c = np.arange(chunk)
@@ -312,7 +336,7 @@ def match_words_pallas_packed(packed_rows, ttok, tlen, tdollar, chunk_ids,
             pl.BlockSpec((BT, 1), lambda i: (i, 0)),
             pl.BlockSpec((chunk, wpc), lambda i: (0, 0)),
             pl.BlockSpec((chunk, wpc), lambda i: (0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),  # packed_rows stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),  # never blocked: DMA source
         ],
         out_specs=pl.BlockSpec((nc * BT, wpc), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b // BT * nc * BT, wpc), jnp.uint32),

@@ -179,6 +179,9 @@ class ShardedPartitionedMatcher:
         self.full_uploads = 0
         self.delta_uploads = 0
         self.upload_bytes = 0
+        # (device id, shard shape) of the last step's output: where the
+        # batch really ran (chip_smoke.py --chips 4 checks four devices)
+        self.last_out_shards: list = []
 
     def _global_step(self, budget_per_dev: int):
         step = self._gsteps.get(budget_per_dev)
@@ -195,7 +198,9 @@ class ShardedPartitionedMatcher:
             out_specs=P(axes),
         )
         def gstep(rows, ttok, tlen, td, cids):
-            words = scan_words_impl(rows, ttok, tlen, td, cids)
+            # fenced from the tail for compile time (match_fused_impl)
+            words = lax.optimization_barrier(
+                scan_words_impl(rows, ttok, tlen, td, cids))
             # per-device packed [budget, routes... | cnts...]: routes are
             # topic-LOCAL (widx*32+bitpos) and cnts is the shard's per-topic
             # count vector — shard-major == topic-major, so the host
@@ -224,7 +229,9 @@ class ShardedPartitionedMatcher:
             out_specs=P(axes),
         )
         def fstep(rows, fid_rows, ttok, tlen, td, cids):
-            words = scan_words_impl(rows, ttok, tlen, td, cids)
+            # fenced from the tail for compile time (match_fused_impl)
+            words = lax.optimization_barrier(
+                scan_words_impl(rows, ttok, tlen, td, cids))
             # per-device [fids(budget)... | cnts(bl)...] int32: each shard
             # resolves its topic slice's routes to GLOBAL fids through the
             # replicated row→fid map and sorts (topic, fid) on device —
@@ -436,37 +443,31 @@ class ShardedPartitionedMatcher:
                 out = self._match_fused(dev, inputs, chunk_ids, b, padded)
                 self.fused_batches += 1
                 return out
-            try:
-                # still deciding: a compile/availability failure here is a
-                # legitimate reason to fall back, not a corruption signal
-                got = self._match_fused(dev, inputs, chunk_ids, b, padded)
-            except Exception as e:
-                log.warning("sharded fused pipeline unavailable (%s); using "
-                            "the words+host-decode path", e)
-                self._fused = False
-                got = None
-            if got is not None:
-                if self._fused is None:
-                    # first-use self-check against the legacy wire + host
-                    # decode (same contract as the local matcher). A
-                    # zero-match batch must not latch the verify on an
-                    # empty-vs-empty comparison — serve the reference and
-                    # stay undecided until real matches flow.
-                    want = self._match_global_unfused(
-                        dev, inputs, chunk_ids, b, padded)
-                    if not any(len(np.asarray(w)) for w in want):
-                        return want
-                    agree = len(got) == len(want) and all(
-                        np.array_equal(a, w) for a, w in zip(got, want))
-                    self._fused = agree
-                    if not agree:
-                        log.warning("sharded fused pipeline disagrees with "
-                                    "the host-decode reference; disabled")
-                        _DEVPROF.auto_dump("fused_verify_disagreement")
-                        return want
-                    log.info("sharded fused pipeline verified; enabled")
-                self.fused_batches += 1
-                return got
+            # still deciding: a compile or run failure propagates — only a
+            # DISAGREEMENT with the reference (below) rules the fused
+            # pipeline out
+            got = self._match_fused(dev, inputs, chunk_ids, b, padded)
+            if self._fused is None:
+                # first-use self-check against the legacy wire + host
+                # decode (same contract as the local matcher). A
+                # zero-match batch must not latch the verify on an
+                # empty-vs-empty comparison — serve the reference and
+                # stay undecided until real matches flow.
+                want = self._match_global_unfused(
+                    dev, inputs, chunk_ids, b, padded)
+                if not any(len(np.asarray(w)) for w in want):
+                    return want
+                agree = len(got) == len(want) and all(
+                    np.array_equal(a, w) for a, w in zip(got, want))
+                self._fused = agree
+                if not agree:
+                    log.warning("sharded fused pipeline disagrees with "
+                                "the host-decode reference; disabled")
+                    _DEVPROF.auto_dump("fused_verify_disagreement")
+                    return want
+                log.info("sharded fused pipeline verified; enabled")
+            self.fused_batches += 1
+            return got
         return self._match_global_unfused(dev, inputs, chunk_ids, b, padded)
 
     def _match_fused(self, dev, inputs, chunk_ids, b: int, padded: int) -> list:
@@ -487,6 +488,8 @@ class ShardedPartitionedMatcher:
                 _pj("sharded_fused", step, dev, self._dev_fids, *inputs,
                     _key_extra=("budget", gd))
                 if _DEVPROF.enabled else step(dev, self._dev_fids, *inputs))
+            self.last_out_shards = [(sh.device.id, tuple(sh.data.shape))
+                                    for sh in out_dev.addressable_shards]
             arr = fetch(out_dev, "sharded fused fetch")
             per_dev = arr.reshape(self.ndev, gd + bl)
             cn = per_dev[:, gd:].astype(np.int64)
@@ -526,6 +529,8 @@ class ShardedPartitionedMatcher:
             out_dev = (_pj("sharded_global", step, dev, *inputs,
                            _key_extra=("budget", gd))
                        if _DEVPROF.enabled else step(dev, *inputs))
+            self.last_out_shards = [(sh.device.id, tuple(sh.data.shape))
+                                    for sh in out_dev.addressable_shards]
             arr = fetch(out_dev, "sharded match fetch")
             per_dev = arr.reshape(self.ndev, gd + bl)
             cn = per_dev[:, gd:].astype(np.int64)  # [ndev, bl], shard-major

@@ -12,6 +12,7 @@ SURVEY.md §7 "hard parts".
 
 from __future__ import annotations
 
+import logging
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -30,6 +31,9 @@ from rmqtt_tpu.router.base import (
     round_robin_choice_factory,
 )
 from rmqtt_tpu.router.relations import RelationsMap, expand_matches_raw
+
+
+_LOG = logging.getLogger("rmqtt_tpu.router")
 
 
 class _TreeSide:
@@ -73,29 +77,28 @@ class XlaRouter(Router):
                 "mesh is only supported with backend='partitioned' and no "
                 "explicit device (use parallel.ShardedMatcher for dense)"
             )
+        # first backend touch of the process: takes the chip, raises when no
+        # accelerator answers and the CPU was not asked for (utils/jaxenv.py)
+        from rmqtt_tpu.utils.jaxenv import device_identity
+
+        self.device_ident = device_identity()
+        _LOG.info("device router on platform=%s device_kind=%s devices=%d",
+                  self.device_ident["platform"],
+                  self.device_ident["device_kind"],
+                  self.device_ident["device_count"])
         if backend == "partitioned":
             from rmqtt_tpu.ops.partitioned import PartitionedMatcher, PartitionedTable
 
             self.table = table or PartitionedTable()
             use_mesh = None if mesh == "auto" else mesh
             if mesh == "auto" and device is None:
-                try:
-                    # the platform guard MUST run before the first backend
-                    # touch: jax.devices() hangs forever on a wedged
-                    # accelerator grant (tpuprobe; memoized, instant when the
-                    # process already chose a platform)
-                    from rmqtt_tpu.utils.tpuprobe import ensure_safe_platform
+                import jax
 
-                    if ensure_safe_platform() != "cpu":
-                        import jax
+                devs = jax.devices()
+                if len(devs) > 1 and devs[0].platform == "tpu":
+                    from rmqtt_tpu.parallel.sharded import make_mesh
 
-                        devs = jax.devices()
-                        if len(devs) > 1 and devs[0].platform == "tpu":
-                            from rmqtt_tpu.parallel.sharded import make_mesh
-
-                            use_mesh = make_mesh(devices=devs, dp=len(devs), fp=1)
-                except Exception:
-                    use_mesh = None
+                    use_mesh = make_mesh(devices=devs, dp=len(devs), fp=1)
             if use_mesh is not None:
                 from rmqtt_tpu.parallel.sharded import ShardedPartitionedMatcher
 
@@ -136,9 +139,13 @@ class XlaRouter(Router):
 
             self._side = NativeTrie()
             self._side_native = True
-        except Exception:
+        except Exception as e:
             from rmqtt_tpu.core.trie import TopicTree
 
+            _LOG.warning(
+                "native runtime unavailable (%s); the host mirror is the "
+                "Python trie — no adaptive hybrid, dropped above 200K filters "
+                "(device_info().host_mirror says which one serves)", e)
             self._side = _TreeSide(TopicTree())
         # large batches route adaptively between the trie mirror and the
         # device (ops/hybrid.py): which path wins depends on table scale
@@ -393,6 +400,34 @@ class XlaRouter(Router):
             "stage_dispatch_ms_total": round(sn.get("dispatch", 0) / 1e6, 3),
             "stage_fetch_ms_total": round(sn.get("fetch", 0) / 1e6, 3),
             "stage_decode_ms_total": round(sn.get("decode", 0) / 1e6, 3),
+        }
+
+    def device_info(self) -> Dict[str, object]:
+        """What serves matches in this process, for ``/api/v1/device``: the
+        device as JAX reports it, the matcher class (and how many devices
+        its mesh spans), the words producer in use and why, and which host
+        mirror backs the hybrid/failover plane."""
+        from rmqtt_tpu.utils.jaxenv import compile_cache_stats
+
+        m = self.matcher
+        mesh = getattr(m, "mesh", None)
+        producer = getattr(m, "words_producer", None)
+        return {
+            **self.device_ident,
+            "matcher": type(m).__name__,
+            "mesh_devices": int(mesh.devices.size) if mesh is not None else 1,
+            "words_producer": producer() if callable(producer) else
+            {"name": "lax", "why": "the only producer of this matcher"},
+            "host_mirror": ("native" if self._side_native
+                            else "python" if self._side is not None
+                            else "none"),
+            "hybrid_max": self._hybrid_max,
+            # [batches, topics] each backend served, and the hybrid's
+            # current large-batch choice (None until both paths are timed)
+            "hybrid_served": {k: list(v)
+                              for k, v in self._hybrid.served.items()},
+            "hybrid_choice": self._hybrid.choice,
+            "compile_cache": compile_cache_stats(),
         }
 
     def device_hbm(self) -> Dict[str, float]:

@@ -30,14 +30,25 @@ _build_failed = False
 
 
 def _build() -> bool:
+    """``make`` the library under a per-process name in the same directory,
+    then ``os.replace`` it into place: several processes racing the
+    on-demand build (``--workers N``, test workers) each install a whole
+    file, and none ever loads a half-written one."""
+    tmp = f".build-{os.getpid()}-{_LIB_PATH.name}"
     try:
         subprocess.run(
-            ["make", "-s"], cwd=_RUNTIME_DIR, check=True, capture_output=True, timeout=120
+            ["make", "-s", f"LIB={tmp}"], cwd=_RUNTIME_DIR, check=True,
+            capture_output=True, timeout=120
         )
+        os.replace(_RUNTIME_DIR / tmp, _LIB_PATH)
         return True
     except (subprocess.CalledProcessError, subprocess.TimeoutExpired, OSError) as e:
-        log.warning("native runtime build failed: %s", e)
+        log.warning("native runtime build failed: %s%s", e,
+                    (b"\n" + e.stderr).decode(errors="replace")
+                    if getattr(e, "stderr", None) else "")
         return False
+    finally:
+        (_RUNTIME_DIR / tmp).unlink(missing_ok=True)
 
 
 def load() -> Optional[ctypes.CDLL]:

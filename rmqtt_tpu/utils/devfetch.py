@@ -1,14 +1,15 @@
 """Timeout-guarded device→host fetches.
 
-On this hardware class the accelerator grant can wedge mid-run (NOTES.md):
-a ``np.asarray`` of a device array then blocks forever inside PJRT, taking
-the whole process with it — round 2's cfg5 bench died exactly there, losing
-every result already measured. When ``RMQTT_FETCH_TIMEOUT`` (seconds) is
-set, fetches run on a daemon worker thread and raise ``TimeoutError``
-instead of hanging, so callers (bench ``guarded()``, the routing service)
-can record the failure and continue/exit. Unset (the default, e.g. broker
-production paths on a healthy chip) it is a plain ``np.asarray`` — no
-thread, no overhead.
+``np.asarray`` of a device array blocks until the device has produced it.
+If the device never does — a hung kernel, a lost chip — the call blocks
+forever and takes the whole process with it, results already measured
+included. When ``RMQTT_FETCH_TIMEOUT`` (seconds) is set, fetches run on a
+daemon worker thread and raise ``TimeoutError`` instead of hanging, so a
+caller (a bench's per-config guard, the routing service) can record the
+failure and continue or exit. Unset — the default everywhere: the first
+chip run (``chip_smoke.py``, PR 21) completed every fetch, so nothing arms
+it on a healthy chip — it is a plain ``np.asarray``: no thread, no
+overhead.
 """
 
 from __future__ import annotations
@@ -39,7 +40,7 @@ def set_fetch_timeout(seconds: Optional[float]) -> None:
 
 
 def fetch(arr, what: str = "device fetch") -> np.ndarray:
-    """``np.asarray(arr)`` with the configured wedge guard."""
+    """``np.asarray(arr)`` under the configured deadline."""
     t = fetch_timeout()
     if t is None:
         return np.asarray(arr)
@@ -58,6 +59,6 @@ def fetch(arr, what: str = "device fetch") -> np.ndarray:
         return box["v"]
     if "e" in box:
         raise box["e"]
-    # the worker stays parked on the wedged fetch; daemon=True means it
-    # cannot block process exit
+    # the worker stays parked on the fetch; daemon=True means it cannot
+    # block process exit
     raise TimeoutError(f"{what} exceeded {t:.0f}s (wedged accelerator?)")

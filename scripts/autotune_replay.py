@@ -6,9 +6,9 @@ devprof rollups as traffic flows — but every process still STARTS from
 the static defaults and re-learns the workload from scratch. This script
 closes the offline half of the loop: it replays recorded evidence —
 devprof flight-recorder dumps (``rmqtt_tpu.devprof_dump/1``), bench
-artifacts (``BENCH_r*.json`` / ``.chip_hunt/cfgN.json``, which embed a
+artifacts (``BENCH_r*.json``, which embed a
 ``devprof`` snapshot), or raw ``/api/v1/device`` bodies — and fits the
-knob vector a broker (or the next chip-hunter window) should START from:
+knob vector a broker should START from:
 
 - **pad_floor** from the merged per-interval batch-size histogram: the
   pow2 cover of the p50 batch when small batches dominate, pulled down
@@ -21,12 +21,11 @@ knob vector a broker (or the next chip-hunter window) should START from:
   of near-empty batches (the micro-batch window the cfg1 regime wants).
 
 Output is the fitted knob dict plus (``--env``) the matching ``RMQTT_*``
-environment — the exact seeding seam ``scripts/chip_hunter.py
---autotune`` uses per ladder config, so TPU windows compound instead of
-restarting from defaults.
+environment, so a process starts from what the last one learned instead
+of from the defaults.
 
 Usage:
-  python scripts/autotune_replay.py .chip_hunt/devprof_cfg*.json
+  python scripts/autotune_replay.py .devprof/*.json
   python scripts/autotune_replay.py BENCH_r0*.json --json
   python scripts/autotune_replay.py dumps/*.json --env   # shell-ready
   python scripts/autotune_replay.py --history /var/lib/rmqtt/history
@@ -64,8 +63,8 @@ def extract_snapshots(doc: dict) -> List[dict]:
     """Pull every devprof snapshot-shaped dict out of one artifact,
     whatever its generation: a flight-recorder dump (``snapshot`` key +
     schema), a bench artifact (``devprof`` embed), a raw ``/api/v1/device``
-    body (has ``compile``+``dispatch`` at top level), or a chip-hunter
-    checkpoint wrapping any of those."""
+    body (has ``compile``+``dispatch`` at top level), or a checkpoint
+    wrapping any of those."""
     out: List[dict] = []
     if not isinstance(doc, dict):
         return out

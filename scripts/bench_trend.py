@@ -45,7 +45,8 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 #: goodput keys probed per config entry, most-representative first (the
 #: router-level number is what a broker user gets; raw device otherwise)
-_GOODPUT_KEYS = ("router_topics_per_sec", "tpu_topics_per_sec",
+_GOODPUT_KEYS = ("router_topics_per_sec", "device_topics_per_sec",
+                 "tpu_topics_per_sec",  # rounds 1-5: CPU runs under this name
                  "cpu_topics_per_sec")
 
 

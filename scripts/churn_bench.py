@@ -47,11 +47,11 @@ def main() -> None:
     if args.cpu:
         import os
 
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    else:
-        from rmqtt_tpu.utils.tpuprobe import ensure_safe_platform
+        os.environ["JAX_PLATFORMS"] = "cpu"
+    from rmqtt_tpu.utils.jaxenv import device_identity, setup_compile_cache
 
-        ensure_safe_platform()
+    setup_compile_cache()
+    device_identity()  # raises when no accelerator answers and no --cpu
 
     import bench  # reuses the generators + table builders
 

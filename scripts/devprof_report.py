@@ -4,7 +4,7 @@
 The profiler (`rmqtt_tpu/broker/devprof.py`) writes dump artifacts —
 ``{"schema": "rmqtt_tpu.devprof_dump/1", "snapshot": ..., "flight": [...]}``
 — on failover trips, fused-verify disagreement, retrace storms and failed
-bench/chip-hunter configs (``bench.py`` guarded handler, ``.devprof/``).
+bench configs (``bench.py`` guarded handler, ``.devprof/``).
 This script turns one into the tables an operator reads first:
 
   * top shape keys by trace (compile) time, per kernel — the "what kept

@@ -106,7 +106,11 @@ async def test_retain_sync_on_set_and_startup(brokers, clusters):
     b1, b2 = brokers
     pub = await TestClient.connect(b1.port, "pub-ret")
     await pub.publish("synced/t", b"keepme", retain=True, qos=1)
-    await asyncio.sleep(0.2)  # broadcast propagation
+    # broadcast propagation: poll, a fixed 0.2 s was short on a loaded box
+    for _ in range(250):
+        if b2.ctx.retain.get("synced/t") is not None:
+            break
+        await asyncio.sleep(0.02)
     # node 2 has the retained copy locally
     assert b2.ctx.retain.get("synced/t") is not None
     late = await TestClient.connect(b2.port, "late")

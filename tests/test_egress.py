@@ -385,10 +385,13 @@ def test_wheel_fires_idle_refiles_active_rearms_veto():
         try:
             idle = _FakeState(_time.monotonic())
             active = _FakeState(_time.monotonic())
-            wheel.arm(idle, 0.2)
-            wheel.arm(active, 0.2)
+            # a 1 s timeout against a 0.06 s refresh: on a loaded box one
+            # sleep(0.06) stretched past the former 0.2 s and the ACTIVE
+            # entry fired. Wider waits, same assertions.
+            wheel.arm(idle, 1.0)
+            wheel.arm(active, 1.0)
             assert wheel.sessions == 2
-            deadline = _time.monotonic() + 5.0  # 1-core CI: generous
+            deadline = _time.monotonic() + 10.0
             while not idle._closing.is_set() and _time.monotonic() < deadline:
                 await asyncio.sleep(0.06)
                 active._last_packet = _time.monotonic()

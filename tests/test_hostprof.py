@@ -205,6 +205,23 @@ def test_gc_pauses_counted_with_dispatch_correlation(prof):
     assert prof._gc_cb not in gc.callbacks
 
 
+@pytest.mark.timeout(20)
+def test_gc_callback_never_blocks_on_the_profiler_lock(prof):
+    """A collection can start inside one of the profiler's own locked
+    sections, on the thread holding the lock (any allocation there): the
+    gc hook must queue its sample instead of acquiring — a blocking acquire
+    deadlocked a broker under a 1M-subscription load. The queued sample is
+    folded by the next callback that finds the lock free."""
+    with prof._lock:  # what auto_dump/snapshot hold while they allocate
+        prof._gc_cb("start", {"generation": 0})
+        prof._gc_cb("stop", {"generation": 0, "collected": 3})
+    assert prof.gc_pauses[0] == 0 and len(prof._gc_pending) == 1
+    prof._gc_cb("start", {"generation": 0})
+    prof._gc_cb("stop", {"generation": 0, "collected": 4})
+    assert prof.gc_pauses[0] == 2 and prof.gc_collected[0] == 7
+    assert not prof._gc_pending
+
+
 # ------------------------------------------------------------ trigger pins
 
 

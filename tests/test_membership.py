@@ -300,6 +300,8 @@ def test_fast_fail_kick_with_dead_peer():
                     pass
             except (ConnectionError, OSError):
                 pass
+            finally:
+                writer.close()  # else the server side stays half-open
 
         hole = await asyncio.start_server(swallow, "127.0.0.1", 0)
         hole_port = hole.sockets[0].getsockname()[1]
@@ -319,9 +321,13 @@ def test_fast_fail_kick_with_dead_peer():
             assert brokers[0].ctx.metrics.get("cluster.kick_skipped") > base_skip
             await client.close()
         finally:
+            # Python 3.12's Server.wait_closed() waits for every accepted
+            # connection to be closed, and the blackhole's only ends when
+            # the cluster's PeerClient hangs up: tear the cluster down first
+            # (the old order waited forever), and bound the wait anyway
             hole.close()
-            await hole.wait_closed()
             await _teardown(brokers, clusters)
+            await asyncio.wait_for(hole.wait_closed(), 5.0)
 
     asyncio.run(run())
 

@@ -286,3 +286,23 @@ def test_full_scrape_grammar_all_planes(tmp_path):
             HOSTPROF.configure(enabled=False)
 
     asyncio.run(asyncio.wait_for(run(), 60))
+
+
+def test_roofline_peaks_are_keyed_by_device_kind():
+    """The HBM model takes its peak from a table keyed by jax device_kind;
+    a part that is not in the table is an error, never the v5e's number."""
+    import pytest
+
+    from rmqtt_tpu.bench.roofline_model import model_table, peak_hbm_gbps
+    from rmqtt_tpu.ops.partitioned import PartitionedTable
+
+    assert peak_hbm_gbps("TPU v5 lite") == 819.0
+    with pytest.raises(ValueError, match="DEVICE_PEAKS"):
+        peak_hbm_gbps("cpu")
+    t = PartitionedTable()
+    for f in ("a/+/c", "a/b/#", "x/y"):
+        t.add(f)
+    m = model_table(t, [1, 2, 3], "TPU v5 lite")
+    assert m["device_kind"] == "TPU v5 lite" and m["hbm_gbps"] == 819.0
+    with pytest.raises(ValueError):
+        model_table(t, [1], "TPU v99")
