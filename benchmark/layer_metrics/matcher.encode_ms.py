@@ -1,0 +1,13 @@
+"""Host-clock milliseconds per device batch in the matcher's ``encode`` section
+(topics to padded token / candidate arrays): the ``rmqtt/matcher.encode`` spans' time over the runs of ``jit_match_*``
+programs in the same trace. On an executor thread, so GIL wait is inside.
+Absent where the trace holds no such span or no such run."""
+
+from _stages import matcher_ms
+
+SPEC = {"layer": "device matcher ops/partitioned.py", "unit": "ms/batch",
+        "source": "program_span", "moves": "deliveries_per_s"}
+
+
+def read(run: dict):
+    return matcher_ms(run, "encode")

@@ -379,6 +379,11 @@ class ServerContext:
         # the router records its kernel.dispatch stage through the shared
         # registry (router/base.py telemetry seam)
         router.telemetry = self.telemetry
+        # ... and the busy-clock stages of its match path (hybrid backends,
+        # relations expansion, the device matcher's four sections)
+        use_tele = getattr(router, "use_telemetry", None)
+        if use_tele is not None:
+            use_tele(self.telemetry)
         # device-table churn knobs ([routing] section): applied to whatever
         # table/matcher the router owns, duck-typed so trie/native routers
         # (no device mirror) are untouched
@@ -805,6 +810,15 @@ class ServerContext:
             s.in_inflights += len(sess.in_qos2)
         # routing-service gauges (per-exec stats parity, context.rs:506-555)
         for k, v in self.routing.stats().items():
+            setattr(s, k, v)
+        # served-path stage layer (broker/telemetry.py Stage): cumulative
+        # count / busy ms per stage — monotone, so a reader subtracts two
+        # snapshots; zeros when disabled (the window histograms' buckets
+        # ride the admin API's body only: http_api.stats_body). With
+        # them the CPU the process and THIS thread have
+        # burnt: the admin API and the history collector build this body
+        # on the loop thread, so the thread's is the event loop's
+        for k, v in self.telemetry.stage_stats().items():
             setattr(s, k, v)
         # overload gauges (broker/overload.py): state + breaker health
         s.overload_state = int(self.overload.state)

@@ -248,6 +248,11 @@ class DeviceProfiler:
         return tuple(k(a) for a in args) + tuple(
             (n, k(v)) for n, v in sorted(kwargs.items()))
 
+    def seen(self, kernel: str, key: Tuple) -> bool:
+        """Has this jit signature been through ``note_jit``? A never-seen
+        one is about to trace + compile."""
+        return (kernel, key) in self._seen
+
     def note_jit(self, kernel: str, key: Tuple, dur_ns: int) -> bool:
         """Record one jit-seam call. → True iff this (kernel, key) was a
         never-seen signature (a trace+compile). Called only when enabled

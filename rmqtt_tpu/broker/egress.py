@@ -49,6 +49,7 @@ import time
 from typing import List, Optional, Set
 
 from rmqtt_tpu.broker.hooks import HookType
+from rmqtt_tpu.broker.telemetry import NULL_TELEMETRY
 from rmqtt_tpu.utils.failpoints import FAILPOINTS
 
 #: default high-water mark, matching the legacy send_raw drain gate
@@ -61,12 +62,16 @@ class EgressBuf:
     """Per-connection frame vector + once-per-tick micro-flush."""
 
     __slots__ = ("writer", "metrics", "high_water", "_vec", "_bytes",
-                 "_scheduled", "_closed")
+                 "_scheduled", "_closed", "_tele", "_st_flush")
 
-    def __init__(self, writer, metrics, high_water: int = DEFAULT_HIGH_WATER) -> None:
+    def __init__(self, writer, metrics, high_water: int = DEFAULT_HIGH_WATER,
+                 telemetry=None) -> None:
         self.writer = writer
         self.metrics = metrics
         self.high_water = high_water
+        # busy-clock stage ``egress.flush`` (broker/telemetry.py Stage)
+        self._tele = telemetry if telemetry is not None else NULL_TELEMETRY
+        self._st_flush = self._tele.stage("egress.flush")
         self._vec: List[bytes] = []
         self._bytes = 0
         self._scheduled = False
@@ -98,6 +103,7 @@ class EgressBuf:
         n_bytes, self._bytes = self._bytes, 0
         if self._closed:
             return
+        tok = self._st_flush.begin(len(vec)) if self._tele.enabled else 0
         try:
             if _FP_EGRESS.action is not None:  # chaos seam (failpoints.py)
                 _FP_EGRESS.fire_sync()
@@ -119,6 +125,9 @@ class EgressBuf:
             except Exception:
                 pass
             return
+        finally:
+            if tok:
+                self._st_flush.end(tok)
         self.metrics.inc("net.egress_flushes")
         self.metrics.inc("net.egress_bytes", n_bytes)
         if len(vec) > 1:

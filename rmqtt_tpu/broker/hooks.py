@@ -101,6 +101,11 @@ class HookRegistry:
 
         return unregister
 
+    def has(self, htype: HookType) -> bool:
+        """Is any handler registered? (``fire`` on an empty chain returns
+        without yielding to the loop; a handler may suspend.)"""
+        return bool(self._handlers.get(htype))
+
     def handlers(self, htype: HookType) -> List[Handler]:
         return [h for _, _, h in self._handlers.get(htype, [])]
 

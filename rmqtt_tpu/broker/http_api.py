@@ -44,6 +44,13 @@ def sysinfo() -> dict:
     return out
 
 
+def stats_body(ctx) -> dict:
+    """The ``"stats"`` object of ``/api/v1/stats`` (and a peer's reply to
+    STATS_GET): the gauge surface plus the window histograms' cumulative
+    bucket counts (``Telemetry.bucket_stats``)."""
+    return {**ctx.stats().to_json(), **ctx.telemetry.bucket_stats()}
+
+
 def client_info(s) -> dict:
     """Serialized client/session row (api.rs clients payload shape)."""
     return {
@@ -359,7 +366,7 @@ class HttpApi:
             # merge — all our exposed gauges are Sum-mode counts). "nodes"
             # counts the nodes actually summed, not the configured peers —
             # a down peer contributes nothing to either number.
-            total = dict(ctx.stats().to_json())
+            total = stats_body(ctx)
             replies = await _cluster_merge(
                 ctx, M.STATS_GET, {}, lambda r: [r] if "stats" in r else []
             )
@@ -376,7 +383,7 @@ class HttpApi:
                     total[k] = round(total[k] / nodes, 3)
             return 200, {"nodes": nodes, "stats": total}, J
         if path == "/api/v1/stats":
-            nodes = [{"node": ctx.node_id, "stats": ctx.stats().to_json()}]
+            nodes = [{"node": ctx.node_id, "stats": stats_body(ctx)}]
             nodes += await _cluster_merge(
                 ctx, M.STATS_GET, {}, lambda r: [r] if "stats" in r else []
             )

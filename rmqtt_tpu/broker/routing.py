@@ -33,7 +33,7 @@ import time
 from typing import List, Optional, Tuple
 
 from rmqtt_tpu.broker.failover import _swallow_abandoned
-from rmqtt_tpu.broker.telemetry import NULL_TELEMETRY, Telemetry
+from rmqtt_tpu.broker.telemetry import NULL_TELEMETRY, PROFILER, Telemetry
 from rmqtt_tpu.broker.tracing import CURRENT_TRACE
 from rmqtt_tpu.router.base import Id, Router, SubRelationsMap
 from rmqtt_tpu.router.cache import MatchCache
@@ -67,6 +67,9 @@ class RoutingService:
         self._rec_hit = self.tele.recorder("publish.cache_hit")
         self._rec_miss = self.tele.recorder("publish.cache_miss")
         self._rec_qwait = self.tele.recorder("routing.queue_wait")
+        # busy-clock stages of the dispatch (telemetry.Stage)
+        self._st_plan = self.tele.stage("routing.plan")
+        self._st_resolve = self.tele.stage("routing.resolve")
         self.max_batch = max_batch
         self.linger = linger_ms / 1000.0
         self._q: asyncio.Queue = asyncio.Queue(maxsize=max_queue)
@@ -238,6 +241,7 @@ class RoutingService:
 
     def start(self) -> None:
         loop = asyncio.get_running_loop()
+        self.tele.bind_loop()  # this thread's busy time is the loop's
         if self._task is None:
             self._task = loop.create_task(self._run())
         if self._completer is None and hasattr(self.router, "submit_batch_raw"):
@@ -397,11 +401,21 @@ class RoutingService:
                 groups[j][0].append(i)
         return items, groups
 
-    def _resolve(self, batch, results, groups=None) -> None:
-        """Resolve waiters from per-item results. A result slot may be an
-        EXCEPTION (the poisoned-batch isolation path, ``_isolate``): it
-        rejects only that item's waiters — the co-batched publishes still
-        resolve normally."""
+    def _resolve(self, batch, results, groups=None, seq: int = 0) -> None:
+        """Resolve waiters from per-item results (the ``routing.resolve``
+        stage: futures set, cache fill; ``seq`` names the batch on its
+        span). A result slot may be an EXCEPTION (the poisoned-batch
+        isolation path, ``_isolate``): it rejects only that item's waiters
+        — the co-batched publishes still resolve normally."""
+        if not self.tele.enabled:
+            return self._resolve_now(batch, results, groups)
+        tok = self._st_resolve.begin(len(batch), seq)
+        try:
+            self._resolve_now(batch, results, groups)
+        finally:
+            self._st_resolve.end(tok)
+
+    def _resolve_now(self, batch, results, groups) -> None:
         if groups is None:
             for (_, _, fut, raw, _t, _tr), res in zip(batch, results):
                 if fut.done():
@@ -471,8 +485,19 @@ class RoutingService:
                 raise
 
     async def _dispatch_one(self, loop, batch, inline_ok, pipelined) -> None:
+        tele = self.tele
+        # the dispatch counter is the batch's seq: every span of this batch
+        # carries it, on whichever thread (telemetry.Stage)
+        seq = tele.batch_seq = self.dispatches + 1
+        # routing.plan: everything between the collect and the router call —
+        # the plan itself, hot-key attribution and the per-item queue-wait
+        # accounting (synchronous; one profiler poll per dispatch)
+        tok = 0
+        if tele.enabled:
+            PROFILER.poll()
+            tok = self._st_plan.begin(len(batch), seq)
         items, groups = self._plan(batch)
-        self.dispatches += 1
+        self.dispatches = seq
         self.dispatched_items += len(items)
         hk = self.hotkeys
         if hk is not None:
@@ -483,9 +508,8 @@ class RoutingService:
             len(items) if self.dispatches == 1
             else 0.9 * self.batch_size_ema + 0.1 * len(items)
         )
-        tele = self.tele
         t_disp = 0
-        if tele.enabled:
+        if tok:
             t_disp = time.perf_counter_ns()
             rec_qwait = self._rec_qwait
             for it in batch:
@@ -496,6 +520,7 @@ class RoutingService:
                     if tr is not None:  # same t0/t_disp reads as the stage
                         tr.add("routing.queue_wait", it[4], wait, it[1])
             tele.record("routing.batch_size", len(items))
+            self._st_plan.end(tok)
         fo = self.failover
         if fo is not None and fo.active:
             # device plane is out: route through the host trie mirror and
@@ -513,7 +538,7 @@ class RoutingService:
             except Exception as e:
                 await self._isolate(loop, batch, items, groups, e)
                 return
-            self._resolve(batch, results, groups)
+            self._resolve(batch, results, groups, seq)
             if t_disp:
                 self._record_match(t_disp, len(items), batch)
             return
@@ -549,13 +574,14 @@ class RoutingService:
                 # trip on it
                 self.inflight -= 1
                 self._pipe_sem.release()
-                self._resolve(batch, payload, groups)
+                self._resolve(batch, payload, groups, seq)
                 if t_disp:
                     self._record_match(t_disp, len(items), batch)
                 if fo is not None and self._served_by_device():
                     fo.note_device_ok()
                 return
-            await self._completion_q.put((batch, groups, payload, t_disp, items))
+            await self._completion_q.put(
+                (batch, groups, payload, t_disp, items, seq))
             return
         self.inflight += 1
         try:
@@ -571,7 +597,7 @@ class RoutingService:
             self.inflight -= 1
             raise
         self.inflight -= 1
-        self._resolve(batch, results, groups)
+        self._resolve(batch, results, groups, seq)
         if t_disp:
             self._record_match(t_disp, len(items), batch)
         if fo is not None and self._served_by_device():
@@ -728,7 +754,8 @@ class RoutingService:
     async def _complete_loop(self) -> None:
         loop = asyncio.get_running_loop()
         while True:
-            batch, groups, handle, t_disp, items = await self._completion_q.get()
+            batch, groups, handle, t_disp, items, seq = (
+                await self._completion_q.get())
             fo = self.failover
             try:
                 try:
@@ -750,7 +777,7 @@ class RoutingService:
                     await self._device_failed(
                         loop, batch, items, groups, e, "complete_error", t_disp)
                 else:
-                    self._resolve(batch, results, groups)
+                    self._resolve(batch, results, groups, seq)
                     if t_disp:
                         self._record_match(t_disp, len(items), batch)
                     if fo is not None:

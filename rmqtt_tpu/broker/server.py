@@ -856,7 +856,12 @@ def main() -> None:
     if args.workers and args.workers > 1:
         _supervise_workers(args, sys.argv[1:])
         return
-    asyncio.run(_amain(args))
+    # the stock selector loop with one addition: a profiler trace of this
+    # process shows where the loop waited (broker/telemetry.py)
+    from rmqtt_tpu.broker.telemetry import IdleSpanSelector
+
+    asyncio.run(_amain(args), loop_factory=lambda: asyncio.SelectorEventLoop(
+        IdleSpanSelector()))
 
 
 if __name__ == "__main__":

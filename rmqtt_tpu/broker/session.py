@@ -74,6 +74,9 @@ class DeliverItem:
     # (broker/tracing.py): the deliver loop runs in another task, so the
     # context rides the item instead of the contextvar
     trace: object = None
+    # perf_counter_ns at Session.enqueue (0 with telemetry off): the
+    # deliver loop's pop closes the ``deliver.queue_wait`` stage on it
+    t_enq: int = 0
 
 
 def encode_qos0_frame(msg: Message, version: int, retain: bool, rem) -> bytes:
@@ -180,6 +183,8 @@ class Session:
                 and self.limits.session_expiry > 0):
             item.did = dur.on_enqueue(self.client_id, item)
         policy = Policy.DROP_CURRENT if item.qos == 0 and self.connected else Policy.DROP_EARLY
+        if self.ctx.telemetry.enabled:
+            item.t_enq = time.perf_counter_ns()
         dropped = self.deliver_queue.push(item, policy)
         if dropped is not None:
             self.ctx.metrics.drop("queue_full")
@@ -400,6 +405,18 @@ class SessionState:
         # per-stage fast recorder (memoized in the registry; a no-op when
         # telemetry is disabled — the t0 guard means it's never called)
         self._rec_e2e = ctx.telemetry.recorder("publish.e2e")
+        self._rec_dqwait = ctx.telemetry.recorder("deliver.queue_wait")
+        # busy-clock stages of this connection's share of the served path
+        # (telemetry.Stage; every begin/end below guards on tele.enabled)
+        stage = ctx.telemetry.stage
+        self._st_decode = stage("ingress.decode")
+        self._st_publish = stage("ingress.publish")
+        self._st_credit = stage("deliver.credit_wait")
+        self._st_send = stage("deliver.send")
+        self._st_ack_in = stage("ack.in")
+        self._st_ack_out = stage("ack.out")
+        # clock of the last publish.e2e close: ack.out opens on it
+        self._t_e2e_end = 0
         # packets a client pipelined behind CONNECT in the same TCP segment
         # (legal without waiting for CONNACK); replayed by _read_loop
         self.early_packets: list = []
@@ -414,14 +431,25 @@ class SessionState:
 
             self._egress = EgressBuf(
                 writer, ctx.metrics,
-                high_water=getattr(ctx, "egress_high_water", 64 * 1024))
+                high_water=getattr(ctx, "egress_high_water", 64 * 1024),
+                telemetry=ctx.telemetry)
 
     # ------------------------------------------------------------------ io
     async def send(self, packet) -> None:
         await self.send_raw(self.codec.encode(packet))
 
     async def send_raw(self, data: bytes) -> None:
+        async with self._wlock:
+            if self._write(data):
+                await self.writer.drain()
+
+    def _write(self, data: bytes) -> bool:
+        """The synchronous half of ``send_raw`` (the caller holds ``_wlock``
+        or has seen it free, and does not yield before acting on the
+        answer): queue the frame; → True when the caller must
+        ``writer.drain()`` under the lock."""
         eb = self._egress
+        transport = getattr(self.writer, "transport", None)
         if eb is not None:
             # coalesced path: the frame joins the connection's per-tick
             # vector; one call_soon flush hands everything queued this
@@ -429,32 +457,25 @@ class SessionState:
             # high-water mark flush inline and drain — same backpressure
             # the legacy gate applied, now counting our own pending bytes
             # too (the transport can't see frames still in the vector).
-            async with self._wlock:
-                eb.feed(data)
-                transport = getattr(self.writer, "transport", None)
-                if transport is None:
-                    eb.flush()
-                    await self.writer.drain()
-                elif (eb.pending_bytes + transport.get_write_buffer_size()
-                      > eb.high_water):
-                    eb.flush()
-                    self.ctx.metrics.inc("net.egress_drains")
-                    await self.writer.drain()
-            return
-        async with self._wlock:
-            self.writer.write(data)
-            # drain only under backpressure: an await per delivered message
-            # halves throughput, and asyncio buffers safely below the
-            # high-water mark (the 64KB gate bounds growth between drains).
-            # Writers that only flush ON drain (WsWriter) keep draining
-            # every send.
-            transport = getattr(self.writer, "transport", None)
-            if (
-                getattr(self.writer, "buffers_until_drain", False)
+            eb.feed(data)
+            if transport is None:
+                eb.flush()
+                return True
+            if (eb.pending_bytes + transport.get_write_buffer_size()
+                    > eb.high_water):
+                eb.flush()
+                self.ctx.metrics.inc("net.egress_drains")
+                return True
+            return False
+        self.writer.write(data)
+        # drain only under backpressure: an await per delivered message
+        # halves throughput, and asyncio buffers safely below the
+        # high-water mark (the 64KB gate bounds growth between drains).
+        # Writers that only flush ON drain (WsWriter) keep draining
+        # every send.
+        return (getattr(self.writer, "buffers_until_drain", False)
                 or transport is None
-                or transport.get_write_buffer_size() > 64 * 1024
-            ):
-                await self.writer.drain()
+                or transport.get_write_buffer_size() > 64 * 1024)
 
     async def close(self, kicked: bool = False) -> None:
         self._kicked = self._kicked or kicked
@@ -539,14 +560,20 @@ class SessionState:
             if not data:
                 return
             self._last_packet = time.monotonic()
+            tok = (self._st_decode.begin(len(data))
+                   if self.ctx.telemetry.enabled else 0)
             try:
                 packets = self.codec.feed(data)
             except ProtocolViolation as e:
+                if tok:
+                    self._st_decode.end(tok)
                 self.ctx.metrics.inc("protocol.errors")
                 # v5: name the violation before closing (DISCONNECT 0x95
                 # packet-too-large / 0x81 malformed; disconnect.rs reasons)
                 await self._disconnect_with(e.reason_code)
                 return
+            if tok:
+                self._st_decode.end(tok)
             for p in packets:
                 await self._handle(p)
             if self.codec.pending_error is not None:
@@ -565,7 +592,10 @@ class SessionState:
                 # credit-gated (session.rs:362, inflight.rs:319): wake on the
                 # ack that frees a slot instead of sleep-polling (which
                 # capped QoS1/2 delivery at ~window/10ms per session)
+                t0 = time.perf_counter_ns() if self.ctx.telemetry.enabled else 0
                 await s.out_inflight.wait_credit()
+                if t0:
+                    self._st_credit.add_wait(time.perf_counter_ns() - t0)
                 continue
             item = s.deliver_queue.pop()
             if item is None:
@@ -575,12 +605,9 @@ class SessionState:
     async def _deliver(self, item: DeliverItem) -> None:
         s = self.s
         msg = item.msg
-        # per-subscriber delivery span — only when the publish's trace is
-        # actually recording (sampled, or already slow-promoted): unsampled
-        # and disabled deliveries take no timestamps here
-        tr = item.trace
-        t_tr = (time.perf_counter_ns()
-                if tr is not None and (tr.sampled or tr.slow) else 0)
+        # the expiry hook comes first: a plugin's hook may suspend, so the
+        # busy section opens after it (with no hook registered the await
+        # returns without yielding and costs the section nothing)
         expired = await self.ctx.hooks.fire(
             HookType.MESSAGE_EXPIRY_CHECK, s.id, msg, initial=msg.is_expired()
         )
@@ -594,6 +621,13 @@ class SessionState:
                 self.ctx.durability.on_ack(s.client_id, item.did)
             await self.ctx.hooks.fire(HookType.MESSAGE_DROPPED, s.id, msg, "expired")
             return
+        # deliver.send: props, OutEntry push, encode, egress feed. ONE
+        # clock read opens it and closes deliver.queue_wait (enqueue →
+        # here; the pop is a few attribute loads back)
+        tok = 0
+        if item.t_enq:
+            tok = self._st_send.begin(trace=item.trace)
+            self._rec_dqwait(abs(tok) - item.t_enq, None, item.trace)
         props: Dict[int, object] = {
             k: v
             for k, v in msg.properties.items()
@@ -609,6 +643,8 @@ class SessionState:
         if item.qos > 0:
             packet_id = s.out_inflight.alloc_packet_id()
             if packet_id is None:
+                if tok:
+                    self._st_send.end(tok)
                 if item.did and self.ctx.durability is not None:
                     self.ctx.durability.on_ack(s.client_id, item.did)
                 await self.ctx.hooks.fire(HookType.MESSAGE_DROPPED, s.id, msg, "no-packet-id")
@@ -635,15 +671,7 @@ class SessionState:
             if data is None:
                 data = cache[key] = encode_qos0_frame(
                     msg, self.codec.version, item.retain, rem)
-            await self.send_raw(data)
-            self.ctx.metrics.inc("messages.delivered")
-            hk = self.ctx.hotkeys
-            if hk.enabled:  # delivering-subscriber attribution seam
-                hk.on_deliver(s.client_id)
-            if t_tr:
-                item.trace.add("deliver.send", t_tr,
-                               time.perf_counter_ns() - t_tr,
-                               {"client": s.client_id, "qos": 0})
+            await self._send_staged(data, tok, item.trace, 0)
             await self.ctx.hooks.fire(HookType.MESSAGE_DELIVERED, s.id, msg, None)
             return
         # outbound topic alias AFTER the drop checks: an alias must never be
@@ -668,15 +696,38 @@ class SessionState:
             packet_id=packet_id,
             properties=props if self.codec.version == pk.V5 else {},
         )
-        await self.send(pub)
+        await self._send_staged(self.codec.encode(pub), tok, item.trace, item.qos)
+        await self.ctx.hooks.fire(HookType.MESSAGE_DELIVERED, s.id, msg, None)
+
+    async def _send_in_stage(self, data: bytes, st, tok: int) -> int:
+        """Queue one encoded frame and close the busy section ``tok`` of
+        stage ``st`` (0 = telemetry off); → the section's ns. With the
+        write lock free the feed is synchronous and inside the section;
+        the clock stops before any await that can suspend (a drain under
+        back-pressure, the lock held by a draining sender)."""
+        if self._wlock.locked():
+            dur = st.end(tok) if tok else 0
+            await self.send_raw(data)
+            return dur
+        drain = self._write(data)
+        dur = st.end(tok) if tok else 0
+        if drain:
+            async with self._wlock:
+                await self.writer.drain()
+        return dur
+
+    async def _send_staged(self, data: bytes, tok: int, trace, qos: int) -> None:
+        """The tail of ``_deliver``: send, close ``deliver.send``, count."""
+        dur = await self._send_in_stage(data, self._st_send, tok)
         self.ctx.metrics.inc("messages.delivered")
         hk = self.ctx.hotkeys
         if hk.enabled:  # delivering-subscriber attribution seam
-            hk.on_deliver(s.client_id)
-        if t_tr:
-            item.trace.add("deliver.send", t_tr, time.perf_counter_ns() - t_tr,
-                           {"client": s.client_id, "qos": item.qos})
-        await self.ctx.hooks.fire(HookType.MESSAGE_DELIVERED, s.id, msg, None)
+            hk.on_deliver(self.s.client_id)
+        if trace is not None and tok and (trace.sampled or trace.slow):
+            # the per-subscriber span of a recording trace, on the
+            # stage's own clock pair
+            trace.add("deliver.send", abs(tok), dur,
+                      {"client": self.s.client_id, "qos": qos})
 
     async def _retry_loop(self) -> None:
         s = self.s
@@ -742,26 +793,28 @@ class SessionState:
         s = self.s
         if isinstance(p, pk.Publish):
             await self._on_publish(p)
-        elif isinstance(p, pk.Puback):
+        elif isinstance(p, (pk.Puback, pk.Pubcomp)):
+            # ack.in: the window release (and what rides it), up to the
+            # acked hook — a plugin's hook may suspend
+            tok = self._st_ack_in.begin() if self.ctx.telemetry.enabled else 0
             e = s.out_inflight.ack(p.packet_id)
             if e is not None:
                 self._record_ack_rtt(e)
                 if e.did and self.ctx.durability is not None:
                     self.ctx.durability.on_ack(s.client_id, e.did)
+            if tok:
+                self._st_ack_in.end(tok)
+            if e is not None:
                 await self.ctx.hooks.fire(HookType.MESSAGE_ACKED, s.id, e.msg, None)
         elif isinstance(p, pk.Pubrec):
+            tok = self._st_ack_in.begin() if self.ctx.telemetry.enabled else 0
             e = s.out_inflight.pubrec(p.packet_id)
+            if tok:
+                self._st_ack_in.end(tok)
             if e is not None:
                 await self.send(pk.Pubrel(p.packet_id))
             elif self.codec.version == pk.V5:
                 await self.send(pk.Pubrel(p.packet_id, RC_PACKET_ID_NOT_FOUND))
-        elif isinstance(p, pk.Pubcomp):
-            e = s.out_inflight.ack(p.packet_id)
-            if e is not None:
-                self._record_ack_rtt(e)
-                if e.did and self.ctx.durability is not None:
-                    self.ctx.durability.on_ack(s.client_id, e.did)
-                await self.ctx.hooks.fire(HookType.MESSAGE_ACKED, s.id, e.msg, None)
         elif isinstance(p, pk.Pubrel):
             removed = s.in_qos2.remove(p.packet_id)
             dur = self.ctx.durability
@@ -844,6 +897,18 @@ class SessionState:
 
     # -------------------------------------------------------------- publish
     async def _on_publish(self, p: pk.Publish) -> None:
+        # ingress.publish: from the decoded PUBLISH to the fan-out's call
+        # (alias, hot-key attribution, admission, then _publish_admit)
+        tok = (self._st_publish.begin()
+               if self.ctx.telemetry.enabled else 0)
+        tok = await self._on_publish_staged(p, tok)
+        if tok:
+            self._st_publish.end(tok)
+
+    async def _on_publish_staged(self, p: pk.Publish, tok: int) -> int:
+        """→ the ``ingress.publish`` token where the section is still open (a
+        publish refused before the pipeline: a rare path, whose answer may
+        be sent inside the section), else 0: ``_publish`` closed it."""
         s = self.s
         self.ctx.metrics.inc("publish.received")
         # v5 topic alias resolution (session.rs:994-998)
@@ -852,25 +917,25 @@ class SessionState:
             if alias is not None:
                 if not (1 <= int(alias) <= s.limits.max_topic_aliases_in):
                     await self._disconnect_with(RC_TOPIC_ALIAS_INVALID)
-                    return
+                    return tok
                 if p.topic:
                     self._alias_in[int(alias)] = p.topic
                 else:
                     topic = self._alias_in.get(int(alias))
                     if topic is None:
                         await self._disconnect_with(RC_TOPIC_ALIAS_INVALID)
-                        return
+                        return tok
                     p.topic = topic
         if p.qos > self.ctx.cfg.max_qos:
             await self._disconnect_with(RC_UNSPECIFIED_ERROR)
-            return
+            return tok
         # QoS2 DUP resend of an ALREADY-ACCEPTED publish answers with the
         # dedup PUBREC before admission runs: the retransmit is not new
         # work, and refusing it would strand its in_qos2 entry (the client
         # abandons the flow without PUBREL, shrinking the window forever)
         if p.qos == 2 and p.packet_id in s.in_qos2:
             await self.send(pk.Pubrec(p.packet_id))
-            return
+            return tok
         # hot-key attribution ingress seam (broker/hotkeys.py): topic by
         # count AND payload bytes, publishing client. After alias
         # resolution (the key must be the real topic) and the QoS2 dedup
@@ -905,14 +970,14 @@ class SessionState:
                 # QoS0: nothing to answer — the drop is counted and traced
             else:
                 self._closing.set()
-            return
+            return tok
         # QoS2 ingress window insert (session.rs:908-963)
         if p.qos == 2:
             if not s.in_qos2.add(p.packet_id):
                 from rmqtt_tpu.broker.types import RC_RECEIVE_MAX_EXCEEDED
 
                 await self.send(pk.Pubrec(p.packet_id, RC_RECEIVE_MAX_EXCEEDED))
-                return
+                return tok
             # durability: a persistent publisher's dedup-window entry is
             # journaled BEFORE the fan-out's own pending records — a
             # timer-driven commit landing mid-publish must never persist
@@ -922,7 +987,7 @@ class SessionState:
             dur = self.ctx.durability
             if dur is not None and s.limits.session_expiry > 0:
                 dur.on_qos2_open(s.client_id, p.packet_id)
-        accepted, reason = await self._publish(p)
+        accepted, reason = await self._publish(p, tok)
         if p.qos == 2 and not accepted:
             # refused: clear the dedup entry — in memory AND in the
             # journal (before the barrier), so a restored stale entry can
@@ -938,17 +1003,29 @@ class SessionState:
         # across kill -9. Amortized: every concurrent publisher shares one
         # commit; no-op when nothing is buffered. QoS0 has no ack and
         # rides the flush window instead.
+        barrier = False
         if p.qos > 0:
             dur = self.ctx.durability
             if dur is not None and dur.dirty:
+                barrier = True
                 await dur.barrier()
-        if p.qos == 1:
-            await self.send(pk.Puback(p.packet_id, reason if self.codec.version == pk.V5 else 0))
-        elif p.qos == 2:
-            await self.send(pk.Pubrec(p.packet_id, reason if self.codec.version == pk.V5 else 0))
+        if p.qos == 0:
+            return 0
+        # ack.out: the publisher's PUBACK/PUBREC, encode + feed. It opens
+        # on the clock read that closed publish.e2e unless a durability
+        # barrier suspended in between
+        ack = (pk.Puback if p.qos == 1 else pk.Pubrec)(
+            p.packet_id, reason if self.codec.version == pk.V5 else 0)
+        st = self._st_ack_out
+        tok = 0
+        if self.ctx.telemetry.enabled:
+            tok = st.begin() if barrier else st.begin_at(self._t_e2e_end)
+        await self._send_in_stage(self.codec.encode(ack), st, tok)
+        return 0
 
-    async def _publish(self, p: pk.Publish) -> Tuple[bool, int]:
-        """The ingress pipeline (session.rs _publish :966-1064).
+    async def _publish(self, p: pk.Publish, tok: int = 0) -> Tuple[bool, int]:
+        """The ingress pipeline (session.rs _publish :966-1064); ``tok`` is
+        the open ``ingress.publish`` section, closed before the fan-out.
 
         Records the ``publish.e2e`` stage: PUBLISH decode handed to the
         pipeline → the last local forward enqueued (cluster scatter
@@ -962,18 +1039,19 @@ class SessionState:
         pair, so tracing adds no clock reads to this path."""
         ctx = self.ctx
         t0 = time.perf_counter_ns() if ctx.telemetry.enabled else 0
-        trace = tok = None
+        trace = ctx_tok = None
         if t0:
             trace = ctx.tracer.begin(p.topic)
             if trace is not None:
-                tok = CURRENT_TRACE.set(trace)
+                ctx_tok = CURRENT_TRACE.set(trace)
         try:
-            accepted, reason = await self._publish_inner(p)
+            accepted, reason = await self._publish_inner(p, tok)
         finally:
-            if tok is not None:
-                CURRENT_TRACE.reset(tok)
+            if ctx_tok is not None:
+                CURRENT_TRACE.reset(ctx_tok)
         if t0:
-            dur = time.perf_counter_ns() - t0
+            now = self._t_e2e_end = time.perf_counter_ns()
+            dur = now - t0
             self._rec_e2e(dur, p.topic, trace)
             if trace is not None:
                 trace.add("publish.ingress", t0, dur,
@@ -981,24 +1059,54 @@ class SessionState:
                 ctx.tracer.finish(trace)
         return accepted, reason
 
-    async def _publish_inner(self, p: pk.Publish) -> Tuple[bool, int]:
+    async def _publish_inner(self, p: pk.Publish, tok: int = 0) -> Tuple[bool, int]:
+        """``tok``: the open ``ingress.publish`` section (0 = telemetry
+        off); it covers admission and closes before the fan-out."""
+        verdict, msg, tok = await self._publish_admit(p, tok)
+        if tok:
+            self._st_publish.end(tok)
+        if verdict is not None:
+            return verdict
+        count = await self.ctx.registry.forwards(msg)
+        if count == 0:
+            await self.ctx.hooks.fire(HookType.MESSAGE_NONSUBSCRIBED, self.s.id, msg, None)
+            return True, RC_NO_MATCHING_SUBSCRIBERS
+        return True, RC_SUCCESS
+
+    async def _hook_staged(self, tok: int, htype: HookType, *args, initial=None):
+        """``hooks.fire`` from inside the ingress.publish section: a
+        registered handler may suspend, so the busy clock stops across it.
+        → (the chain's value, the section's live token)."""
+        hooks = self.ctx.hooks
+        if tok and hooks.has(htype):
+            self._st_publish.lap(tok)
+            value = await hooks.fire(htype, *args, initial=initial)
+            return value, self._st_publish.begin()
+        return await hooks.fire(htype, *args, initial=initial), tok
+
+    async def _publish_admit(self, p: pk.Publish, tok: int):
+        """Everything between the decoded PUBLISH and the fan-out: topic
+        check, publish hook, ACL, retain, $delayed. → (verdict, message,
+        token): ``verdict`` is None when the message goes on to
+        ``registry.forwards``, else the (accepted, reason) answer."""
         s = self.s
         delay_secs = None
         topic = p.topic
         try:
             delay_secs, topic = parse_delayed(topic)
         except ValueError:
-            return False, RC_TOPIC_NAME_INVALID
+            return (False, RC_TOPIC_NAME_INVALID), None, tok
         if not topic_valid(topic):
-            return False, RC_TOPIC_NAME_INVALID
+            return (False, RC_TOPIC_NAME_INVALID), None, tok
         msg = Message.from_publish(
             p, from_id=s.id, topic=topic, delay_interval=delay_secs,
             expiry_cap=s.limits.max_message_expiry,
         )
         # hook may transform the message (message_publish, session.rs:1008)
-        hooked = await self.ctx.hooks.fire(HookType.MESSAGE_PUBLISH, s.id, msg, initial=msg)
+        hooked, tok = await self._hook_staged(
+            tok, HookType.MESSAGE_PUBLISH, s.id, msg, initial=msg)
         if hooked is None:
-            return False, RC_UNSPECIFIED_ERROR
+            return (False, RC_UNSPECIFIED_ERROR), None, tok
         msg = hooked
         # ACL (message_publish_check_acl, session.rs:1011-1032)
         from rmqtt_tpu.broker.acl import Action
@@ -1006,13 +1114,13 @@ class SessionState:
         acl = self.ctx.acl.check(
             Action.PUBLISH, msg.topic, s.connect_info.username, s.client_id
         )
-        allow = await self.ctx.hooks.fire(
-            HookType.MESSAGE_PUBLISH_CHECK_ACL, s.id, msg, initial=acl.allow
-        )
+        allow, tok = await self._hook_staged(
+            tok, HookType.MESSAGE_PUBLISH_CHECK_ACL, s.id, msg, initial=acl.allow)
         if not allow:
             self.ctx.metrics.inc("publish.acl_denied")
-            await self.ctx.hooks.fire(HookType.MESSAGE_DROPPED, s.id, msg, "acl-denied")
-            return False, RC_NOT_AUTHORIZED
+            _, tok = await self._hook_staged(
+                tok, HookType.MESSAGE_DROPPED, s.id, msg, "acl-denied")
+            return (False, RC_NOT_AUTHORIZED), None, tok
         if msg.retain:
             if not self.ctx.retain.set(msg.topic, msg):
                 self.ctx.metrics.inc("retain.refused")
@@ -1026,14 +1134,11 @@ class SessionState:
             if not self.ctx.delayed.push(delay_secs, stripped, did=did):
                 if did:
                     dur.on_delayed_done(did)  # refused: resolve the record
-                await self.ctx.hooks.fire(HookType.MESSAGE_DROPPED, s.id, msg, "delayed-cap")
-                return False, RC_UNSPECIFIED_ERROR
-            return True, RC_SUCCESS
-        count = await self.ctx.registry.forwards(msg)
-        if count == 0:
-            await self.ctx.hooks.fire(HookType.MESSAGE_NONSUBSCRIBED, s.id, msg, None)
-            return True, RC_NO_MATCHING_SUBSCRIBERS
-        return True, RC_SUCCESS
+                _, tok = await self._hook_staged(
+                    tok, HookType.MESSAGE_DROPPED, s.id, msg, "delayed-cap")
+                return (False, RC_UNSPECIFIED_ERROR), None, tok
+            return (True, RC_SUCCESS), None, tok
+        return None, msg, tok
 
     async def _disconnect_with(self, reason: int) -> None:
         if self.codec.version == pk.V5:

@@ -34,6 +34,7 @@ class SessionRegistry:
         # restored snapshots), so a takeover AFTER a partition heals always
         # out-fences both partition-era owners.
         self._fence_epoch = 0
+        self._st_fanout = ctx.telemetry.stage("fanout.enqueue")
 
     # ------------------------------------------------------------- fencing
     @property
@@ -243,12 +244,17 @@ class SessionRegistry:
                 else "messages.route_cache_miss")
         count = 0
         wire_cache: dict = {}  # one encoded-frame cache per fan-out
+        # fanout.enqueue: the per-subscriber enqueue loop (synchronous)
+        tele = self.ctx.telemetry
+        tok = self._st_fanout.begin() if tele.enabled else 0
         for node_id, relations in relmap.items():
             # single-node: everything is local; cluster mode dispatches
             # remote nodes over the cluster backend (round 2+)
             for rel in relations:
                 count += self._deliver_local(rel.id.client_id, rel.topic_filter,
                                              rel.opts, msg, wire_cache, trace)
+        if tok:
+            self._st_fanout.end(tok)
         return count
 
     def _deliver_local(

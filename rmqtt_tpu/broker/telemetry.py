@@ -38,14 +38,31 @@ slow-op ring
     (topic, batch size, cache hit/miss) — the "what was that stall?"
     log that histograms by design cannot answer.
 
-Stage names are pre-registered (``STAGES``) so every surface — JSON
-endpoints, Prometheus, $SYS, the dashboard — is shape-stable whether or
-not traffic (or telemetry itself) has happened yet.
+``Stage``
+    The served path's BUSY clock: one ``perf_counter_ns`` pair around a
+    section that holds no ``await`` that can suspend, so on the loop thread
+    the sum is work and never suspension (a histogram stage around an
+    ``await`` times the queue it parks in). ``count`` and ``busy_ns`` are
+    cumulative and ride ``/api/v1/stats`` as flat monotone numbers
+    (``stage_stats``), beside the cumulative bucket counts of the
+    ``WINDOW_HISTS`` — a reader subtracts two snapshots and has the
+    window's busy share, and the window's quantile from the bucket deltas
+    (``bucket_quantile``). When, and only when, a JAX profiler session is
+    on (``PROFILER.poll``, asked once per loop turn / routing dispatch) the
+    same section is also a ``jax.profiler.TraceAnnotation("rmqtt/<stage>")``
+    so it lands on the host plane of the trace that holds the device's
+    operations, on the thread that ran it.
+
+Stage names are pre-registered (``STAGES``, ``SERVED_STAGES``) so every
+surface — JSON endpoints, Prometheus, $SYS, the dashboard — is
+shape-stable whether or not traffic (or telemetry itself) has happened yet.
 """
 
 from __future__ import annotations
 
 import re
+import selectors
+import sys
 import threading
 import time
 from collections import deque
@@ -64,11 +81,49 @@ STAGES = (
     "routing.queue_wait",  # batcher ingress-queue park time per item
     "routing.match",       # per-dispatch backend match latency (batch)
     "routing.batch_size",  # dispatch batch-size distribution (count, not ns)
+    "deliver.queue_wait",  # Session.enqueue → deliver_queue.pop() per item
     "deliver.ack_rtt",     # QoS1/2 delivery → PUBACK/PUBCOMP round trip
     "kernel.dispatch",     # router kernel/trie match call (native/xla)
 )
 
 UNITS: Dict[str, str] = {"routing.batch_size": "count"}
+
+# the served path's busy stages, entry point down (see ``Stage``); the
+# section each one brackets is named at its call site
+SERVED_STAGES = (
+    "ingress.decode",        # codec.feed(data) per read chunk (session.py)
+    "ingress.publish",       # _publish_inner up to registry.forwards
+    "routing.plan",          # RoutingService._plan(batch)
+    "routing.match.side",    # AdaptiveHybrid._side_match (host trie mirror)
+    "routing.match.device",  # device submit half + complete half (hybrid)
+    "matcher.encode",        # ops/partitioned.py: the four stage_ns sections
+    "matcher.dispatch",
+    "matcher.fetch",
+    "matcher.decode",
+    "matcher.compile",       # a jit seam call whose shape key was never seen
+    "routing.expand",        # XlaRouter._expand (relations expansion)
+    "routing.resolve",       # RoutingService._resolve (futures, cache fill)
+    "fanout.enqueue",        # SessionRegistry.forwards' _deliver_local loop
+    "deliver.credit_wait",   # out_inflight.wait_credit(): a WAIT, not busy
+    "deliver.send",          # _deliver: props, OutEntry, encode, feed
+    "egress.flush",          # EgressBuf.flush (one vectored write)
+    "ack.in",                # subscriber PUBACK/PUBREC/PUBCOMP → window release
+    "ack.out",               # publisher's PUBACK/PUBREC encode + send
+)
+# these run on the loop thread for some batches and on an executor thread
+# for others: the loop thread is the wall and an executor thread's wall
+# holds GIL wait, so the two are kept apart (``*_exec_*`` keys)
+BY_THREAD = frozenset(
+    ("routing.match.side", "routing.match.device", "routing.expand"))
+# executor-thread stages by nature (several threads at once: locked adds)
+_OFF_LOOP = frozenset(s for s in SERVED_STAGES if s.startswith("matcher."))
+# a suspension, timed across its await: counted apart from busy time and
+# never a span (another task's sections would interleave with it)
+_WAITS = frozenset(("deliver.credit_wait",))
+# histograms whose cumulative bucket counts ride the flat stats body, so a
+# reader can take the quantile of a WINDOW (the *_p99_ms gauges are since
+# process start and cannot be subtracted)
+WINDOW_HISTS = ("routing.queue_wait", "deliver.queue_wait", "publish.e2e")
 
 # recorder buffer fold threshold: big enough to amortize the fold loop,
 # small enough that a mid-burst fold stall is microseconds
@@ -186,6 +241,181 @@ class Histogram:
         }
 
 
+def bucket_quantile(counts: Iterable[int], q: float) -> float:
+    """``Histogram.quantile`` over bare bucket counts — e.g. the DELTAS of
+    two snapshots of a stage's cumulative buckets, which are the histogram
+    of the samples between them. → the upper edge (ns) of the bucket that
+    holds the q-th sample, exact to a factor of 2; 0.0 when empty."""
+    h = Histogram()
+    h.counts = list(counts)
+    return h.quantile(q)
+
+
+class _Profiler:
+    """Is a JAX profiler session on in this process? Learned from the
+    profiler itself (``TraceMe.is_enabled``, ~40 ns), asked once per loop
+    turn / routing dispatch — never per span; a stage boundary reads the
+    cached ``on``. jax is never imported from here: a broker on the trie
+    router does not load it, and no session can be on without it."""
+
+    __slots__ = ("on", "annotation", "loop_tid", "_tls")
+
+    def __init__(self) -> None:
+        self.on = False
+        self.annotation = None  # jax.profiler.TraceAnnotation, once jax is loaded
+        self.loop_tid = threading.get_ident()  # re-bound by Telemetry.bind_loop
+        self._tls = threading.local()  # per thread: open annotations, batch seq
+
+    def poll(self) -> bool:
+        cls = self.annotation
+        if cls is None:
+            mod = sys.modules.get("jax.profiler")
+            if mod is None:
+                return False
+            cls = self.annotation = mod.TraceAnnotation
+        on = self.on = cls.is_enabled()
+        return on
+
+    # -- reached only while a session is on (or to close what it opened)
+    def open(self, span: str, n: int, batch: int, trace: Any) -> None:
+        tls = self._tls
+        if not batch:
+            batch = getattr(tls, "batch", 0)
+        if trace is None:
+            trace = CURRENT_TRACE.get()
+        kw = {}
+        if batch:
+            kw["batch"] = batch
+        if n:
+            kw["n"] = n
+        if trace is not None and trace.sampled:
+            kw["tid"] = trace.tid
+        ann = self.annotation(span, **kw)
+        ann.__enter__()
+        try:
+            tls.open.append(ann)
+        except AttributeError:
+            tls.open = [ann]
+
+    def close(self) -> None:
+        self._tls.open.pop().__exit__(None, None, None)
+
+    def set_batch(self, seq: int) -> None:
+        self._tls.batch = seq
+
+
+#: process-global like the profiler session it mirrors
+PROFILER = _Profiler()
+
+
+class IdleSpanSelector(selectors.DefaultSelector):
+    """The event loop's selector (``server.py`` hands it to
+    ``asyncio.run(loop_factory=...)``): one profiler poll per loop turn,
+    and while a session is on a span around every ``select``, on the same
+    clock as the stages: ``rmqtt/loop.idle`` where it may block (the loop
+    has nothing queued and waits for the network), ``rmqtt/loop.poll``
+    where it may not (work is queued: the span is the system call and
+    re-taking the GIL after it). Spans only, no counter."""
+
+    def select(self, timeout=None):
+        if not PROFILER.poll():
+            return super().select(timeout)
+        poll = timeout is not None and timeout <= 0
+        with PROFILER.annotation("rmqtt/loop.poll" if poll else "rmqtt/loop.idle"):
+            return super().select(timeout)
+
+
+class Stage:
+    """One served-path stage: cumulative ``count`` / ``busy_ns`` from one
+    ``perf_counter_ns`` pair per section, and a span on the profiler's
+    clock while a session is on.
+
+        tok = st.begin(n)   # clock read (+ annotation entered, if tracing)
+        ...                 # the section: no await that can suspend
+        st.end(tok)         # clock read, count += 1, busy_ns += the section
+
+    Where a section has to cross an await that can suspend (``send_raw``
+    under back-pressure, the device between submit and complete),
+    ``lap(tok)`` stops the clock before it and a fresh ``begin`` starts it
+    again after; only ``end`` counts. The token is the start clock,
+    NEGATED when an annotation was opened for the section — so ``end``
+    closes exactly what ``begin`` opened even if the session starts or
+    stops in between, with nothing allocated and no second lookup.
+
+    Callers guard on ``Telemetry.enabled`` exactly like the histogram
+    stages: disabled, no boundary reads a clock or builds an object."""
+
+    __slots__ = ("name", "span", "count", "busy_ns", "xcount", "xbusy_ns",
+                 "by_thread", "_lock", "_keys")
+
+    def __init__(self, name: str) -> None:
+        self.name = name
+        self.span = "rmqtt/" + name
+        # its flat keys on the stats surface (``Telemetry.stage_stats``)
+        key = "stage_" + prom_sanitize(name)
+        kind = "wait" if name in _WAITS else "busy"
+        self._keys = (f"{key}_count", f"{key}_{kind}_ms_total",
+                      f"{key}_exec_count", f"{key}_exec_busy_ms_total")
+        self.count = 0
+        self.busy_ns = 0
+        # executor-thread share of a BY_THREAD stage (count/busy_ns are
+        # then the loop thread's alone)
+        self.xcount = 0
+        self.xbusy_ns = 0
+        self.by_thread = name in BY_THREAD
+        # `+=` is a read-modify-write: stages that several threads run at
+        # once take a lock (per batch, never per publish)
+        self._lock = (threading.Lock()
+                      if self.by_thread or name in _OFF_LOOP else None)
+
+    def begin(self, n: int = 0, batch: int = 0, trace: Any = None) -> int:
+        if PROFILER.on:
+            PROFILER.open(self.span, n, batch, trace)
+            return -time.perf_counter_ns()
+        return time.perf_counter_ns()
+
+    def begin_at(self, t0: int) -> int:
+        """``begin`` on a clock read the caller already took (``t0`` > 0):
+        boundaries that coincide share one read."""
+        if PROFILER.on:
+            PROFILER.open(self.span, 0, 0, None)
+            return -t0
+        return t0
+
+    def lap(self, tok: int) -> int:
+        """Stop the clock without counting; → the section's ns."""
+        return self._close(tok, 0)
+
+    def end(self, tok: int) -> int:
+        """Stop the clock and count one pass; → the section's ns."""
+        return self._close(tok, 1)
+
+    def _close(self, tok: int, n: int) -> int:
+        now = time.perf_counter_ns()
+        if tok < 0:
+            PROFILER.close()
+            tok = -tok
+        dt = now - tok
+        lock = self._lock
+        if lock is None:
+            self.count += n
+            self.busy_ns += dt
+            return dt
+        with lock:
+            if self.by_thread and threading.get_ident() != PROFILER.loop_tid:
+                self.xcount += n
+                self.xbusy_ns += dt
+            else:
+                self.count += n
+                self.busy_ns += dt
+        return dt
+
+    def add_wait(self, dur_ns: int) -> None:
+        """A suspension timed across its await (``_WAITS``): no span."""
+        self.count += 1
+        self.busy_ns += dur_ns
+
+
 class _Span:
     """Enabled-mode timer: one perf_counter_ns pair around the block."""
 
@@ -224,7 +454,7 @@ class Telemetry:
     """Per-node latency registry: stage histograms + the slow-op ring."""
 
     __slots__ = ("enabled", "slow_ms", "slow_ns", "slow_ops", "_h",
-                 "_recorders", "_folds", "_reg_lock")
+                 "_recorders", "_folds", "_reg_lock", "_stages", "batch_seq")
 
     def __init__(
         self,
@@ -238,6 +468,11 @@ class Telemetry:
         self.slow_ns = int(slow_ms * 1e6)
         self.slow_ops: deque = deque(maxlen=max(1, slow_log_max))
         self._h: Dict[str, Histogram] = {name: Histogram() for name in stages}
+        self._stages: Dict[str, Stage] = {n: Stage(n) for n in SERVED_STAGES}
+        # the routing service's dispatch counter at the batch now being
+        # matched: router-side spans read it so one batch's spans share
+        # ``batch=<seq>`` across threads (dispatches are serialized)
+        self.batch_seq = 0
         self._recorders: Dict[str, Callable] = {}
         self._folds: Dict[str, Callable[[], None]] = {}
         # guards recorder CREATION (rare): first calls can come from
@@ -251,6 +486,40 @@ class Telemetry:
         if h is None:
             h = self._h[name] = Histogram()
         return h
+
+    def stage(self, name: str) -> Stage:
+        """The busy-clock stage ``name`` (memoized; callers keep the object
+        and guard their ``begin``/``end`` on ``self.enabled``)."""
+        st = self._stages.get(name)
+        if st is None:
+            with self._reg_lock:
+                st = self._stages.setdefault(name, Stage(name))
+        return st
+
+    @staticmethod
+    def bind_loop() -> None:
+        """Call ON the event-loop thread: BY_THREAD stages tell the loop's
+        busy time from an executor thread's by it."""
+        PROFILER.loop_tid = threading.get_ident()
+
+    def batch_begin(self, seq: int = 0) -> int:
+        """Router side of a routing batch, on whichever thread runs it: →
+        the batch's seq while a profiler session is on (else 0, and
+        nothing is touched). Spans opened on this thread until
+        ``batch_end`` carry it. ``seq``: what an earlier ``batch_begin``
+        returned, where the same batch is picked up again on another
+        thread (the device path's complete half)."""
+        if not seq:
+            if not PROFILER.on:
+                return 0
+            seq = self.batch_seq
+        PROFILER.set_batch(seq)
+        return seq
+
+    @staticmethod
+    def batch_end(seq: int) -> None:
+        if seq:
+            PROFILER.set_batch(0)
 
     def record(self, name: str, dur_ns: int, detail: Any = None,
                trace: Any = None) -> None:
@@ -365,6 +634,46 @@ class Telemetry:
         """Quantile of a ns-stage in milliseconds (admin/stat gauges)."""
         self.flush()
         return round(self.hist(name).quantile(q) / 1e6, 3)
+
+    def stage_stats(self) -> Dict[str, float]:
+        """The flat, monotone face of the stage layer on the stats surface
+        (``ServerContext.stats``): per stage ``stage_<name>_count`` and
+        ``stage_<name>_busy_ms_total`` (``_wait_ms_total`` for a wait;
+        ``_exec_*`` twins for the BY_THREAD stages). Beside them
+        ``host_loop_cpu_ms_total`` — ``time.thread_time`` of the CALLING
+        thread, which for the admin API and the history collector is the
+        event loop's — and ``host_proc_cpu_ms_total``: how busy the one
+        Python thread is, at no cost to the hot path. Shape-stable: every
+        key is present, all zeros, when disabled. Every key sums across
+        nodes under ``/stats/sum``'s suffix rules."""
+        on = self.enabled
+        out: Dict[str, float] = {
+            "host_loop_cpu_ms_total":
+                round(time.thread_time_ns() / 1e6, 3) if on else 0.0,
+            "host_proc_cpu_ms_total":
+                round(time.process_time_ns() / 1e6, 3) if on else 0.0,
+        }
+        for name in SERVED_STAGES:
+            st = self._stages[name]
+            count, ms, xcount, xms = st._keys
+            out[count] = st.count
+            out[ms] = round(st.busy_ns / 1e6, 3)
+            if st.by_thread:
+                out[xcount] = st.xcount
+                out[xms] = round(st.xbusy_ns / 1e6, 3)
+        return out
+
+    def bucket_stats(self) -> Dict[str, int]:
+        """The 40 cumulative bucket counts ``hist_<name>_b<i>`` of each
+        WINDOW_HISTS histogram, flat and monotone: a reader of
+        ``/api/v1/stats`` subtracts two snapshots and has the histogram of
+        the samples between them. Served by the admin API's stats bodies
+        only (the history timeline and the scrape have the histograms'
+        own surfaces). All zeros, same keys, when disabled."""
+        self.flush()
+        return {f"hist_{prom_sanitize(name)}_b{i:02d}": c
+                for name in WINDOW_HISTS
+                for i, c in enumerate(self.hist(name).counts)}
 
     def snapshot(self) -> dict:
         """The `/api/v1/latency` body: shape-stable in disabled mode (all

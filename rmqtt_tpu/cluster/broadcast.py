@@ -342,7 +342,9 @@ async def handle_common_message(ctx, mtype: str, body, cluster=None, from_node=N
         limit = int(body.get("limit", 100))
         return {"clients": [client_info(s) for s in list(ctx.registry.sessions())[:limit]]}
     if mtype == M.STATS_GET:
-        return {"node": ctx.node_id, "stats": ctx.stats().to_json()}
+        from rmqtt_tpu.broker.http_api import stats_body
+
+        return {"node": ctx.node_id, "stats": stats_body(ctx)}
     if mtype == M.DATA:
         # opaque data channel (grpc.rs Message::Data); carries the admin
         # API's cluster queries that have no dedicated variant

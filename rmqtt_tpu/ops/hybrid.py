@@ -66,11 +66,22 @@ class AdaptiveHybrid:
         # served-share counters per backend: [batches, topics] — what the
         # device actually served vs the host mirror (device_info() surface)
         self.served = {"side": [0, 0], "device": [0, 0]}
+        # per backend: times _bump replaced the rate EMA outright (a single
+        # sample 2.5x off the EMA), and large batches sent to the slower
+        # path to refresh its EMA — what decides who serves, in numbers
+        self.regime_jumps = {"side": 0, "device": 0}
+        self.probes = {"side": 0, "device": 0}
+        # busy-clock stages (broker/telemetry.py Stage), wired by the
+        # router; the match entry points take ``staged`` from their caller
+        self._st_side = self._st_dev = None
         # EMA state is touched from both the submit and the completion
         # executor threads (RoutingService pipelining); the GIL keeps it
         # memory-safe but probe cadence / rate attribution would skew —
         # RLock because _bump_device nests into _bump
         self._lock = threading.RLock()
+
+    def use_stages(self, side, device) -> None:
+        self._st_side, self._st_dev = side, device
 
     # ------------------------------------------------------------- internals
     def _bump(self, key: str, rate: float) -> None:
@@ -80,6 +91,8 @@ class AdaptiveHybrid:
                 # regime jump (compile finished, chip co-located, table grew):
                 # converge immediately instead of over many EMA steps
                 self._rate[key] = rate
+                if cur is not None:
+                    self.regime_jumps[key] += 1
             else:
                 self._rate[key] = (1 - EMA_ALPHA) * cur + EMA_ALPHA * rate
 
@@ -99,9 +112,13 @@ class AdaptiveHybrid:
             c[0] += 1
             c[1] += n
 
-    def _side_match(self, topics: Sequence[str]) -> List[np.ndarray]:
+    def _side_match(self, topics: Sequence[str],
+                    staged: bool = False) -> List[np.ndarray]:
         self._note("side", len(topics))
-        t0 = time.perf_counter()
+        # one clock pair: the ``routing.match.side`` stage when staged (the
+        # rate sample below reuses its reads), else the rate sample's own
+        tok = self._st_side.begin(len(topics)) if staged else 0
+        t0 = 0.0 if tok else time.perf_counter()
         if len(topics) > 1 and hasattr(self.side, "match_batch"):
             # one native call for the whole batch: the per-topic ctypes
             # round trip (~7µs) would otherwise dominate and misprice the
@@ -109,17 +126,24 @@ class AdaptiveHybrid:
             rows = self.side.match_batch(list(topics))
         else:
             rows = [self.side.match(t) for t in topics]
-        dt = time.perf_counter() - t0
+        dt = (self._st_side.end(tok) / 1e9 if tok
+              else time.perf_counter() - t0)
         if len(topics) > self.small_max and dt > 0:
             self._bump("side", len(topics) / dt)
         return rows
 
-    def _device_match(self, topics: Sequence[str]) -> List[np.ndarray]:
+    def _device_match(self, topics: Sequence[str],
+                      staged: bool = False) -> List[np.ndarray]:
         self._note("device", len(topics))
         if _FP_DISPATCH.action is not None:
             _FP_DISPATCH.fire_sync()
+        tok = self._st_dev.begin(len(topics)) if staged else 0
         t0 = time.perf_counter()
-        rows = self.matcher.match(topics)
+        try:
+            rows = self.matcher.match(topics)
+        finally:
+            if tok:
+                self._st_dev.end(tok)
         if _FP_COMPLETE.action is not None:
             _FP_COMPLETE.fire_sync()
         with self._lock:
@@ -139,7 +163,9 @@ class AdaptiveHybrid:
             if s is None:
                 return "side"
             if self._n_large % self.probe_every == 0:
-                return "side" if s < d else "device"  # probe the slower path
+                slower = "side" if s < d else "device"  # probe the slower path
+                self.probes[slower] += 1
+                return slower
             return "side" if s >= d else "device"
 
     # ------------------------------------------------------------------ api
@@ -160,18 +186,23 @@ class AdaptiveHybrid:
             return None
         return "side" if s >= d else "device"
 
-    def match(self, topics: Sequence[str]) -> List[np.ndarray]:
+    def match(self, topics: Sequence[str],
+              staged: bool = False) -> List[np.ndarray]:
+        """``staged``: the caller has telemetry on and wired — time the
+        backend that serves as its ``routing.match.*`` stage."""
         if self.side is None:
-            return self._device_match(topics)
+            return self._device_match(topics, staged)
         if self.matcher is None or len(topics) <= self.small_max:
-            return self._side_match(topics)
+            return self._side_match(topics, staged)
         if self._pick() == "side":
-            return self._side_match(topics)
-        return self._device_match(topics)
+            return self._side_match(topics, staged)
+        return self._device_match(topics, staged)
 
-    def match_submit(self, topics: Sequence[str]):
+    def match_submit(self, topics: Sequence[str], staged: bool = False):
         """Pipelined form: device submissions stay asynchronous; trie-served
-        batches resolve inside submit (they are µs-scale)."""
+        batches resolve inside submit (they are µs-scale). The device
+        stage's busy time is the submit half plus the complete half — the
+        wait between them is the device's and the completion queue's."""
         if self.side is None or (
             self.matcher is not None and len(topics) > self.small_max
             and self._pick() == "device"
@@ -180,18 +211,28 @@ class AdaptiveHybrid:
                 self._note("device", len(topics))
                 if _FP_DISPATCH.action is not None:
                     _FP_DISPATCH.fire_sync()
-                return ("device", self.matcher.match_submit(topics),
-                        len(topics), time.perf_counter())
-            return ("sync", self._device_match(topics))
-        return ("sync", self._side_match(topics))
+                tok = self._st_dev.begin(len(topics)) if staged else 0
+                try:
+                    payload = self.matcher.match_submit(topics)
+                finally:
+                    if tok:
+                        self._st_dev.lap(tok)
+                return ("device", payload, len(topics), time.perf_counter())
+            return ("sync", self._device_match(topics, staged))
+        return ("sync", self._side_match(topics, staged))
 
-    def match_complete(self, handle) -> List[np.ndarray]:
+    def match_complete(self, handle, staged: bool = False) -> List[np.ndarray]:
         if handle[0] == "sync":
             return handle[1]
         _kind, payload, n, t_submit = handle
         if _FP_COMPLETE.action is not None:
             _FP_COMPLETE.fire_sync()
-        rows = self.matcher.match_complete(payload)
+        tok = self._st_dev.begin(n) if staged else 0
+        try:
+            rows = self.matcher.match_complete(payload)
+        finally:
+            if tok:
+                self._st_dev.end(tok)
         now = time.perf_counter()
         with self._lock:
             last = self._last_dev_complete

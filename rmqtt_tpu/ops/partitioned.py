@@ -51,6 +51,9 @@ _FP_UPLOAD = FAILPOINTS.register("device.upload")
 #: reports hit-vs-trace through it when enabled; call sites guard on
 #: ``_DEVPROF.enabled`` so the disabled cost is one attribute check
 from rmqtt_tpu.broker.devprof import DEVPROF as _DEVPROF
+from rmqtt_tpu.broker.telemetry import Stage as _Stage
+
+_STAGE_KEYS = ("encode", "dispatch", "fetch", "decode")
 
 
 def _pj(kernel: str, fn, *args, **kwargs):
@@ -58,19 +61,31 @@ def _pj(kernel: str, fn, *args, **kwargs):
     is enabled (sites use ``_pj(...) if _DEVPROF.enabled else <direct>``).
     The shape key mirrors jax's own executable-cache signature, so a
     never-seen key is a trace+compile by construction and the timed wall
-    of that first call brackets its cost (jit traces synchronously).
+    of that first call brackets its cost (jit traces synchronously): that
+    call is the ``matcher.compile`` stage (broker/telemetry.py), on the
+    same clock pair.
 
     ``_key_extra`` (reserved, not forwarded to ``fn``) appends static
     state that is baked into the CALLABLE rather than its arguments —
     e.g. the sharded per-budget step closures, where arg shapes alone are
     identical across budget regrows but each regrow is a real recompile."""
     extra = kwargs.pop("_key_extra", None)
-    t0 = time.perf_counter_ns()
-    out = fn(*args, **kwargs)
     key = _DEVPROF.key_of(args, kwargs)
     if extra is not None:
         key = key + (extra,)
-    _DEVPROF.note_jit(kernel, key, time.perf_counter_ns() - t0)
+    tele = _DEVPROF.telemetry
+    if tele is not None and tele.enabled and not _DEVPROF.seen(kernel, key):
+        st = tele.stage("matcher.compile")
+        tok = st.begin()
+        try:
+            out = fn(*args, **kwargs)
+        finally:
+            dur = st.end(tok)
+    else:
+        t0 = time.perf_counter_ns()
+        out = fn(*args, **kwargs)
+        dur = time.perf_counter_ns() - t0
+    _DEVPROF.note_jit(kernel, key, dur)
     return out
 
 from rmqtt_tpu.core.topic import HASH, PLUS, is_metadata, split_levels
@@ -1231,21 +1246,28 @@ def words_any_impl(tiles, ttok, tlen, tdollar, chunk_ids, *, layout=None,
                    use_pallas: bool = False, interpret: bool = False):
     """The one words-producer seam: legacy or packed tiles × lax scan or
     Pallas wave kernel, all statically selected so every combination traces
-    into a single dispatch when embedded in a larger jit."""
-    if use_pallas:
+    into a single dispatch when embedded in a larger jit.
+
+    Every operation it lowers to carries the named scope ``scan`` (the
+    tail's are ``compact`` / ``resolve`` / ``sort`` / ``counts``): metadata
+    only — the compiled program is the same — by which a profiler trace's
+    device time is summed per phase (PERF.md §3)."""
+    with jax.named_scope("scan"):
+        if use_pallas:
+            if layout is None:
+                from rmqtt_tpu.ops.pallas_match import match_words_pallas
+
+                return match_words_pallas(tiles, ttok, tlen, tdollar,
+                                          chunk_ids, interpret=interpret)
+            from rmqtt_tpu.ops.pallas_match import match_words_pallas_packed
+
+            return match_words_pallas_packed(
+                tiles, ttok, tlen, tdollar, chunk_ids, layout=layout,
+                interpret=interpret)
         if layout is None:
-            from rmqtt_tpu.ops.pallas_match import match_words_pallas
-
-            return match_words_pallas(tiles, ttok, tlen, tdollar, chunk_ids,
-                                      interpret=interpret)
-        from rmqtt_tpu.ops.pallas_match import match_words_pallas_packed
-
-        return match_words_pallas_packed(tiles, ttok, tlen, tdollar, chunk_ids,
-                                         layout=layout, interpret=interpret)
-    if layout is None:
-        return scan_words_impl(tiles, ttok, tlen, tdollar, chunk_ids)
-    return scan_words_packed_impl(tiles, ttok, tlen, tdollar, chunk_ids,
-                                  layout=layout)
+            return scan_words_impl(tiles, ttok, tlen, tdollar, chunk_ids)
+        return scan_words_packed_impl(tiles, ttok, tlen, tdollar, chunk_ids,
+                                      layout=layout)
 
 
 def compact_global_impl(words, budget: int):
@@ -1282,32 +1304,36 @@ def compact_global_impl(words, budget: int):
     → packed [budget + B] uint16|uint32: [routes..., cnts...]
     """
     b, w = words.shape
-    flat = words.ravel()
-    nz = flat != jnp.uint32(0)
-    nzi = nz.astype(jnp.int32)
-    pos = jnp.cumsum(nzi) - nzi  # exclusive prefix sum
-    # non-nz (and overflow) slots land at index==budget → dropped. The
-    # sentinel index is duplicated across every zero word, so this scatter
-    # must NOT claim unique_indices (implementation-defined corruption on
-    # backends that exploit the flag before dropping OOB updates).
-    idx = jnp.where(nz & (pos < budget), pos, budget)
-    wsrc = lax.broadcasted_iota(jnp.int32, (b, w), 1).ravel()
-    widx = jnp.zeros((budget,), jnp.int32).at[idx].set(wsrc, mode="drop")
-    bits = jnp.zeros((budget,), jnp.uint32).at[idx].set(flat, mode="drop")
+    with jax.named_scope("compact"):
+        flat = words.ravel()
+        nz = flat != jnp.uint32(0)
+        nzi = nz.astype(jnp.int32)
+        pos = jnp.cumsum(nzi) - nzi  # exclusive prefix sum
+        # non-nz (and overflow) slots land at index==budget → dropped. The
+        # sentinel index is duplicated across every zero word, so this
+        # scatter must NOT claim unique_indices (implementation-defined
+        # corruption on backends that exploit the flag before dropping OOB
+        # updates).
+        idx = jnp.where(nz & (pos < budget), pos, budget)
+        wsrc = lax.broadcasted_iota(jnp.int32, (b, w), 1).ravel()
+        widx = jnp.zeros((budget,), jnp.int32).at[idx].set(wsrc, mode="drop")
+        bits = jnp.zeros((budget,), jnp.uint32).at[idx].set(flat, mode="drop")
     # stage 2: expand the compacted words' bits into route slots
-    bitm = (bits[:, None] >> jnp.arange(32, dtype=jnp.uint32)) & jnp.uint32(1)
-    rnzi = bitm.astype(jnp.int32).ravel()  # [budget*32]
-    rpos = jnp.cumsum(rnzi) - rnzi
-    ridx = jnp.where((rnzi > 0) & (rpos < budget), rpos, budget)
-    # one dtype for routes AND counts (they ship as one array); strict <
-    # because a count can reach w*32 itself (a topic matching every row)
-    rdt = jnp.uint16 if w * 32 < 0x10000 else jnp.uint32
-    rval = (
-        widx[:, None] * 32 + jnp.arange(32, dtype=jnp.int32)
-    ).ravel().astype(rdt)
-    routes = jnp.zeros((budget,), rdt).at[ridx].set(rval, mode="drop")
-    cnts = jnp.sum(lax.population_count(words).astype(jnp.int32), axis=1)
-    return jnp.concatenate([routes, cnts.astype(rdt)])
+    with jax.named_scope("resolve"):
+        bitm = (bits[:, None] >> jnp.arange(32, dtype=jnp.uint32)) & jnp.uint32(1)
+        rnzi = bitm.astype(jnp.int32).ravel()  # [budget*32]
+        rpos = jnp.cumsum(rnzi) - rnzi
+        ridx = jnp.where((rnzi > 0) & (rpos < budget), rpos, budget)
+        # one dtype for routes AND counts (they ship as one array); strict <
+        # because a count can reach w*32 itself (a topic matching every row)
+        rdt = jnp.uint16 if w * 32 < 0x10000 else jnp.uint32
+        rval = (
+            widx[:, None] * 32 + jnp.arange(32, dtype=jnp.int32)
+        ).ravel().astype(rdt)
+        routes = jnp.zeros((budget,), rdt).at[ridx].set(rval, mode="drop")
+    with jax.named_scope("counts"):
+        cnts = jnp.sum(lax.population_count(words).astype(jnp.int32), axis=1)
+        return jnp.concatenate([routes, cnts.astype(rdt)])
 
 
 def match_global_impl(packed_rows, ttok, tlen, tdollar, chunk_ids, budget: int,
@@ -1380,37 +1406,41 @@ def fused_compact_decode_impl(words, fid_rows, chunk_ids, budget: int):
     share cfg11 attributes)."""
     b, w = words.shape
     wpc = WORDS_PER_CHUNK
-    chunk_ids = chunk_ids.astype(jnp.int32)
-    fid_flat = fid_rows.reshape(-1)
-    flat = words.ravel()
-    nz = flat != jnp.uint32(0)
-    nzi = nz.astype(jnp.int32)
-    pos = jnp.cumsum(nzi) - nzi
-    # sentinel index == budget → OOB-dropped (see compact_global_impl on
-    # why these scatters must not claim unique indices)
-    idx = jnp.where(nz & (pos < budget), pos, budget)
-    wsrc = lax.broadcasted_iota(jnp.int32, (b, w), 1).ravel()
-    tsrc = lax.broadcasted_iota(jnp.int32, (b, w), 0).ravel()
-    widx = jnp.zeros((budget,), jnp.int32).at[idx].set(wsrc, mode="drop")
-    wtop = jnp.zeros((budget,), jnp.int32).at[idx].set(tsrc, mode="drop")
-    bits = jnp.zeros((budget,), jnp.uint32).at[idx].set(flat, mode="drop")
+    with jax.named_scope("compact"):
+        chunk_ids = chunk_ids.astype(jnp.int32)
+        fid_flat = fid_rows.reshape(-1)
+        flat = words.ravel()
+        nz = flat != jnp.uint32(0)
+        nzi = nz.astype(jnp.int32)
+        pos = jnp.cumsum(nzi) - nzi
+        # sentinel index == budget → OOB-dropped (see compact_global_impl
+        # on why these scatters must not claim unique indices)
+        idx = jnp.where(nz & (pos < budget), pos, budget)
+        wsrc = lax.broadcasted_iota(jnp.int32, (b, w), 1).ravel()
+        tsrc = lax.broadcasted_iota(jnp.int32, (b, w), 0).ravel()
+        widx = jnp.zeros((budget,), jnp.int32).at[idx].set(wsrc, mode="drop")
+        wtop = jnp.zeros((budget,), jnp.int32).at[idx].set(tsrc, mode="drop")
+        bits = jnp.zeros((budget,), jnp.uint32).at[idx].set(flat, mode="drop")
     # stage 2: expand compacted words' bits into fid slots. Unfilled word
     # slots keep (widx=0, wtop=0) — their gathers stay in range and their
     # lanes all carry zero bits, so every one of them is dropped.
-    bitm = (bits[:, None] >> jnp.arange(32, dtype=jnp.uint32)) & jnp.uint32(1)
-    rnzi = bitm.astype(jnp.int32).ravel()
-    rpos = jnp.cumsum(rnzi) - rnzi
-    ridx = jnp.where((rnzi > 0) & (rpos < budget), rpos, budget)
-    rows = (
-        chunk_ids[wtop, widx // wpc] * CHUNK + (widx % wpc) * 32
-    )[:, None] + jnp.arange(32, dtype=jnp.int32)[None, :]
-    fvals = fid_flat[rows.ravel()]
-    tvals = jnp.broadcast_to(wtop[:, None], (budget, 32)).ravel()
-    tj = jnp.full((budget,), b, jnp.int32).at[ridx].set(tvals, mode="drop")
-    fids = jnp.zeros((budget,), jnp.int32).at[ridx].set(fvals, mode="drop")
-    _tj_s, fid_s = lax.sort((tj, fids), num_keys=2)
-    cnts = jnp.sum(lax.population_count(words).astype(jnp.int32), axis=1)
-    return jnp.concatenate([fid_s, cnts])
+    with jax.named_scope("resolve"):
+        bitm = (bits[:, None] >> jnp.arange(32, dtype=jnp.uint32)) & jnp.uint32(1)
+        rnzi = bitm.astype(jnp.int32).ravel()
+        rpos = jnp.cumsum(rnzi) - rnzi
+        ridx = jnp.where((rnzi > 0) & (rpos < budget), rpos, budget)
+        rows = (
+            chunk_ids[wtop, widx // wpc] * CHUNK + (widx % wpc) * 32
+        )[:, None] + jnp.arange(32, dtype=jnp.int32)[None, :]
+        fvals = fid_flat[rows.ravel()]
+        tvals = jnp.broadcast_to(wtop[:, None], (budget, 32)).ravel()
+        tj = jnp.full((budget,), b, jnp.int32).at[ridx].set(tvals, mode="drop")
+        fids = jnp.zeros((budget,), jnp.int32).at[ridx].set(fvals, mode="drop")
+    with jax.named_scope("sort"):
+        _tj_s, fid_s = lax.sort((tj, fids), num_keys=2)
+    with jax.named_scope("counts"):
+        cnts = jnp.sum(lax.population_count(words).astype(jnp.int32), axis=1)
+        return jnp.concatenate([fid_s, cnts])
 
 
 def match_fused_impl(tiles, fid_rows, ttok, tlen, tdollar, chunk_ids,
@@ -1812,9 +1842,13 @@ class PartitionedMatcher:
         # a deque under a concurrent append raises
         self._prof_pending: deque = deque()
         self._prof_lock = threading.Lock()
-        # per-stage wall-clock attribution (cfg11): zero-overhead when off
+        # per-stage wall-clock attribution (cfg11): zero-overhead when off.
+        # The four sections are busy-clock stages (broker/telemetry.py
+        # Stage): the matcher's own until ``use_telemetry`` hands it the
+        # registry's ``matcher.*`` stages, whose sections are then also
+        # spans of a profiler trace
         self.stage_timing = False
-        self.stage_ns = {"encode": 0, "dispatch": 0, "fetch": 0, "decode": 0}
+        self._stages = {k: _Stage("matcher." + k) for k in _STAGE_KEYS}
         # segmented-table mode: device tables above this byte budget split
         # into multiple arrays scanned per segment (one huge device_put +
         # compile at 10M subs is round 2's undiagnosed cfg4 on-chip failure;
@@ -1840,6 +1874,15 @@ class PartitionedMatcher:
         self._dev_dtype: Optional[type] = None
         self._dev_up_chunks = 0
         self._dev_fid_map: Optional[np.ndarray] = None
+
+    def use_telemetry(self, tele) -> None:
+        """Count the four sections into ``tele``'s ``matcher.*`` stages."""
+        self._stages = {k: tele.stage("matcher." + k) for k in _STAGE_KEYS}
+
+    @property
+    def stage_ns(self) -> Dict[str, int]:
+        """Cumulative ns per section (encode / dispatch / fetch / decode)."""
+        return {k: st.busy_ns for k, st in self._stages.items()}
 
     def _decide_pallas(self, dev, ttok, tlen, tdollar, chunk_ids) -> bool:
         """Verify the Pallas words producer against the lax scan on this
@@ -2271,7 +2314,8 @@ class PartitionedMatcher:
             # attribute: concurrent submits on one matcher (pipelined
             # executor threads) must not cross-attribute their padding
             _meta["padded"] = padded
-        t_enc = time.perf_counter_ns() if self.stage_timing else 0
+        st = self._stages
+        tok = st["encode"].begin(b) if self.stage_timing else 0
         want_groups = self.compact_mode == "global"
         while True:
             enc, enc_epoch = t.encode_topics_versioned(
@@ -2297,10 +2341,9 @@ class PartitionedMatcher:
             break
         snap = _Snap(self._dev_version, self._dev_epoch, self._dev_fid_map)
         _ttok, tlen, tdollar, chunk_ids, _nc = enc[:5]
-        if t_enc:
-            now = time.perf_counter_ns()
-            self.stage_ns["encode"] += now - t_enc
-            t_enc = now
+        if tok:
+            # one clock read closes encode and opens dispatch
+            tok = st["dispatch"].begin_at(abs(tok) + st["encode"].end(tok))
         try:
             if self._segments is not None:
                 return self._submit_segmented(tt, tlen, tdollar, chunk_ids, b,
@@ -2367,8 +2410,8 @@ class PartitionedMatcher:
             return ("k", b, chunk_ids, words, (dev, tt, tlen, tdollar, lay),
                     wi, wb, cn, self.max_words, snap)
         finally:
-            if t_enc:
-                self.stage_ns["dispatch"] += time.perf_counter_ns() - t_enc
+            if tok:
+                st["dispatch"].end(tok)
 
     # ------------------------------------------------- NC split-dispatch
     SPLIT_MIN_BATCH = 1024  # small batches are dispatch-bound, not compute
@@ -2583,7 +2626,8 @@ class PartitionedMatcher:
         _tag, b, padded, rerun, packed, g = handle
         (dev, fdev, tt, tlen, tdollar, chunk_ids, grouped, lay,
          use_pallas) = rerun
-        t0 = time.perf_counter_ns() if self.stage_timing else 0
+        st = self._stages
+        tok = st["fetch"].begin(b) if self.stage_timing else 0
         while True:
             arr = fetch(packed, "fused match fetch")
             cn = arr[g:].astype(np.int64)
@@ -2614,18 +2658,17 @@ class PartitionedMatcher:
                         dev, fdev, tt, tlen, tdollar, *grouped, budget=g,
                         layout=lay, use_pallas=use_pallas,
                         interpret=self._pallas_interpret))
-        if t0:
-            now = time.perf_counter_ns()
-            self.stage_ns["fetch"] += now - t0
-            t0 = now
+        if tok:
+            # one clock read closes fetch and opens decode
+            tok = st["decode"].begin_at(abs(tok) + st["fetch"].end(tok))
         if cn[b:].any():
             # same fail-loudly contract as the host decoders: a padded topic
             # (tlen=-2, can match nothing) with routes is a device bug
             raise AssertionError("padded topic produced routes — device bug")
         out = self._split_fused_wire(arr, cn, n, b)
         self.fused_batches += 1
-        if t0:
-            self.stage_ns["decode"] += time.perf_counter_ns() - t0
+        if tok:
+            st["decode"].end(tok)
         return out
 
     @staticmethod
@@ -2642,7 +2685,8 @@ class PartitionedMatcher:
     def _complete_fused_split(self, handle) -> List[np.ndarray]:
         _tag, b, order, meta, parts, ctx, packed, budgets = handle
         dev, fdev, lay = ctx
-        t0 = time.perf_counter_ns() if self.stage_timing else 0
+        st = self._stages
+        tok = st["fetch"].begin(b) if self.stage_timing else 0
         while True:
             arr = fetch(packed, "fused match fetch")
             segs = []
@@ -2670,10 +2714,9 @@ class PartitionedMatcher:
                 if _DEVPROF.enabled else
                 _match_fused_split(dev, fdev, tuple(parts), budgets,
                                    layout=lay))
-        if t0:
-            now = time.perf_counter_ns()
-            self.stage_ns["fetch"] += now - t0
-            t0 = now
+        if tok:
+            # one clock read closes fetch and opens decode
+            tok = st["decode"].begin_at(abs(tok) + st["fetch"].end(tok))
         out: List[Optional[np.ndarray]] = [None] * b
         pos = 0
         for (s, pb, tier), (fid_seg, cn) in zip(meta, segs):
@@ -2684,8 +2727,8 @@ class PartitionedMatcher:
                 out[orig] = r
             pos += s
         self.fused_batches += 1
-        if t0:
-            self.stage_ns["decode"] += time.perf_counter_ns() - t0
+        if tok:
+            st["decode"].end(tok)
         return out
 
     def prewarm(self, batch_sizes: Sequence[int] = (1, 8)) -> None:
@@ -3017,8 +3060,8 @@ class PartitionedMatcher:
             # per-stage ns deltas (PR9 stage_timing). Pipelined overlap can
             # smear attribution between ADJACENT records (stage counters
             # are matcher-cumulative); totals stay exact
-            rec["stage_ns"] = {k: self.stage_ns[k] - sn0[k]
-                               for k in self.stage_ns}
+            sn1 = self.stage_ns
+            rec["stage_ns"] = {k: sn1[k] - sn0[k] for k in sn1}
         _DEVPROF.note_dispatch(rec, rec["submit_ns"] + rec["complete_ns"])
         return out
 
@@ -3096,7 +3139,8 @@ class PartitionedMatcher:
     def _complete_global(self, handle) -> List[np.ndarray]:
         _tag, b, chunk_ids, words, dev_inputs, packed, g, fid_base, snap = handle
         padded, nc = chunk_ids.shape
-        t0 = time.perf_counter_ns() if self.stage_timing else 0
+        st = self._stages
+        tok = st["fetch"].begin(b) if self.stage_timing else 0
         while True:
             # ONE fetch per match: [routes..., cnts...] (counts are
             # truncation-exact, so overflow is detectable from the same
@@ -3131,17 +3175,16 @@ class PartitionedMatcher:
                         if prof else _match_global_grouped(
                             dev, ttok, tlen, tdollar, *grouped, budget=g,
                             layout=lay))
-        if t0:
-            now = time.perf_counter_ns()
-            self.stage_ns["fetch"] += now - t0
-            t0 = now
+        if tok:
+            # one clock read closes fetch and opens decode
+            tok = st["decode"].begin_at(abs(tok) + st["fetch"].end(tok))
         out = self._decode_revalidated(
             snap, fid_base,
             lambda fid_map, overlay, strict: _decode_routes(
                 arr[:n], cn, chunk_ids, b, fid_map,
                 overlay=overlay, strict=strict))
-        if t0:
-            self.stage_ns["decode"] += time.perf_counter_ns() - t0
+        if tok:
+            st["decode"].end(tok)
         return out
 
     def match(self, topics: Sequence[str], pad_to_pow2: bool = True) -> List[np.ndarray]:

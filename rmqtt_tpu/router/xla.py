@@ -13,7 +13,6 @@ SURVEY.md §7 "hard parts".
 from __future__ import annotations
 
 import logging
-import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -169,6 +168,9 @@ class XlaRouter(Router):
         # bypasses the hybrid to exercise the device matcher on purpose
         self._fp_dispatch = FAILPOINTS.register("device.dispatch")
         self._fp_complete = FAILPOINTS.register("device.complete")
+        from rmqtt_tpu.broker.telemetry import NULL_TELEMETRY
+
+        self.use_telemetry(NULL_TELEMETRY)  # re-wired by ServerContext
 
     def add(self, topic_filter: str, id: Id, opts: SubscriptionOptions) -> None:
         if self._relations.add(topic_filter, id, opts):
@@ -214,21 +216,35 @@ class XlaRouter(Router):
     def matches_raw(self, from_id: Optional[Id], topic: str):
         return self.matches_batch_raw([(from_id, topic)])[0]
 
+    def use_telemetry(self, tele) -> None:
+        """Wired by ServerContext beside ``router.telemetry``: the busy
+        stages of the match path (broker/telemetry.py ``Stage``) — the
+        relations expansion here, the hybrid's two backends, the device
+        matcher's four sections."""
+        self._st_expand = tele.stage("routing.expand")
+        self._hybrid.use_stages(tele.stage("routing.match.side"),
+                                tele.stage("routing.match.device"))
+        use = getattr(self.matcher, "use_telemetry", None)
+        if use is not None:
+            use(tele)
+
     def matches_batch_raw(self, items: Sequence[Tuple[Optional[Id], str]]):
         topics = [topic for _, topic in items]
         tele = self.telemetry
-        t0 = time.perf_counter_ns() if tele is not None and tele.enabled else 0
-        rows = self._hybrid.match(topics)
-        if t0:
-            # recorder, not record(): executor threads record this stage
-            # concurrently with the loop — append + locked fold keeps
-            # totals exact (see telemetry.recorder)
-            tele.recorder("kernel.dispatch")(
-                time.perf_counter_ns() - t0,
-                {"backend": "xla", "batch": len(items)})
-        return self._expand(items, rows)
+        on = tele is not None and tele.enabled
+        seq = tele.batch_begin() if on else 0
+        try:
+            return self._expand(items, self._hybrid.match(topics, on))
+        finally:
+            if seq:
+                tele.batch_end(seq)
 
     def _expand(self, items, fid_rows):
+        """Matched fids → per-item relations: the ``routing.expand`` stage,
+        on whichever thread the match ran."""
+        tele = self.telemetry
+        tok = (self._st_expand.begin(len(items))
+               if tele is not None and tele.enabled else 0)
         out = []
         f2f = self._fid_to_filter
         for (from_id, _topic), fids in zip(items, fid_rows):
@@ -236,6 +252,8 @@ class XlaRouter(Router):
             out.append(
                 expand_matches_raw(matched, self._relations, from_id, self._is_online)
             )
+        if tok:
+            self._st_expand.end(tok)
         return out
 
     # pipelined halves (RoutingService overlap): submit encodes + dispatches,
@@ -248,28 +266,27 @@ class XlaRouter(Router):
         items = list(items)
         topics = [topic for _, topic in items]
         tele = self.telemetry
-        t0 = time.perf_counter_ns() if tele is not None and tele.enabled else 0
-        h = self._hybrid.match_submit(topics)
-        if h[0] == "sync":
-            out = True, self._expand(items, h[1])
-            if t0:
-                tele.recorder("kernel.dispatch")(
-                    time.perf_counter_ns() - t0,
-                    {"backend": "xla-sync", "batch": len(items)})
-            return out
-        # async device dispatch: the kernel stage closes at complete time
-        return False, (items, h, t0)
+        on = tele is not None and tele.enabled
+        seq = tele.batch_begin() if on else 0
+        try:
+            h = self._hybrid.match_submit(topics, on)
+            if h[0] == "sync":
+                return True, self._expand(items, h[1])
+            # async device dispatch: the device stage closes at complete time
+            return False, (items, h, on, seq)
+        finally:
+            if seq:
+                tele.batch_end(seq)
 
     def complete_batch_raw(self, handle):
-        items, h, t0 = handle
-        rows = self._hybrid.match_complete(h)
-        if t0:
-            tele = self.telemetry
-            if tele is not None:
-                tele.recorder("kernel.dispatch")(
-                    time.perf_counter_ns() - t0,
-                    {"backend": "xla", "batch": len(items)})
-        return self._expand(items, rows)
+        items, h, on, seq = handle
+        if seq:
+            self.telemetry.batch_begin(seq)
+        try:
+            return self._expand(items, self._hybrid.match_complete(h, on))
+        finally:
+            if seq:
+                self.telemetry.batch_end(seq)
 
     def prewarm(self, batch_sizes=(1, 8)) -> None:
         """Pre-compile the device matcher's small dispatch shapes (and
@@ -427,6 +444,10 @@ class XlaRouter(Router):
             "hybrid_served": {k: list(v)
                               for k, v in self._hybrid.served.items()},
             "hybrid_choice": self._hybrid.choice,
+            # per backend: times _bump replaced the rate EMA outright, and
+            # large batches sent to the slower path to refresh its EMA
+            "hybrid_regime_jumps": dict(self._hybrid.regime_jumps),
+            "hybrid_probes": dict(self._hybrid.probes),
             "compile_cache": compile_cache_stats(),
         }
 

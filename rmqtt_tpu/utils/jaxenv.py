@@ -57,6 +57,13 @@ def setup_compile_cache() -> str:
         # JAX's default skips compiles under a second — which is every small
         # dispatch shape a restarted broker wants back first
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # the named scopes of the match programs (ops/partitioned.py) are
+    # metadata, and by default the cache key leaves metadata out: an
+    # executable cached before a scope was added comes back WITHOUT it, and
+    # a profiler trace then shows the old names ("executables loaded from
+    # the cache may have stale metadata", JAX's own note on this option).
+    # The trace is how the device's time is read, so the key holds it.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
