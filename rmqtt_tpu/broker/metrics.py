@@ -79,6 +79,8 @@ class Stats:
         self.routing_compactions = 0
         self.routing_compact_ms_total = 0.0  # cumulative → summed, not averaged
         self.routing_cand_cache_invalidations = 0
+        self.routing_encode_topics = 0
+        self.routing_encode_host_resolved = 0
         self.routing_fused_batches = 0
         # per-stage device dispatch attribution (PR9 stage_timing promoted
         # to the live surface via XlaRouter.device_stats): cumulative ms,

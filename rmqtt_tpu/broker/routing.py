@@ -172,6 +172,8 @@ class RoutingService:
             # /stats/sum), NOT _ms (averaged like latency percentiles)
             "routing_compact_ms_total": d.get("compact_ms", 0.0),
             "routing_cand_cache_invalidations": d.get("cand_cache_invalidations", 0),
+            "routing_encode_topics": d.get("encode_topics", 0),
+            "routing_encode_host_resolved": d.get("encode_host_resolved", 0),
             "routing_fused_batches": d.get("fused_batches", 0),
             # per-stage device dispatch attribution (PR9 stage_timing via
             # XlaRouter.device_stats): cumulative ms → _total suffix (summed

@@ -341,22 +341,30 @@ class PartitionedTable:
         # transient per-mutation dirty set (chunks touched by the op)
         self._txn: Optional[List[int]] = None
         self._undo_pending: List[Tuple[int, int]] = []
-        # per-(t0[,t1[,t2]]) candidate caches: key -> (chunk ids, gid);
-        # invalidated SELECTIVELY: partition key -> cache keys consulting
-        # it, so a mutation only drops the entries it could affect
+        # python encoder's per-(t0[,t1[,t2]]) candidate cache: key ->
+        # (chunk ids, gid); invalidated SELECTIVELY: partition key -> cache
+        # keys consulting it, so a mutation only drops the entries it could
+        # affect. The native encoder walks its partition mirror per topic
+        # instead and keeps no such cache.
         self._cand_cache: Dict[Tuple, Tuple[np.ndarray, int]] = {}
         self._cand_keys_of: Dict[Tuple, Set] = {}
         self._gid_seq = 0
         self.cand_cache_invalidations = 0
         # size bound: selective invalidation means entries for never-mutated
         # partitions would otherwise accumulate forever under high-
-        # cardinality publish streams; past the cap the caches (and the
+        # cardinality publish streams; past the cap the cache (and the
         # key registry, which also holds invalidated-entry tombstones)
-        # clear wholesale — cheap and rare
+        # clears wholesale — cheap and rare
         self.cand_cache_max = 65536
-        self._nenc_entries = 0
         # native (C++) encoder: None = not tried yet, False = unavailable
         self._nenc = None
+        # partition keys mutated since the native mirror was last synced
+        # (pushed in one call by the next encode; never a call per mutation)
+        self._parts_dirty: Set[Tuple] = set()
+        # topics encoded for the device / those whose candidates per-topic
+        # Python resolved (all under _encode_py, none under the native path)
+        self.encode_topics_total = 0
+        self.encode_host_resolved = 0
         self._nc_cap = 32
         # narrow dtypes while ids fit: halves the per-batch host→device
         # upload of ttok/chunk_ids AND the device
@@ -605,6 +613,8 @@ class PartitionedTable:
             del self._fid_undo_old[:half]
         self._txn = None
         self._undo_pending = []
+        if self._nenc:
+            self._parts_dirty.add(key)
         self._invalidate_cand(key)
 
     def _invalidate_cand(self, key: Tuple) -> None:
@@ -615,21 +625,9 @@ class PartitionedTable:
             return
         n = 0
         cache = self._cand_cache
-        enc = self._nenc
         for ck in cache_keys:
-            if ck[0] == "p":
-                if cache.pop(ck[1], None) is not None:
-                    n += 1
-            elif enc and enc.has_cache_del:
-                d = enc.cache_del(ck[1])
-                n += d
-                # keep the live-entry count honest or steady churn
-                # would trip the size cap with a near-empty cache
-                self._nenc_entries = max(0, self._nenc_entries - d)
-            # without rt_enc_cache_del there is nothing selective to do:
-            # _encode_native already wholesale-clears the stale cache at
-            # the next batch (cache_version != version), so a per-key
-            # clear here would just empty it N times per mutation
+            if cache.pop(ck, None) is not None:
+                n += 1
         self.cand_cache_invalidations += n
 
     def _register_cand(self, levels: Sequence[str], cache_key: Tuple) -> None:
@@ -899,9 +897,9 @@ class PartitionedTable:
         self.delta.reset(self.version)
         self._cand_cache.clear()
         self._cand_keys_of.clear()
-        if self._nenc:
-            self._nenc.cache_clear()
-            self._nenc_entries = 0
+        # the epoch bump makes the next native encode resync its partition
+        # mirror wholesale
+        self._parts_dirty.clear()
 
     def _filter_of_fid(self, fid: int) -> List[str]:
         """Decode a live fid's filter levels back from the row data."""
@@ -982,6 +980,8 @@ class PartitionedTable:
         with_groups: bool = False,
     ):
         batch = len(topics)
+        self.encode_topics_total += batch
+        self.encode_host_resolved += batch
         b = pad_batch_to or batch
         lvl = self.max_levels
         tlen = np.full((b,), -2, dtype=np.int16)
@@ -1021,7 +1021,7 @@ class PartitionedTable:
                 ent = (self._candidates_for(levels), self._gid_seq)
                 self._gid_seq += 1
                 cache[ckey] = ent
-                self._register_cand(levels, ("p", ckey))
+                self._register_cand(levels, ckey)
             cand, gid = ent
             groups[j] = gid
             per_topic_chunks.append(cand)
@@ -1040,43 +1040,52 @@ class PartitionedTable:
             return ttok, tlen, tdollar, chunk_ids, nc, groups + 1  # padded -> 0
         return ttok, tlen, tdollar, chunk_ids, nc
 
+    def _sync_native(self, enc) -> None:
+        """Bring the native token and partition mirrors up to date: one call
+        for the new tokens and two or three for the partitions, however
+        much was mutated since the last encode. Caller holds the table
+        lock."""
+        toks = self.tokens._strs
+        if enc.tokens_synced < len(toks):
+            enc.add_tokens(toks[enc.tokens_synced:], _FIRST_TOK + enc.tokens_synced)
+            enc.tokens_synced = len(toks)
+        # per key: exclusive chunks first, then (appended) the shared ones —
+        # _candidates_for's order
+        if enc.parts_epoch != self.layout_epoch:
+            # compaction install (or first use): the layout changed wholesale
+            enc.parts_clear()
+            enc.parts_put(self._excl_chunks.keys(), self._excl_chunks.values(), False)
+            enc.parts_put(self._shared_chunks_of.keys(),
+                          self._shared_chunks_of.values(), True)
+            enc.parts_epoch = self.layout_epoch
+        elif self._parts_dirty:
+            keys = list(self._parts_dirty)
+            enc.parts_put(keys, [self._excl_chunks.get(k, ()) for k in keys], False)
+            shared = {k: occ for k in keys if (occ := self._shared_chunks_of.get(k))}
+            if shared:
+                enc.parts_put(shared.keys(), shared.values(), True)
+        self._parts_dirty.clear()
+
     def _encode_native(
         self, topics: Sequence[str | Sequence[str]], pad_batch_to: Optional[int],
         with_groups: bool = False,
     ):
-        """C++ hot path for ``encode_topics`` (runtime/encode.cc): tokenize +
-        candidate-cache lookup natively; only distinct-prefix cache misses
-        walk the Python partition maps."""
+        """C++ hot path for ``encode_topics`` (runtime/encode.cc): tokenize
+        and resolve every topic's candidate chunks natively, against a
+        mirror of the partition maps, in ONE call for the batch — no Python
+        and no native call per topic, however many prefixes are new."""
         enc = self._nenc
         batch = len(topics)
+        self.encode_topics_total += batch
         b = pad_batch_to or batch
         lvl = self.max_levels
-        toks = self.tokens._strs
-        for i in range(enc.tokens_synced, len(toks)):
-            enc.add_token(toks[i], _FIRST_TOK + i)
-        enc.tokens_synced = len(toks)
-        # mutations invalidate native entries selectively at mutation time
-        # (_invalidate_cand → enc.cache_del); only a wholesale layout change
-        # (compact install) still clears the native cache. Encoders without
-        # cache_del support (stale prebuilt .so) keep the per-version clear.
-        if enc.cache_epoch != self.layout_epoch or (
-            not enc.has_cache_del and enc.cache_version != self.version
-        ):
-            enc.cache_clear()
-            self._nenc_entries = 0
-            enc.cache_epoch = self.layout_epoch
-            enc.cache_version = self.version
-        if self._nenc_entries >= self.cand_cache_max:
-            # size cap, applied BETWEEN batches only: rt_enc_cache_clear
-            # resets the native gid counter, so clearing mid-batch would
-            # let fresh gids collide with ones already issued to earlier
-            # topics of the same encode (aliasing the grouped upload)
-            enc.cache_clear()
-            self._nenc_entries = 0
-            self._cand_keys_of.clear()
-        if batch and any(not isinstance(t, str) for t in topics):
-            topics = [t if isinstance(t, str) else "/".join(t) for t in topics]
-        blob = ("\x00".join(topics) + "\x00").encode() if batch else b"\x00"
+        self._sync_native(enc)
+        try:
+            blob = ("\x00".join(topics) + "\x00").encode()
+        except TypeError:  # some topic came as a level sequence
+            blob = ("\x00".join(
+                [t if isinstance(t, str) else "/".join(t) for t in topics]
+            ) + "\x00").encode()
         while True:
             nc_cap = self._nc_cap
             ttok = np.zeros((b, lvl), dtype=np.int32)
@@ -1085,35 +1094,9 @@ class PartitionedTable:
             cand = np.zeros((b, nc_cap), dtype=np.int32)
             counts = np.zeros((b,), dtype=np.int32)
             group = np.full((b,), -1, dtype=np.int32)  # padded rows stay -1
-            if batch:
-                miss = enc.encode(
-                    blob, batch, lvl, ttok, tlen, tdollar, nc_cap, cand, counts,
-                    group,
-                )
-                # dedupe misses by prefix key: a cold cache (fresh table
-                # version) must not hand every repeated hot topic its own
-                # gid — that would disable the grouped upload exactly when
-                # it pays most
-                put: Dict[bytes, Tuple[int, np.ndarray]] = {}
-                for j in miss:
-                    levels = split_levels(topics[j])
-                    key = "/".join(levels[:3]).encode()
-                    hit = put.get(key)
-                    if hit is None:
-                        chunks = self._candidates_for(levels)
-                        hit = (enc.cache_put(key, chunks), chunks)
-                        self._nenc_entries += 1
-                        put[key] = hit
-                        # registrations are only consumed by the selective
-                        # cache_del branch; without it they'd accumulate in
-                        # _cand_keys_of forever (the per-version wholesale
-                        # clear never pops them)
-                        if enc.has_cache_del:
-                            self._register_cand(levels, ("n", key))
-                    group[j], chunks = hit
-                    counts[j] = len(chunks)
-                    cand[j, : min(len(chunks), nc_cap)] = chunks[:nc_cap]
-            mx = int(counts.max(initial=1))
+            mx = enc.encode(
+                blob, batch, lvl, ttok, tlen, tdollar, nc_cap, cand, counts, group,
+            )
             nc = max(1, 1 << (max(1, mx) - 1).bit_length())  # pow2 bucket
             if nc > nc_cap:
                 self._nc_cap = nc  # sticky: grows, never shrinks

@@ -409,6 +409,10 @@ class XlaRouter(Router):
             "compactions": getattr(t, "compactions", 0),
             "compact_ms": round(getattr(t, "compact_ms", 0.0), 3),
             "cand_cache_invalidations": getattr(t, "cand_cache_invalidations", 0),
+            # topics encoded for the device / those whose candidate chunks
+            # per-topic Python resolved (0 while the native encoder serves)
+            "encode_topics": getattr(t, "encode_topics_total", 0),
+            "encode_host_resolved": getattr(t, "encode_host_resolved", 0),
             # batches served end-to-end by the fused device pipeline
             # (ops/partitioned.py): nonzero proves host decode is off the
             # per-batch path

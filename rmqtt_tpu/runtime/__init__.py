@@ -13,11 +13,12 @@ sources; environments without a toolchain fall back to the Python trie.
 from __future__ import annotations
 
 import ctypes
+import itertools
 import logging
 import os
 import subprocess
 from pathlib import Path
-from typing import List, Optional, Sequence
+from typing import Collection, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -86,28 +87,25 @@ def load() -> Optional[ctypes.CDLL]:
     lib.rt_trie_match_batch.restype = ctypes.c_int64
     lib.rt_enc_new.restype = ctypes.c_void_p
     lib.rt_enc_free.argtypes = [ctypes.c_void_p]
-    lib.rt_enc_add_token.argtypes = [
-        ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int32, ctypes.c_int32,
+    lib.rt_enc_add_tokens.argtypes = [
+        ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int64, ctypes.c_int32,
     ]
-    lib.rt_enc_cache_clear.argtypes = [ctypes.c_void_p]
-    lib.rt_enc_cache_put.argtypes = [
-        ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int32,
-        ctypes.POINTER(ctypes.c_int32), ctypes.c_int32,
+    lib.rt_enc_add_tokens.restype = ctypes.c_int64
+    lib.rt_enc_parts_clear.argtypes = [ctypes.c_void_p]
+    lib.rt_enc_parts_put.argtypes = [
+        ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64,
+        ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int32),
+        ctypes.c_int32,
     ]
-    lib.rt_enc_cache_put.restype = ctypes.c_int32
-    if hasattr(lib, "rt_enc_cache_del"):  # absent in pre-delta .so builds
-        lib.rt_enc_cache_del.argtypes = [
-            ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int32,
-        ]
-        lib.rt_enc_cache_del.restype = ctypes.c_int32
+    lib.rt_enc_parts_put.restype = ctypes.c_int64
     lib.rt_enc_encode.argtypes = [
         ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int64, ctypes.c_int32,
         ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int32),
         ctypes.POINTER(ctypes.c_uint8), ctypes.c_int32,
         ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int32),
-        ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_int32),
     ]
-    lib.rt_enc_encode.restype = ctypes.c_int64
+    lib.rt_enc_encode.restype = ctypes.c_int32
     lib.rt_match_decode.argtypes = [
         ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_uint32),
         ctypes.c_int64, ctypes.c_int64,
@@ -264,9 +262,10 @@ def _i32p(a: np.ndarray):
 class NativeEncoder:
     """ctypes wrapper over the C++ batched topic encoder (runtime/encode.cc).
 
-    Owns the native token-dict mirror and candidate-chunk cache for one
-    ``PartitionedTable``; the table syncs tokens incrementally and clears
-    the cache on mutation (see partitioned.py ``_encode_native``).
+    Owns the native mirrors of one ``PartitionedTable``'s token dictionary
+    and partition-key → chunk-ids maps; the table pushes new tokens and the
+    keys its mutations touched before each encode, in a fixed number of
+    calls (see partitioned.py ``_sync_native``). No call here is per topic.
     """
 
     def __init__(self) -> None:
@@ -276,9 +275,7 @@ class NativeEncoder:
         self._lib = lib
         self._ptr = ctypes.c_void_p(lib.rt_enc_new())
         self.tokens_synced = 0  # count of TokenDict entries pushed so far
-        self.cache_version = -1  # table.version the candidate cache reflects
-        self.cache_epoch = -1  # table.layout_epoch the cache was built under
-        self.has_cache_del = hasattr(lib, "rt_enc_cache_del")
+        self.parts_epoch = -1  # table.layout_epoch the partition mirror reflects
 
     def __del__(self) -> None:
         ptr = getattr(self, "_ptr", None)
@@ -286,29 +283,34 @@ class NativeEncoder:
             self._lib.rt_enc_free(ptr)
             self._ptr = None
 
-    def add_token(self, s: str, tid: int) -> None:
-        b = s.encode()
-        self._lib.rt_enc_add_token(self._ptr, b, len(b), tid)
+    def add_tokens(self, strs: Sequence[str], first_id: int) -> None:
+        """Intern ``strs`` as ids ``first_id, first_id + 1, ...``."""
+        blob = ("/".join(strs) + "/").encode()
+        n = self._lib.rt_enc_add_tokens(self._ptr, blob, len(blob), first_id)
+        if n != len(strs):  # a level holding '/' would shift every later id
+            raise RuntimeError(f"native token mirror took {n} of {len(strs)} levels")
 
-    def cache_clear(self) -> None:
-        self._lib.rt_enc_cache_clear(self._ptr)
+    def parts_clear(self) -> None:
+        self._lib.rt_enc_parts_clear(self._ptr)
 
-    def cache_del(self, key: bytes) -> int:
-        """Erase one prefix entry (selective invalidation); returns the
-        number of entries dropped. A stale prebuilt .so without the symbol
-        degrades to a full clear — correct, just colder."""
-        if not self.has_cache_del:
-            self.cache_clear()
-            return 1
-        return self._lib.rt_enc_cache_del(self._ptr, key, len(key))
-
-    def cache_put(self, key: bytes, chunks: np.ndarray) -> int:
-        """→ the gid the native side assigned to this entry (authoritative —
-        no Python-side mirror counter to drift out of sync)."""
-        chunks = np.ascontiguousarray(chunks, dtype=np.int32)
-        return self._lib.rt_enc_cache_put(
-            self._ptr, key, len(key), _i32p(chunks), len(chunks)
+    def parts_put(self, keys: Collection[Tuple], lists: Collection[Collection[int]],
+                  append: bool) -> None:
+        """Install partition keys' chunk ids (``lists[i]`` for ``keys[i]``):
+        replace each key's list (an empty one erases the key), or with
+        ``append`` extend it. All iteration is C-level — a compaction
+        install resyncs ~100K keys through here on the routing path."""
+        n = len(keys)
+        if not n:
+            return
+        blob = ("/".join(map("/".join, keys)) + "/").encode()
+        counts = np.fromiter(map(len, lists), dtype=np.int32, count=n)
+        chunks = np.fromiter(itertools.chain.from_iterable(lists), dtype=np.int32,
+                             count=int(counts.sum()))
+        rc = self._lib.rt_enc_parts_put(
+            self._ptr, blob, len(blob), n, _i32p(counts), _i32p(chunks), int(append)
         )
+        if rc != n:
+            raise RuntimeError(f"native partition mirror refused key #{-rc - 1} of {n}")
 
     def encode(
         self,
@@ -322,17 +324,15 @@ class NativeEncoder:
         cand: np.ndarray,
         cand_counts: np.ndarray,
         group: np.ndarray,
-    ) -> np.ndarray:
-        """Returns the indices of topics whose prefix key missed the cache;
-        ``group`` receives each topic's candidate-row gid (-1 on miss)."""
-        miss = np.empty(n, dtype=np.int32)
-        nmiss = self._lib.rt_enc_encode(
+    ) -> int:
+        """Fills every output for the whole batch; returns the largest
+        candidate count (> ``nc_cap``: rows truncated, grow and retry)."""
+        return self._lib.rt_enc_encode(
             self._ptr, blob, n, max_levels,
             _i32p(ttok), _i32p(tlen),
             tdollar.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-            nc_cap, _i32p(cand), _i32p(cand_counts), _i32p(group), _i32p(miss),
+            nc_cap, _i32p(cand), _i32p(cand_counts), _i32p(group),
         )
-        return miss[:nmiss]
 
 
 def match_decode_routes(routes: np.ndarray, counts: np.ndarray,

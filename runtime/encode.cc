@@ -1,14 +1,18 @@
 // C++ batched publish-topic encoder for the partitioned automaton.
 //
-// Host-side encode (tokenize + candidate-chunk lookup) was the measured
-// bottleneck of the TPU routing path (NOTES.md: 0.064s per 16K topics in
-// Python — at 10x kernel speed the host becomes the wall). This implements
-// the hot loop of rmqtt_tpu/ops/partitioned.py::PartitionedTable.encode_topics
-// natively: split levels, token-dict lookup, $-prefix flag, and the
-// candidate-chunk cache keyed by the topic's first <=3 levels. The cache
-// MISS path (walking the partition maps) stays in Python — it runs once per
-// distinct 3-level prefix, then the result is installed here via
-// rt_enc_cache_put.
+// Host-side encode (tokenize + candidate-chunk resolution) is the whole
+// host cost of a device batch worth naming (PERF.md §5/§6, PR 26). This
+// implements rmqtt_tpu/ops/partitioned.py::PartitionedTable.encode_topics
+// natively for the WHOLE batch in one call: split levels, token-dict
+// lookup, $-prefix flag, and the candidate-chunk walk itself — the encoder
+// holds a mirror of the table's partition-key → chunk-ids maps
+// (`_excl_chunks` / `_shared_chunks_of`), derives each topic's <= 15
+// partition keys (`topic_partitions`) and unions their chunks in
+// `_candidates_for`'s order. Nothing comes back to Python per topic,
+// whatever share of the batch has a never-seen prefix. The table keeps the
+// mirror in step the way it keeps the tokens: mutations mark the keys they
+// touched, the next encode pushes those keys' chunk lists in one or two
+// rt_enc_parts_put calls (a compaction install resyncs wholesale).
 //
 // Exposed as a C ABI for ctypes (no pybind11 in this image). Thread safety:
 // external, same contract as topics.cc.
@@ -24,8 +28,9 @@
 
 namespace {
 
-constexpr int32_t kUnkTok = 3;  // ops/encode.py UNK_TOK
-constexpr int32_t kPadTok = 0;  // ops/encode.py PAD_TOK
+constexpr int32_t kUnkTok = 3;   // ops/encode.py UNK_TOK
+constexpr int32_t kPadTok = 0;   // ops/encode.py PAD_TOK
+constexpr int32_t kPlusTok = 1;  // ops/encode.py PLUS_TOK
 
 // Heterogeneous hashing: lets find() take a string_view without
 // materializing a std::string per level (the encode loop does one lookup
@@ -39,11 +44,6 @@ struct SvHash {
 struct SvEq {
   using is_transparent = void;
   bool operator()(std::string_view a, std::string_view b) const noexcept { return a == b; }
-};
-
-struct CacheEntry {
-  std::vector<int32_t> chunks;
-  int32_t gid;  // stable per-entry group id (dedup key for uploads)
 };
 
 // Heterogeneous unordered lookup (P1690) only ships in libstdc++ from GCC
@@ -63,22 +63,118 @@ auto sv_find(const Map& m, std::string_view k) {
 }
 #endif
 
-struct Encoder {
-  std::unordered_map<std::string, int32_t, SvHash, SvEq> tokens;
-  // first-(<=3)-level topic prefix -> candidate chunk ids
-  std::unordered_map<std::string, CacheEntry, SvHash, SvEq> cand_cache;
-  int32_t next_gid = 0;
+// partitioned.py partition_key kinds. A key is (kind, k0, k1, k2) with
+// every k a token id or kPlusTok (unused slots 0) — the token-id image of
+// the Python tuple ("4", k0, k1, k2) etc. Every literal level of a stored
+// key was interned when its filter row was written, so a topic level the
+// dictionary does not know (kUnkTok) cannot name any partition.
+enum Kind : int32_t { kHash = 0, k1 = 1, k2 = 2, k2E = 3, kH3 = 4, k4 = 5, kBad = -1 };
+
+struct PartKey {
+  int32_t kind, a, b, c;
+  bool operator==(const PartKey& o) const noexcept {
+    return kind == o.kind && a == o.a && b == o.b && c == o.c;
+  }
+};
+struct PartKeyHash {
+  size_t operator()(const PartKey& k) const noexcept {
+    uint64_t h = static_cast<uint32_t>(k.kind);
+    for (int32_t v : {k.a, k.b, k.c}) {
+      h ^= static_cast<uint32_t>(v);
+      h *= 0x9E3779B97F4A7C15ull;
+      h ^= h >> 29;
+    }
+    return static_cast<size_t>(h);
+  }
 };
 
-// Key = the raw topic bytes up to (not including) the third '/'. This is
-// exactly partitioned.py's (min(len,3), levels[:3]) tuple key: the slice
-// preserves both the level strings and how many levels (<=3) it covers.
-std::string_view prefix_key(std::string_view topic) {
-  size_t slashes = 0;
-  for (size_t i = 0; i < topic.size(); ++i) {
-    if (topic[i] == '/' && ++slashes == 3) return topic.substr(0, i);
+// Next '/'-terminated segment of [*p, end): levels cannot hold a '/', so
+// the token and partition-key streams delimit themselves with it whatever
+// other bytes a level holds. False when no terminator is left.
+bool next_segment(const char** p, const char* end, std::string_view* out) {
+  const void* slash =
+      *p < end ? std::memchr(*p, '/', static_cast<size_t>(end - *p)) : nullptr;
+  if (!slash) return false;
+  const char* q = static_cast<const char*>(slash);
+  *out = std::string_view(*p, static_cast<size_t>(q - *p));
+  *p = q + 1;
+  return true;
+}
+
+Kind kind_of(std::string_view s) {
+  if (s == "#") return kHash;
+  if (s == "1") return k1;
+  if (s == "2") return k2;
+  if (s == "2E") return k2E;
+  if (s == "H3") return kH3;
+  if (s == "4") return k4;
+  return kBad;
+}
+
+struct Encoder {
+  std::unordered_map<std::string, int32_t, SvHash, SvEq> tokens;
+  // partition key -> chunk ids it occupies (exclusive, then shared)
+  std::unordered_map<PartKey, std::vector<int32_t>, PartKeyHash> parts;
+  // per-batch memo: a topic's candidate row is a function of its first
+  // <= 3 key tokens and min(levels, 3) — the first topic of the batch with
+  // a given prefix walks, later ones copy its row and share its group id
+  std::unordered_map<PartKey, int32_t, PartKeyHash> seen_prefix;
+  // chunk-id dedup stamps (partitions share boundary / shared chunks)
+  std::vector<uint32_t> stamp;
+  uint32_t stamp_gen = 0;
+
+  int32_t key_token(std::string_view lev) const {
+    if (lev == "+") return kPlusTok;
+    auto it = sv_find(tokens, lev);
+    return it == tokens.end() ? kUnkTok : it->second;
   }
-  return topic;
+};
+
+// Union `key`'s chunks into one topic's candidate row: first occurrence
+// wins, the TRUE count runs on past nc_cap (the caller grows and retries).
+inline void add_part(Encoder* enc, const PartKey& key, int32_t* out,
+                     int32_t nc_cap, int32_t* count) {
+  if (key.a == kUnkTok || key.b == kUnkTok || key.c == kUnkTok) return;
+  auto it = enc->parts.find(key);
+  if (it == enc->parts.end()) return;
+  for (int32_t cid : it->second) {
+    uint32_t& st = enc->stamp[static_cast<size_t>(cid)];
+    if (st == enc->stamp_gen) continue;
+    st = enc->stamp_gen;
+    if (*count < nc_cap) out[*count] = cid;
+    ++*count;
+  }
+}
+
+// partitioned.py topic_partitions + _candidates_for for one topic whose
+// first min(nlev, 3) key tokens are t[0..2].
+int32_t walk_parts(Encoder* enc, const int32_t* t, int32_t nlev, int32_t* out,
+                   int32_t nc_cap) {
+  if (++enc->stamp_gen == 0) {  // wrapped: stale stamps could alias
+    std::fill(enc->stamp.begin(), enc->stamp.end(), 0u);
+    enc->stamp_gen = 1;
+  }
+  int32_t c = 0;
+  const int32_t P = kPlusTok;
+  add_part(enc, {kHash, 0, 0, 0}, out, nc_cap, &c);
+  add_part(enc, {k2, t[0], 0, 0}, out, nc_cap, &c);
+  add_part(enc, {k2, P, 0, 0}, out, nc_cap, &c);
+  if (nlev == 1) {
+    add_part(enc, {k1, t[0], 0, 0}, out, nc_cap, &c);
+    add_part(enc, {k1, P, 0, 0}, out, nc_cap, &c);
+    return c;
+  }
+  const int32_t pairs[4][2] = {{t[0], t[1]}, {t[0], P}, {P, t[1]}, {P, P}};
+  for (const auto& p : pairs) add_part(enc, {kH3, p[0], p[1], 0}, out, nc_cap, &c);
+  if (nlev == 2) {
+    for (const auto& p : pairs) add_part(enc, {k2E, p[0], p[1], 0}, out, nc_cap, &c);
+    return c;
+  }
+  for (const auto& p : pairs) {
+    add_part(enc, {k4, p[0], p[1], t[2]}, out, nc_cap, &c);
+    add_part(enc, {k4, p[0], p[1], P}, out, nc_cap, &c);
+  }
+  return c;
 }
 
 }  // namespace
@@ -89,64 +185,96 @@ void* rt_enc_new() { return new Encoder(); }
 
 void rt_enc_free(void* h) { delete static_cast<Encoder*>(h); }
 
-void rt_enc_add_token(void* h, const char* s, int32_t len, int32_t id) {
-  static_cast<Encoder*>(h)->tokens.emplace(std::string(s, static_cast<size_t>(len)), id);
+// Intern the level strings in `blob` — each followed by '/' — as ids first_id, first_id+1, ... (the table's TokenDict
+// is append-only, so a sync is one contiguous run). Returns how many.
+int64_t rt_enc_add_tokens(void* h, const char* blob, int64_t blob_len,
+                          int32_t first_id) {
+  auto* enc = static_cast<Encoder*>(h);
+  const char* p = blob;
+  std::string_view lev;
+  int64_t n = 0;
+  while (next_segment(&p, blob + blob_len, &lev))
+    enc->tokens.emplace(std::string(lev), first_id + static_cast<int32_t>(n++));
+  return n;
 }
 
-void rt_enc_cache_clear(void* h) {
-  auto* enc = static_cast<Encoder*>(h);
-  enc->cand_cache.clear();
-  enc->next_gid = 0;
-}
+void rt_enc_parts_clear(void* h) { static_cast<Encoder*>(h)->parts.clear(); }
 
-// Erase one cached prefix entry. Selective invalidation: a subscription
-// mutation drops only the prefixes whose candidate sets it could change
-// (partitioned.py _invalidate_cand); survivors keep their gids, which is
-// why gids are monotonic and never reissued outside rt_enc_cache_clear.
-int32_t rt_enc_cache_del(void* h, const char* key, int32_t keylen) {
+// Install n partition keys' chunk lists in one call. `keys` is the n keys'
+// segments — the Python tuple's kind, then its 0-3 levels ("4","a","+","c";
+// "2E","","b"; "#") — each followed by '/'; the kind fixes how many levels
+// follow. `counts[i]` chunk ids of key i follow
+// one another in `chunks`, in the table's own order. With `append` 0 a
+// key's list is replaced (an empty one erases the key); with 1 the ids
+// are appended — the table pushes a key's exclusive chunks first, then its
+// shared ones (`_candidates_for`'s order). Key levels are resolved through
+// the token dictionary, so tokens must be synced first. Returns n, or
+// -(i+1) when key i does not parse (unknown kind / level, or the stream
+// ends early: a caller bug, fail loudly).
+int64_t rt_enc_parts_put(void* h, const char* keys, int64_t keys_len, int64_t n,
+                         const int32_t* counts, const int32_t* chunks,
+                         int32_t append) {
+  static constexpr int32_t kArity[] = {0, 1, 1, 2, 2, 3};  // by Kind
   auto* enc = static_cast<Encoder*>(h);
-  return enc->cand_cache.erase(std::string(key, static_cast<size_t>(keylen)))
-             ? 1
-             : 0;
-}
-
-int32_t rt_enc_cache_put(void* h, const char* key, int32_t keylen,
-                         const int32_t* chunks, int32_t n) {
-  auto* enc = static_cast<Encoder*>(h);
-  auto& e = enc->cand_cache[std::string(key, static_cast<size_t>(keylen))];
-  e.chunks.assign(chunks, chunks + n);
-  e.gid = enc->next_gid++;
-  return e.gid;  // the authoritative gid — callers must not mirror-count
+  const char* p = keys;
+  const char* const end = keys + keys_len;
+  auto segment = [&](std::string_view* out) { return next_segment(&p, end, out); };
+  for (int64_t i = 0; i < n; ++i) {
+    std::string_view seg;
+    PartKey pk{kBad, 0, 0, 0};
+    if (!segment(&seg) || (pk.kind = kind_of(seg)) == kBad) return -(i + 1);
+    int32_t* lev[3] = {&pk.a, &pk.b, &pk.c};
+    for (int32_t l = 0; l < kArity[pk.kind]; ++l) {
+      if (!segment(&seg) || (*lev[l] = enc->key_token(seg)) == kUnkTok) return -(i + 1);
+    }
+    const int32_t cnt = counts[i];
+    if (cnt == 0) {
+      if (!append) enc->parts.erase(pk);
+      continue;
+    }
+    auto& v = enc->parts[pk];
+    if (!append) v.clear();
+    v.insert(v.end(), chunks, chunks + cnt);
+    const int32_t mx = *std::max_element(chunks, chunks + cnt);
+    chunks += cnt;
+    if (static_cast<size_t>(mx) >= enc->stamp.size())
+      enc->stamp.resize(static_cast<size_t>(mx) + 1, 0u);
+  }
+  return p == end ? n : -(n + 1);
 }
 
 // Encode n '\0'-separated topics. Fills ttok [n, max_levels] (PAD beyond the
-// topic's levels), tlen [n] (full level count), tdollar [n], and for topics
-// whose prefix key is cached: cand [n, nc_cap] (0-padded) + cand_counts [n]
-// (the TRUE count, even when > nc_cap — caller grows nc_cap and retries) +
-// group [n] (the cache entry's stable gid — identical candidate rows share
-// a gid, letting the caller upload each distinct row once).
-// Topics with an uncached prefix get cand_counts[j] = group[j] = -1 and
-// their index appended to miss_idx. Returns the number of misses.
-int64_t rt_enc_encode(void* h, const char* blob, int64_t n, int32_t max_levels,
+// topic's levels), tlen [n] (full level count), tdollar [n], cand
+// [n, nc_cap] (each topic's candidate chunk ids, 0-padded), cand_counts [n]
+// (the TRUE count, even when > nc_cap — caller grows nc_cap and retries)
+// and group [n]: topics of this batch with equal prefix keys — hence equal
+// candidate rows — share one id >= 0, letting the caller upload each
+// distinct row once. Returns the batch's largest candidate count.
+int32_t rt_enc_encode(void* h, const char* blob, int64_t n, int32_t max_levels,
                       int32_t* ttok, int32_t* tlen, uint8_t* tdollar, int32_t nc_cap,
-                      int32_t* cand, int32_t* cand_counts, int32_t* group,
-                      int32_t* miss_idx) {
+                      int32_t* cand, int32_t* cand_counts, int32_t* group) {
   auto* enc = static_cast<Encoder*>(h);
   const auto& tokens = enc->tokens;
-  const auto& cache = enc->cand_cache;
-  int64_t misses = 0;
+  auto& seen = enc->seen_prefix;
+  seen.clear();
+  int32_t max_count = 0;
   const char* p = blob;
   for (int64_t j = 0; j < n; ++j) {
     const char* topic_start = p;
     int32_t* row = ttok + j * max_levels;
     int32_t nlev = 0;
+    int32_t kt[3] = {0, 0, 0};  // key tokens of the first <= 3 levels
     const char* lev_start = p;
     for (;; ++p) {
       if (*p == '/' || *p == '\0') {
-        if (nlev < max_levels) {
-          auto it = sv_find(tokens,
-              std::string_view(lev_start, static_cast<size_t>(p - lev_start)));
-          row[nlev] = it == tokens.end() ? kUnkTok : it->second;
+        if (nlev < max_levels || nlev < 3) {
+          std::string_view lev(lev_start, static_cast<size_t>(p - lev_start));
+          auto it = sv_find(tokens, lev);
+          const int32_t tok = it == tokens.end() ? kUnkTok : it->second;
+          if (nlev < max_levels) row[nlev] = tok;
+          // a literal "+" level names the PLUS partitions, as the Python
+          // walk's string equality has it (no valid publish carries one)
+          if (nlev < 3) kt[nlev] = lev == "+" ? kPlusTok : tok;
         }
         ++nlev;
         if (*p == '\0') break;
@@ -156,25 +284,25 @@ int64_t rt_enc_encode(void* h, const char* blob, int64_t n, int32_t max_levels,
     for (int32_t i = nlev; i < max_levels; ++i) row[i] = kPadTok;
     tlen[j] = nlev;
     tdollar[j] = topic_start[0] == '$' ? 1 : 0;
-    std::string_view topic(topic_start, static_cast<size_t>(p - topic_start));
-    auto it = sv_find(cache, prefix_key(topic));
-    if (it == cache.end()) {
-      cand_counts[j] = -1;
-      group[j] = -1;
-      miss_idx[misses++] = static_cast<int32_t>(j);
+    const int32_t kl = nlev < 3 ? nlev : 3;
+    int32_t* out = cand + j * nc_cap;
+    auto [it, fresh] = seen.try_emplace(PartKey{kl, kt[0], kt[1], kt[2]},
+                                        static_cast<int32_t>(j));
+    int32_t c;
+    if (fresh) {
+      c = walk_parts(enc, kt, kl, out, nc_cap);
     } else {
-      const auto& chunks = it->second.chunks;
-      int32_t c = static_cast<int32_t>(chunks.size());
-      cand_counts[j] = c;
-      group[j] = it->second.gid;
-      int32_t w = c < nc_cap ? c : nc_cap;
-      int32_t* out = cand + j * nc_cap;
-      std::memcpy(out, chunks.data(), static_cast<size_t>(w) * sizeof(int32_t));
-      for (int32_t i = w; i < nc_cap; ++i) out[i] = 0;
+      c = cand_counts[it->second];
+      std::memcpy(out, cand + static_cast<int64_t>(it->second) * nc_cap,
+                  static_cast<size_t>(c < nc_cap ? c : nc_cap) * sizeof(int32_t));
     }
+    for (int32_t i = c; i < nc_cap; ++i) out[i] = 0;
+    cand_counts[j] = c;
+    group[j] = it->second;
+    if (c > max_count) max_count = c;
     ++p;  // skip '\0'
   }
-  return misses;
+  return max_count;
 }
 
 }  // extern "C"

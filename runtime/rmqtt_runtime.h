@@ -21,14 +21,15 @@ int64_t rt_trie_match_batch(void* trie, const char* blob, int64_t n,
 // encode.cc — batched publish-topic encoder
 void* rt_enc_new();
 void rt_enc_free(void* enc);
-void rt_enc_add_token(void* enc, const char* s, int32_t len, int32_t id);
-void rt_enc_cache_clear(void* enc);
-int32_t rt_enc_cache_put(void* enc, const char* key, int32_t keylen,
-                         const int32_t* chunks, int32_t n);
-int64_t rt_enc_encode(void* enc, const char* blob, int64_t n, int32_t max_levels,
+int64_t rt_enc_add_tokens(void* enc, const char* blob, int64_t blob_len,
+                          int32_t first_id);
+void rt_enc_parts_clear(void* enc);
+int64_t rt_enc_parts_put(void* enc, const char* keys, int64_t keys_len, int64_t n,
+                         const int32_t* counts, const int32_t* chunks,
+                         int32_t append);
+int32_t rt_enc_encode(void* enc, const char* blob, int64_t n, int32_t max_levels,
                       int32_t* ttok, int32_t* tlen, uint8_t* tdollar, int32_t nc_cap,
-                      int32_t* cand, int32_t* cand_counts, int32_t* group,
-                      int32_t* miss_idx);
+                      int32_t* cand, int32_t* cand_counts, int32_t* group);
 int64_t rt_match_decode(const int32_t* wi, const uint32_t* wb, int64_t b,
                         int64_t k, const int32_t* chunk_ids, int64_t nc,
                         int32_t wpc, int32_t chunk, const int64_t* fid_map,

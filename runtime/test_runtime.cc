@@ -47,29 +47,102 @@ static void test_trie() {
 
 static void test_encoder() {
   void* e = rt_enc_new();
-  rt_enc_add_token(e, "sensor", 6, 10);
-  rt_enc_add_token(e, "", 0, 11);  // empty level token
-  int32_t chunks[3] = {1, 2, 3};
-  rt_enc_cache_put(e, "sensor/a/b", 10, chunks, 3);
+  // ids 10.. : "sensor", "", "a", "b"
+  assert(rt_enc_add_tokens(e, "sensor//a/b/", 12, 10) == 4);
+  // the partition maps of a hand-written table: each key's segments (the
+  // Python tuple's kind and levels), every one followed by '/'
+  std::string keys;
+  std::vector<int32_t> counts, chunks;
+  auto put = [&](const char* k, std::vector<int32_t> ids) {
+    keys += k;
+    counts.push_back(static_cast<int32_t>(ids.size()));
+    chunks.insert(chunks.end(), ids.begin(), ids.end());
+  };
+  put("#/", {7});                // bare '#': every topic
+  put("2/sensor/", {3});         // sensor/#
+  put("4/sensor/+/b/", {5});     // sensor/+/b...
+  put("4/+/a/+/", {9, 5});       // +/a/+...
+  put("H3/sensor/a/", {2});      // sensor/a/#
+  put("2E//b/", {4});            // '/b' exactly (empty first level)
+  put("1/+/", {6});              // '+'
+  put("4/a/a/a/", {8});          // never consulted below
+  auto flush = [&](int32_t append) {
+    int64_t rc = rt_enc_parts_put(e, keys.data(), static_cast<int64_t>(keys.size()),
+                                  static_cast<int64_t>(counts.size()), counts.data(),
+                                  chunks.data(), append);
+    keys.clear();
+    counts.clear();
+    chunks.clear();
+    return rc;
+  };
+  assert(flush(0) == 8);
+  // shared chunks arrive in a second, appending call (7 repeats '#''s
+  // chunk: first occurrence wins in the walk); an empty append is a no-op
+  put("2/sensor/", {7});
+  put("1/+/", {});
+  assert(flush(1) == 2);
+  put("9/x/", {1});
+  assert(flush(0) == -1);  // unknown kind
+  put("1/nosuch/", {1});
+  assert(flush(0) == -1);  // unknown level
+  put("#/", {7});
+  put("4/a/a", {1});
+  assert(flush(0) == -2);  // stream ends inside key 1
+  put("1/a/a/", {1});
+  assert(flush(0) == -2);  // bytes left over after the last key
+
   std::string blob;
-  blob += "sensor/a/b/c/d";  // cached prefix
-  blob.push_back('\0');
-  blob += "unknown/levels/here";  // miss
-  blob.push_back('\0');
-  blob += "";  // empty topic
-  blob.push_back('\0');
-  const int64_t n = 3;
+  auto topic = [&](const char* t) {
+    blob += t;
+    blob.push_back('\0');
+  };
+  topic("sensor/a/b/c/d");       // 0: walk order: # 7 | 2/sensor 3 | H3 2 | 4/sensor/+/b 5 | 4/+/a/+ 9
+  topic("unknown/levels/here");  // 1: only '#'
+  topic("");                     // 2: one empty level: '#', 1/+
+  topic("/b");                   // 3: '#', 2E//b
+  topic("sensor/a/b/zzz");       // 4: same prefix as 0: same row, same group
+  topic("$sys");                 // 5: unknown single level
+  topic("sensor");               // 6: '#', 2/sensor, 1/+
+  const int64_t n = 7;
   const int32_t lvl = 8, cap = 4;
-  std::vector<int32_t> ttok(n * lvl), tlen(n), cand(n * cap), cnt(n), grp(n), miss(n);
+  std::vector<int32_t> ttok(n * lvl), tlen(n), cand(n * cap, -1), cnt(n), grp(n);
   std::vector<uint8_t> dollar(n);
-  int64_t misses = rt_enc_encode(e, blob.data(), n, lvl, ttok.data(), tlen.data(),
-                                 dollar.data(), cap, cand.data(), cnt.data(),
-                                 grp.data(), miss.data());
-  assert(misses == 2);
-  assert(tlen[0] == 5 && cnt[0] == 3);
-  assert(ttok[0] == 10);
-  assert(grp[0] == 0 && grp[1] == -1 && grp[2] == -1);  // gid of the put entry
-  rt_enc_cache_clear(e);
+  int32_t mx = rt_enc_encode(e, blob.data(), n, lvl, ttok.data(), tlen.data(),
+                             dollar.data(), cap, cand.data(), cnt.data(), grp.data());
+  assert(mx == 5);  // topic 0 overflows cap 4: true count, row truncated
+  assert(tlen[0] == 5 && cnt[0] == 5);
+  assert(ttok[0] == 10 && ttok[1] == 12 && ttok[2] == 13 && ttok[3] == 3 && ttok[5] == 0);
+  const int32_t want0[4] = {7, 3, 2, 5};
+  assert(std::memcmp(&cand[0], want0, sizeof want0) == 0);
+  assert(cnt[1] == 1 && cand[cap] == 7 && cand[cap + 1] == 0);
+  assert(tlen[2] == 1 && cnt[2] == 2 && cand[2 * cap] == 7 && cand[2 * cap + 1] == 6);
+  assert(tlen[3] == 2 && cnt[3] == 2 && cand[3 * cap] == 7 && cand[3 * cap + 1] == 4);
+  assert(cnt[4] == 5 && std::memcmp(&cand[4 * cap], want0, sizeof want0) == 0);
+  assert(grp[0] == 0 && grp[4] == 0 && grp[1] == 1 && grp[2] == 2 && grp[3] == 3);
+  assert(dollar[5] == 1 && dollar[0] == 0 && cnt[5] == 2);
+  assert(cnt[6] == 3 && cand[6 * cap] == 7 && cand[6 * cap + 1] == 3 && cand[6 * cap + 2] == 6);
+  // retry with room: the whole row, in the table's order
+  const int32_t cap2 = 8;
+  std::vector<int32_t> cand2(n * cap2, -1);
+  mx = rt_enc_encode(e, blob.data(), n, lvl, ttok.data(), tlen.data(), dollar.data(),
+                     cap2, cand2.data(), cnt.data(), grp.data());
+  const int32_t want0b[8] = {7, 3, 2, 5, 9, 0, 0, 0};
+  assert(mx == 5 && std::memcmp(&cand2[0], want0b, sizeof want0b) == 0);
+  // a count of 0 erases a key; the walk follows
+  put("2/sensor/", {});
+  assert(flush(0) == 1);
+  mx = rt_enc_encode(e, blob.data(), n, lvl, ttok.data(), tlen.data(), dollar.data(),
+                     cap2, cand2.data(), cnt.data(), grp.data());
+  assert(mx == 4 && cand2[1] == 2 && cnt[6] == 2);
+  // fewer levels than the key needs (max_levels 1) still walks 3 levels
+  std::vector<int32_t> ttok1(n);
+  mx = rt_enc_encode(e, blob.data(), n, 1, ttok1.data(), tlen.data(), dollar.data(),
+                     cap2, cand2.data(), cnt.data(), grp.data());
+  assert(mx == 4 && ttok1[0] == 10 && tlen[0] == 5);
+  rt_enc_parts_clear(e);
+  mx = rt_enc_encode(e, blob.data(), n, lvl, ttok.data(), tlen.data(), dollar.data(),
+                     cap2, cand2.data(), cnt.data(), grp.data());
+  assert(mx == 0 && cnt[0] == 0 && cand2[0] == 0);
   rt_enc_free(e);
 }
 

@@ -175,45 +175,240 @@ def test_jit_signature_stability_under_churn():
     assert grown <= 4, f"churn thrashed XLA compiles: {grown} new signatures"
 
 
-def test_native_encode_matches_python_path():
-    """The C++ encoder (runtime/encode.cc) must agree bit-for-bit with the
-    Python encode path on tokens, lengths, $-flags and candidate chunks."""
-    import random
+def _native_or_skip(table):
+    """The table's native encoder (built on first encode), or skip."""
+    table.encode_topics(["warm"])
+    if not table._nenc:
+        pytest.skip("native runtime unavailable")
+    return table._nenc
 
-    import numpy as np
 
-    from rmqtt_tpu.core.topic import filter_valid
+def _encode_both(table, topics, pad=None):
+    """Encode ``topics`` natively, then through ``_encode_py`` on the same
+    table state (the plain reference); assert the encode tuples are
+    bit-identical and return both (with groups)."""
+    enc = table._nenc
+    assert enc, "native encoder not serving"
+    native = table.encode_topics(topics, pad_batch_to=pad, with_groups=True)
+    table._nenc = False
+    try:
+        py = table.encode_topics(topics, pad_batch_to=pad, with_groups=True)
+    finally:
+        table._nenc = enc
+    for a, b, name in zip(native[:4], py[:4], ["ttok", "tlen", "tdollar", "chunk_ids"]):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        assert np.array_equal(a, b), name
+    assert native[4] == py[4]
+    return native, py
 
+
+def _mixed_stream():
     rng = random.Random(11)
-    table = PartitionedTable()
+    filters = set()
     words = ["a", "b", "c", "", "+", "sensor", "ünïcode"]
-    n = 0
-    while n < 500:
+    while len(filters) < 500:
         levels = [rng.choice(words) for _ in range(rng.randint(1, 6))]
         if rng.random() < 0.25:
             levels[-1] = "#"
         f = "/".join(levels)
         if filter_valid(f):
-            table.add(f)
-            n += 1
+            filters.add(f)
     topics = [
         "/".join(rng.choice(["a", "b", "c", "", "sensor", "ünïcode", "$sys"]) for _ in range(rng.randint(1, 6)))
         for _ in range(200)
     ] + ["$sys/x", "", "a"]
-    native = table.encode_topics(topics, pad_batch_to=256)
-    if table._nenc in (None, False):
-        import pytest
+    return sorted(filters), topics
 
-        pytest.skip("native runtime unavailable")
-    # force the pure-python path on the same table
+
+def _single_plus_stream():
+    """cfg2's shape: depth 3-5 filters with one '+', and a publish stream in
+    which (nearly) every topic has a 3-level prefix of its own — plus the
+    short, '$', empty-level and unknown-token topics the walk must get
+    right without a cache in front of it."""
+    rng = random.Random(7)
+    filters = set()
+    while len(filters) < 1500:
+        depth = rng.randint(3, 5)
+        levels = [f"l{d}n{rng.randrange(40 * (d + 1))}" for d in range(depth)]
+        levels[rng.randrange(depth)] = "+"
+        filters.add("/".join(levels))
+    filters = sorted(filters)
+    # short / wildcard-first / '#' / '$' filters so every partition kind exists
+    filters += ["#", "+", "l0n1", "l0n1/#", "+/#", "l0n2/l1n3", "+/l1n3", "l0n2/+",
+                "l0n2/l1n3/#", "+/+/#", "$SYS/#", "$SYS/+/x", "/+/x", "//", "l0n1//l2n5"]
+    topics = []
+    for i in range(400):
+        f = rng.choice(filters[:1500]).split("/")
+        topics.append("/".join(
+            f"l{d}n{rng.randrange(40 * (d + 1))}" if lev == "+" else lev
+            for d, lev in enumerate(f)))
+    topics += ["l0n1", "l0n2/l1n3", "nosuch", "nosuch/l1n3", "l0n2/nosuch/l2n1",
+               "nosuch/never/seen/before", "$SYS/broker/x", "$SYS", "", "/", "//",
+               "/l1n3/x", "l0n1//l2n5", "l0n1/l1n1/", "+/l1n3", "l0n2/+/x", "#",
+               "l0n1/l1n2/l2n3/l3n4/l4n5/l5n6/l6n7/l7n8/l8n9/deeper/than/max_levels"]
+    return filters, topics
+
+
+@pytest.mark.parametrize("stream", [_mixed_stream, _single_plus_stream],
+                         ids=["mixed", "single_plus_all_new_prefixes"])
+def test_native_encode_matches_python_path(stream):
+    """The C++ encoder (runtime/encode.cc) must agree bit-for-bit with the
+    Python encode path on tokens, lengths, $-flags and candidate chunks —
+    with nearly every topic a never-seen 3-level prefix as much as on a
+    stream that repeats them."""
+    filters, topics = stream()
+    table = PartitionedTable()
+    for f in filters:
+        table.add(f)
+    _native_or_skip(table)
+    _encode_both(table, topics, pad=512)
+    _encode_both(table, topics[:7])  # unpadded, second pass over seen prefixes
+    table.compact()
+    _encode_both(table, topics, pad=512)
+
+
+def test_native_encode_follows_mutations():
+    """The native partition mirror is synced lazily: an encode after every
+    kind of mutation must see it (a stale mirror fails the parity)."""
+    table = PartitionedTable()
+    table.compact_async = False
+    base = [table.add(f) for f in ("a/b/c", "a/+/c", "+/b/#", "x/y", "#")]
+    _native_or_skip(table)
+    topics = ["a/b/c", "a/q/c", "z/b/c/d", "x/y", "x", "big/k/7", "big/k/9/z", "n/e/w"]
+    _encode_both(table, topics)
+    # add: a brand-new partition, and one more row in an existing one
+    new = [table.add("n/e/w"), table.add("a/b/c/d")]
+    nat, _ = _encode_both(table, topics)
+    assert nat[3][topics.index("n/e/w")].any()
+    # remove: the partition's last shared row goes, its chunk list empties
+    table.remove(new[0])
+    table.remove(base[3])
+    _encode_both(table, topics)
+    # many new one-row partitions, then one partition that outgrows its
+    # shared chunks and migrates to an exclusive one (_alloc_row)
+    big = [table.add(f"big/k/{i}") for i in range(CHUNK + 3)]
+    _encode_both(table, topics)
+    wide = [table.add(f"w/w/w/{i}") for i in range(CHUNK + 3)]
+    assert table._excl_chunks.get(("4", "w", "w", "w")), "no shared->exclusive migration"
+    assert ("4", "w", "w", "w") not in table._shared_chunks_of
+    _encode_both(table, topics + ["w/w/w/5", "w/w/w"])
+    # frees inside the exclusive chunk, then re-adds
+    for fid in wide[:40]:
+        table.remove(fid)
+    _encode_both(table, topics + ["w/w/w/5"])
+    # compaction install: wholesale relayout, chunk ids all change
+    epoch = table.layout_epoch
+    table.compact()
+    assert table.layout_epoch == epoch + 1
+    _encode_both(table, topics + ["w/w/w/77"])
+    for fid in big[:10]:
+        table.remove(fid)
+    table.add("after/compact/x")
+    _encode_both(table, topics + ["after/compact/x"])
+    # force_full_refresh bumps the epoch without moving a row
+    table.force_full_refresh()
+    _encode_both(table, topics)
+
+
+def test_native_encode_groups():
+    """``groups``: equal gid ⇒ equal candidate row; padded rows 0; real rows
+    positive. (Native ids are batch-local, python's come from its cache, so
+    only the contract is compared, not the ids.)"""
+    filters, topics = _single_plus_stream()
+    table = PartitionedTable()
+    for f in filters:
+        table.add(f)
+    _native_or_skip(table)
+    topics = topics + topics[:50] + ["l0n1/l1n1/l2n1/a", "l0n1/l1n1/l2n1/b", "nosuch/x", "other/x"]
+    for enc in _encode_both(table, topics, pad=1024):
+        chunk_ids, groups = np.asarray(enc[3]), np.asarray(enc[5])
+        assert groups.shape == (1024,)
+        assert (groups[: len(topics)] > 0).all() and (groups[len(topics):] == 0).all()
+        first = {}
+        for j, g in enumerate(groups[: len(topics)].tolist()):
+            assert np.array_equal(chunk_ids[j], chunk_ids[first.setdefault(g, j)]), (j, g)
+    nat = table.encode_topics(topics, with_groups=True)[5]
+    # repeats of a prefix share a gid (the grouped upload's whole point)
+    assert nat[len(topics) - 4] == nat[len(topics) - 3]
+    for j in range(50):
+        assert nat[400 + 18 + j] == nat[j]
+
+
+def test_encode_counters_and_call_counts():
+    """``encode_host_resolved`` stays 0 under the native encoder however
+    many prefixes are new, and counts every topic under ``_encode_py``; a
+    native encode makes a number of native calls that does not depend on
+    the batch; a subscribe or unsubscribe makes none."""
+    filters, topics = _single_plus_stream()
+    table = PartitionedTable()
+    for f in filters:
+        table.add(f)
+    real = _native_or_skip(table)
+
+    class Counting:
+        """Stub encoder: counts every call that reaches the native side."""
+
+        def __init__(self):
+            self.calls = []
+
+        def __getattr__(self, name):
+            attr = getattr(real, name)
+            if not callable(attr):
+                return attr
+
+            def call(*a, **k):
+                self.calls.append(name)
+                return attr(*a, **k)
+
+            return call
+
+        def __setattr__(self, name, value):
+            if name == "calls":
+                object.__setattr__(self, name, value)
+            else:
+                setattr(real, name, value)
+
+    stub = Counting()
+    table._nenc = stub
+    t0, h0 = table.encode_topics_total, table.encode_host_resolved
+    table.encode_topics(topics, pad_batch_to=512)  # all-new prefixes
+    assert stub.calls == ["encode"], stub.calls
+    assert table.encode_topics_total - t0 == len(topics)
+    assert table.encode_host_resolved == h0
+    # mutations: no native call, whatever they touch
+    stub.calls = []
+    fids = [table.add(f"churn/{i}/+") for i in range(200)]
+    for fid in fids[:100]:
+        table.remove(fid)
+    table.add("brand/new/token/levels")
+    assert stub.calls == []
+    # the next encode syncs the new tokens in one call and the dirty
+    # partitions in two (exclusive chunks, then shared), whatever their number
+    table.encode_topics(topics, pad_batch_to=512)
+    assert stub.calls == ["add_tokens", "parts_put", "parts_put", "encode"], stub.calls
+    stub.calls = []
+    table.encode_topics(topics[:3])
+    table.encode_topics(topics * 3)
+    assert stub.calls == ["encode", "encode"], stub.calls
+    assert table.encode_host_resolved == h0
+    # the python fallback resolves every topic on the host
     table._nenc = False
-    table._cand_cache.clear()
-    table._cand_keys_of.clear()
-    py = table.encode_topics(topics, pad_batch_to=256)
-    names = ["ttok", "tlen", "tdollar", "chunk_ids"]
-    for a, b, name in zip(native[:4], py[:4], names):
-        assert np.array_equal(np.asarray(a), np.asarray(b)), name
-    assert native[4] == py[4]
+    t1 = table.encode_topics_total
+    table.encode_topics(topics)
+    assert table.encode_topics_total - t1 == len(topics)
+    assert table.encode_host_resolved - h0 == len(topics)
+
+
+def test_native_encode_survives_nul_in_filter_levels():
+    """Token and partition sync are length-delimited: a filter level holding
+    a NUL (nothing validates it away) must not shift any other token's id."""
+    table = PartitionedTable()
+    table.add("a\x00b/c/d")
+    table.add("x/y/z")
+    _native_or_skip(table)
+    nat, _ = _encode_both(table, ["x/y/z", "q/y/z"])
+    assert nat[3][0].any() and not nat[3][1].any()
 
 
 def test_pallas_kernel_interpret_matches_lax():
