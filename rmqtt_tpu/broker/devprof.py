@@ -55,6 +55,7 @@ so per-matcher registries would double-count shared compilations.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import logging
 import os
@@ -67,6 +68,18 @@ from rmqtt_tpu.broker.telemetry import Histogram, prom_sanitize
 from rmqtt_tpu.broker.tracing import CURRENT_TRACE
 
 _LOG = logging.getLogger("rmqtt_tpu.devprof")
+
+
+class NeedsCompile(Exception):
+    """A jit seam met a shape key the registry has never seen on a thread
+    that may not compile (``DeviceProfiler.compiles("forbid")``): the
+    caller answers the batch some other way and has the program compiled
+    off the routing path (``ops/hybrid.py``)."""
+
+    def __init__(self, kernel: str, key: Tuple) -> None:
+        super().__init__(f"{kernel}: no program for this shape key yet")
+        self.sig = (kernel, key)
+
 
 DUMP_SCHEMA = "rmqtt_tpu.devprof_dump/1"
 
@@ -163,6 +176,7 @@ class DeviceProfiler:
         #: annotate (wired by ServerContext); None outside a broker
         self.telemetry = None
         self._lock = threading.Lock()
+        self._tls = threading.local()  # per thread: the rule of compiles()
         self._reset_state(ring)
 
     def _reset_state(self, ring: int) -> None:
@@ -247,6 +261,25 @@ class DeviceProfiler:
 
         return tuple(k(a) for a in args) + tuple(
             (n, k(v)) for n, v in sorted(kwargs.items()))
+
+    @contextlib.contextmanager
+    def compiles(self, rule: str):
+        """What a never-seen shape key does on this thread inside the
+        block: ``"forbid"`` — the seam raises ``NeedsCompile`` in place of
+        compiling (the routing path of a hybrid that can answer from its
+        host mirror); ``"off_path"`` — it compiles, and the time is not the
+        routing path's (no ``matcher.compile`` stage). Outside any block a
+        seam compiles where it stands, as a matcher called directly does."""
+        tls = self._tls
+        old = getattr(tls, "rule", None)
+        tls.rule = rule
+        try:
+            yield
+        finally:
+            tls.rule = old
+
+    def compile_rule(self) -> Optional[str]:
+        return getattr(self._tls, "rule", None)
 
     def seen(self, kernel: str, key: Tuple) -> bool:
         """Has this jit signature been through ``note_jit``? A never-seen

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import asyncio
 import time
+from collections import deque
 from dataclasses import dataclass, field, replace
 from typing import Dict, Optional, Tuple
 
@@ -25,7 +26,8 @@ from rmqtt_tpu.broker.delayed import parse_delayed
 from rmqtt_tpu.broker.fitter import Limits
 from rmqtt_tpu.broker.hooks import HookType
 from rmqtt_tpu.broker.inflight import InInflight, MomentStatus, OutEntry, OutInflight
-from rmqtt_tpu.broker.queue import DeliverQueue, Policy
+from rmqtt_tpu.broker.queue import DeliverQueue, Hold, Policy
+from rmqtt_tpu.broker.telemetry import PROFILER
 from rmqtt_tpu.broker.tracing import CURRENT_TRACE
 from rmqtt_tpu.broker.types import (
     ConnectInfo,
@@ -139,6 +141,9 @@ class Session:
         # _fence_kicked guards the racing repair paths)
         self.fence: tuple = (0, id.node_id)
         self._fence_kicked = False
+        # stall timer of the publishes held on this session's deliver queue
+        # (_enqueue_crowded); None while none is held
+        self._hold_timer: Optional[asyncio.TimerHandle] = None
 
     # ---------------------------------------------------------------- fanout
     def enqueue(self, item: DeliverItem) -> None:
@@ -182,32 +187,120 @@ class Session:
         if (dur is not None and item.qos > 0 and item.did == 0
                 and self.limits.session_expiry > 0):
             item.did = dur.on_enqueue(self.client_id, item)
-        policy = Policy.DROP_CURRENT if item.qos == 0 and self.connected else Policy.DROP_EARLY
         if self.ctx.telemetry.enabled:
             item.t_enq = time.perf_counter_ns()
-        dropped = self.deliver_queue.push(item, policy)
-        if dropped is not None:
-            self.ctx.metrics.drop("queue_full")
-            hk = self.ctx.hotkeys
-            if hk.enabled:
-                hk.on_drop("queue_full", self.client_id)
-            if dur is not None and dropped.did:
-                # a terminal drop resolves the pending record, or recovery
-                # would resurrect a message the broker chose to shed
-                dur.on_ack(self.client_id, dropped.did)
-            asyncio.get_running_loop().create_task(
-                self.ctx.hooks.fire(HookType.MESSAGE_DROPPED, self.id, dropped.msg, "queue-full")
-            )
+        # the one compare the common case pays: a queue at most half full
+        # takes the entry as it is
+        q = self.deliver_queue
+        n = len(q)
+        if n > q.half:
+            self._enqueue_crowded(item, q, n)
+        else:
+            q.put(item)
         if not self.connected:
             asyncio.get_running_loop().create_task(
                 self.ctx.hooks.fire(HookType.OFFLINE_MESSAGE, self.id, item.msg, None)
             )
+
+    def _enqueue_crowded(self, item: DeliverItem, q: DeliverQueue, n: int) -> None:
+        """``enqueue`` into a queue more than half full (counted: the
+        benchmark's ``deliver.queue_over_half_share_pct``), and what a FULL
+        queue does. A named departure from `rmqtt/src/queue.rs`, which drops:
+
+        A QoS1/2 delivery for a connected session goes in past the limit
+        on behalf of its publish's ``Hold`` (``SessionState.hold``): the
+        publisher's PUBACK/PUBREC waits until this queue is back under its
+        limit, when every publish held on it is released together —
+        backpressure from the subscriber's drain rate to a closed-loop or
+        Receive-Maximum-bound publisher, so an acked message is never paid
+        for with a queued one; and publishers that were blocked anyway come
+        back side by side, as one batch for the routing service. Only the ack is
+        held, never a read loop: the publisher's connection goes on reading
+        (its own PUBACKs among them, so a client subscribed to what it
+        publishes, or two that feed each other, keep draining the queues
+        they wait on).
+
+        Upstream's policy stays the last resort: QoS0 (``DROP_CURRENT``), an
+        offline session (``DROP_EARLY``), a publish with no ack to hold
+        (wills, delayed and injected publishes, another node's), a publisher
+        with ``max_inflight`` acks held already, and a consumer that took
+        nothing for a whole retry interval of the outbound window
+        (``_holds_due``) — one dead subscriber cannot stall a fleet."""
+        self.ctx.metrics.inc("deliver.queue_over_half")
+        if n < q.maxlen:
+            q.put(item)
+            return
+        if item.qos and self.connected and not q.stalled:
+            msg = item.msg
+            pub = (self.ctx.registry.get(msg.from_id.client_id)
+                   if msg.from_id is not None else None)
+            st = pub.state if pub is not None else None
+            hold = st.hold(msg) if st is not None else None
+            if hold is not None:
+                q.push_over(item, hold)
+                if self._hold_timer is None:
+                    self._hold_timer = asyncio.get_running_loop().call_later(
+                        self.out_inflight.retry_interval, self._holds_due,
+                        q.progress)
+                return
+        policy = Policy.DROP_CURRENT if item.qos == 0 and self.connected else Policy.DROP_EARLY
+        dropped = q.push(item, policy)
+        if dropped is not None:
+            self._queue_full_drop(dropped)
+
+    def _queue_full_drop(self, dropped: DeliverItem) -> None:
+        self.ctx.metrics.drop("queue_full")
+        hk = self.ctx.hotkeys
+        if hk.enabled:
+            hk.on_drop("queue_full", self.client_id)
+        dur = self.ctx.durability
+        if dur is not None and dropped.did:
+            # a terminal drop resolves the pending record, or recovery
+            # would resurrect a message the broker chose to shed
+            dur.on_ack(self.client_id, dropped.did)
+        asyncio.get_running_loop().create_task(
+            self.ctx.hooks.fire(HookType.MESSAGE_DROPPED, self.id, dropped.msg, "queue-full")
+        )
+
+    def _holds_due(self, seen: int) -> None:
+        """The stall timer of the held publishes, one retry interval after
+        it was armed with the queue's ``progress`` then. Progress since:
+        look again in another interval (so a consumer is given up between
+        one and two intervals after its last pop). None: the consumer is
+        stalled — its holds are released (the publishers get their acks),
+        the queue is cut back to its limit oldest first, counted as
+        ``queue_full``, and until its next pop a full queue drops again."""
+        self._hold_timer = None
+        q = self.deliver_queue
+        if not q._holds:
+            return
+        if q.progress != seen:
+            self._hold_timer = asyncio.get_running_loop().call_later(
+                self.out_inflight.retry_interval, self._holds_due, q.progress)
+            return
+        q.stalled = True
+        self._drop_holds()
+
+    def _drop_holds(self) -> None:
+        q = self.deliver_queue
+        q.release_all()
+        for it in q.trim():
+            self._queue_full_drop(it)
 
     # --------------------------------------------------------------- offline
     def on_disconnect(self, clean: bool, kicked: bool = False) -> None:
         """Socket gone: schedule will + expiry (session.rs:405-494)."""
         self.connected = False
         self.state = None
+        # publishes held on this queue wait for a consumer that is gone:
+        # release them; a session that lives on offline keeps its limit
+        if self._hold_timer is not None:
+            self._hold_timer.cancel()
+            self._hold_timer = None
+        if self.limits.session_expiry > 0:
+            self._drop_holds()
+        else:
+            self.deliver_queue.release_all()
         # durability: anchor the expiry countdown so a broker restart
         # resumes the remaining window instead of a fresh one
         dur = self.ctx.durability
@@ -406,6 +499,7 @@ class SessionState:
         # telemetry is disabled — the t0 guard means it's never called)
         self._rec_e2e = ctx.telemetry.recorder("publish.e2e")
         self._rec_dqwait = ctx.telemetry.recorder("deliver.queue_wait")
+        self._rec_hold = ctx.telemetry.recorder("fanout.hold")
         # busy-clock stages of this connection's share of the served path
         # (telemetry.Stage; every begin/end below guards on tele.enabled)
         stage = ctx.telemetry.stage
@@ -417,6 +511,15 @@ class SessionState:
         self._st_ack_out = stage("ack.out")
         # clock of the last publish.e2e close: ack.out opens on it
         self._t_e2e_end = 0
+        # backpressure from full deliver queues (Session._enqueue_crowded):
+        # the QoS1/2 message this connection is fanning out right now, the
+        # Hold a full queue made for it, and the acks that wait — in the
+        # order their publishes came, each behind its hold (None: it only
+        # waits its turn) — for the one task that sends them
+        self._pub_msg: Optional[Message] = None
+        self._hold: Optional[Hold] = None
+        self._held_acks: deque = deque()
+        self._held_task: Optional[asyncio.Task] = None
         # packets a client pipelined behind CONNECT in the same TCP segment
         # (legal without waiting for CONNACK); replayed by _read_loop
         self.early_packets: list = []
@@ -512,6 +615,8 @@ class SessionState:
         finally:
             for t in tasks + [closer]:
                 t.cancel()
+            if self._held_task is not None:
+                self._held_task.cancel()
             if wheel_entry is not None:
                 wheel.disarm(wheel_entry)
             try:
@@ -988,6 +1093,9 @@ class SessionState:
             if dur is not None and s.limits.session_expiry > 0:
                 dur.on_qos2_open(s.client_id, p.packet_id)
         accepted, reason = await self._publish(p, tok)
+        hold = self._hold  # made by a full deliver queue of the fan-out
+        if hold is not None:
+            self._hold = None
         if p.qos == 2 and not accepted:
             # refused: clear the dedup entry — in memory AND in the
             # journal (before the barrier), so a restored stale entry can
@@ -1016,12 +1124,73 @@ class SessionState:
         # barrier suspended in between
         ack = (pk.Puback if p.qos == 1 else pk.Pubrec)(
             p.packet_id, reason if self.codec.version == pk.V5 else 0)
+        if hold is not None or self._held_acks:
+            # held for deliver-queue room, or behind an ack that is: the
+            # read loop goes on, _send_held_acks sends it in its turn
+            self._defer_ack(hold, self.codec.encode(ack))
+            return 0
         st = self._st_ack_out
         tok = 0
         if self.ctx.telemetry.enabled:
             tok = st.begin() if barrier else st.begin_at(self._t_e2e_end)
         await self._send_in_stage(self.codec.encode(ack), st, tok)
         return 0
+
+    # ------------------------------------------- deliver-queue backpressure
+    def hold(self, msg: Message) -> Optional[Hold]:
+        """For ``Session._enqueue_crowded``: the Hold of ``msg`` if it is
+        the QoS1/2 publish this connection is fanning out (made on the
+        first call), else None — as when ``max_inflight`` (the Receive
+        Maximum this broker grants) of its acks are held already: a client
+        that publishes on regardless is not slowed by one ack more."""
+        if msg is not self._pub_msg:
+            return None
+        h = self._hold
+        if h is None:
+            if len(self._held_acks) >= self.s.limits.max_inflight:
+                return None
+            span = None
+            if PROFILER.on:
+                # not a Stage section (it spans awaits, and holds overlap):
+                # its own annotation, closed where the hold ends
+                span = PROFILER.annotation("rmqtt/fanout.hold")
+                span.__enter__()
+            h = self._hold = Hold(time.perf_counter_ns(), span)
+            self.ctx.metrics.inc("fanout.held")
+        return h
+
+    def _defer_ack(self, hold: Optional[Hold], frame: bytes) -> None:
+        self._held_acks.append((hold, frame))
+        if self._held_task is None:
+            self._held_task = asyncio.get_running_loop().create_task(
+                self._send_held_acks(), name=f"held-acks:{self.s.client_id}")
+
+    async def _send_held_acks(self) -> None:
+        """Send the held acks in publish order, each when its hold is
+        released (the queues it overfilled are back under their limits,
+        or gave their consumer up). An ack leaves the chain only once sent,
+        so a later publish's ack queues behind it."""
+        chain = self._held_acks
+        tele = self.ctx.telemetry
+        st = self._st_ack_out
+        try:
+            while chain:
+                hold, frame = chain[0]
+                if hold is not None:
+                    await hold.wait()
+                    hold.end_span()
+                    if tele.enabled:
+                        self._rec_hold(time.perf_counter_ns() - hold.t0, None, None)
+                tok = st.begin() if tele.enabled else 0
+                await self._send_in_stage(frame, st, tok)
+                chain.popleft()
+        except OSError:
+            pass  # the socket went away: run() is closing the connection
+        finally:
+            self._held_task = None
+            for hold, _frame in chain:  # cancelled: the connection closes
+                if hold is not None:
+                    hold.end_span()
 
     async def _publish(self, p: pk.Publish, tok: int = 0) -> Tuple[bool, int]:
         """The ingress pipeline (session.rs _publish :966-1064); ``tok`` is
@@ -1067,7 +1236,11 @@ class SessionState:
             self._st_publish.end(tok)
         if verdict is not None:
             return verdict
+        if p.qos:
+            # a full deliver queue may hold this publish's ack (hold())
+            self._pub_msg = msg
         count = await self.ctx.registry.forwards(msg)
+        self._pub_msg = None
         if count == 0:
             await self.ctx.hooks.fire(HookType.MESSAGE_NONSUBSCRIBED, self.s.id, msg, None)
             return True, RC_NO_MATCHING_SUBSCRIBERS

@@ -35,6 +35,10 @@ class SessionRegistry:
         # out-fences both partition-era owners.
         self._fence_epoch = 0
         self._st_fanout = ctx.telemetry.stage("fanout.enqueue")
+        # the deliver-queue backpressure counters (Session._enqueue_crowded)
+        # exist from the start: a reader tells "never" from "no such counter"
+        for name in ("fanout.enqueues", "fanout.held", "deliver.queue_over_half"):
+            ctx.metrics.inc(name, 0)
 
     # ------------------------------------------------------------- fencing
     @property
@@ -255,6 +259,8 @@ class SessionRegistry:
                                              rel.opts, msg, wire_cache, trace)
         if tok:
             self._st_fanout.end(tok)
+        if count:
+            self.ctx.metrics.inc("fanout.enqueues", count)
         return count
 
     def _deliver_local(

@@ -83,6 +83,7 @@ STAGES = (
     "routing.batch_size",  # dispatch batch-size distribution (count, not ns)
     "deliver.queue_wait",  # Session.enqueue → deliver_queue.pop() per item
     "deliver.ack_rtt",     # QoS1/2 delivery → PUBACK/PUBCOMP round trip
+    "fanout.hold",         # a publish's ack held for deliver-queue room → released
     "kernel.dispatch",     # router kernel/trie match call (native/xla)
 )
 
@@ -123,7 +124,8 @@ _WAITS = frozenset(("deliver.credit_wait",))
 # histograms whose cumulative bucket counts ride the flat stats body, so a
 # reader can take the quantile of a WINDOW (the *_p99_ms gauges are since
 # process start and cannot be subtracted)
-WINDOW_HISTS = ("routing.queue_wait", "deliver.queue_wait", "publish.e2e")
+WINDOW_HISTS = ("routing.queue_wait", "deliver.queue_wait", "publish.e2e",
+                "fanout.hold")
 
 # recorder buffer fold threshold: big enough to amortize the fold loop,
 # small enough that a mid-burst fold stall is microseconds

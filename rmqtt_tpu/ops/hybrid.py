@@ -19,7 +19,16 @@ Policy:
   EMA, so regime changes (table growth, a busier host or device) flip
   the routing within a bounded number of batches;
 - with no device matcher (or no trie side) the surviving path serves
-  everything.
+  everything;
+- no compile on the routing path: while the hybrid may choose (adaptivity
+  on, which needs the native mirror) a large batch whose device program
+  has no compiled shape yet — a never-seen padded size, candidate-count
+  bucket or regrown slot budget, by the device profiler's shape-key
+  registry — is answered by the trie, and the same batch is run through
+  the matcher on a thread of its own, once, against the live table, which
+  compiles what it needs; later batches of that shape go to the device.
+  No list made at start can be the right one: the table's chunk count
+  changes during a load and slot budgets are discovered by traffic.
 
 ``match_submit``/``match_complete`` preserve the device path's pipelining
 (dispatch N+1 overlaps compute N) — the bench and the RoutingService both
@@ -28,13 +37,24 @@ drive it; trie-served batches complete synchronously inside submit.
 
 from __future__ import annotations
 
+import contextlib
+import logging
 import threading
 import time
 from typing import List, Optional, Sequence
 
 import numpy as np
 
+from rmqtt_tpu.broker.devprof import DEVPROF as _DEVPROF, NeedsCompile
 from rmqtt_tpu.utils.failpoints import FAILPOINTS
+
+_LOG = logging.getLogger("rmqtt_tpu.ops")
+
+
+def _padded(n: int) -> int:
+    """The device matcher's batch padding (the next power of two)."""
+    return 1 << (n - 1).bit_length() if n > 1 else n
+
 
 EMA_ALPHA = 0.3  # weight of the newest rate sample
 
@@ -55,7 +75,7 @@ class AdaptiveHybrid:
         self.small_max = small_max
         self.probe_every = probe_every
         self._rate = {"side": None, "device": None}  # EMA topics/s
-        self._n_large = 0
+        self.large_batches = 0
         self._dev_samples = 0  # first device sample includes XLA compile
         self._last_dev_complete = None  # for pipelined-rate attribution
         # which backend served the most recent synchronous match — read by
@@ -71,6 +91,13 @@ class AdaptiveHybrid:
         # path to refresh its EMA — what decides who serves, in numbers
         self.regime_jumps = {"side": 0, "device": 0}
         self.probes = {"side": 0, "device": 0}
+        # large batches [batches, topics] the trie answered because their
+        # device program was not compiled yet (of ``large_batches`` large ones)
+        self.compiling_side = [0, 0]
+        # programs being compiled off the routing path: shape key → the
+        # batch that met it, run by one worker thread at a time
+        self._to_compile: dict = {}
+        self._compiler: Optional[threading.Thread] = None
         # busy-clock stages (broker/telemetry.py Stage), wired by the
         # router; the match entry points take ``staged`` from their caller
         self._st_side = self._st_dev = None
@@ -112,9 +139,13 @@ class AdaptiveHybrid:
             c[0] += 1
             c[1] += n
 
-    def _side_match(self, topics: Sequence[str],
-                    staged: bool = False) -> List[np.ndarray]:
+    def _side_match(self, topics: Sequence[str], staged: bool = False,
+                    compiling: bool = False) -> List[np.ndarray]:
         self._note("side", len(topics))
+        if compiling:
+            with self._lock:
+                self.compiling_side[0] += 1
+                self.compiling_side[1] += len(topics)
         # one clock pair: the ``routing.match.side`` stage when staged (the
         # rate sample below reuses its reads), else the rate sample's own
         tok = self._st_side.begin(len(topics)) if staged else 0
@@ -134,16 +165,24 @@ class AdaptiveHybrid:
 
     def _device_match(self, topics: Sequence[str],
                       staged: bool = False) -> List[np.ndarray]:
-        self._note("device", len(topics))
+        if self._is_compiling(len(topics)):
+            return self._side_match(topics, staged, compiling=True)
         if _FP_DISPATCH.action is not None:
             _FP_DISPATCH.fire_sync()
         tok = self._st_dev.begin(len(topics)) if staged else 0
         t0 = time.perf_counter()
         try:
-            rows = self.matcher.match(topics)
+            with self._no_compile():
+                rows = self.matcher.match(topics)
+        except NeedsCompile as e:
+            rows = None
+            self._compile_off_path(e.sig, topics)
         finally:
             if tok:
                 self._st_dev.end(tok)
+        if rows is None:
+            return self._side_match(topics, staged, compiling=True)
+        self._note("device", len(topics))
         if _FP_COMPLETE.action is not None:
             _FP_COMPLETE.fire_sync()
         with self._lock:
@@ -151,18 +190,76 @@ class AdaptiveHybrid:
             self._last_dev_complete = time.perf_counter()
         return rows
 
+    # ------------------------------------------- no compile on the path
+    def _no_compile(self):
+        """The rule a device call of the routing path runs under: hand a
+        never-compiled shape back (``NeedsCompile``) where the trie can
+        answer in its place and the hybrid is free to choose — with
+        adaptivity off large batches are PINNED to the device, compile and
+        all (``RMQTT_HYBRID_MAX=0``, ``RMQTT_HYBRID_ADAPT=0``); and the
+        shape-key registry is the device profiler's, so without it nothing
+        can be told."""
+        if (self.side is not None and self.probe_every > 0
+                and _DEVPROF.enabled):
+            return _DEVPROF.compiles("forbid")
+        return contextlib.nullcontext()
+
+    def _is_compiling(self, n: int) -> bool:
+        """Does a batch of this padded size wait to be compiled already? A
+        second one is then not encoded just to learn the same."""
+        if not self._to_compile:
+            return False
+        with self._lock:
+            return any(_padded(len(t)) == _padded(n)
+                       for t in self._to_compile.values())
+
+    def _compile_off_path(self, sig, topics: Sequence[str]) -> None:
+        """Have the program ``sig`` compiled, once: the batch that met it
+        joins the worker's list (one thread, started here when none runs,
+        gone when the list is empty)."""
+        with self._lock:
+            if sig in self._to_compile:
+                return
+            self._to_compile[sig] = list(topics)
+            if self._compiler is None:
+                self._compiler = threading.Thread(
+                    target=self._compile_loop, name="rmqtt-compile",
+                    daemon=True)
+                self._compiler.start()
+
+    def _compile_loop(self) -> None:
+        while True:
+            with self._lock:
+                if not self._to_compile:
+                    self._compiler = None
+                    return
+                sig, topics = next(iter(self._to_compile.items()))
+            try:
+                # the whole round trip: what the submit half needs and what
+                # the complete half regrows both compile here. The result
+                # is dropped, and no rate sample is taken: compile seconds
+                # say nothing of what a batch costs
+                with _DEVPROF.compiles("off_path"):
+                    self.matcher.match(topics)
+            except Exception:
+                _LOG.exception("compiling %s off the routing path failed; "
+                               "the next batch of its shape tries again",
+                               sig[0])
+            with self._lock:
+                del self._to_compile[sig]
+
     def _pick(self) -> str:
         """Route a large batch; probes keep the loser's EMA fresh."""
         if self.probe_every <= 0:
             return "device"  # adaptivity off: fixed size threshold only
         with self._lock:
-            self._n_large += 1
+            self.large_batches += 1
             s, d = self._rate["side"], self._rate["device"]
             if d is None:
                 return "device"
             if s is None:
                 return "side"
-            if self._n_large % self.probe_every == 0:
+            if self.large_batches % self.probe_every == 0:
                 slower = "side" if s < d else "device"  # probe the slower path
                 self.probes[slower] += 1
                 return slower
@@ -208,31 +305,52 @@ class AdaptiveHybrid:
             and self._pick() == "device"
         ):
             if hasattr(self.matcher, "match_submit"):
-                self._note("device", len(topics))
+                if self._is_compiling(len(topics)):
+                    return ("sync", self._side_match(topics, staged, True))
                 if _FP_DISPATCH.action is not None:
                     _FP_DISPATCH.fire_sync()
                 tok = self._st_dev.begin(len(topics)) if staged else 0
                 try:
-                    payload = self.matcher.match_submit(topics)
+                    with self._no_compile():
+                        payload = self.matcher.match_submit(topics)
+                except NeedsCompile as e:
+                    payload = None
+                    self._compile_off_path(e.sig, topics)
                 finally:
                     if tok:
                         self._st_dev.lap(tok)
-                return ("device", payload, len(topics), time.perf_counter())
+                if payload is None:
+                    return ("sync", self._side_match(topics, staged, True))
+                self._note("device", len(topics))
+                return ("device", payload, topics, time.perf_counter())
             return ("sync", self._device_match(topics, staged))
         return ("sync", self._side_match(topics, staged))
 
     def match_complete(self, handle, staged: bool = False) -> List[np.ndarray]:
         if handle[0] == "sync":
             return handle[1]
-        _kind, payload, n, t_submit = handle
+        _kind, payload, topics, t_submit = handle
+        n = len(topics)
         if _FP_COMPLETE.action is not None:
             _FP_COMPLETE.fire_sync()
         tok = self._st_dev.begin(n) if staged else 0
         try:
-            rows = self.matcher.match_complete(payload)
+            with self._no_compile():
+                rows = self.matcher.match_complete(payload)
+        except NeedsCompile as e:
+            # the batch overflowed its slot budget and the regrown program
+            # is not compiled: the device's answer is dropped for the trie's
+            rows = None
+            self._compile_off_path(e.sig, topics)
         finally:
             if tok:
                 self._st_dev.end(tok)
+        if rows is None:
+            with self._lock:  # submit counted it for the device
+                c = self.served["device"]
+                c[0] -= 1
+                c[1] -= n
+            return self._side_match(topics, staged, compiling=True)
         now = time.perf_counter()
         with self._lock:
             last = self._last_dev_complete

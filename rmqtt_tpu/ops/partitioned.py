@@ -50,7 +50,7 @@ _FP_UPLOAD = FAILPOINTS.register("device.upload")
 #: device-plane profiler (broker/devprof.py): every jit entry seam below
 #: reports hit-vs-trace through it when enabled; call sites guard on
 #: ``_DEVPROF.enabled`` so the disabled cost is one attribute check
-from rmqtt_tpu.broker.devprof import DEVPROF as _DEVPROF
+from rmqtt_tpu.broker.devprof import DEVPROF as _DEVPROF, NeedsCompile
 from rmqtt_tpu.broker.telemetry import Stage as _Stage
 
 _STAGE_KEYS = ("encode", "dispatch", "fetch", "decode")
@@ -63,7 +63,7 @@ def _pj(kernel: str, fn, *args, **kwargs):
     never-seen key is a trace+compile by construction and the timed wall
     of that first call brackets its cost (jit traces synchronously): that
     call is the ``matcher.compile`` stage (broker/telemetry.py), on the
-    same clock pair.
+    same clock pair — where it compiles on the caller's own path.
 
     ``_key_extra`` (reserved, not forwarded to ``fn``) appends static
     state that is baked into the CALLABLE rather than its arguments —
@@ -73,8 +73,15 @@ def _pj(kernel: str, fn, *args, **kwargs):
     key = _DEVPROF.key_of(args, kwargs)
     if extra is not None:
         key = key + (extra,)
+    # a never-seen key on a thread that may not compile (the hybrid's
+    # routing path) is handed back; one compiled off the path is no time of
+    # the path's (DeviceProfiler.compiles)
+    fresh = not _DEVPROF.seen(kernel, key)
+    rule = _DEVPROF.compile_rule() if fresh else None
+    if rule == "forbid":
+        raise NeedsCompile(kernel, key)
     tele = _DEVPROF.telemetry
-    if tele is not None and tele.enabled and not _DEVPROF.seen(kernel, key):
+    if fresh and rule is None and tele is not None and tele.enabled:
         st = tele.stage("matcher.compile")
         tok = st.begin()
         try:
@@ -1783,6 +1790,8 @@ class PartitionedMatcher:
         # slots) inflate every later 1-topic match's fetch to megabytes —
         # the low-load p99 path must keep its own small budget
         self._budgets: Dict[Tuple[int, int], int] = {}
+        # slots a topic a new shape's budget starts with; _regrown raises it
+        self._slots_per_topic = 4
         # NC split-dispatch (RMQTT_NC_SPLIT=0 disables): bucket big batches
         # by candidate count so padding chunks stop dominating device compute
         self._split = os.environ.get("RMQTT_NC_SPLIT", "1") != "0"
@@ -1862,6 +1871,12 @@ class PartitionedMatcher:
         """Count the four sections into ``tele``'s ``matcher.*`` stages."""
         self._stages = {k: tele.stage("matcher." + k) for k in _STAGE_KEYS}
 
+    def _timed(self) -> bool:
+        """Are this call's four sections the routing path's? Not where the
+        batch only runs to compile its programs off the path
+        (``ops/hybrid.py``): its seconds are compile time, no batch's cost."""
+        return self.stage_timing and _DEVPROF.compile_rule() != "off_path"
+
     @property
     def stage_ns(self) -> Dict[str, int]:
         """Cumulative ns per section (encode / dispatch / fetch / decode)."""
@@ -1888,6 +1903,9 @@ class PartitionedMatcher:
             # bench builds one per config) must not pay the compile again
             self.pallas_why = _PALLAS_WHY
             return _PALLAS_RACED
+        if _DEVPROF.compile_rule() == "forbid":
+            # the verify and the race below compile both producers
+            raise NeedsCompile("words_pallas_verify", chunk_ids.shape)
         layout = self._dev_playout
         self._pallas_interpret = platform != "tpu"
         args = (dev, ttok, tlen, tdollar, chunk_ids)
@@ -2298,13 +2316,18 @@ class PartitionedMatcher:
             # executor threads) must not cross-attribute their padding
             _meta["padded"] = padded
         st = self._stages
-        tok = st["encode"].begin(b) if self.stage_timing else 0
+        tok = st["encode"].begin(b) if self._timed() else 0
         want_groups = self.compact_mode == "global"
         while True:
             enc, enc_epoch = t.encode_topics_versioned(
                 topics, pad_batch_to=padded, with_groups=want_groups
             )
-            dev = self._refresh()
+            try:
+                dev = self._refresh()
+            except NeedsCompile:  # a delta scatter of a never-seen shape
+                if tok:
+                    st["encode"].end(tok)
+                raise
             if self._dev_epoch != enc_epoch:
                 # a compaction installed between the encode and the device
                 # refresh: the chunk ids reference the OLD layout while the
@@ -2451,8 +2474,23 @@ class PartitionedMatcher:
     def _budget_for(self, padded: int, nc: int) -> int:
         g = self._budgets.get((padded, nc))
         if g is None:
-            g = max(256, 1 << (4 * padded - 1).bit_length())
+            g = max(256, 1 << (self._slots_per_topic * padded - 1).bit_length())
             self._budgets[(padded, nc)] = g
+        return g
+
+    def _regrown(self, n: int, b: int, padded: int) -> int:
+        """The slot budget of a shape whose batch of ``b`` topics (padded to
+        ``padded``) made ``n`` routes, more than it had slots for. Every
+        budget is a program of its own, so a shape should reach its budget
+        in one step, not one compile per doubling as its batches fill up:
+        a bucket at least half full asks for what a FULL one would need at
+        its rate; and what a topic of a real batch needed is where the next
+        new shape starts (``_budget_for``) instead of at 4 slots a topic."""
+        if 2 * b > padded:
+            n = -(-n * padded // b)
+        g = 1 << max(8, (n - 1).bit_length())
+        if b >= 64:  # a lone topic's fan-out says little of a batch's
+            self._slots_per_topic = max(self._slots_per_topic, g // padded)
         return g
 
     # ------------------------------------------------- fused pipeline
@@ -2610,37 +2648,42 @@ class PartitionedMatcher:
         (dev, fdev, tt, tlen, tdollar, chunk_ids, grouped, lay,
          use_pallas) = rerun
         st = self._stages
-        tok = st["fetch"].begin(b) if self.stage_timing else 0
-        while True:
-            arr = fetch(packed, "fused match fetch")
-            cn = arr[g:].astype(np.int64)
-            n = int(cn.sum())
-            if n <= g:
-                break
-            g = 1 << max(8, (n - 1).bit_length())
-            key = (chunk_ids.shape[0], chunk_ids.shape[1])
-            self._budgets[key] = max(self._budgets.get(key, 0), g)
-            prof = _DEVPROF.enabled
-            if grouped is None:
-                packed = (
-                    _pj("match_fused", _match_fused, dev, fdev, tt, tlen,
-                        tdollar, chunk_ids, budget=g, layout=lay,
-                        use_pallas=use_pallas,
-                        interpret=self._pallas_interpret)
-                    if prof else _match_fused(
-                        dev, fdev, tt, tlen, tdollar, chunk_ids, budget=g,
-                        layout=lay, use_pallas=use_pallas,
-                        interpret=self._pallas_interpret))
-            else:
-                packed = (
-                    _pj("match_fused_grouped", _match_fused_grouped, dev,
-                        fdev, tt, tlen, tdollar, *grouped, budget=g,
-                        layout=lay, use_pallas=use_pallas,
-                        interpret=self._pallas_interpret)
-                    if prof else _match_fused_grouped(
-                        dev, fdev, tt, tlen, tdollar, *grouped, budget=g,
-                        layout=lay, use_pallas=use_pallas,
-                        interpret=self._pallas_interpret))
+        tok = st["fetch"].begin(b) if self._timed() else 0
+        try:
+            while True:
+                arr = fetch(packed, "fused match fetch")
+                cn = arr[g:].astype(np.int64)
+                n = int(cn.sum())
+                if n <= g:
+                    break
+                g = self._regrown(n, b, padded)
+                key = (chunk_ids.shape[0], chunk_ids.shape[1])
+                self._budgets[key] = max(self._budgets.get(key, 0), g)
+                prof = _DEVPROF.enabled
+                if grouped is None:
+                    packed = (
+                        _pj("match_fused", _match_fused, dev, fdev, tt, tlen,
+                            tdollar, chunk_ids, budget=g, layout=lay,
+                            use_pallas=use_pallas,
+                            interpret=self._pallas_interpret)
+                        if prof else _match_fused(
+                            dev, fdev, tt, tlen, tdollar, chunk_ids, budget=g,
+                            layout=lay, use_pallas=use_pallas,
+                            interpret=self._pallas_interpret))
+                else:
+                    packed = (
+                        _pj("match_fused_grouped", _match_fused_grouped, dev,
+                            fdev, tt, tlen, tdollar, *grouped, budget=g,
+                            layout=lay, use_pallas=use_pallas,
+                            interpret=self._pallas_interpret)
+                        if prof else _match_fused_grouped(
+                            dev, fdev, tt, tlen, tdollar, *grouped, budget=g,
+                            layout=lay, use_pallas=use_pallas,
+                            interpret=self._pallas_interpret))
+        except NeedsCompile:  # a regrown budget has no program yet
+            if tok:
+                st["fetch"].end(tok)
+            raise
         if tok:
             # one clock read closes fetch and opens decode
             tok = st["decode"].begin_at(abs(tok) + st["fetch"].end(tok))
@@ -2669,34 +2712,39 @@ class PartitionedMatcher:
         _tag, b, order, meta, parts, ctx, packed, budgets = handle
         dev, fdev, lay = ctx
         st = self._stages
-        tok = st["fetch"].begin(b) if self.stage_timing else 0
-        while True:
-            arr = fetch(packed, "fused match fetch")
-            segs = []
-            regrow = list(budgets)
-            ok = True
-            o = 0
-            for bi, ((s, pb, tier), g) in enumerate(zip(meta, budgets)):
-                fid_seg = arr[o : o + g]
-                cn = arr[o + g : o + g + pb].astype(np.int64)
-                o += g + pb
-                segs.append((fid_seg, cn))
-                n = int(cn.sum())
-                if n > g:
-                    ok = False
-                    g2 = 1 << max(8, (n - 1).bit_length())
-                    regrow[bi] = g2
-                    self._budgets[(pb, tier)] = max(
-                        self._budgets.get((pb, tier), 0), g2)
-            if ok:
-                break
-            budgets = tuple(regrow)
-            packed = (
-                _pj("match_fused_split", _match_fused_split, dev, fdev,
-                    tuple(parts), budgets, layout=lay)
-                if _DEVPROF.enabled else
-                _match_fused_split(dev, fdev, tuple(parts), budgets,
-                                   layout=lay))
+        tok = st["fetch"].begin(b) if self._timed() else 0
+        try:
+            while True:
+                arr = fetch(packed, "fused match fetch")
+                segs = []
+                regrow = list(budgets)
+                ok = True
+                o = 0
+                for bi, ((s, pb, tier), g) in enumerate(zip(meta, budgets)):
+                    fid_seg = arr[o : o + g]
+                    cn = arr[o + g : o + g + pb].astype(np.int64)
+                    o += g + pb
+                    segs.append((fid_seg, cn))
+                    n = int(cn.sum())
+                    if n > g:
+                        ok = False
+                        g2 = self._regrown(n, s, pb)
+                        regrow[bi] = g2
+                        self._budgets[(pb, tier)] = max(
+                            self._budgets.get((pb, tier), 0), g2)
+                if ok:
+                    break
+                budgets = tuple(regrow)
+                packed = (
+                    _pj("match_fused_split", _match_fused_split, dev, fdev,
+                        tuple(parts), budgets, layout=lay)
+                    if _DEVPROF.enabled else
+                    _match_fused_split(dev, fdev, tuple(parts), budgets,
+                                       layout=lay))
+        except NeedsCompile:  # a regrown budget has no program yet
+            if tok:
+                st["fetch"].end(tok)
+            raise
         if tok:
             # one clock read closes fetch and opens decode
             tok = st["decode"].begin_at(abs(tok) + st["fetch"].end(tok))
@@ -2940,7 +2988,7 @@ class PartitionedMatcher:
                 n = int(cn.sum())
                 if n > g:
                     ok = False
-                    g2 = 1 << max(8, (n - 1).bit_length())
+                    g2 = self._regrown(n, s, pb)
                     regrow[bi] = g2
                     self._budgets[(pb, tier)] = max(
                         self._budgets.get((pb, tier), 0), g2
@@ -3123,41 +3171,46 @@ class PartitionedMatcher:
         _tag, b, chunk_ids, words, dev_inputs, packed, g, fid_base, snap = handle
         padded, nc = chunk_ids.shape
         st = self._stages
-        tok = st["fetch"].begin(b) if self.stage_timing else 0
-        while True:
-            # ONE fetch per match: [routes..., cnts...] (counts are
-            # truncation-exact, so overflow is detectable from the same
-            # array that carries the routes)
-            arr = fetch(packed, "match result fetch")
-            cn = arr[g:].astype(np.int64)
-            n = int(cn.sum())
-            if n <= g:
-                break
-            g = 1 << max(8, (n - 1).bit_length())
-            # sticky pow2 regrow for this batch shape
-            self._budgets[(padded, nc)] = max(self._budgets.get((padded, nc), 0), g)
-            prof = _DEVPROF.enabled
-            if words is not None:
-                packed = (_pj("compact_global", _compact_global, words,
-                              budget=g)
-                          if prof else _compact_global(words, budget=g))
-            else:
-                dev, ttok, tlen, tdollar, grouped, lay = dev_inputs
-                if grouped is None:
-                    packed = (
-                        _pj("match_global", _match_global, dev, ttok, tlen,
-                            tdollar, chunk_ids, budget=g, layout=lay)
-                        if prof else _match_global(
-                            dev, ttok, tlen, tdollar, chunk_ids, budget=g,
-                            layout=lay))
+        tok = st["fetch"].begin(b) if self._timed() else 0
+        try:
+            while True:
+                # ONE fetch per match: [routes..., cnts...] (counts are
+                # truncation-exact, so overflow is detectable from the same
+                # array that carries the routes)
+                arr = fetch(packed, "match result fetch")
+                cn = arr[g:].astype(np.int64)
+                n = int(cn.sum())
+                if n <= g:
+                    break
+                g = self._regrown(n, b, padded)
+                # sticky pow2 regrow for this batch shape
+                self._budgets[(padded, nc)] = max(self._budgets.get((padded, nc), 0), g)
+                prof = _DEVPROF.enabled
+                if words is not None:
+                    packed = (_pj("compact_global", _compact_global, words,
+                                  budget=g)
+                              if prof else _compact_global(words, budget=g))
                 else:
-                    packed = (
-                        _pj("match_global_grouped", _match_global_grouped,
-                            dev, ttok, tlen, tdollar, *grouped, budget=g,
-                            layout=lay)
-                        if prof else _match_global_grouped(
-                            dev, ttok, tlen, tdollar, *grouped, budget=g,
-                            layout=lay))
+                    dev, ttok, tlen, tdollar, grouped, lay = dev_inputs
+                    if grouped is None:
+                        packed = (
+                            _pj("match_global", _match_global, dev, ttok, tlen,
+                                tdollar, chunk_ids, budget=g, layout=lay)
+                            if prof else _match_global(
+                                dev, ttok, tlen, tdollar, chunk_ids, budget=g,
+                                layout=lay))
+                    else:
+                        packed = (
+                            _pj("match_global_grouped", _match_global_grouped,
+                                dev, ttok, tlen, tdollar, *grouped, budget=g,
+                                layout=lay)
+                            if prof else _match_global_grouped(
+                                dev, ttok, tlen, tdollar, *grouped, budget=g,
+                                layout=lay))
+        except NeedsCompile:  # a regrown budget has no program yet
+            if tok:
+                st["fetch"].end(tok)
+            raise
         if tok:
             # one clock read closes fetch and opens decode
             tok = st["decode"].begin_at(abs(tok) + st["fetch"].end(tok))

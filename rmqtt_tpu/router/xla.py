@@ -452,6 +452,11 @@ class XlaRouter(Router):
             # large batches sent to the slower path to refresh its EMA
             "hybrid_regime_jumps": dict(self._hybrid.regime_jumps),
             "hybrid_probes": dict(self._hybrid.probes),
+            # large batches the hybrid routed, and [batches, topics] of
+            # them the mirror answered because their device program was
+            # still being compiled off the routing path (ops/hybrid.py)
+            "hybrid_large_batches": self._hybrid.large_batches,
+            "hybrid_compiling_side": list(self._hybrid.compiling_side),
             "compile_cache": compile_cache_stats(),
         }
 

@@ -382,7 +382,11 @@ def test_e2e_slow_consumer_sheds_qos0_flow_controls_qos1():
             labeled = sum(v for k, v in m.items()
                           if k.startswith("messages.dropped."))
             assert m["messages.dropped"] == labeled
-            # QoS1 to the same slow consumer: accepted, flow-controlled
+            # QoS1 to the same slow consumer: accepted, flow-controlled. Its
+            # queue is full and it takes nothing, so the first PUBACK is
+            # held for the outbound window's retry interval (shortened
+            # here) before the drop policy takes over again
+            ctx.registry.get("ov-sub").out_inflight.retry_interval = 0.2
             for _ in range(30):
                 await pub.publish("ov/t", b"q1", qos=1)
             sub = ctx.registry.get("ov-sub")
