@@ -524,6 +524,14 @@ class ServerContext:
             self.cfg.egress_coalesce
             and os.environ.get("RMQTT_EGRESS_COALESCE", "") != "0")
         self.egress_high_water = int(self.cfg.egress_high_water)
+        # the one flush per loop turn of every coalescing connection, and
+        # its native thread (started by the first job; none where the
+        # runtime library did not load)
+        self.egress_hub = None
+        if self.egress_coalesce:
+            from rmqtt_tpu.broker.egress import EgressHub
+
+            self.egress_hub = EgressHub(self.telemetry)
         self.keepalive_wheel = None
         if (self.cfg.keepalive_wheel
                 and os.environ.get("RMQTT_KEEPALIVE_WHEEL", "") != "0"):
@@ -761,6 +769,8 @@ class ServerContext:
             await self.durability.stop()
         if self.keepalive_wheel is not None:
             await self.keepalive_wheel.stop()
+        if self.egress_hub is not None:
+            self.egress_hub.close()
         await self.autotune.stop()
         await self.slo.stop()
         await self.overload.stop()
@@ -860,6 +870,15 @@ class ServerContext:
         s.net_egress_bytes = self.metrics.get("net.egress_bytes")
         s.net_egress_coalesced = self.metrics.get("net.egress_coalesced")
         s.net_egress_drains = self.metrics.get("net.egress_drains")
+        # of the flushes, those the native egress thread wrote (off the
+        # loop) and those it handed back in part; the thread's own clock
+        s.net_egress_offloop_flushes = self.metrics.get(
+            "net.egress_offloop_flushes")
+        s.net_egress_offloop_partial = self.metrics.get(
+            "net.egress_offloop_partial")
+        if self.egress_hub is not None:
+            (s.egress_thread_busy_ms_total, s.egress_thread_sends,
+             s.egress_thread_jobs) = self.egress_hub.thread_stats()
         wheel = self.keepalive_wheel
         if wheel is not None:
             s.net_wheel_sessions = wheel.sessions

@@ -874,6 +874,8 @@ const KEYS=["connections","sessions","subscriptions","subscriptions_shared",
  "host_gc_pauses","host_gc_pause_ms_total","host_open_fds","host_threads",
  "net_egress_frames","net_egress_flushes","net_egress_bytes",
  "net_egress_coalesced","net_egress_drains",
+ "net_egress_offloop_flushes","net_egress_offloop_partial",
+ "egress_thread_busy_ms_total","egress_thread_sends","egress_thread_jobs",
  "net_wheel_sessions","net_wheel_timeouts",
  "routing_failover_state",
  "routing_failovers","routing_switchbacks","routing_failover_host_routed",

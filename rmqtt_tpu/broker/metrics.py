@@ -206,6 +206,9 @@ class Stats:
         # flushes = vectored writes issued (frames/flushes ≈ syscall
         # batching factor), coalesced = frames that shared a flush with an
         # earlier one, drains = high-water backpressure flushes;
+        # offloop_flushes = flushes the native egress thread wrote,
+        # offloop_partial = those it handed back in part (EAGAIN, a short
+        # write), egress_thread_* = that thread's busy clock, sends, jobs;
         # wheel_sessions = connections currently armed on the keepalive
         # wheel, wheel_timeouts = idle kills the wheel fired
         self.net_egress_frames = 0
@@ -213,6 +216,11 @@ class Stats:
         self.net_egress_bytes = 0
         self.net_egress_coalesced = 0
         self.net_egress_drains = 0
+        self.net_egress_offloop_flushes = 0
+        self.net_egress_offloop_partial = 0
+        self.egress_thread_busy_ms_total = 0.0
+        self.egress_thread_sends = 0
+        self.egress_thread_jobs = 0
         self.net_wheel_sessions = 0
         self.net_wheel_timeouts = 0
         # telemetry-history gauges (broker/history.py), filled by

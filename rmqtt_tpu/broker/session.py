@@ -524,9 +524,11 @@ class SessionState:
         # (legal without waiting for CONNACK); replayed by _read_loop
         self.early_packets: list = []
         # coalesced egress (broker/egress.py): one vectored send per loop
-        # tick instead of one write per frame. buffers_until_drain writers
-        # (WsWriter) stay on the legacy path — their transport only
-        # flushes on drain(), which the coalescer's tick flush never calls
+        # tick instead of one write per frame, made by the context's hub —
+        # on the native egress thread where the connection allows it.
+        # buffers_until_drain writers (WsWriter) stay on the legacy path —
+        # their transport only flushes on drain(), which the coalescer's
+        # tick flush never calls
         self._egress = None
         if (getattr(ctx, "egress_coalesce", False)
                 and not getattr(writer, "buffers_until_drain", False)):
@@ -535,7 +537,8 @@ class SessionState:
             self._egress = EgressBuf(
                 writer, ctx.metrics,
                 high_water=getattr(ctx, "egress_high_water", 64 * 1024),
-                telemetry=ctx.telemetry)
+                telemetry=ctx.telemetry,
+                hub=getattr(ctx, "egress_hub", None))
 
     # ------------------------------------------------------------------ io
     async def send(self, packet) -> None:
@@ -555,11 +558,12 @@ class SessionState:
         transport = getattr(self.writer, "transport", None)
         if eb is not None:
             # coalesced path: the frame joins the connection's per-tick
-            # vector; one call_soon flush hands everything queued this
-            # tick to the transport as a single vectored write. Past the
+            # vector; the hub's one flush per tick writes everything
+            # queued this tick as a single vectored write. Past the
             # high-water mark flush inline and drain — same backpressure
             # the legacy gate applied, now counting our own pending bytes
-            # too (the transport can't see frames still in the vector).
+            # too (the transport can't see frames still in the vector, nor
+            # those the native thread is writing).
             eb.feed(data)
             if transport is None:
                 eb.flush()
@@ -630,7 +634,8 @@ class SessionState:
                 pass
             if self._egress is not None:
                 # push any still-vectored frames (the kicked DISCONNECT
-                # above included) into the transport before close()
+                # above included) into the transport before close(), behind
+                # what the native thread is still writing (flush waits)
                 self._egress.flush()
                 self._egress.close()
             try:

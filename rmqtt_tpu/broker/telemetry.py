@@ -107,7 +107,7 @@ SERVED_STAGES = (
     "fanout.enqueue",        # SessionRegistry.forwards' _deliver_local loop
     "deliver.credit_wait",   # out_inflight.wait_credit(): a WAIT, not busy
     "deliver.send",          # _deliver: props, OutEntry, encode, feed
-    "egress.flush",          # EgressBuf.flush (one vectored write)
+    "egress.flush",          # a transport write, or a turn's hand-off (egress.py)
     "ack.in",                # subscriber PUBACK/PUBREC/PUBCOMP → window release
     "ack.out",               # publisher's PUBACK/PUBREC encode + send
 )

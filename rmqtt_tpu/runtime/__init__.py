@@ -59,7 +59,7 @@ def load() -> Optional[ctypes.CDLL]:
         return _lib
     if _build_failed:
         return None
-    srcs = [_RUNTIME_DIR / "topics.cc", _RUNTIME_DIR / "encode.cc", _RUNTIME_DIR / "codec.cc"]
+    srcs = [_RUNTIME_DIR / n for n in ("topics.cc", "encode.cc", "codec.cc", "egress.cc")]
     if not _LIB_PATH.exists() or any(
         s.exists() and s.stat().st_mtime > _LIB_PATH.stat().st_mtime for s in srcs
     ):
@@ -141,8 +141,30 @@ def load() -> Optional[ctypes.CDLL]:
         lib.rt_codec_encode_publish.restype = ctypes.c_int64
     lib.rt_topic_validate.argtypes = [ctypes.c_char_p, ctypes.c_int64, ctypes.c_int32]
     lib.rt_topic_validate.restype = ctypes.c_int
+    if hasattr(lib, "rt_egress_new"):  # absent in stale .so builds
+        _egress_protos(lib)
     _lib = lib
     return lib
+
+
+def _egress_protos(lib) -> None:
+    lib.rt_egress_new.restype = ctypes.c_void_p
+    lib.rt_egress_free.argtypes = [ctypes.c_void_p]
+    lib.rt_egress_free.restype = None
+    lib.rt_egress_eventfd.argtypes = [ctypes.c_void_p]
+    lib.rt_egress_eventfd.restype = ctypes.c_int32
+    lib.rt_egress_submit.argtypes = [
+        ctypes.c_void_p, ctypes.c_int64, ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_char_p), ctypes.POINTER(ctypes.c_int64),
+    ]
+    lib.rt_egress_submit.restype = ctypes.c_int64
+    lib.rt_egress_collect.argtypes = [
+        ctypes.c_void_p, ctypes.POINTER(ctypes.c_int64), ctypes.c_int64]
+    lib.rt_egress_collect.restype = ctypes.c_int64
+    lib.rt_egress_wait.argtypes = [ctypes.c_void_p, ctypes.c_int64, ctypes.c_int32]
+    lib.rt_egress_wait.restype = ctypes.c_int32
+    lib.rt_egress_stats.argtypes = [ctypes.c_void_p, ctypes.POINTER(ctypes.c_int64)]
+    lib.rt_egress_stats.restype = None
 
 
 CODEC_STRIDE = 10  # int64 slots per frame record (runtime/codec.cc)
@@ -253,6 +275,76 @@ class NativeTrie:
             rows.append(out[off : off + c].copy())
             off += c
         return rows
+
+
+class EgressThread:
+    """ctypes wrapper over the library's one thread (runtime/egress.cc): it
+    does the non-blocking socket writes of one job per event-loop turn
+    without ever taking the GIL. Every method is the event loop's to call.
+
+    ``submit`` / ``collect`` / ``stats`` are a copy and a mutex hold, so
+    they go through a ``PyDLL`` handle and keep the GIL (a ``CDLL`` call
+    drops and re-takes it, which on the saturated loop thread is the cost
+    this thread exists to remove); ``wait`` and ``close`` can block and
+    release it."""
+
+    _COLLECT_CAP = 1024
+
+    def __init__(self) -> None:
+        lib = load()
+        if lib is None or not hasattr(lib, "rt_egress_new"):
+            raise RuntimeError("native runtime without egress.cc")
+        self._lib = lib
+        held = ctypes.PyDLL(str(_LIB_PATH))  # the same mapping, GIL kept
+        _egress_protos(held)
+        self._submit = held.rt_egress_submit
+        self._collect = held.rt_egress_collect
+        self._stats = held.rt_egress_stats
+        ptr = lib.rt_egress_new()
+        if not ptr:
+            raise RuntimeError("rt_egress_new failed (eventfd / thread)")
+        self._ptr = ctypes.c_void_p(ptr)
+        self.eventfd = int(lib.rt_egress_eventfd(self._ptr))
+        self._rows = (ctypes.c_int64 * (3 * self._COLLECT_CAP))()
+
+    def close(self) -> None:
+        """Sends what is queued, joins the thread, closes the eventfd."""
+        ptr, self._ptr = self._ptr, None
+        if ptr:
+            self._lib.rt_egress_free(ptr)
+
+    __del__ = close
+
+    def submit(self, fds: Sequence[int], bufs: Sequence[bytes]) -> int:
+        """One job: ``bufs[i]`` to ``fds[i]``, in this order; the bytes are
+        copied before this returns. Each fd stays open, and out of any
+        other job, until its completion is collected. → the ticket of the
+        last entry (entry ``i``'s is ``ticket - n + i + 1``)."""
+        n = len(fds)
+        return self._submit(self._ptr, n, (ctypes.c_int32 * n)(*fds),
+                            (ctypes.c_char_p * n)(*bufs),
+                            (ctypes.c_int64 * n)(*map(len, bufs)))
+
+    def collect(self) -> List[Tuple[int, int, int]]:
+        """→ the (fd, bytes written, errno) posted since the last call."""
+        out: List[Tuple[int, int, int]] = []
+        cap = self._COLLECT_CAP
+        while True:
+            n = self._collect(self._ptr, self._rows, cap)
+            flat = self._rows[:3 * n]
+            out.extend(zip(flat[0::3], flat[1::3], flat[2::3]))
+            if n < cap:
+                return out
+
+    def wait(self, ticket: int, timeout_ms: int) -> bool:
+        """Block until the entry with ``ticket`` is posted."""
+        return bool(self._lib.rt_egress_wait(self._ptr, ticket, timeout_ms))
+
+    def stats(self) -> Tuple[int, int, int]:
+        """→ (busy ns, sends, jobs) of the thread since it started."""
+        out = (ctypes.c_int64 * 3)()
+        self._stats(self._ptr, out)
+        return out[0], out[1], out[2]
 
 
 def _i32p(a: np.ndarray):

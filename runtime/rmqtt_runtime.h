@@ -52,4 +52,14 @@ int64_t rt_codec_encode_publish(const uint8_t* topic, int64_t topic_len,
                                 int32_t packet_id, uint8_t* out, int64_t cap);
 int rt_topic_validate(const uint8_t* s, int64_t len, int is_filter);
 
+// egress.cc — off-loop socket writes (the library's one thread)
+void* rt_egress_new();
+void rt_egress_free(void* eg);
+int32_t rt_egress_eventfd(void* eg);
+int64_t rt_egress_submit(void* eg, int64_t n, const int32_t* fds,
+                         const uint8_t* const* bufs, const int64_t* lens);
+int64_t rt_egress_collect(void* eg, int64_t* out, int64_t cap);
+int32_t rt_egress_wait(void* eg, int64_t ticket, int32_t timeout_ms);
+void rt_egress_stats(void* eg, int64_t* out);
+
 }  // extern "C"

@@ -1,10 +1,19 @@
 // Sanitizer test driver for the native runtime (topics.cc, encode.cc,
-// codec.cc). Built with -fsanitize=address,undefined by `make sancheck`
-// (run from tests/test_native.py): exercises every C ABI entry point with
-// normal, boundary, and malformed inputs so leaks, overflows and UB are
+// codec.cc, egress.cc). Built with -fsanitize=address,undefined by `make
+// sancheck` and with -fsanitize=thread by `make tsancheck` (both run from
+// tests/test_native.py): exercises every C ABI entry point with normal,
+// boundary, and malformed inputs so leaks, overflows, UB and races are
 // caught even though the Python test suite runs against the unsanitized
-// library. Thread safety is external by contract (the GIL serializes
-// callers), so the sanitizer story is ASan/UBSan, not TSan.
+// library. Only egress.cc has a thread of its own (test_egress plays the
+// event loop against it); for the rest thread safety is external by
+// contract, so TSan has nothing to see there.
+
+#include <fcntl.h>
+#include <poll.h>
+#include <sys/socket.h>
+#include <unistd.h>
+
+#include <cerrno>
 
 #include <cassert>
 #include <cstdint>
@@ -278,7 +287,127 @@ static void test_codec() {
   assert(rt_topic_validate((const uint8_t*)"", 0, 1) == 0);
 }
 
+// One connection as the broker's loop sees it: a non-blocking stream
+// socket it writes (ours[0]) and the peer's end (ours[1]).
+static void make_pair(int sv[2], int sndbuf = 0) {
+  assert(socketpair(AF_UNIX, SOCK_STREAM, 0, sv) == 0);
+  assert(fcntl(sv[0], F_SETFL, O_NONBLOCK) == 0);
+  if (sndbuf) {
+    assert(setsockopt(sv[0], SOL_SOCKET, SO_SNDBUF, &sndbuf, sizeof sndbuf) == 0);
+  }
+}
+
+static std::string read_n(int fd, size_t n) {
+  std::string got(n, '\0');
+  size_t have = 0;
+  while (have < n) {
+    ssize_t r = read(fd, got.data() + have, n - have);
+    assert(r > 0);
+    have += static_cast<size_t>(r);
+  }
+  return got;
+}
+
+// Collect until `want` completions came, waking on the eventfd like the
+// loop's reader does.
+static std::vector<int64_t> collect_n(void* eg, size_t want) {
+  std::vector<int64_t> rows;
+  int64_t buf[3 * 8];  // a small cap, so the "call again" arm runs
+  while (rows.size() < 3 * want) {
+    pollfd p{rt_egress_eventfd(eg), POLLIN, 0};
+    assert(poll(&p, 1, 5000) >= 0);
+    int64_t n;
+    do {
+      n = rt_egress_collect(eg, buf, 8);
+      rows.insert(rows.end(), buf, buf + 3 * n);
+    } while (n == 8);
+  }
+  return rows;
+}
+
+static void test_egress() {
+  void* eg = rt_egress_new();
+  assert(eg);
+  assert(rt_egress_eventfd(eg) >= 0);
+  // 1) 32 connections, 20 jobs: every byte arrives, per connection in order
+  constexpr int N = 32, ROUNDS = 20;
+  int sv[N][2];
+  for (auto& p : sv) make_pair(p);
+  std::vector<std::string> sent(N);
+  for (int r = 0; r < ROUNDS; r++) {
+    std::vector<std::string> data(N);
+    int32_t fds[N];
+    const uint8_t* bufs[N];
+    int64_t lens[N];
+    for (int i = 0; i < N; i++) {
+      data[i] = "c" + std::to_string(i) + "r" + std::to_string(r) + "|" +
+                std::string(static_cast<size_t>(r * 7 + i), 'x') + ";";
+      fds[i] = sv[i][0];
+      bufs[i] = reinterpret_cast<const uint8_t*>(data[i].data());
+      lens[i] = static_cast<int64_t>(data[i].size());
+      sent[i] += data[i];
+    }
+    const int64_t ticket = rt_egress_submit(eg, N, fds, bufs, lens);
+    assert(ticket == static_cast<int64_t>(N) * (r + 1));
+    data.clear();  // the job owns a copy: ours may go at once
+    if (r % 3 == 0) assert(rt_egress_wait(eg, ticket - r % N, 5000) == 1);
+    auto rows = collect_n(eg, N);
+    for (size_t i = 0; i < N; i++) {
+      assert(rows[3 * i] == sv[i][0]);  // completions in job order
+      assert(rows[3 * i + 2] == 0);
+    }
+  }
+  for (int i = 0; i < N; i++) assert(read_n(sv[i][1], sent[i].size()) == sent[i]);
+  // 2) a peer that does not read, a small send buffer: a partial write (or
+  // EAGAIN once full) is reported and NOT retried
+  int slow[2];
+  make_pair(slow, 4096);
+  std::string big(1 << 20, 'z');
+  int32_t fd1 = slow[0];
+  const uint8_t* b1 = reinterpret_cast<const uint8_t*>(big.data());
+  int64_t l1 = static_cast<int64_t>(big.size());
+  rt_egress_submit(eg, 1, &fd1, &b1, &l1);
+  auto rows = collect_n(eg, 1);
+  assert(rows[0] == fd1 && rows[1] > 0 && rows[1] < l1 && rows[2] == 0);
+  rt_egress_submit(eg, 1, &fd1, &b1, &l1);
+  rows = collect_n(eg, 1);
+  assert(rows[1] == 0 && (rows[2] == EAGAIN || rows[2] == EWOULDBLOCK));
+  // 3) errors come back as errnos, one per connection, and the job goes on:
+  // a peer that went away gives EPIPE (and no SIGPIPE), a closed fd EBADF
+  int dead[2], gone[2];
+  make_pair(dead);
+  make_pair(gone);
+  close(dead[1]);
+  close(gone[0]), close(gone[1]);
+  int32_t fds3[3] = {dead[0], gone[0], sv[1][0]};
+  const uint8_t* b3[3] = {reinterpret_cast<const uint8_t*>("bye"),
+                          reinterpret_cast<const uint8_t*>("bye"),
+                          reinterpret_cast<const uint8_t*>("bye")};
+  int64_t l3[3] = {3, 3, 3};
+  const int64_t t3 = rt_egress_submit(eg, 3, fds3, b3, l3);
+  assert(rt_egress_wait(eg, t3, 5000) == 1);
+  assert(rt_egress_wait(eg, t3 - 2, 0) == 1);  // posted already: no wait
+  rows = collect_n(eg, 3);
+  assert(rows[0] == dead[0] && rows[1] == 0 && rows[2] == EPIPE);
+  assert(rows[3] == gone[0] && rows[4] == 0 && rows[5] == EBADF);
+  assert(rows[6] == sv[1][0] && rows[7] == 3 && rows[8] == 0);
+  assert(read_n(sv[1][1], 3) == "bye");
+  assert(rt_egress_wait(eg, t3 + 1, 10) == 0);  // never submitted: times out
+  int64_t st[3];
+  rt_egress_stats(eg, st);
+  assert(st[0] > 0 && st[1] == N * ROUNDS + 5 && st[2] == ROUNDS + 3);
+  // 4) free with a job still queued: it is sent before the thread ends
+  int32_t fd4 = sv[0][0];
+  rt_egress_submit(eg, 1, &fd4, b3, l3);
+  rt_egress_free(eg);
+  assert(read_n(sv[0][1], 3) == "bye");
+  for (auto& p : sv) close(p[0]), close(p[1]);
+  close(slow[0]), close(slow[1]), close(dead[0]);
+  rt_egress_free(nullptr);
+}
+
 int main() {
+  test_egress();
   test_trie();
   test_encoder();
   test_match_decode();

@@ -125,6 +125,22 @@ def test_every_acked_publish_reaches_every_matching_subscriber(seed):
     assert 0 < m["deliver.queue_over_half"] <= m["fanout.enqueues"]
 
 
+def test_acked_fanout_through_the_native_egress_thread(monkeypatch):
+    """The same fleet with every eligible flush written by the native egress
+    thread (``_MIN_JOB`` 1): held PUBACKs, deliveries and the consumers'
+    windows cross the hand-off, and still no acked publish is lost."""
+    from rmqtt_tpu.broker import egress
+
+    if not egress.EgressHub().native:
+        pytest.skip("native runtime (egress.cc) unavailable")
+    monkeypatch.setattr(egress, "_MIN_JOB", 1)
+    m, want, got = asyncio.run(asyncio.wait_for(_fleet(14), 100))
+    assert want - got == set() and got - want == set()
+    assert m.get("messages.dropped", 0) == 0
+    assert m.get("fanout.held", 0) > 0
+    assert m["net.egress_offloop_flushes"] > 0.9 * m["net.egress_flushes"]
+
+
 # ------------------------------------------------ (b) progress, no deadlock
 async def _feeders(pairs, n: int):
     """``pairs``: [(client id, the topic it subscribes to, the topic it

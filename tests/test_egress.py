@@ -8,18 +8,28 @@ the PUBLISH they follow — one FIFO vector serves the connection), the
 `RMQTT_EGRESS_COALESCE=0` / `[network]` kill-switch restoring the exact
 legacy byte stream, the slow-consumer drain gate still engaging, and
 `buffers_until_drain` writers (WsWriter) bypassing the coalescer so
-their flush-on-drain contract holds. The timer wheel must preserve
+their flush-on-drain contract holds. Since PR 28 a coalesced flush takes
+one of two paths, chosen per connection from what the hub observes: the
+asyncio transport ("loop"), or the native egress thread ("native": a plain
+stream socket, its transport idle, the runtime library loaded). The
+identity pins run over both, and the native path's own hazards (a partial
+write, a close or an error with a write in flight, an fd the kernel may
+reuse) have cases of their own. The timer wheel must preserve
 keepalive *semantics* (idle eviction, traffic re-arms, v5
 server-keep-alive override) while collapsing task count to O(1) per
 worker."""
 
 import asyncio
+import os
+import socket
+import struct
 
 import pytest
 
+from rmqtt_tpu.broker import egress as egress_mod
 from rmqtt_tpu.broker.codec import MqttCodec, packets as pk, props as P
 from rmqtt_tpu.broker.context import BrokerConfig, ServerContext
-from rmqtt_tpu.broker.egress import EgressBuf, KeepaliveWheel
+from rmqtt_tpu.broker.egress import EgressBuf, EgressHub, KeepaliveWheel
 from rmqtt_tpu.broker.metrics import Metrics
 from rmqtt_tpu.broker.server import MqttBroker
 
@@ -28,6 +38,40 @@ from tests.mqtt_client import TestClient
 
 def run_async(fn, timeout=30.0):
     asyncio.run(asyncio.wait_for(fn(), timeout=timeout))
+
+
+def _need_native():
+    if not EgressHub().native:
+        pytest.skip("native runtime (egress.cc) unavailable")
+
+
+@pytest.fixture(params=["loop", "native"])
+def path(request, monkeypatch):
+    """Which coalesced path a broker's plain-TCP sessions take. "loop": no
+    connection is eligible for the native thread, so every flush is the
+    hub's write through the asyncio transport (what TLS, a busy transport
+    or an absent library get). "native": every eligible flush goes to the
+    thread — ``_MIN_JOB`` 1, so a turn of a single connection too."""
+    if request.param == "native":
+        _need_native()
+        monkeypatch.setattr(egress_mod, "_MIN_JOB", 1)
+    else:
+        monkeypatch.setattr(egress_mod, "_offloop_fd", lambda writer: -1)
+    return request.param
+
+
+def _offloop(b) -> int:
+    return b.ctx.metrics.get("net.egress_offloop_flushes")
+
+
+def _check_path(b, path) -> None:
+    """The broker's flushes went where the case says they go."""
+    if path == "native":
+        assert _offloop(b) > 0, "no flush took the native thread"
+        assert _offloop(b) <= b.ctx.metrics.get("net.egress_flushes")
+    else:
+        assert _offloop(b) == 0
+        assert b.ctx.egress_hub.thread_stats() == (0.0, 0, 0)
 
 
 # ------------------------------------------------------------ EgressBuf
@@ -139,36 +183,60 @@ async def _raw_sub_stream(port, cid, topic, n_expect):
     return stream
 
 
-def test_coalesce_kill_switch_byte_identical():
-    """The same publish sequence produces the byte-identical subscriber
-    stream with the coalescer on (default) and off (`egress_coalesce`
-    false — the `RMQTT_EGRESS_COALESCE=0` path resolves into the same
-    ctx flag, pinned in test_kill_switch_env_overrides_conf below)."""
+async def _stream_leg(coalesce, n_subs=1, check=None):
+    """→ the raw broker→client streams of ``n_subs`` subscribers of one
+    topic over 20 publishes."""
+    b = MqttBroker(ServerContext(BrokerConfig(
+        port=0, egress_coalesce=coalesce)))
+    await b.start()
+    try:
+        tasks = [asyncio.create_task(
+            _raw_sub_stream(b.port, "ks-sub%d" % i, "ks/t", 20))
+            for i in range(n_subs)]
+        await asyncio.sleep(0.3)  # SUBSCRIBEs land before publishes
+        c = await TestClient.connect(b.port, "ks-pub")
+        for i in range(20):
+            await c.publish("ks/t", b"payload-%03d" % i, qos=0,
+                            wait_ack=False)
+        streams = [await asyncio.wait_for(t, 10.0) for t in tasks]
+        await c.disconnect_clean()
+        if check is not None:
+            _check_path(b, check)
+        return streams
+    finally:
+        await b.stop()
 
-    async def leg(coalesce):
-        b = MqttBroker(ServerContext(BrokerConfig(
-            port=0, egress_coalesce=coalesce)))
-        await b.start()
-        try:
-            task = asyncio.create_task(
-                _raw_sub_stream(b.port, "ks-sub", "ks/t", 20))
-            await asyncio.sleep(0.3)  # SUBSCRIBE lands before publishes
-            c = await TestClient.connect(b.port, "ks-pub")
-            for i in range(20):
-                await c.publish("ks/t", b"payload-%03d" % i, qos=0,
-                                wait_ack=False)
-            stream = await asyncio.wait_for(task, 10.0)
-            await c.disconnect_clean()
-            return stream
-        finally:
-            await b.stop()
+
+def test_coalesce_kill_switch_byte_identical(path):
+    """The same publish sequence produces the byte-identical subscriber
+    stream with the coalescer on (default; either path) and off
+    (`egress_coalesce` false — the `RMQTT_EGRESS_COALESCE=0` path resolves
+    into the same ctx flag, pinned in test_kill_switch_env_overrides_conf
+    below)."""
 
     async def run():
-        on = await leg(True)
-        off = await leg(False)
+        on = await _stream_leg(True, check=path)
+        off = await _stream_leg(False)
         assert on == off, "coalescer changed the wire bytes"
 
     run_async(run)
+
+
+def test_native_streams_identical_across_64_connections():
+    """A fan-out of 64: every turn hands the thread one job of many
+    connections (the default ``_MIN_JOB``). Each connection's stream is
+    byte for byte, frame after frame, what the legacy per-frame writer
+    puts on the wire."""
+    _need_native()
+
+    async def run():
+        native = await _stream_leg(True, 64, check="native")
+        legacy = await _stream_leg(False, 64)
+        assert len(native) == 64
+        for i, (a, b) in enumerate(zip(native, legacy)):
+            assert a == b, f"connection {i}: the stream changed"
+
+    run_async(run, timeout=60.0)
 
 
 def test_kill_switch_env_overrides_conf(monkeypatch):
@@ -188,10 +256,11 @@ def test_kill_switch_env_overrides_conf(monkeypatch):
     assert ctx.keepalive_wheel is not None
 
 
-def test_qos12_ack_flow_ordered_under_coalescer():
+def test_qos12_ack_flow_ordered_under_coalescer(path):
     """QoS1/2 control frames share the subscriber's coalesced vector with
     its PUBLISH deliveries: the full exactly-once flow must complete and
-    payload order must hold across flush ticks."""
+    payload order must hold across flush ticks (and across the frames that
+    wait in the vector behind a native write in flight)."""
 
     async def run():
         b = MqttBroker(ServerContext(BrokerConfig(port=0)))
@@ -210,13 +279,14 @@ def test_qos12_ack_flow_ordered_under_coalescer():
             await sub.expect_nothing()  # exactly once
             await sub.disconnect_clean()
             await pub.disconnect_clean()
+            _check_path(b, path)
         finally:
             await b.stop()
 
     run_async(run)
 
 
-def test_slow_consumer_still_drains():
+def test_slow_consumer_still_drains(path):
     """Regression for the send_raw high-water gate: the coalescer counts
     its own pending bytes plus the transport buffer, so a subscriber
     that stops reading still pushes the deliver loop into flush+drain()
@@ -246,6 +316,7 @@ def test_slow_consumer_still_drains():
                 "slow consumer never hit the drain gate"
             writer.close()
             await pub.disconnect_clean()
+            _check_path(b, path)
         finally:
             await b.stop()
 
@@ -276,10 +347,348 @@ def test_ws_writer_bypasses_coalescer():
             p = await asyncio.wait_for(ws.recv_packet(), 5.0)
             assert isinstance(p, pk.Publish) and p.payload == b"over-ws"
             await tcp.disconnect_clean()
+            assert _offloop(b) == 0  # a turn of one TCP connection: inline
         finally:
             await b.stop()
 
     run_async(run)
+
+
+# ------------------------------------------------- the native egress path
+
+
+def test_tls_and_no_library_sessions_stay_on_the_loop(tmp_path, monkeypatch):
+    """Who takes the native path is observed: a TLS session never does
+    (the transport encrypts: the bytes on the socket are not ours), nor
+    does any session of a context whose runtime library lacks egress.cc.
+    Both still coalesce and deliver."""
+    import ssl
+    import subprocess
+
+    from rmqtt_tpu import runtime
+
+    _need_native()
+    monkeypatch.setattr(egress_mod, "_MIN_JOB", 1)
+    cert, key = str(tmp_path / "c.pem"), str(tmp_path / "k.pem")
+    subprocess.run(
+        ["openssl", "req", "-x509", "-newkey", "rsa:2048", "-nodes",
+         "-keyout", key, "-out", cert, "-days", "1", "-subj", "/CN=localhost"],
+        check=True, capture_output=True)
+
+    async def drive(b, port, sslctx, cids):
+        clients = []
+        for cid in cids:
+            r, w = await asyncio.open_connection("127.0.0.1", port, ssl=sslctx)
+            codec = MqttCodec(pk.V311)
+            w.write(codec.encode(pk.Connect(client_id=cid)))
+            w.write(codec.encode(
+                pk.Subscribe(1, [("tls/t", pk.SubOpts(qos=0))])))
+            await w.drain()
+            await _read_frame(r)
+            await _read_frame(r)
+            clients.append((r, w))
+        for i in range(10):
+            w.write(codec.encode(pk.Publish(topic="tls/t", payload=b"%d" % i)))
+        await w.drain()
+        for r, _ in clients:
+            got = [MqttCodec(pk.V311).feed(await _read_frame(r))[0].payload
+                   for _ in range(10)]
+            assert got == [b"%d" % i for i in range(10)]
+        assert b.ctx.metrics.get("net.egress_flushes") > 0
+        assert _offloop(b) == 0
+        assert b.ctx.egress_hub.thread_stats() == (0.0, 0, 0)
+        for _, w in clients:
+            w.close()
+
+    async def run():
+        b = MqttBroker(ServerContext(BrokerConfig(
+            port=0, tls_port=0, tls_cert=cert, tls_key=key)))
+        await b.start()
+        try:
+            cctx = ssl.SSLContext(ssl.PROTOCOL_TLS_CLIENT)
+            cctx.check_hostname = False
+            cctx.verify_mode = ssl.CERT_NONE
+            await drive(b, b.tls_port, cctx, ["tls-a", "tls-b"])
+        finally:
+            await b.stop()
+        # a library from before egress.cc (the stale-.so rule)
+        ctx = ServerContext(BrokerConfig(port=0))
+        real = runtime.load()
+
+        class _Stale:
+            def __getattr__(self, name):
+                if name.startswith("rt_egress"):
+                    raise AttributeError(name)
+                return getattr(real, name)
+
+        with monkeypatch.context() as mp:
+            mp.setattr(runtime, "load", lambda: _Stale())
+            ctx.egress_hub = EgressHub(ctx.telemetry)
+        assert ctx.egress_hub.native is False
+        b = MqttBroker(ctx)
+        await b.start()
+        try:
+            await drive(b, b.port, None, ["nolib-a", "nolib-b"])
+        finally:
+            await b.stop()
+
+    run_async(run)
+
+
+def _small_rcvbuf_socket(port) -> socket.socket:
+    sock = socket.socket()
+    sock.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, 4096)
+    sock.connect(("127.0.0.1", port))
+    sock.setblocking(False)
+    return sock
+
+
+def test_native_partial_write_goes_back_to_the_transport(monkeypatch):
+    """A peer that stops reading, behind small socket buffers: the native
+    thread's send comes back short (or EAGAIN) and is NOT retried there —
+    the remainder goes to the asyncio transport, later frames follow it
+    through the same buffer in order, and the high-water gate drains at
+    ``egress_high_water`` as it does on the loop path."""
+    _need_native()
+    monkeypatch.setattr(egress_mod, "_MIN_JOB", 1)
+
+    async def run():
+        b = MqttBroker(ServerContext(BrokerConfig(
+            port=0, egress_high_water=32 * 1024)))
+        await b.start()
+        try:
+            codec = MqttCodec(pk.V311)
+            reader, writer = await asyncio.open_connection(
+                sock=_small_rcvbuf_socket(b.port), limit=1 << 12)
+            writer.write(codec.encode(pk.Connect(client_id="part-sub")))
+            writer.write(codec.encode(
+                pk.Subscribe(1, [("part/t", pk.SubOpts(qos=0))])))
+            await writer.drain()
+            await _read_frame(reader)  # CONNACK
+            await _read_frame(reader)  # SUBACK; then stop reading
+            state = b.ctx.registry._sessions["part-sub"].state
+            state.writer.transport.get_extra_info("socket").setsockopt(
+                socket.SOL_SOCKET, socket.SO_SNDBUF, 4096)
+            m = b.ctx.metrics
+            pub = await TestClient.connect(b.port, "part-pub")
+            sent = 0
+            while sent < 200 and not (m.get("net.egress_offloop_partial")
+                                      and m.get("net.egress_drains")):
+                await pub.publish("part/t", struct.pack(">I", sent) * 2048,
+                                  qos=0, wait_ack=False)
+                sent += 1
+                await asyncio.sleep(0.005)
+            assert m.get("net.egress_offloop_partial") > 0, \
+                "the native write never came back short"
+            assert m.get("net.egress_drains") > 0, \
+                "the high-water gate never engaged"
+            # the gate holds at the mark: what waits for the socket is the
+            # mark plus at most the frame that crossed it, not the backlog
+            eb = state._egress
+            assert (eb.pending_bytes
+                    + state.writer.transport.get_write_buffer_size()
+                    <= 32 * 1024 + 2 * 8200)
+            # the consumer wakes up: every frame, whole, in publish order
+            decode, got = MqttCodec(pk.V311), []
+            while len(got) < sent:
+                chunk = await asyncio.wait_for(reader.read(65536), 10.0)
+                assert chunk, "stream closed early"
+                got += decode.feed(chunk)
+            assert [p.payload for p in got] == [
+                struct.pack(">I", i) * 2048 for i in range(sent)]
+            assert _offloop(b) > 0
+            writer.close()
+            await pub.disconnect_clean()
+        finally:
+            await b.stop()
+
+    run_async(run, timeout=60.0)
+
+
+def test_pending_bytes_count_the_write_in_flight():
+    """The high-water gate in ``Session._write`` reads ``pending_bytes``:
+    bytes the native thread has not reported yet are still pending."""
+    eb = EgressBuf(_RecWriter(), Metrics())
+    eb._vec, eb._bytes = [b"abc"], 3
+    assert eb.pending_bytes == 3
+    eb._inflight = b"x" * 100
+    assert eb.pending_bytes == 103
+
+
+async def _server_pairs(n):
+    """→ (server, [(server-side reader, writer, client socket)] * n)."""
+    accepted: asyncio.Queue = asyncio.Queue()
+
+    async def on_conn(r, w):
+        await accepted.put((r, w))
+
+    srv = await asyncio.start_server(on_conn, "127.0.0.1", 0)
+    port = srv.sockets[0].getsockname()[1]
+    pairs = []
+    for _ in range(n):
+        c = socket.create_connection(("127.0.0.1", port))
+        r, w = await accepted.get()
+        pairs.append((r, w, c))
+    return srv, pairs
+
+
+def _n_fds() -> int:
+    return len(os.listdir("/proc/self/fd"))
+
+
+def test_native_close_and_error_with_a_write_in_flight(monkeypatch):
+    """The connection's last flush before ``writer.close()`` waits for the
+    write in flight and follows it (order kept, nothing lost); a transport
+    that aborts under a write in flight takes nothing with it: the thread
+    writes the buf's own dup, so the kernel may hand the transport's fd
+    number to a new connection at once and that one never sees a byte; a
+    hard error from the thread closes the writer; the ``net.egress``
+    failpoint fires on this path too; no fd leaks."""
+    from rmqtt_tpu.utils.failpoints import FAILPOINTS
+
+    _need_native()
+    monkeypatch.setattr(egress_mod, "_MIN_JOB", 1)
+
+    async def run():
+        base = _n_fds()
+        m = Metrics()
+        hub = EgressHub()
+        srv, pairs = await _server_pairs(4)
+        try:
+            bufs = [EgressBuf(w, m, hub=hub) for _, w, _ in pairs]
+            assert all(eb._sock_fd >= 0 for eb in bufs)
+            # (1) close with a write in flight, many times over: frames fed
+            # this turn, the hand-off, then at once the closing flush
+            (_, w0, c0), eb0 = pairs[0], bufs[0]
+            want = b""
+            for i in range(200):
+                eb0.feed(b"<%d>" % i)
+                await asyncio.sleep(0)  # the hub's turn: handed off
+                eb0.feed(b"[bye %d]" % i)  # waits behind the write in flight
+                eb0.flush()  # what run()'s finally does before close()
+                assert eb0._inflight is None and not eb0._vec
+                want += b"<%d>[bye %d]" % (i, i)
+            await w0.drain()
+            c0.settimeout(5)
+            got = b""
+            while len(got) < len(want):
+                got += c0.recv(65536)
+            assert got == want
+            assert m.get("net.egress_offloop_flushes") >= 100
+            # (2) the transport aborts under a write in flight; its fd number
+            # is reused at once by new connections
+            (_, w1, c1), eb1 = pairs[1], bufs[1]
+            eb1.feed(b"last words")
+            await asyncio.sleep(0)
+            w1.transport.abort()
+            await asyncio.sleep(0)  # connection_lost: the transport's fd closed
+            srv2, fresh = await _server_pairs(8)
+            eb1.flush()
+            eb1.close()
+            await asyncio.sleep(0.05)
+            hub._collect()
+            assert eb1._fd == -1, "the dup outlived its buf"
+            c1.settimeout(5)
+            assert c1.recv(100) in (b"last words", b"")  # sent, or cut by the abort
+            for _, w, c in fresh:
+                c.setblocking(False)
+                with pytest.raises(BlockingIOError):
+                    c.recv(100)  # a stranger's bytes
+                w.close()
+                c.close()
+            srv2.close()
+            # (3) a peer that reset: the thread's send fails hard, the buf
+            # closes the writer (reading is paused, so only the write path
+            # can notice)
+            (_, w2, c2), eb2 = pairs[2], bufs[2]
+            w2.transport.pause_reading()
+            c2.setsockopt(socket.SOL_SOCKET, socket.SO_LINGER,
+                          struct.pack("ii", 1, 0))
+            c2.close()  # RST
+            await asyncio.sleep(0.05)
+            for _ in range(50):
+                if eb2._closed:
+                    break
+                eb2.feed(b"anyone there")
+                await asyncio.sleep(0.02)
+            assert eb2._closed and w2.transport.is_closing()
+            eb2.feed(b"ignored")
+            await asyncio.sleep(0.02)
+            assert not eb2._vec or eb2._closed
+            # (4) the failpoint: fires at the hand-off, closes the writer
+            (_, w3, c3), eb3 = pairs[3], bufs[3]
+            FAILPOINTS.set("net.egress", "times(1, error)")
+            try:
+                eb3.feed(b"never sent")
+                await asyncio.sleep(0)
+                await asyncio.sleep(0.02)
+            finally:
+                FAILPOINTS.clear_all()
+            assert eb3._closed and w3.transport.is_closing()
+            c3.settimeout(5)
+            assert c3.recv(100) == b""
+        finally:
+            for eb in bufs:
+                eb.close()
+            for _, w, c in pairs:
+                w.close()
+                c.close()
+            srv.close()
+            await srv.wait_closed()
+            hub.close()
+        await asyncio.sleep(0.05)
+        assert _n_fds() <= base, "an fd leaked"
+
+    run_async(run, timeout=60.0)
+
+
+def test_native_kicked_disconnect_follows_its_publishes(monkeypatch):
+    """A v5 session taken over while deliveries are on their way: the old
+    connection gets its PUBLISHes in order and then the DISCONNECT (0x8E),
+    whether or not a native write was in flight when ``run()`` closed."""
+    from rmqtt_tpu.broker.types import RC_SESSION_TAKEN_OVER
+
+    _need_native()
+    monkeypatch.setattr(egress_mod, "_MIN_JOB", 1)
+
+    async def run():
+        b = MqttBroker(ServerContext(BrokerConfig(port=0)))
+        await b.start()
+        try:
+            pub = await TestClient.connect(b.port, "kick-pub")
+            for rnd in range(10):
+                reader, writer = await asyncio.open_connection(
+                    "127.0.0.1", b.port)
+                codec = MqttCodec(pk.V5)
+                writer.write(codec.encode(
+                    pk.Connect(client_id="kick-me", protocol=pk.V5)))
+                writer.write(codec.encode(
+                    pk.Subscribe(1, [("kick/t", pk.SubOpts(qos=0))])))
+                await writer.drain()
+                await _read_frame(reader)
+                await _read_frame(reader)
+                for i in range(30):
+                    await pub.publish("kick/t", b"r%d-%02d" % (rnd, i),
+                                      qos=0, wait_ack=False)
+                await asyncio.sleep(0.002 * rnd)
+                usurper = await TestClient.connect(
+                    b.port, "kick-me", version=pk.V5)
+                raw = await asyncio.wait_for(reader.read(-1), 5.0)  # to EOF
+                frames = MqttCodec(pk.V5).feed(raw)
+                assert isinstance(frames[-1], pk.Disconnect), frames[-3:]
+                assert frames[-1].reason_code == RC_SESSION_TAKEN_OVER
+                pubs = frames[:-1]
+                assert all(isinstance(p, pk.Publish) for p in pubs)
+                assert [p.payload for p in pubs] == [
+                    b"r%d-%02d" % (rnd, i) for i in range(len(pubs))]
+                writer.close()
+                await usurper.disconnect_clean()
+            assert _offloop(b) > 0
+            await pub.disconnect_clean()
+        finally:
+            await b.stop()
+
+    run_async(run, timeout=60.0)
 
 
 # ------------------------------------------------------- native encode
