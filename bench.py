@@ -447,8 +447,8 @@ _ON_TPU = False
 
 def measure_stream(matcher, topics, micro_sizes=(2048, 4096), depth=3,
                    min_batches=24):
-    """Burst p99 under a CONTINUOUS pipelined micro-batch stream (VERDICT
-    r3 item 3): instead of one serial batch-sized dispatch (sum of stages —
+    """Burst p99 under a CONTINUOUS pipelined micro-batch stream:
+    instead of one serial batch-sized dispatch (sum of stages —
     258.7ms standing at cfg3/16K), micro-batches stream through
     submit/complete with ``depth`` in flight, so per-batch latency tends to
     the slowest stage. Per-batch latency = submit→complete wall time while
@@ -521,8 +521,8 @@ def measure_hybrid(matcher, side, topics, batch_size):
 def run_retained(matcher, retained_topics, publish_topics):
     """Config 5 extra: concurrent retained-scan (SUBSCRIBE) + publish routing.
 
-    The scan side runs the PARTITIONED inverse matcher (ops/retained_part,
-    VERDICT r4 item 3): a realistic subscriber mix — mostly prefix filters
+    The scan side runs the PARTITIONED inverse matcher (ops/retained_part):
+    a realistic subscriber mix — mostly prefix filters
     that prune to a few partition chunks, a tail of broad multi-wildcard
     filters that genuinely scan everything — pipelined against the publish
     stream so scan dispatch overlaps publish compute."""
@@ -591,7 +591,7 @@ def run_cache_config(name, rng):
     router path under zipf-skewed publish traffic (the hot-topic regime the
     cache targets) — cache-on vs cache-off topics/s with hit rate, plus the
     uniform miss-heavy stream to bound the cache's overhead. Runs entirely
-    host-side: the number is provable without a TPU window (VERDICT r5)."""
+    host-side: the number is provable without a TPU window."""
     from rmqtt_tpu.core.topic import parse_shared
     from rmqtt_tpu.router.base import Id, SubscriptionOptions
     from rmqtt_tpu.router.cache import MatchCache, cached_matches_raw
@@ -1273,7 +1273,7 @@ def run_smallbatch_config(name, rng):
     for m in (m_fused, m_plain):  # warmup/compile + fused verify
         m.match(batches[0])
         m.match(batches[1])
-        m.prewarm((bs,))
+        m.prewarm(bs)
         m.stage_timing = True
 
     lat = {"fused": [], "unfused": []}
@@ -2415,7 +2415,7 @@ def run_autotune_config(name, rng):
         # and phantom retrace storms hold the tuner (the controller's
         # counter baselines prime from the profiler at construction)
         m = PartitionedMatcher(table)
-        m.prewarm((1, 8))  # the static default: sticky pad floor 8
+        m.prewarm()  # the static default: sticky pad floor 8
         svc = None
         if auto_on:
             shim = type("_RouterShim", (), {})()
@@ -2494,7 +2494,7 @@ def run_autotune_config(name, rng):
     prior = (DEVPROF.enabled, DEVPROF.interval_s)
     DEVPROF.configure(enabled=True, interval_s=0.05)
     warm = PartitionedMatcher(table)
-    warm.match(big_batches[0])  # fused verify + pallas decide
+    warm.match(big_batches[0])  # fused verify
     for floor in (8, 4, 2, 1):
         warm.set_pad_floor(floor)
         for t in pool:

@@ -507,7 +507,6 @@ def constants_phase(wl: Workload, want_platform: str) -> dict:
                             "fused step alone compiles ~40 s for v5e at "
                             "16384x32 (ahead-of-time figure, not a chip time)"},
         "dispatch_8": small_t, f"dispatch_{len(big)}": big_t,
-        "words_producer": m.words_producer(),
         "fused_batches": m.fused_batches,
         "routes_per_big_batch": int(sum(len(r) for r in m.match(big))),
         "device_to_host": {"bytes": nbytes, "seconds": statistics.median(reads),

@@ -1,7 +1,7 @@
 """Device-plane autotuner: devprof rollups → live kernel-knob selection.
 
 Every performance-critical knob the kernel PRs grew — sticky pad floor,
-batch window, fused/pallas on-off, the delta-upload gate — shipped as a
+batch window, fused on-off, the delta-upload gate — shipped as a
 static env-flag/TOML matrix a human re-derives per workload (the cfg1
 small-batch 0.06x cliff in BENCH_LAST_TPU.json is exactly a mistuned pad
 floor). This module closes the loop the ROADMAP item-1 follow-on names:
@@ -66,7 +66,7 @@ log = logging.getLogger("rmqtt_tpu.autotune")
 #: knobs whose change can alter DEVICE results/shape discipline: their
 #: canary commit additionally requires a device-vs-trie oracle verify
 DEVICE_KNOBS = frozenset(
-    {"pad_floor", "fused", "pallas", "delta_uploads", "packed"})
+    {"pad_floor", "fused", "delta_uploads", "packed"})
 
 #: batch-wait ladder (ms) for the micro-batch window rule
 LINGER_LADDER = (0.0, 0.5, 1.0, 2.0)

@@ -11,7 +11,7 @@ makes those diagnosable in production:
 
 ``shape-key registry`` (compile/retrace tracking)
     Every ``jax.jit`` entry seam in the matcher stack (match / fused /
-    compact / split / delta-scatter / pallas — ``ops/partitioned.py``,
+    compact / split / delta-scatter — ``ops/partitioned.py``,
     ``parallel/sharded.py``) reports one ``note_jit(kernel, key, ns)``
     per dispatch. ``jax.jit`` caches executables on exactly the
     (static-args, arg-shapes/dtypes) signature, so a never-seen key IS a

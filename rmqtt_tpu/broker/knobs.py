@@ -1,7 +1,7 @@
 """Runtime knob registry: the device plane's kill-switches, consolidated.
 
 Every performance-critical toggle grown over the kernel PRs lived in its
-own corner: ``RMQTT_FUSED`` / ``RMQTT_PACKED`` / ``RMQTT_PALLAS`` as env
+own corner: ``RMQTT_FUSED`` / ``RMQTT_PACKED`` as env
 reads inside ``ops/partitioned.py``, ``RMQTT_DELTA_UPLOADS`` duplicated
 across three matchers, ``RMQTT_HYBRID_MAX`` in ``router/xla.py``, the
 sticky pad floor latched by ``prewarm()``, the batcher window on
@@ -41,7 +41,6 @@ from typing import Any, Callable, Dict, List, Optional
 KNOB_CATALOG = (
     "fused",          # fused match→compact→decode pipeline (RMQTT_FUSED)
     "packed",         # bit-packed automaton tiles (RMQTT_PACKED)
-    "pallas",         # hand-pipelined Pallas kernel (RMQTT_PALLAS)
     "delta_uploads",  # incremental HBM scatter vs full repack (RMQTT_DELTA_UPLOADS)
     "hybrid_max",     # trie-vs-device batch threshold (RMQTT_HYBRID_MAX)
     "prewarm",        # pre-compile small shapes at start ([routing] prewarm)
@@ -65,7 +64,7 @@ class Knob:
     def row(self) -> dict:
         v = self.get()
         if self.kind == "tristate" and v is None:
-            v = "auto"  # None = decide-on-first-use (fused/pallas verify)
+            v = "auto"  # None = decide-on-first-use (the fused verify)
         return {"name": self.name, "value": v, "source": self.source,
                 "writable": self.set is not None, "kind": self.kind}
 
@@ -171,11 +170,6 @@ def build_registry(router, routing, cfg=None, environ=None) -> KnobRegistry:
             # the resident array keeps its layout until then
             lambda v, m=matcher: setattr(m, "_packed_pref", bool(v)),
             source=src("RMQTT_PACKED"), kind="bool")
-    if matcher is not None and hasattr(matcher, "_pallas"):
-        reg.register(
-            "pallas", lambda m=matcher: m._pallas,
-            lambda v, m=matcher: setattr(m, "_pallas", _tristate(v)),
-            source=src("RMQTT_PALLAS"), kind="tristate")
     if matcher is not None and hasattr(matcher, "delta_enabled"):
         reg.register(
             "delta_uploads", lambda m=matcher: m.delta_enabled,

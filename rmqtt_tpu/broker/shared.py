@@ -160,7 +160,7 @@ class SessionRegistry:
 
         ``limit`` enforces $limit/$exclusive immediately before the relation
         insert — atomic on this node (no awaits in between); under raft the
-        replicated count still has a cross-node race window (PLAN.md).
+        replicated count still has a cross-node race window.
         """
         if limit is not None and self.ctx.router.subscribers_count(
             stripped, exclude_client=session.client_id

@@ -19,9 +19,10 @@ single-level partitions when the topic has one level. Each partition owns
 fixed-size row *chunks* (``CHUNK`` rows) in the flat table, so churn is O(1)
 and the kernel sees a per-topic list of chunk ids: one `lax.scan` step
 gathers a [B, CHUNK] row tile per candidate chunk, applies the same level
-formula as `ops.match`, and packs words; a final word-level ``top_k``
-compacts matches exactly like the dense path. Per-topic work drops from
-O(F) to O(candidate rows) — the trie's pruning, with dense regular tiles.
+formula as `ops.match`, and packs words; the fused tail compacts them
+batch-globally, resolves rows to filter ids and sorts per topic on the
+device. Per-topic work drops from O(F) to O(candidate rows) — the trie's
+pruning, with dense regular tiles.
 """
 
 from __future__ import annotations
@@ -48,8 +49,8 @@ from rmqtt_tpu.utils.failpoints import FAILPOINTS
 _FP_UPLOAD = FAILPOINTS.register("device.upload")
 
 #: device-plane profiler (broker/devprof.py): every jit entry seam below
-#: reports hit-vs-trace through it when enabled; call sites guard on
-#: ``_DEVPROF.enabled`` so the disabled cost is one attribute check
+#: goes through ``_pj``, which reports hit-vs-trace when it is enabled and
+#: calls straight through when it is not
 from rmqtt_tpu.broker.devprof import DEVPROF as _DEVPROF, NeedsCompile
 from rmqtt_tpu.broker.telemetry import Stage as _Stage
 
@@ -57,8 +58,8 @@ _STAGE_KEYS = ("encode", "dispatch", "fetch", "decode")
 
 
 def _pj(kernel: str, fn, *args, **kwargs):
-    """One PROFILED jit-seam call — only reached when the device profiler
-    is enabled (sites use ``_pj(...) if _DEVPROF.enabled else <direct>``).
+    """One jit-seam call: ``fn(*args, **kwargs)``, profiled while the
+    device profiler is enabled.
     The shape key mirrors jax's own executable-cache signature, so a
     never-seen key is a trace+compile by construction and the timed wall
     of that first call brackets its cost (jit traces synchronously): that
@@ -70,6 +71,8 @@ def _pj(kernel: str, fn, *args, **kwargs):
     e.g. the sharded per-budget step closures, where arg shapes alone are
     identical across budget regrows but each regrow is a real recompile."""
     extra = kwargs.pop("_key_extra", None)
+    if not _DEVPROF.enabled:
+        return fn(*args, **kwargs)
     key = _DEVPROF.key_of(args, kwargs)
     if extra is not None:
         key = key + (extra,)
@@ -112,7 +115,7 @@ from rmqtt_tpu.ops.encode import (
 )
 from rmqtt_tpu.utils.devfetch import fetch
 
-# module-scope logger: _refresh/_decide_pallas sit on the dispatch path and
+# module-scope logger: _refresh/_decide_fused sit on the dispatch path and
 # must not pay a per-call `import logging`
 _LOG = logging.getLogger("rmqtt_tpu.ops")
 
@@ -1232,28 +1235,15 @@ def scan_words_packed_impl(packed32, ttok, tlen, tdollar, chunk_ids, *,
     return jnp.moveaxis(words, 0, 1).reshape(b, nc * WORDS_PER_CHUNK)
 
 
-def words_any_impl(tiles, ttok, tlen, tdollar, chunk_ids, *, layout=None,
-                   use_pallas: bool = False, interpret: bool = False):
-    """The one words-producer seam: legacy or packed tiles × lax scan or
-    Pallas wave kernel, all statically selected so every combination traces
-    into a single dispatch when embedded in a larger jit.
+def words_any_impl(tiles, ttok, tlen, tdollar, chunk_ids, *, layout=None):
+    """The one words-producer seam: the lax scan over legacy or packed
+    tiles, statically selected by ``layout``.
 
     Every operation it lowers to carries the named scope ``scan`` (the
     tail's are ``compact`` / ``resolve`` / ``sort`` / ``counts``): metadata
     only — the compiled program is the same — by which a profiler trace's
     device time is summed per phase (PERF.md §3)."""
     with jax.named_scope("scan"):
-        if use_pallas:
-            if layout is None:
-                from rmqtt_tpu.ops.pallas_match import match_words_pallas
-
-                return match_words_pallas(tiles, ttok, tlen, tdollar,
-                                          chunk_ids, interpret=interpret)
-            from rmqtt_tpu.ops.pallas_match import match_words_pallas_packed
-
-            return match_words_pallas_packed(
-                tiles, ttok, tlen, tdollar, chunk_ids, layout=layout,
-                interpret=interpret)
         if layout is None:
             return scan_words_impl(tiles, ttok, tlen, tdollar, chunk_ids)
         return scan_words_packed_impl(tiles, ttok, tlen, tdollar, chunk_ids,
@@ -1263,10 +1253,10 @@ def words_any_impl(tiles, ttok, tlen, tdollar, chunk_ids, *, layout=None,
 def compact_global_impl(words, budget: int):
     """Packed words [B, W] → batch-global ROUTE-level compaction.
 
-    Per-topic ``top_k`` (below) must fetch ``max_words`` slots for EVERY
-    topic to cover the worst one — measured 32 slots against a batch
-    average of ~6 nonzero words at 1M subs, so >80% of the device→host
-    transfer is padding.
+    A per-topic fixed-width compaction must fetch the worst topic's slots
+    for EVERY topic — measured 32 slots against a batch average of ~6
+    nonzero words at 1M subs, so >80% of the device→host transfer is
+    padding.
     And the measured word occupancy is ~1.12 set bits, so even compacted
     (key, bits) words cost ~7 bytes per route. Here the whole batch shares
     one ``budget`` of per-ROUTE slots, filled in two stages:
@@ -1434,15 +1424,13 @@ def fused_compact_decode_impl(words, fid_rows, chunk_ids, budget: int):
 
 
 def match_fused_impl(tiles, fid_rows, ttok, tlen, tdollar, chunk_ids,
-                     budget: int, layout=None, use_pallas: bool = False,
-                     interpret: bool = False):
-    """The fused dispatch: words (lax or Pallas, legacy or packed tiles) →
+                     budget: int, layout=None):
+    """The fused dispatch: words (legacy or packed tiles) →
     global compaction → on-device fid decode+sort, ONE jit call whose
     output is the final ``[budget + B]`` int32 fid buffer. Nothing but
     final fids and counts comes back to the host."""
     words = words_any_impl(tiles, ttok, tlen, tdollar, chunk_ids,
-                           layout=layout, use_pallas=use_pallas,
-                           interpret=interpret)
+                           layout=layout)
     # compile-time fence, not a semantic one: with the scan and the
     # compact/sort tail in one fusion scope the v5e compiler took 212 s for
     # this program at B=16384, NC=32 (0.5 s + 35 s for the halves alone);
@@ -1454,20 +1442,16 @@ def match_fused_impl(tiles, fid_rows, ttok, tlen, tdollar, chunk_ids,
 
 
 def match_fused_grouped_impl(tiles, fid_rows, ttok, tlen, tdollar, uniq_cand,
-                             inv, budget: int, layout=None,
-                             use_pallas: bool = False,
-                             interpret: bool = False):
+                             inv, budget: int, layout=None):
     """Fused dispatch over the deduplicated candidate upload."""
     chunk_ids = uniq_cand[inv.astype(jnp.int32)]
     return match_fused_impl(tiles, fid_rows, ttok, tlen, tdollar, chunk_ids,
-                            budget, layout, use_pallas, interpret)
+                            budget, layout)
 
 
 def match_fused_split_impl(tiles, fid_rows, parts, budgets, layout=None):
     """Fused NC split-dispatch: every bucket's fused output concatenates on
-    device — one dispatch, one fetch, zero host decode. Buckets are padded
-    to arbitrary pow2 sizes (often below the Pallas BT grid), so the split
-    form always uses the lax words producer."""
+    device — one dispatch, one fetch, zero host decode."""
     outs = [
         match_fused_impl(tiles, fid_rows, *p, budget=g, layout=layout)
         for p, g in zip(parts, budgets)
@@ -1477,72 +1461,19 @@ def match_fused_split_impl(tiles, fid_rows, parts, budgets, layout=None):
 
 _match_global_split = jax.jit(match_global_split_impl,
                               static_argnames=("budgets", "layout"))
-
-
 _match_global = jax.jit(match_global_impl, static_argnames=("budget", "layout"))
 _match_global_grouped = jax.jit(match_global_grouped_impl,
                                 static_argnames=("budget", "layout"))
-_compact_global = jax.jit(compact_global_impl, static_argnames=("budget",))
-_match_fused = jax.jit(match_fused_impl,
-                       static_argnames=("budget", "layout", "use_pallas",
-                                        "interpret"))
+_match_fused = jax.jit(match_fused_impl, static_argnames=("budget", "layout"))
 _match_fused_grouped = jax.jit(match_fused_grouped_impl,
-                               static_argnames=("budget", "layout",
-                                                "use_pallas", "interpret"))
+                               static_argnames=("budget", "layout"))
 _match_fused_split = jax.jit(match_fused_split_impl,
                              static_argnames=("budgets", "layout"))
-#: standalone jitted Pallas words producer (the words+compact two-dispatch
-#: form the fused pipeline replaces; still used when fused is off)
-_jit_words_pallas = jax.jit(
-    functools.partial(words_any_impl, use_pallas=True),
-    static_argnames=("layout", "interpret"))
 
-# process-wide pallas verify+race outcome (None = not yet decided) and the
-# sentence that explains it; each race costs a pallas compile, so every
-# matcher in the process shares it
-_PALLAS_RACED: Optional[bool] = None
-_PALLAS_WHY = "undecided: no TPU batch of >=1024 topics has raced it yet"
-
-
-def _platform(dev) -> str:
-    """Platform of a device array (single source for the decide paths)."""
-    return next(iter(dev.devices())).platform if hasattr(dev, "devices") else ""
-
-
-def _pallas_bt() -> int:
-    """The Pallas wave width (import-guarded for environments without the
-    pallas extras)."""
-    try:
-        from rmqtt_tpu.ops.pallas_match import BT
-
-        return BT
-    except ImportError:  # pragma: no cover - depends on install
-        return 1 << 30  # never a divisor → pallas never selected
-
-
-def compact_words_impl(words, max_words: int):
-    """Packed words → (word_idx, word_bits, counts) compaction (shared by
-    the lax and Pallas word producers)."""
-    counts = jnp.sum(lax.population_count(words).astype(jnp.int32), axis=1)
-    w = words.shape[1]
-    kw = min(max_words, w)
-    val = jnp.where(words != 0, jnp.int32(w) - jnp.arange(w, dtype=jnp.int32), 0)
-    _, word_idx = lax.top_k(val, kw)
-    word_bits = jnp.take_along_axis(words, word_idx, axis=1)
-    return word_idx, word_bits, counts
-
-
-def match_partitioned_impl(packed_rows, ttok, tlen, tdollar, chunk_ids,
-                           max_words: int, layout=None):
-    """Gather-based partitioned match → (word_idx, word_bits, counts)."""
-    words = words_any_impl(packed_rows, ttok, tlen, tdollar, chunk_ids,
-                           layout=layout)
-    return compact_words_impl(words, max_words)
-
-
-_match_partitioned = jax.jit(match_partitioned_impl,
-                             static_argnames=("max_words", "layout"))
-_compact_words = jax.jit(compact_words_impl, static_argnames=("max_words",))
+#: the batch size ``prewarm`` latches as the sticky pad floor and compiles:
+#: the least padded batch of a warmed matcher, so a lone publish reuses one
+#: compiled program instead of compiling shapes 1, 2 and 4 of its own
+PREWARM_FLOOR = 8
 
 
 def pack_device_rows(t: PartitionedTable) -> np.ndarray:
@@ -1558,8 +1489,7 @@ def pack_device_rows(t: PartitionedTable) -> np.ndarray:
     row-major ``[.., CHUNK, L+3]`` tile pads L+3=11 lanes to 128 — 11.6x
     the HBM footprint and gather traffic (measured as a 1.07 GB resident
     table at 1M subs). ``[.., L+3, CHUNK]`` keeps the minor dim at 256
-    full lanes (and 128-aligned for the Pallas kernel's HBM→VMEM DMA
-    slices); only the 11→16 sublane pad remains.
+    full lanes; only the 11→16 sublane pad remains.
 
     Dtype matters the same way: while the token vocabulary fits (tracked
     by the table's upload narrowing), tiles ship as int16 — the per-batch
@@ -1625,8 +1555,8 @@ def pack_device_rows_packed(t: PartitionedTable, layout: PackedLayout) -> np.nda
     """Bit-packed device mirror: flat ``[up_chunks, groups*CHUNK]`` int32 —
     four byte planes per int32 lane (encode.group_byte_planes), chunk c's
     plane g occupying lanes ``[g*CHUNK, (g+1)*CHUNK)`` of row c. The flat
-    2D shape is deliberate: the minor dim is a 128 multiple (Pallas DMA
-    alignment) and the sublane dim is the chunk count, so the array carries
+    2D shape is deliberate: the minor dim is a 128 multiple (whole
+    lanes) and the sublane dim is the chunk count, so the array carries
     NO tile-padding waste — unlike a 3D int8 ``[.., planes, CHUNK]`` layout,
     whose 9→32 sublane pad would triple the resident bytes and erase the
     packing win. Per-chunk gather traffic drops from ``(L+3)*CHUNK*2`` bytes
@@ -1769,46 +1699,38 @@ class _Snap:
 class PartitionedMatcher:
     """Device mirror + batched match over a ``PartitionedTable``.
 
-    On TPU the inner loop can run as a hand-pipelined Pallas kernel
-    (`ops/pallas_match.py`); it is enabled only after an on-device
-    self-check against the lax path agrees (env ``RMQTT_PALLAS=0/1``
-    forces it off/on) — routing results must never depend on an
-    unverified kernel.
+    Words come from the lax scan (packed or legacy tiles, by what the table
+    can pack); the output is fused on the device (compact → fid resolve →
+    sort), verified once against lax words → global compact → host decode,
+    which is also the fallback — routing results must never depend on an
+    unverified device path. NC-split and segmented are dispatch forms of
+    those two.
     """
 
-    def __init__(self, table: PartitionedTable, device=None, max_words: int = 32,
-                 compact: Optional[str] = None) -> None:
+    def __init__(self, table: PartitionedTable, device=None) -> None:
         self.table = table
         self.device = device
-        self.max_words = max_words
-        # 'global' = batch-global nonzero compaction (one shared slot budget,
-        # ~4x less device→host transfer than per-topic top_k at measured
-        # match rates); 'topk' = per-topic fixed-width slots
-        self.compact_mode = compact or os.environ.get("RMQTT_COMPACT", "global")
-        # sticky pow2 slot budgets for 'global' mode, PER (padded batch, NC)
-        # shape: one shared budget would let a 16K-topic batch (e.g. 128K
-        # slots) inflate every later 1-topic match's fetch to megabytes —
+        # sticky pow2 slot budgets of the batch-global compaction, PER
+        # (padded batch, NC) shape: one shared budget would let a 16K-topic
+        # batch (e.g. 128K slots) inflate every later 1-topic match's fetch
+        # to megabytes —
         # the low-load p99 path must keep its own small budget
         self._budgets: Dict[Tuple[int, int], int] = {}
         # slots a topic a new shape's budget starts with; _regrown raises it
         self._slots_per_topic = 4
-        # NC split-dispatch (RMQTT_NC_SPLIT=0 disables): bucket big batches
-        # by candidate count so padding chunks stop dominating device compute
-        self._split = os.environ.get("RMQTT_NC_SPLIT", "1") != "0"
+        # NC split-dispatch: bucket big batches by candidate count so
+        # padding chunks stop dominating device compute
+        self._split = True
         self._dev_version = -1
         self._dev_arrays = None
-        self._pallas: Optional[bool] = None  # None = not decided yet
-        self.pallas_why = _PALLAS_WHY
-        self._pallas_interpret = False  # CPU (tests): run the kernel interpreted
         # --- fused match→compact→decode pipeline (RMQTT_FUSED=0/1 forces
         # off/on; default verifies against the lax+host-decode reference on
-        # the first global-mode batch and falls back if anything disagrees —
-        # same contract as the Pallas kernel: an unverified fused path must
-        # never change routing results). Requires 'global' compact mode.
+        # the first batch with matches and falls back if anything
+        # disagrees: an unverified fused path must never change routing
+        # results)
         env_fused = os.environ.get("RMQTT_FUSED", "")
         self._fused: Optional[bool] = (
-            False if env_fused == "0" or self.compact_mode != "global"
-            else (True if env_fused == "1" else None)
+            False if env_fused == "0" else (True if env_fused == "1" else None)
         )
         self.fused_batches = 0  # batches served end-to-end on device
         # --- bit-packed tiles (RMQTT_PACKED=0 restores legacy int16/int32
@@ -1882,113 +1804,6 @@ class PartitionedMatcher:
         """Cumulative ns per section (encode / dispatch / fetch / decode)."""
         return {k: st.busy_ns for k, st in self._stages.items()}
 
-    def _decide_pallas(self, dev, ttok, tlen, tdollar, chunk_ids) -> bool:
-        """Verify the Pallas words producer against the lax scan on this
-        batch, then race them; → use it? A kernel that does not compile or
-        run RAISES: on a TPU a selected kernel that Mosaic refuses is a
-        fault to fix, not a reason to change producer quietly. Only a
-        wrong answer or a lost race keeps the lax producer, and
-        ``words_producer()`` says which."""
-        global _PALLAS_RACED, _PALLAS_WHY
-        env = os.environ.get("RMQTT_PALLAS", "")
-        if env == "0":
-            self.pallas_why = "RMQTT_PALLAS=0"
-            return False
-        platform = _platform(dev)
-        if platform != "tpu" and env != "1":
-            self.pallas_why = f"platform is {platform or 'unknown'}, not tpu"
-            return False
-        if env != "1" and _PALLAS_RACED is not None:
-            # one verify+race per process: a fresh matcher per table (the
-            # bench builds one per config) must not pay the compile again
-            self.pallas_why = _PALLAS_WHY
-            return _PALLAS_RACED
-        if _DEVPROF.compile_rule() == "forbid":
-            # the verify and the race below compile both producers
-            raise NeedsCompile("words_pallas_verify", chunk_ids.shape)
-        layout = self._dev_playout
-        self._pallas_interpret = platform != "tpu"
-        args = (dev, ttok, tlen, tdollar, chunk_ids)
-        # the kernel variant matching the RESIDENT tile format
-        pallas_fn = jax.jit(functools.partial(
-            words_any_impl, layout=layout, use_pallas=True,
-            interpret=self._pallas_interpret))
-        lax_fn = jax.jit(functools.partial(words_any_impl, layout=layout))
-        got = fetch(pallas_fn(*args), "pallas verify fetch")
-        want = fetch(lax_fn(*args), "lax verify fetch")
-        if not np.array_equal(got, want):
-            _LOG.warning("pallas match kernel disagrees with lax path; disabled")
-            self.pallas_why = "disagrees with the lax scan on a live batch"
-            if env != "1":
-                _PALLAS_RACED, _PALLAS_WHY = False, self.pallas_why
-            return False
-        if env == "1":
-            self.pallas_why = f"RMQTT_PALLAS=1, verified on {platform}"
-            _LOG.info("pallas match kernel verified on %s; enabled", platform)
-            return True
-
-        # correctness is necessary, not sufficient: race both producers on
-        # this batch (already compiled above) and keep the faster one
-        def clock(fn, reps=3):
-            fn(*args).block_until_ready()
-            t0 = time.perf_counter()
-            for _ in range(reps):
-                fn(*args).block_until_ready()
-            return (time.perf_counter() - t0) / reps
-
-        t_pallas = clock(pallas_fn)
-        t_lax = clock(lax_fn)
-        _PALLAS_RACED = bool(t_pallas < t_lax)
-        _PALLAS_WHY = self.pallas_why = (
-            "verified; %s the race at %dx%d: pallas %.2f ms vs lax %.2f ms"
-            % ("won" if _PALLAS_RACED else "lost", chunk_ids.shape[0],
-               chunk_ids.shape[1], t_pallas * 1e3, t_lax * 1e3))
-        _LOG.info("pallas match kernel %s", self.pallas_why)
-        return _PALLAS_RACED
-
-    def _maybe_decide_pallas(self, dev, ttok, tlen, tdollar, chunk_ids) -> None:
-        """Run the pallas verify+race decision if this batch qualifies
-        (shared by the words-then-compact path and the fused pipeline;
-        _pallas_bt() keeps installs without the pallas extras on lax)."""
-        if self._pallas is not None or chunk_ids.shape[0] % _pallas_bt():
-            return
-        env = os.environ.get("RMQTT_PALLAS", "")
-        if (env not in ("0", "1") and _PALLAS_RACED is None
-                and chunk_ids.shape[0] < 1024 and _platform(dev) == "tpu"):
-            # the verify+race decision latches for the process lifetime:
-            # deciding on an unrepresentative tiny batch (a broker's
-            # first match is often ONE topic, padded to BT) would let
-            # per-call overhead disqualify the kernel for the large-batch
-            # regime it was built for — stay on lax until a real batch.
-            # Every OTHER undecided case (non-TPU, forced env, settled
-            # race) resolves compile-free inside _decide_pallas, so
-            # small-batch-only processes still latch and stop BT padding
-            return
-        self._pallas = self._decide_pallas(dev, ttok, tlen, tdollar, chunk_ids)
-
-    def words_producer(self) -> Dict[str, str]:
-        """Which words producer serves batches of this matcher, and why
-        (the ``/api/v1/device`` surface). The NC-split and segmented
-        dispatch forms always scan with lax."""
-        return {"name": "pallas" if self._pallas else "lax",
-                "why": self.pallas_why}
-
-    def _words(self, dev, ttok, tlen, tdollar, chunk_ids):
-        if chunk_ids.shape[0] % _pallas_bt():
-            return None  # pallas grid needs a BT-multiple batch
-        self._maybe_decide_pallas(dev, ttok, tlen, tdollar, chunk_ids)
-        if self._pallas:
-            if _DEVPROF.enabled:
-                return _pj("words_pallas", _jit_words_pallas,
-                           dev, ttok, tlen, tdollar, chunk_ids,
-                           layout=self._dev_playout,
-                           interpret=self._pallas_interpret)
-            return _jit_words_pallas(
-                dev, ttok, tlen, tdollar, chunk_ids,
-                layout=self._dev_playout, interpret=self._pallas_interpret,
-            )
-        return None
-
     def _refresh(self):
         t = self.table
         if self._dev_version == t.version and (
@@ -2033,21 +1848,11 @@ class PartitionedMatcher:
             if self.device
             else jax.device_put
         )
-        if packed.nbytes > self._seg_bytes and self.compact_mode == "global":
+        if packed.nbytes > self._seg_bytes:
             self._dev_arrays = None
             self._dev_fids = None
             self._segments = self._build_segments(packed, fids2d, put)
         else:
-            if packed.nbytes > self._seg_bytes:
-                # only the 'global' wire format supports segment merge;
-                # a topk-mode table crossing the budget at runtime must
-                # keep working (single array, round-2 behavior), not
-                # start raising on every publish
-                _LOG.warning(
-                    "table %dMB exceeds RMQTT_SEG_BYTES but compact_mode"
-                    "=%r cannot segment; keeping one device array",
-                    packed.nbytes >> 20, self.compact_mode,
-                )
             self._segments = None
             try:
                 self._dev_arrays = put(packed)
@@ -2055,17 +1860,7 @@ class PartitionedMatcher:
             except Exception as e:
                 # oversize-table fail-soft (cfg4's "pre NC-split table"
                 # compile death): a failed whole-table upload retries as
-                # bounded segments instead of wedging the run; when the
-                # wire format cannot segment, fail with actionable sizing
-                # guidance rather than a bare backend error
-                if self.compact_mode != "global":
-                    raise RuntimeError(
-                        f"device table upload failed at {packed.nbytes >> 20}"
-                        f"MB ({t.nchunks} chunks, {t.size} filters) and "
-                        f"compact_mode={self.compact_mode!r} cannot use "
-                        "segmented tables; switch to RMQTT_COMPACT=global "
-                        "or lower the table size"
-                    ) from e
+                # bounded segments instead of wedging the run
                 self._seg_bytes = max(
                     64 << 20, min(self._seg_bytes, packed.nbytes // 4)
                 )
@@ -2098,8 +1893,8 @@ class PartitionedMatcher:
 
     def _want_fids(self) -> bool:
         """Device fid rows are packed/uploaded only while the fused
-        pipeline can serve batches (global mode, not ruled out)."""
-        return self._fused is not False and self.compact_mode == "global"
+        pipeline can serve batches (not ruled out)."""
+        return self._fused is not False
 
     def _try_delta_refresh(self, t: PartitionedTable, dt, layout) -> bool:
         """Scatter-write only the dirty chunks into the resident device
@@ -2140,22 +1935,16 @@ class PartitionedMatcher:
                 # pow2-padded scatter: one compiled executable per pow2
                 # dirty-chunk bucket — the "one compiled scatter under
                 # steady churn" invariant the profiler makes checkable
-                self._dev_arrays = (
-                    _pj("delta_scatter",
-                        lambda a, i, v: a.at[i].set(v),
-                        self._dev_arrays, idx, vals)
-                    if _DEVPROF.enabled else
-                    self._dev_arrays.at[idx].set(vals))
+                self._dev_arrays = _pj(
+                    "delta_scatter", lambda a, i, v: a.at[i].set(v),
+                    self._dev_arrays, idx, vals)
                 if ftiles is not None:
                     fidx, fvals = _pad_scatter_pow2(
                         np.asarray(cids, dtype=np.int32), ftiles
                     )
-                    self._dev_fids = (
-                        _pj("delta_scatter_fids",
-                            lambda a, i, v: a.at[i].set(v),
-                            self._dev_fids, fidx, fvals)
-                        if _DEVPROF.enabled else
-                        self._dev_fids.at[fidx].set(fvals))
+                    self._dev_fids = _pj(
+                        "delta_scatter_fids", lambda a, i, v: a.at[i].set(v),
+                        self._dev_fids, fidx, fvals)
             else:
                 self._apply_segment_delta(t, cids, tiles, ftiles)
             self.uploads += 1
@@ -2293,16 +2082,6 @@ class PartitionedMatcher:
         b = len(topics)
         if pad_to_pow2:
             padded = 1 << (b - 1).bit_length() if b > 1 else b
-            if self._pallas is not False:
-                # pad to the pallas grid multiple only while that backend is
-                # (possibly) in play — the lax path must not pay 8x on
-                # single-topic matches after pallas is ruled out
-                try:
-                    from rmqtt_tpu.ops.pallas_match import BT
-
-                    padded = max(BT, padded)
-                except ImportError:
-                    self._pallas = False
             if padded < self._pad_floor:
                 # sticky small-batch shape floor (prewarm()): a 1-topic
                 # publish reuses the already-compiled floor-shape
@@ -2317,10 +2096,9 @@ class PartitionedMatcher:
             _meta["padded"] = padded
         st = self._stages
         tok = st["encode"].begin(b) if self._timed() else 0
-        want_groups = self.compact_mode == "global"
         while True:
             enc, enc_epoch = t.encode_topics_versioned(
-                topics, pad_batch_to=padded, with_groups=want_groups
+                topics, pad_batch_to=padded, with_groups=True
             )
             try:
                 dev = self._refresh()
@@ -2354,67 +2132,25 @@ class PartitionedMatcher:
             if self._segments is not None:
                 return self._submit_segmented(tt, tlen, tdollar, chunk_ids, b,
                                               snap)
-            if self._fused is not False and self.compact_mode == "global":
+            if self._fused is not False:
                 handle = self._submit_fused(
-                    dev, tt, tlen, tdollar, chunk_ids,
-                    enc[5] if want_groups else None, padded, b, snap)
+                    dev, tt, tlen, tdollar, chunk_ids, enc[5], padded, b, snap)
                 if handle is not None:
                     return handle
-            words = self._words(dev, tt, tlen, tdollar, chunk_ids)
+            split = self._split_plan(chunk_ids, b)
+            if split is not None:
+                return self._submit_split(
+                    dev, tt, tlen, tdollar, chunk_ids, split, 0, snap
+                )
             lay = self._dev_playout
-            prof = _DEVPROF.enabled
-            if self.compact_mode == "global":
-                if words is not None:
-                    g = self._budget_for(padded, _nc)
-                    packed = (
-                        _pj("compact_global", _compact_global, words, budget=g)
-                        if prof else _compact_global(words, budget=g))
-                    return ("g", b, chunk_ids, words,
-                            (dev, tt, tlen, tdollar, None, lay), packed, g, 0,
-                            snap)
-                split = self._split_plan(chunk_ids, b)
-                if split is not None:
-                    return self._submit_split(
-                        dev, tt, tlen, tdollar, chunk_ids, split, 0, snap
-                    )
-                grouped = self._group_inputs(enc[5], chunk_ids)
-                g = self._budget_for(padded, _nc)
-                if grouped is None:  # batch doesn't dedup; plain upload
-                    packed = (
-                        _pj("match_global", _match_global, dev, tt, tlen,
-                            tdollar, chunk_ids, budget=g, layout=lay)
-                        if prof else _match_global(
-                            dev, tt, tlen, tdollar, chunk_ids, budget=g,
-                            layout=lay))
-                else:
-                    packed = (
-                        _pj("match_global_grouped", _match_global_grouped,
-                            dev, tt, tlen, tdollar, *grouped, budget=g,
-                            layout=lay)
-                        if prof else _match_global_grouped(
-                            dev, tt, tlen, tdollar, *grouped, budget=g,
-                            layout=lay))
-                # the handle carries ITS OWN budget: a sticky widening by a
-                # later handle must not mask this one's truncation
-                return ("g", b, chunk_ids, words,
-                        (dev, tt, tlen, tdollar, grouped, lay), packed, g, 0,
-                        snap)
-            if words is not None:
-                wi, wb, cn = (
-                    _pj("compact_words", _compact_words, words,
-                        max_words=self.max_words)
-                    if prof else _compact_words(words, max_words=self.max_words))
-            else:
-                wi, wb, cn = (
-                    _pj("match_partitioned", _match_partitioned, dev, tt,
-                        tlen, tdollar, chunk_ids, max_words=self.max_words,
-                        layout=lay)
-                    if prof else _match_partitioned(
-                        dev, tt, tlen, tdollar, chunk_ids,
-                        max_words=self.max_words, layout=lay))
-            # same contract: the handle carries ITS OWN max_words
-            return ("k", b, chunk_ids, words, (dev, tt, tlen, tdollar, lay),
-                    wi, wb, cn, self.max_words, snap)
+            grouped = self._group_inputs(enc[5], chunk_ids)
+            g = self._budget_for(padded, _nc)
+            packed = self._run_global(dev, tt, tlen, tdollar, chunk_ids,
+                                      grouped, g, lay)
+            # the handle carries ITS OWN budget: a sticky widening by a
+            # later handle must not mask this one's truncation
+            return ("g", b, chunk_ids, (dev, tt, tlen, tdollar, grouped, lay),
+                    packed, g, 0, snap)
         finally:
             if tok:
                 st["dispatch"].end(tok)
@@ -2503,7 +2239,6 @@ class PartitionedMatcher:
         fdev = fdev if fdev is not None else self._dev_fids
         if fdev is None:
             return None
-        self._maybe_decide_pallas(dev, tt, tlen, tdollar, chunk_ids)
         g = self._budget_for(padded, chunk_ids.shape[1])
         if self._fused is None:
             ok, results = self._decide_fused(
@@ -2518,68 +2253,58 @@ class PartitionedMatcher:
         if split is not None:
             return self._submit_fused_split(
                 dev, fdev, tt, tlen, tdollar, chunk_ids, split, lay)
-        use_pallas = (bool(self._pallas)
-                      and chunk_ids.shape[0] % _pallas_bt() == 0)
         grouped = self._group_inputs(groups, chunk_ids) if groups is not None else None
-        prof = _DEVPROF.enabled
-        if grouped is None:
-            packed = (
-                _pj("match_fused", _match_fused, dev, fdev, tt, tlen, tdollar,
-                    chunk_ids, budget=g, layout=lay, use_pallas=use_pallas,
-                    interpret=self._pallas_interpret)
-                if prof else _match_fused(
-                    dev, fdev, tt, tlen, tdollar, chunk_ids, budget=g,
-                    layout=lay, use_pallas=use_pallas,
-                    interpret=self._pallas_interpret))
-        else:
-            packed = (
-                _pj("match_fused_grouped", _match_fused_grouped, dev, fdev,
-                    tt, tlen, tdollar, *grouped, budget=g, layout=lay,
-                    use_pallas=use_pallas, interpret=self._pallas_interpret)
-                if prof else _match_fused_grouped(
-                    dev, fdev, tt, tlen, tdollar, *grouped, budget=g,
-                    layout=lay, use_pallas=use_pallas,
-                    interpret=self._pallas_interpret))
+        packed = self._run_fused(dev, fdev, tt, tlen, tdollar, chunk_ids,
+                                 grouped, g, lay)
         return ("f", b, padded,
-                (dev, fdev, tt, tlen, tdollar, chunk_ids, grouped, lay,
-                 use_pallas), packed, g)
+                (dev, fdev, tt, tlen, tdollar, chunk_ids, grouped, lay),
+                packed, g)
+
+    @staticmethod
+    def _run_fused(dev, fdev, tt, tlen, tdollar, chunk_ids, grouped, g: int,
+                   lay):
+        """Dispatch the fused program (plain or grouped upload) at slot
+        budget ``g``: the first run of a handle and its budget reruns."""
+        if grouped is None:
+            return _pj("match_fused", _match_fused, dev, fdev, tt, tlen,
+                       tdollar, chunk_ids, budget=g, layout=lay)
+        return _pj("match_fused_grouped", _match_fused_grouped, dev, fdev,
+                   tt, tlen, tdollar, *grouped, budget=g, layout=lay)
+
+    @staticmethod
+    def _run_global(dev, tt, tlen, tdollar, chunk_ids, grouped, g: int, lay):
+        """``_run_fused``'s twin for the reference / fallback program:
+        words → global compact, decoded on the host."""
+        if grouped is None:  # batch doesn't dedup; plain upload
+            return _pj("match_global", _match_global, dev, tt, tlen, tdollar,
+                       chunk_ids, budget=g, layout=lay)
+        return _pj("match_global_grouped", _match_global_grouped, dev, tt,
+                   tlen, tdollar, *grouped, budget=g, layout=lay)
 
     def _decide_fused(self, dev, fdev, tt, tlen, tdollar, chunk_ids, b: int,
                       g: int, snap, fid_base: int = 0):
         """First-use self-check of the fused pipeline against the lax
         reference (words → global compact → HOST decode through the
-        snapshot machinery) on the live batch — the same contract as the
-        Pallas kernel's verify: routing results must never depend on an
-        unverified device path. → ``(ok, results)``; results (from the
-        reference, which is correct either way) may be served directly."""
+        snapshot machinery) on the live batch: routing results must never
+        depend on an unverified device path. → ``(ok, results)``; results
+        (from the reference, which is correct either way) may be served
+        directly."""
         lay = self._dev_playout
         log = _LOG
-        # the static kwargs are spelled exactly like the production
-        # dispatch (_submit_fused): jit caches on static-arg VALUES, so
-        # a kwarg-less verify call would compile a second executable —
-        # and the profiler's shape key must match jax's cache key. A
-        # compile or run failure here propagates: only a DISAGREEMENT
-        # (below) may rule the fused pipeline out.
-        packed = (
-            _pj("match_fused", _match_fused, dev, fdev, tt, tlen,
-                tdollar, chunk_ids, budget=g, layout=lay,
-                use_pallas=False, interpret=self._pallas_interpret)
-            if _DEVPROF.enabled else
-            _match_fused(dev, fdev, tt, tlen, tdollar, chunk_ids,
-                         budget=g, layout=lay, use_pallas=False,
-                         interpret=self._pallas_interpret))
+        # the same call as the production dispatch (_run_fused): the verify
+        # must not compile a second executable. A compile or run failure
+        # here propagates: only a DISAGREEMENT (below) may rule the fused
+        # pipeline out.
+        packed = self._run_fused(dev, fdev, tt, tlen, tdollar, chunk_ids,
+                                 None, g, lay)
         got = self._complete_fused(
             ("f", b, chunk_ids.shape[0],
-             (dev, fdev, tt, tlen, tdollar, chunk_ids, None, lay, False),
+             (dev, fdev, tt, tlen, tdollar, chunk_ids, None, lay),
              packed, g))
-        ref_packed = (
-            _pj("match_global", _match_global, dev, tt, tlen, tdollar,
-                chunk_ids, budget=g, layout=lay)
-            if _DEVPROF.enabled else
-            _match_global(dev, tt, tlen, tdollar, chunk_ids, budget=g,
-                          layout=lay))
+        ref_packed = self._run_global(dev, tt, tlen, tdollar, chunk_ids,
+                                      None, g, lay)
         want = self._complete_global(
-            ("g", b, chunk_ids, None, (dev, tt, tlen, tdollar, None, lay),
+            ("g", b, chunk_ids, (dev, tt, tlen, tdollar, None, lay),
              ref_packed, g, fid_base, snap))
         if not any(len(w) for w in want):
             # a zero-match batch (empty table, the broker's prewarm probe)
@@ -2631,12 +2356,8 @@ class PartitionedMatcher:
             parts.append((pt, pl, pd, pc))
             meta.append((s, pb, tier))
             budgets.append(gb)
-        packed = (
-            _pj("match_fused_split", _match_fused_split, dev, fdev,
-                tuple(parts), tuple(budgets), layout=lay)
-            if _DEVPROF.enabled else
-            _match_fused_split(dev, fdev, tuple(parts), tuple(budgets),
-                               layout=lay))
+        packed = _pj("match_fused_split", _match_fused_split, dev, fdev,
+                     tuple(parts), tuple(budgets), layout=lay)
         return ("fs", b, order, meta, parts, (dev, fdev, lay), packed,
                 tuple(budgets))
 
@@ -2645,8 +2366,7 @@ class PartitionedMatcher:
         the host's whole decode is an ``np.split`` by counts (the device
         already resolved rows→fids and sorted per topic)."""
         _tag, b, padded, rerun, packed, g = handle
-        (dev, fdev, tt, tlen, tdollar, chunk_ids, grouped, lay,
-         use_pallas) = rerun
+        dev, fdev, tt, tlen, tdollar, chunk_ids, grouped, lay = rerun
         st = self._stages
         tok = st["fetch"].begin(b) if self._timed() else 0
         try:
@@ -2659,27 +2379,8 @@ class PartitionedMatcher:
                 g = self._regrown(n, b, padded)
                 key = (chunk_ids.shape[0], chunk_ids.shape[1])
                 self._budgets[key] = max(self._budgets.get(key, 0), g)
-                prof = _DEVPROF.enabled
-                if grouped is None:
-                    packed = (
-                        _pj("match_fused", _match_fused, dev, fdev, tt, tlen,
-                            tdollar, chunk_ids, budget=g, layout=lay,
-                            use_pallas=use_pallas,
-                            interpret=self._pallas_interpret)
-                        if prof else _match_fused(
-                            dev, fdev, tt, tlen, tdollar, chunk_ids, budget=g,
-                            layout=lay, use_pallas=use_pallas,
-                            interpret=self._pallas_interpret))
-                else:
-                    packed = (
-                        _pj("match_fused_grouped", _match_fused_grouped, dev,
-                            fdev, tt, tlen, tdollar, *grouped, budget=g,
-                            layout=lay, use_pallas=use_pallas,
-                            interpret=self._pallas_interpret)
-                        if prof else _match_fused_grouped(
-                            dev, fdev, tt, tlen, tdollar, *grouped, budget=g,
-                            layout=lay, use_pallas=use_pallas,
-                            interpret=self._pallas_interpret))
+                packed = self._run_fused(dev, fdev, tt, tlen, tdollar,
+                                         chunk_ids, grouped, g, lay)
         except NeedsCompile:  # a regrown budget has no program yet
             if tok:
                 st["fetch"].end(tok)
@@ -2735,12 +2436,8 @@ class PartitionedMatcher:
                 if ok:
                     break
                 budgets = tuple(regrow)
-                packed = (
-                    _pj("match_fused_split", _match_fused_split, dev, fdev,
-                        tuple(parts), budgets, layout=lay)
-                    if _DEVPROF.enabled else
-                    _match_fused_split(dev, fdev, tuple(parts), budgets,
-                                       layout=lay))
+                packed = _pj("match_fused_split", _match_fused_split, dev,
+                             fdev, tuple(parts), budgets, layout=lay)
         except NeedsCompile:  # a regrown budget has no program yet
             if tok:
                 st["fetch"].end(tok)
@@ -2762,37 +2459,34 @@ class PartitionedMatcher:
             st["decode"].end(tok)
         return out
 
-    def prewarm(self, batch_sizes: Sequence[int] = (1, 8)) -> None:
-        """Pre-compile the small-batch dispatch shapes and latch the
-        LARGEST as the sticky pad floor, so cfg1-style traffic (a lone
-        publish per dispatch) reuses one already-compiled executable
-        instead of paying a fresh XLA compile per distinct tiny shape.
-        Safe to call from a background thread at broker start; matches
-        run against the live table and results are discarded."""
-        sizes = sorted(set(int(s) for s in batch_sizes if s > 0))
-        if self._pad_floor_pinned:
+    def prewarm(self, floor: int = PREWARM_FLOOR) -> None:
+        """Latch ``floor`` as the sticky pad floor and compile its program,
+        so cfg1-style traffic (a lone publish per dispatch) reuses one
+        already-compiled executable instead of paying a fresh XLA compile
+        per distinct tiny shape. The warm-up IS that traffic: one topic,
+        padded to the floor. Safe to call from a background thread at
+        broker start; the match runs against the live table and its
+        result is discarded."""
+        old = self._pad_floor
+        if not self._pad_floor_pinned:
             # an explicit RMQTT_PAD_FLOOR seed (autotune replay) outranks
             # the default latch: warm the SEEDED floor's shape and leave
             # the floor where the operator/fitter put it
-            sizes = [self._pad_floor]
-        if not sizes:
-            return
+            self._pad_floor = max(old, int(floor))
         try:
-            for s in sizes:
-                self.match(["\x00prewarm/nomatch"] * s)
-            old = self._pad_floor
-            if not self._pad_floor_pinned:
-                self._pad_floor = max(self._pad_floor, sizes[-1])
-            if _DEVPROF.enabled:
-                # pad-waste visibility (floor changes included): the cfg1
-                # small-batch regime must SHOW why it pays what it pays
-                _DEVPROF.note_pad_floor(self._pad_floor, old)
-            elif self._pad_floor != old:
-                _LOG.info("sticky pad floor %d -> %d (small batches pad up "
-                          "to this compiled shape)", old, self._pad_floor)
+            self.match(["\x00prewarm/nomatch"])
         except Exception as e:  # pragma: no cover - defensive
+            self._pad_floor = old
             _LOG.warning("matcher prewarm failed (%s); first small "
                          "publishes will pay the compile", e)
+            return
+        if _DEVPROF.enabled:
+            # pad-waste visibility (floor changes included): the cfg1
+            # small-batch regime must SHOW why it pays what it pays
+            _DEVPROF.note_pad_floor(self._pad_floor, old)
+        elif self._pad_floor != old:
+            _LOG.info("sticky pad floor %d -> %d (small batches pad up "
+                      "to this compiled shape)", old, self._pad_floor)
 
     def set_pad_floor(self, floor: int) -> int:
         """Knob seam (broker/knobs.py): set the sticky pad floor to an
@@ -2902,7 +2596,7 @@ class PartitionedMatcher:
             g = self._budget_for(padded, ncs)
             packed = _match_global(dev, ttok, tlen, tdollar, loc, budget=g,
                                    layout=lay)
-            handles.append(("g", b, loc, None,
+            handles.append(("g", b, loc,
                             (dev, ttok, tlen, tdollar, None, lay),
                             packed, g, fid_base, snap))
         return ("M", b, handles)
@@ -2963,11 +2657,8 @@ class PartitionedMatcher:
             meta.append((s, pb, tier))
             budgets.append(g)
         lay = self._dev_playout
-        packed = (
-            _pj("match_global_split", _match_global_split, dev, tuple(parts),
-                tuple(budgets), layout=lay)
-            if _DEVPROF.enabled else
-            _match_global_split(dev, tuple(parts), tuple(budgets), layout=lay))
+        packed = _pj("match_global_split", _match_global_split, dev,
+                     tuple(parts), tuple(budgets), layout=lay)
         return ("s", b, order, meta, parts, (dev, lay), packed, tuple(budgets),
                 fid_base, snap)
 
@@ -2996,11 +2687,8 @@ class PartitionedMatcher:
             if ok:
                 break
             budgets = tuple(regrow)
-            packed = (
-                _pj("match_global_split", _match_global_split, dev,
-                    tuple(parts), budgets, layout=lay)
-                if _DEVPROF.enabled else
-                _match_global_split(dev, tuple(parts), budgets, layout=lay))
+            packed = _pj("match_global_split", _match_global_split, dev,
+                         tuple(parts), budgets, layout=lay)
         # the decode snapshot is taken AFTER the blocking fetch (like every
         # other complete path); _decode_revalidated closes the
         # overlay→gather write window without stalling mutations
@@ -3119,32 +2807,7 @@ class PartitionedMatcher:
             return self._complete_split(handle)
         if handle[0] == "g":
             return self._complete_global(handle)
-        _tag, b, chunk_ids, words, dev_inputs, wi, wb, cn, kw, snap = handle
-        while True:
-            wi, wb, cn = fetch(wi), fetch(wb), fetch(cn)
-            if int(cn[:b].max(initial=0)) <= kw:
-                break
-            # rare: re-run wider; sticky so later batches skip the narrow run
-            kw = 1 << (int(cn[:b].max()) - 1).bit_length()
-            self.max_words = max(self.max_words, kw)
-            prof = _DEVPROF.enabled
-            if words is not None:
-                wi, wb, cn = (
-                    _pj("compact_words", _compact_words, words, max_words=kw)
-                    if prof else _compact_words(words, max_words=kw))
-            else:
-                dev, ttok, tlen, tdollar, lay = dev_inputs
-                wi, wb, cn = (
-                    _pj("match_partitioned", _match_partitioned, dev, ttok,
-                        tlen, tdollar, chunk_ids, max_words=kw, layout=lay)
-                    if prof else _match_partitioned(
-                        dev, ttok, tlen, tdollar, chunk_ids, max_words=kw,
-                        layout=lay))
-        return self._decode_revalidated(
-            snap, 0,
-            lambda fid_map, overlay, strict: _decode_batch(
-                wi[:b], wb[:b], chunk_ids[:b], b, fid_map,
-                overlay=overlay, strict=strict))
+        raise ValueError(f"unknown match handle kind {handle[0]!r}")
 
     def _group_inputs(self, groups: np.ndarray, chunk_ids: np.ndarray):
         """→ (uniq_cand [U_pow2, NC], inv [B]) for the grouped upload, or
@@ -3168,8 +2831,9 @@ class PartitionedMatcher:
         return uniq_cand, inv.astype(inv_dt, copy=False)
 
     def _complete_global(self, handle) -> List[np.ndarray]:
-        _tag, b, chunk_ids, words, dev_inputs, packed, g, fid_base, snap = handle
+        _tag, b, chunk_ids, dev_inputs, packed, g, fid_base, snap = handle
         padded, nc = chunk_ids.shape
+        dev, ttok, tlen, tdollar, grouped, lay = dev_inputs
         st = self._stages
         tok = st["fetch"].begin(b) if self._timed() else 0
         try:
@@ -3185,28 +2849,8 @@ class PartitionedMatcher:
                 g = self._regrown(n, b, padded)
                 # sticky pow2 regrow for this batch shape
                 self._budgets[(padded, nc)] = max(self._budgets.get((padded, nc), 0), g)
-                prof = _DEVPROF.enabled
-                if words is not None:
-                    packed = (_pj("compact_global", _compact_global, words,
-                                  budget=g)
-                              if prof else _compact_global(words, budget=g))
-                else:
-                    dev, ttok, tlen, tdollar, grouped, lay = dev_inputs
-                    if grouped is None:
-                        packed = (
-                            _pj("match_global", _match_global, dev, ttok, tlen,
-                                tdollar, chunk_ids, budget=g, layout=lay)
-                            if prof else _match_global(
-                                dev, ttok, tlen, tdollar, chunk_ids, budget=g,
-                                layout=lay))
-                    else:
-                        packed = (
-                            _pj("match_global_grouped", _match_global_grouped,
-                                dev, ttok, tlen, tdollar, *grouped, budget=g,
-                                layout=lay)
-                            if prof else _match_global_grouped(
-                                dev, ttok, tlen, tdollar, *grouped, budget=g,
-                                layout=lay))
+                packed = self._run_global(dev, ttok, tlen, tdollar,
+                                          chunk_ids, grouped, g, lay)
         except NeedsCompile:  # a regrown budget has no program yet
             if tok:
                 st["fetch"].end(tok)
@@ -3252,43 +2896,6 @@ def _overlay_fids(rows, fids, tj, overlay, strict):
         if not bool(keep.all()):
             return tj[keep], fids[keep]
     return tj, fids
-
-
-def _decode_batch(
-    wi: np.ndarray, wb: np.ndarray, chunk_ids: np.ndarray, b: int,
-    fid_map: np.ndarray, overlay=None, strict: bool = True,
-) -> List[np.ndarray]:
-    """(word_idx, word_bits) → per-topic sorted FILTER-ID arrays.
-
-    Prefers the native decoder (runtime/encode.cc rt_match_decode: bit
-    extraction + fid map + per-topic sort in C++); the numpy fallback below
-    doubles as its differential oracle (tests pin agreement). Decode is the
-    projected co-located host bottleneck, hence the attention. A handle
-    with concurrent-mutation state (overlay / non-strict) takes the numpy
-    path — the rare case where correctness work is needed per row."""
-    if overlay is None and strict:
-        native = _native_decode(wi, wb, chunk_ids, b, fid_map)
-        if native is not None:
-            return native
-    return _numpy_decode(wi, wb, chunk_ids, b, fid_map, overlay, strict)
-
-
-def _native_decode(wi, wb, chunk_ids, b, fid_map) -> Optional[List[np.ndarray]]:
-    try:
-        from rmqtt_tpu import runtime as rt
-    except Exception:
-        return None
-    res = rt.match_decode(
-        np.ascontiguousarray(wi, dtype=np.int32),
-        np.ascontiguousarray(wb, dtype=np.uint32),
-        np.ascontiguousarray(chunk_ids, dtype=np.int32),
-        WORDS_PER_CHUNK, CHUNK, fid_map,
-    )
-    if res is None:
-        return None
-    flat, counts = res
-    bounds = np.cumsum(counts[:-1])
-    return np.split(flat, bounds)
 
 
 def _decode_routes(
@@ -3354,7 +2961,8 @@ def _numpy_decode_routes(
 
 def _group_sorted(tj: np.ndarray, fids: np.ndarray, b: int) -> List[np.ndarray]:
     """(topic index, fid) pairs → per-topic sorted fid arrays via one
-    composite-key sort (shared tail of both numpy decode oracles).
+    composite-key sort (the tail of the numpy decode oracle; it beats a
+    two-key lexsort ~2x on 200K matches).
 
     The pack requires 0 <= fid < 2^32 — a -1 (cleared-row sentinel, would
     mean a kernel or compaction bug) or a fid past 2^32 (4.3 billion add()
@@ -3368,29 +2976,3 @@ def _group_sorted(tj: np.ndarray, fids: np.ndarray, b: int) -> List[np.ndarray]:
     out = composite & np.int64(0xFFFFFFFF)
     bounds = np.searchsorted(tj_sorted, np.arange(1, b))
     return np.split(out, bounds)
-
-
-def _numpy_decode(
-    wi: np.ndarray, wb: np.ndarray, chunk_ids: np.ndarray, b: int,
-    fid_map: np.ndarray, overlay=None, strict: bool = True,
-) -> List[np.ndarray]:
-    """Pure-numpy decode (fallback + differential oracle)."""
-    wpc = WORDS_PER_CHUNK
-    # expand bits only for NONZERO words: scanning the fully-unpacked
-    # [B, K, 32] bool tensor cost ~60ms/16K topics in np.nonzero alone,
-    # while nonzero words are ~2% of the tensor at realistic match rates
-    tjw, kjw = np.nonzero(wb)
-    words = wb[tjw, kjw]
-    bits = (words[:, None] >> np.arange(32, dtype=np.uint32)) & np.uint32(1)
-    nz_i, cols = np.nonzero(bits)
-    tj = tjw[nz_i]
-    widx = wi[tjw, kjw][nz_i]
-    rows = (
-        chunk_ids[tj, widx // wpc].astype(np.int64) * CHUNK
-        + (widx % wpc).astype(np.int64) * 32
-        + cols
-    )
-    fids = fid_map[rows]
-    tj, fids = _overlay_fids(rows, fids, tj, overlay, strict)
-    # one composite-key sort beats a two-key lexsort (~2x on 200K matches)
-    return _group_sorted(tj, fids, b)

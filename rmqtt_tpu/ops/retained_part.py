@@ -1,5 +1,5 @@
 """Partitioned retained-topic scan: the SUBSCRIBE-side inverse match with
-trie-style pruning (VERDICT r4 item 3).
+trie-style pruning.
 
 The dense ``ops.retained.RetainedScanner`` scans every stored topic row per
 SUBSCRIBE filter — O(retained) per scan, measured at 74 scans/s at 1M

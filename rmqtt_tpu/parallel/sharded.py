@@ -133,21 +133,16 @@ class ShardedPartitionedMatcher:
     the ``fp``-sharded dense path above is the scatter-gather analogue.
     """
 
-    def __init__(self, table, mesh: Mesh, max_words: int = 32,
-                 compact: Optional[str] = None) -> None:
+    def __init__(self, table, mesh: Mesh) -> None:
         import os
 
         self.table = table
         self.mesh = mesh
         self.ndev = int(np.prod(list(mesh.shape.values())))
-        self.max_words = max_words
-        # same two modes as the local PartitionedMatcher: 'global' compacts
-        # per DEVICE (each shard prefix-sums its own topic slice into its
-        # own slot budget and returns topic-local route slots + per-topic
-        # counts; shard-major == topic-major, so the host reattributes
-        # globally from the concatenated counts), 'topk' is the per-topic
-        # fixed-width fallback
-        self.compact_mode = compact or os.environ.get("RMQTT_COMPACT", "global")
+        # the compaction is global per DEVICE: each shard prefix-sums its
+        # own topic slice into its own slot budget and returns topic-local
+        # route slots + per-topic counts; shard-major == topic-major, so
+        # the host reattributes globally from the concatenated counts
         self._budgets = {}  # padded batch size -> sticky pow2 PER-DEVICE slots
         self._gsteps = {}  # per-device budget -> jitted shard_map step
         self._fsteps = {}  # per-device budget -> jitted FUSED shard_map step
@@ -158,8 +153,7 @@ class ShardedPartitionedMatcher:
         # (RMQTT_FUSED=0/1 forces off/on), exactly like the local matcher.
         env_fused = os.environ.get("RMQTT_FUSED", "")
         self._fused = (
-            False if env_fused == "0" or self.compact_mode != "global"
-            else (True if env_fused == "1" else None)
+            False if env_fused == "0" else (True if env_fused == "1" else None)
         )
         self.fused_batches = 0
         self._dev_version = -1
@@ -259,7 +253,7 @@ class ShardedPartitionedMatcher:
             return self._dev_rows
         if _FP_UPLOAD.action is not None:  # chaos seam (utils/failpoints.py)
             _FP_UPLOAD.fire_sync()
-        want_fids = self._fused is not False and self.compact_mode == "global"
+        want_fids = self._fused is not False
         with t._mu:
             if self._dev_version == t.version and self._dev_rows is not None:
                 return self._dev_rows
@@ -282,12 +276,10 @@ class ShardedPartitionedMatcher:
                     idx, vals = _pad_scatter_pow2(
                         np.asarray(cids, dtype=np.int32), tiles
                     )
-                    self._dev_rows = (
-                        _pj("sharded_delta_scatter",
-                            lambda a, i, v: a.at[i].set(v),
-                            self._dev_rows, idx, vals)
-                        if _DEVPROF.enabled else
-                        self._dev_rows.at[idx].set(vals))
+                    self._dev_rows = _pj(
+                        "sharded_delta_scatter",
+                        lambda a, i, v: a.at[i].set(v),
+                        self._dev_rows, idx, vals)
                     self.uploads += 1
                     self.delta_uploads += 1
                     nb = tiles.nbytes
@@ -296,12 +288,10 @@ class ShardedPartitionedMatcher:
                         fidx, fvals = _pad_scatter_pow2(
                             np.asarray(cids, dtype=np.int32), ftiles
                         )
-                        self._dev_fids = (
-                            _pj("sharded_delta_scatter_fids",
-                                lambda a, i, v: a.at[i].set(v),
-                                self._dev_fids, fidx, fvals)
-                            if _DEVPROF.enabled else
-                            self._dev_fids.at[fidx].set(fvals))
+                        self._dev_fids = _pj(
+                            "sharded_delta_scatter_fids",
+                            lambda a, i, v: a.at[i].set(v),
+                            self._dev_fids, fidx, fvals)
                         nb += ftiles.nbytes
                     self.upload_bytes += nb
                     if _DEVPROF.enabled:
@@ -362,8 +352,6 @@ class ShardedPartitionedMatcher:
         }
 
     def match(self, topics) -> list:
-        from rmqtt_tpu.ops.partitioned import _decode_batch, _match_partitioned
-
         t = self.table
         if getattr(t, "compact_async", False):
             # same churn trigger as PartitionedMatcher.match_submit (the
@@ -393,20 +381,7 @@ class ShardedPartitionedMatcher:
             jax.device_put(tdollar, batch_spec),
             jax.device_put(chunk_ids, row_spec),
         )
-        if self.compact_mode == "global":
-            return self._match_global(dev, inputs, chunk_ids, b, padded)
-        while True:
-            wi, wb, cn = _match_partitioned(dev, *inputs, max_words=self.max_words)
-            wi, wb, cn = fetch(wi), fetch(wb), fetch(cn)
-            if int(cn[:b].max(initial=0)) <= self.max_words:
-                break
-            # rare overflow: re-run only the kernel, wider (inputs stay on
-            # device; no re-encode/re-upload)
-            self.max_words = 1 << (int(cn[:b].max()) - 1).bit_length()
-        return self._decode_revalidated(
-            lambda fid_map, overlay, strict: _decode_batch(
-                wi[:b], wb[:b], chunk_ids[:b], b, fid_map,
-                overlay=overlay, strict=strict))
+        return self._match_global(dev, inputs, chunk_ids, b, padded)
 
     def _decode_state(self):
         """Same snapshot decode as PartitionedMatcher._snap_decode_state:
@@ -484,10 +459,8 @@ class ShardedPartitionedMatcher:
             # alone are identical across budget regrows, and a regrow IS a
             # recompile the storm detector must see
             step = self._fused_step(gd)
-            out_dev = (
-                _pj("sharded_fused", step, dev, self._dev_fids, *inputs,
-                    _key_extra=("budget", gd))
-                if _DEVPROF.enabled else step(dev, self._dev_fids, *inputs))
+            out_dev = _pj("sharded_fused", step, dev, self._dev_fids, *inputs,
+                          _key_extra=("budget", gd))
             self.last_out_shards = [(sh.device.id, tuple(sh.data.shape))
                                     for sh in out_dev.addressable_shards]
             arr = fetch(out_dev, "sharded fused fetch")
@@ -526,9 +499,8 @@ class ShardedPartitionedMatcher:
             # into the step closure, so arg shapes alone would classify a
             # budget-regrow recompile as a cache hit)
             step = self._global_step(gd)
-            out_dev = (_pj("sharded_global", step, dev, *inputs,
-                           _key_extra=("budget", gd))
-                       if _DEVPROF.enabled else step(dev, *inputs))
+            out_dev = _pj("sharded_global", step, dev, *inputs,
+                          _key_extra=("budget", gd))
             self.last_out_shards = [(sh.device.id, tuple(sh.data.shape))
                                     for sh in out_dev.addressable_shards]
             arr = fetch(out_dev, "sharded match fetch")

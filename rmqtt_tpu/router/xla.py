@@ -288,16 +288,16 @@ class XlaRouter(Router):
             if seq:
                 self.telemetry.batch_end(seq)
 
-    def prewarm(self, batch_sizes=(1, 8)) -> None:
-        """Pre-compile the device matcher's small dispatch shapes (and
-        latch its sticky pad floor) so the first lone publishes after
-        start don't pay an XLA compile. Called by RoutingService.start()
+    def prewarm(self) -> None:
+        """Latch the device matcher's sticky pad floor and pre-compile its
+        dispatch shape so the first lone publishes after start don't pay
+        an XLA compile. Called by RoutingService.start()
         on a background thread; safe no-op for matchers without the hook
         or before any subscription exists (compiles are shape-keyed, so
         warming an empty table still covers the live shapes)."""
         m = getattr(self, "matcher", None)
         if m is not None and hasattr(m, "prewarm"):
-            m.prewarm(batch_sizes)
+            m.prewarm()
 
     def set_hybrid_max(self, n: int) -> int:
         """Knob seam (broker/knobs.py): move the trie-vs-device batch
@@ -432,13 +432,12 @@ class XlaRouter(Router):
 
         m = self.matcher
         mesh = getattr(m, "mesh", None)
-        producer = getattr(m, "words_producer", None)
         return {
             **self.device_ident,
             "matcher": type(m).__name__,
             "mesh_devices": int(mesh.devices.size) if mesh is not None else 1,
-            "words_producer": producer() if callable(producer) else
-            {"name": "lax", "why": "the only producer of this matcher"},
+            "words_producer": {"name": "lax",
+                               "why": "the only producer of this matcher"},
             "host_mirror": ("native" if self._side_native
                             else "python" if self._side is not None
                             else "none"),

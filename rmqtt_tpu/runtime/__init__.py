@@ -106,16 +106,6 @@ def load() -> Optional[ctypes.CDLL]:
         ctypes.POINTER(ctypes.c_int32),
     ]
     lib.rt_enc_encode.restype = ctypes.c_int32
-    lib.rt_match_decode.argtypes = [
-        ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_uint32),
-        ctypes.c_int64, ctypes.c_int64,
-        ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
-        ctypes.c_int32, ctypes.c_int32,
-        ctypes.POINTER(ctypes.c_int64),
-        ctypes.POINTER(ctypes.c_int64), ctypes.c_int64,
-        ctypes.POINTER(ctypes.c_int64),
-    ]
-    lib.rt_match_decode.restype = ctypes.c_int64
     lib.rt_match_decode_routes.argtypes = [
         ctypes.POINTER(ctypes.c_uint32), ctypes.c_int64,
         ctypes.POINTER(ctypes.c_int64),
@@ -456,37 +446,3 @@ def match_decode_routes(routes: np.ndarray, counts: np.ndarray,
             "kernel/compaction bug"
         )
     return out
-
-
-def match_decode(wi: np.ndarray, wb: np.ndarray, chunk_ids: np.ndarray,
-                 wpc: int, chunk: int, fid_map: np.ndarray):
-    """Native compact-words → (flat sorted fids, per-topic counts); None if
-    the runtime is unavailable. Arrays must be C-contiguous int32/uint32
-    except fid_map (int64)."""
-    lib = load()
-    if lib is None:
-        return None
-    b, k = wi.shape
-    nc = chunk_ids.shape[1]
-    fid_map = np.ascontiguousarray(fid_map, dtype=np.int64)
-    counts = np.empty(b, dtype=np.int64)
-    cap = max(64, int(b) * 16)
-    i32 = ctypes.POINTER(ctypes.c_int32)
-    i64 = ctypes.POINTER(ctypes.c_int64)
-    u32 = ctypes.POINTER(ctypes.c_uint32)
-    while True:
-        out = np.empty(cap, dtype=np.int64)
-        total = lib.rt_match_decode(
-            wi.ctypes.data_as(i32), wb.ctypes.data_as(u32), b, k,
-            chunk_ids.ctypes.data_as(i32), nc, wpc, chunk,
-            fid_map.ctypes.data_as(i64),
-            out.ctypes.data_as(i64), cap, counts.ctypes.data_as(i64),
-        )
-        if total < 0:
-            raise AssertionError(
-                "rt_match_decode hit an out-of-range fid (cleared-row "
-                "sentinel or overflow) — kernel/compaction bug"
-            )
-        if total <= cap:
-            return out[:total], counts
-        cap = int(total)

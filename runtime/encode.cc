@@ -307,59 +307,6 @@ int32_t rt_enc_encode(void* h, const char* blob, int64_t n, int32_t max_levels,
 
 }  // extern "C"
 
-// Decode compact match words → per-topic sorted filter ids (the host side
-// of ops/partitioned.py::_decode_batch). For topic t, word slot j covers
-// rows chunk_ids[t, wi[t,j]/wpc]*chunk + (wi[t,j]%wpc)*32 .. +31; set bits
-// map through fid_map. Two-pass contract: fills counts[b] always; writes
-// fids only when the total fits cap (else caller re-calls with a bigger
-// buffer). Returns the total match count.
-int64_t rt_match_decode(const int32_t* wi, const uint32_t* wb, int64_t b,
-                        int64_t k, const int32_t* chunk_ids, int64_t nc,
-                        int32_t wpc, int32_t chunk, const int64_t* fid_map,
-                        int64_t* out_fids, int64_t cap, int64_t* counts) {
-  // first pass: popcounts per topic
-  int64_t total = 0;
-  for (int64_t t = 0; t < b; ++t) {
-    int64_t c = 0;
-    const uint32_t* wrow = wb + t * k;
-    for (int64_t j = 0; j < k; ++j) c += __builtin_popcount(wrow[j]);
-    counts[t] = c;
-    total += c;
-  }
-  if (total > cap) return total;
-  int64_t off = 0;
-  for (int64_t t = 0; t < b; ++t) {
-    if (counts[t] == 0) continue;
-    int64_t* span = out_fids + off;
-    int64_t w = 0;
-    const uint32_t* wrow = wb + t * k;
-    const int32_t* irow = wi + t * k;
-    const int32_t* crow = chunk_ids + t * nc;
-    for (int64_t j = 0; j < k; ++j) {
-      uint32_t bits = wrow[j];
-      if (!bits) continue;
-      const int32_t widx = irow[j];
-      const int64_t base =
-          static_cast<int64_t>(crow[widx / wpc]) * chunk + (widx % wpc) * 32;
-      while (bits) {
-        const int bit = __builtin_ctz(bits);
-        bits &= bits - 1;
-        const int64_t fid = fid_map[base + bit];
-        if (fid < 0 || fid >= (1LL << 32)) {
-          // cleared-row sentinel (-1) or overflow: a kernel/compaction bug
-          // must fail loudly (same contract as the numpy oracle), never
-          // hand a bogus subscriber id to delivery
-          return -1;
-        }
-        span[w++] = fid;
-      }
-    }
-    std::sort(span, span + w);
-    off += w;
-  }
-  return total;
-}
-
 // Decode the ROUTE-level batch-global compaction (ops/partitioned.py
 // compact_global_impl): one widx*32+bitpos entry per match, flat
 // topic-major by the device's two-stage prefix sum; counts[bp] (per

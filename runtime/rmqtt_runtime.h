@@ -30,10 +30,6 @@ int64_t rt_enc_parts_put(void* enc, const char* keys, int64_t keys_len, int64_t 
 int32_t rt_enc_encode(void* enc, const char* blob, int64_t n, int32_t max_levels,
                       int32_t* ttok, int32_t* tlen, uint8_t* tdollar, int32_t nc_cap,
                       int32_t* cand, int32_t* cand_counts, int32_t* group);
-int64_t rt_match_decode(const int32_t* wi, const uint32_t* wb, int64_t b,
-                        int64_t k, const int32_t* chunk_ids, int64_t nc,
-                        int32_t wpc, int32_t chunk, const int64_t* fid_map,
-                        int64_t* out_fids, int64_t cap, int64_t* counts);
 int64_t rt_match_decode_routes(const uint32_t* routes, int64_t n,
                                const int64_t* counts,
                                const int32_t* chunk_ids, int64_t b,

@@ -155,34 +155,6 @@ static void test_encoder() {
   rt_enc_free(e);
 }
 
-static void test_match_decode() {
-  // 2 topics, k=2 word slots, nc=2, wpc=4, chunk=128
-  int32_t wi[4] = {0, 5, 1, 0};
-  uint32_t wb[4] = {0x3u, 0x80000000u, 0x1u, 0u};
-  int32_t chunk_ids[4] = {1, 2, 2, 0};
-  std::vector<int64_t> fid_map(3 * 128);
-  for (size_t i = 0; i < fid_map.size(); ++i) fid_map[i] = 1000 + (int64_t)i;
-  int64_t out[16];
-  int64_t counts[2];
-  int64_t total = rt_match_decode(wi, wb, 2, 2, chunk_ids, 2, 4, 128,
-                                  fid_map.data(), out, 16, counts);
-  assert(total == 4 && counts[0] == 3 && counts[1] == 1);
-  // topic 0: word 0 -> chunk 1 rows 128,129 ; word 5 -> chunk 2 row 2*128+32+31
-  assert(out[0] == 1000 + 128 && out[1] == 1000 + 129);
-  assert(out[2] == 1000 + 2 * 128 + 32 + 31);
-  assert(out[3] == 1000 + 2 * 128 + 32);  // topic 1: word 1 -> chunk 2, +32
-  // overflow contract: counts still filled, nothing written past cap
-  int64_t tiny[1];
-  total = rt_match_decode(wi, wb, 2, 2, chunk_ids, 2, 4, 128, fid_map.data(),
-                          tiny, 1, counts);
-  assert(total == 4 && counts[0] == 3);
-  // a hit on a cleared row (-1 sentinel) fails loudly, never returns -1 fid
-  fid_map[128] = -1;
-  total = rt_match_decode(wi, wb, 2, 2, chunk_ids, 2, 4, 128, fid_map.data(),
-                          out, 16, counts);
-  assert(total == -1);
-}
-
 static void test_match_decode_routes() {
   // route-level entries, b=2 (bp=3 with one padded topic), nc=2, wpc=4
   // (W=8), chunk=128
@@ -410,7 +382,6 @@ int main() {
   test_egress();
   test_trie();
   test_encoder();
-  test_match_decode();
   test_match_decode_routes();
   test_codec();
   std::puts("runtime sanitizer checks passed");

@@ -26,7 +26,7 @@ of from the defaults.
 
 Usage:
   python scripts/autotune_replay.py .devprof/*.json
-  python scripts/autotune_replay.py BENCH_r0*.json --json
+  python scripts/autotune_replay.py bench_out.json --json   # a bench.py artifact
   python scripts/autotune_replay.py dumps/*.json --env   # shell-ready
   python scripts/autotune_replay.py --history /var/lib/rmqtt/history
 
@@ -191,7 +191,6 @@ ENV_SEAMS = {
     "pad_floor": ("RMQTT_PAD_FLOOR", str),
     "fused": ("RMQTT_FUSED", lambda v: "1" if v else "0"),
     "packed": ("RMQTT_PACKED", lambda v: "1" if v else "0"),
-    "pallas": ("RMQTT_PALLAS", lambda v: "1" if v else "0"),
     "delta_uploads": ("RMQTT_DELTA_UPLOADS", lambda v: "1" if v else "0"),
     "hybrid_max": ("RMQTT_HYBRID_MAX", str),
     "linger_ms": ("RMQTT_ROUTING__LINGER_MS", str),

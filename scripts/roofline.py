@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Partitioned-matcher roofline: analytic bytes-moved vs HBM bandwidth.
 
-VERDICT r4 item 2 asked for the achievable ceiling as a NUMBER. This
+The achievable ceiling as a NUMBER. This
 script builds the bench's filter tables (reduced or full), measures the
 real candidate-chunk distribution of the bench's topic streams, and
 computes the per-batch HBM traffic of the scan kernel from the actual

@@ -39,7 +39,6 @@ class _FakeMatcher:
         self._pad_floor = 8
         self._fused = None
         self._packed_pref = True
-        self._pallas = None
         self.delta_enabled = True
 
     def set_pad_floor(self, floor):
@@ -417,7 +416,7 @@ def test_knob_registry_sources_and_write_seams(monkeypatch):
     assert ctx.knobs.source("max_batch") == "conf"
     # an explicit RMQTT_PAD_FLOOR seed survives prewarm's default latch
     # (the autotune-replay seeding workflow for live brokers)
-    ctx.router.prewarm((1, 8))
+    ctx.router.prewarm()
     assert ctx.router.matcher._pad_floor == 16
 
 

@@ -1,7 +1,7 @@
 """Ahead-of-time compiles of the served path's jitted programs for a
 DESCRIBED TPU v5e (no chip attached): what the chip's compiler refuses is
-found here, at no chip time. Interpret mode and the CPU backend hide every
-one of these failures (Mosaic tiling rules, compile time, memory).
+found here, at no chip time. The CPU backend hides every one of these
+failures (compile time, memory).
 
 Shapes are the ones ``chip_smoke.py`` drives: the BASELINE config-3 table
 (1,000,000 mixed ``+``/``#`` filters, seed 0 → 7,889 chunks padded to 8,192,
@@ -69,24 +69,27 @@ def _batch(spec, b, packed=True):
             spec((b, NC), jnp.uint16))
 
 
-def _tiles(spec, packed=True, dtype=jnp.int16):
+def _tiles(spec, packed=True):
     if packed:
         return spec((UP_CHUNKS, LAYOUT.groups * pm.CHUNK), jnp.int32)
-    return spec((UP_CHUNKS, LEVELS + 3, pm.CHUNK), dtype)
+    return spec((UP_CHUNKS, LEVELS + 3, pm.CHUNK), jnp.int16)
 
 
 def _budget(b):
     return max(256, 1 << (4 * b - 1).bit_length())
 
 
-@pytest.mark.parametrize("b", [8, 1024], ids=["prewarm8", "batch1024"])
+@pytest.mark.parametrize(
+    "b", [pm.PREWARM_FLOOR, 128, 256, 512, 1024],
+    ids=["prewarm8", "batch128", "batch256", "batch512", "batch1024"])
 def test_fused_step_compiles(spec, b):
-    """``_match_fused`` at the broker's prewarm shape and at its largest
+    """``_match_fused`` at the broker's prewarm shape, at the padded shapes
+    the cells' device batches take (~508 topics a batch and the smaller
+    ones the hybrid leaves: 128, 256, 512; PERF.md §5) and at its largest
     batch (``batch_max`` = 1024)."""
     c = pm._match_fused.lower(
         _tiles(spec), spec((UP_CHUNKS, pm.CHUNK), jnp.int32), *_batch(spec, b),
-        budget=_budget(b), layout=LAYOUT, use_pallas=False,
-        interpret=False).compile()
+        budget=_budget(b), layout=LAYOUT).compile()
     assert c.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
@@ -98,8 +101,7 @@ def test_fused_grouped_and_split_compile(spec):
     fids = spec((UP_CHUNKS, pm.CHUNK), jnp.int32)
     pm._match_fused_grouped.lower(
         _tiles(spec), fids, ttok, tlen, td, spec((u, NC), jnp.uint16),
-        spec((b,), jnp.int32), budget=_budget(b), layout=LAYOUT,
-        use_pallas=False, interpret=False).compile()
+        spec((b,), jnp.int32), budget=_budget(b), layout=LAYOUT).compile()
     parts = tuple(
         (spec((pb, LAYOUT.nlvl), jnp.int32), spec((pb,), jnp.int16),
          spec((pb,), jnp.bool_), spec((pb, tier), jnp.uint16))
@@ -110,14 +112,11 @@ def test_fused_grouped_and_split_compile(spec):
 
 
 def test_global_compact_reference_compiles(spec):
-    """The fused pipeline's first-use reference: words → global compact
-    (``_match_global``), and the compaction alone (``_compact_global``)."""
+    """The fused pipeline's first-use reference and fallback: words →
+    global compact (``_match_global``)."""
     b = 1024
     pm._match_global.lower(_tiles(spec), *_batch(spec, b), budget=_budget(b),
                            layout=LAYOUT).compile()
-    pm._compact_global.lower(
-        spec((b, NC * pm.WORDS_PER_CHUNK), jnp.uint32),
-        budget=_budget(b)).compile()
 
 
 @pytest.mark.parametrize("packed", [True, False], ids=["packed", "legacy"])
@@ -126,18 +125,6 @@ def test_lax_words_producer_compiles_16k(spec, packed):
     c = words.lower(_tiles(spec, packed), *_batch(spec, 16384, packed),
                     layout=LAYOUT if packed else None).compile()
     assert "tpu_custom_call" not in c.as_text()
-
-
-@pytest.mark.parametrize("tiles", ["packed", "legacy_int16", "legacy_int32"])
-def test_pallas_words_producer_compiles_16k(spec, tiles):
-    """Both Pallas entry points at 16384x32. Mosaic refused all but the
-    int32 legacy tile before the whole-tile DMA repair (pallas_match.py)."""
-    packed = tiles == "packed"
-    dt = jnp.int32 if tiles.endswith("32") else jnp.int16
-    c = pm._jit_words_pallas.lower(
-        _tiles(spec, packed, dt), *_batch(spec, 16384, packed),
-        layout=LAYOUT if packed else None, interpret=False).compile()
-    assert "tpu_custom_call" in c.as_text()
 
 
 def test_retained_scan_step_compiles(spec):

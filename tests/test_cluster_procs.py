@@ -56,6 +56,27 @@ def _wait_port(port: int, timeout: float = 45.0) -> None:
     raise TimeoutError(f"port {port} never opened")
 
 
+async def _connect_when_joined(port: int, client_id: str,
+                               timeout: float = 45.0) -> TestClient:
+    """Connect to a node that has only just opened its listener. A CONNECT
+    needs a raft-committed handshake lock, which a node cannot get before
+    it has found the leader and caught up; on a loaded host that takes
+    longer than the client's 5 s CONNACK wait. So retry as a device would:
+    until the CONNACK says accepted."""
+    deadline = asyncio.get_running_loop().time() + timeout
+    while True:
+        try:
+            c = await TestClient.connect(port, client_id)
+            if c.connack.reason_code == 0:
+                return c
+            await c.close()
+        except (asyncio.TimeoutError, OSError):
+            pass
+        assert asyncio.get_running_loop().time() < deadline, (
+            f"node on port {port} never accepted {client_id}")
+        await asyncio.sleep(0.2)
+
+
 def test_three_process_cluster_with_chaos():
     mports = _free_ports(4)  # mqtt ports (4th for the rejoining node)
     cports = _free_ports(4)  # cluster rpc ports
@@ -98,7 +119,7 @@ def test_three_process_cluster_with_chaos():
         # ---- a replacement node (same id/ports) rejoins and catches up
         spawn(4)
         _wait_port(mports[2])
-        sub3 = await TestClient.connect(mports[2], "proc-sub3")
+        sub3 = await _connect_when_joined(mports[2], "proc-sub3")
         ack = await sub3.subscribe("pc/rejoin/#", qos=1)
         assert ack.reason_codes[0] < 0x80
         deadline = asyncio.get_running_loop().time() + 45.0

@@ -41,7 +41,6 @@ def routed(monkeypatch):
     """→ (router, hybrid, telemetry, reference trie) over a ``mixed_tree``
     table with a few broad filters, the device profiler on as in a broker,
     and every never-seen program slowed by ``SLOW_S``."""
-    import rmqtt_tpu.ops.pallas_match  # noqa: F401  (a broker's prewarm has it)
     from rmqtt_tpu.ops import partitioned as P
     from rmqtt_tpu.router.xla import XlaRouter
 

@@ -49,7 +49,6 @@ def _matcher(nfilters: int = 4):
     t = PartitionedTable()
     fids = [t.add(f"a/b/c{i}") for i in range(nfilters)]
     m = PartitionedMatcher(t)
-    m._pallas = False  # CPU tests: no BT pad floor, padded == pow2(batch)
     return t, m, fids
 
 
@@ -258,7 +257,7 @@ def test_pad_floor_logged_and_annotated(prof, caplog):
     prof.configure(telemetry=tele)
     _t, m, _ = _matcher()
     with caplog.at_level("INFO", logger="rmqtt_tpu.devprof"):
-        m.prewarm((1, 8))
+        m.prewarm()
     assert m._pad_floor == 8
     assert prof.pad_floor == 8
     assert any("pad floor" in r.message for r in caplog.records)
@@ -391,6 +390,39 @@ def test_xla_router_dispatch_reaches_device_surface():
             DEVPROF.configure(enabled=False)
 
     asyncio.run(asyncio.wait_for(run(), 120))
+
+
+def test_device_surface_names_the_words_producer():
+    """``/api/v1/device`` ``backend`` of an xla broker: the matcher has one
+    words producer, and the surface (read by ``chip_smoke.py`` and the
+    benchmark's ``backend`` line) still says which."""
+    from tests.test_http_plugins import http_get
+    from rmqtt_tpu.broker.context import BrokerConfig, ServerContext
+    from rmqtt_tpu.broker.http_api import HttpApi
+    from rmqtt_tpu.broker.server import MqttBroker
+
+    async def run():
+        DEVPROF.reset()
+        ctx = ServerContext(BrokerConfig(port=0, router="xla",
+                                         routing_prewarm=False))
+        b = MqttBroker(ctx)
+        api = HttpApi(ctx, port=0)
+        await b.start()
+        await api.start()
+        try:
+            st, body = await http_get(api.bound_port, "/api/v1/device")
+            assert st == 200
+            be = json.loads(body)["backend"]
+            assert be["matcher"] == "PartitionedMatcher"
+            assert be["words_producer"]["name"] == "lax"
+            assert be["words_producer"]["why"]
+        finally:
+            await api.stop()
+            await b.stop()
+            DEVPROF.reset()
+            DEVPROF.configure(enabled=False)
+
+    asyncio.run(asyncio.wait_for(run(), 60))
 
 
 def test_sys_topic_device_tree():

@@ -49,18 +49,15 @@ def _oracle(fids, topic):
     return sorted(fid for fid, f in fids.items() if match_filter(f, topic))
 
 
-def test_fused_smoke_interpret(monkeypatch):
-    """Fast tier-1 smoke: fused pipeline + packed tiles + the Pallas
-    kernel in interpret mode, one small batch against the semantic
-    oracle."""
-    monkeypatch.setenv("RMQTT_PALLAS", "1")
+def test_fused_smoke():
+    """Fast tier-1 smoke: fused pipeline + packed tiles, one small batch
+    against the semantic oracle."""
     rng = random.Random(2)
     table, fids = _random_table(rng, 120)
     m = PartitionedMatcher(table)
     topics = _random_topics(rng, 24)
     got = m.match(topics)
     assert m._fused is True, "fused pipeline did not pass its self-check"
-    assert m._pallas is True and m._pallas_interpret
     assert m._dev_playout is not None, "packed tiles did not engage"
     for topic, row in zip(topics, got):
         assert sorted(row.tolist()) == _oracle(fids, topic), topic
@@ -105,7 +102,7 @@ def test_fused_equals_reference_property(segmented):
 
 def test_fused_never_enters_host_decode(monkeypatch):
     """THE pin: when the fused pipeline serves a batch, the host decode
-    path (_decode_routes/_decode_batch) is not entered at all."""
+    path (_decode_routes) is not entered at all."""
     rng = random.Random(4)
     table, fids = _random_table(rng, 100)
     m = PartitionedMatcher(table)
@@ -117,7 +114,6 @@ def test_fused_never_enters_host_decode(monkeypatch):
         raise AssertionError("host decode entered on the fused path")
 
     monkeypatch.setattr(P, "_decode_routes", _boom)
-    monkeypatch.setattr(P, "_decode_batch", _boom)
     got = m.match(topics)
     for topic, row in zip(topics, got):
         assert sorted(row.tolist()) == _oracle(fids, topic), topic
@@ -233,7 +229,7 @@ def test_fused_verify_not_latched_by_empty_batches():
     decision waits for a batch with real matches."""
     table = PartitionedTable()
     m = PartitionedMatcher(table)
-    m.prewarm((1, 8))  # the broker-start shape: prewarm before any sub
+    m.prewarm()  # the broker-start shape: prewarm before any sub
     assert m._fused is None, "vacuous empty-table batch latched the verify"
     fids = {table.add("a/b"): "a/b", table.add("a/+"): "a/+"}
     (row,) = m.match(["a/b"])
@@ -242,13 +238,13 @@ def test_fused_verify_not_latched_by_empty_batches():
 
 
 def test_prewarm_latches_pad_floor():
-    """prewarm() compiles the small shapes and latches the sticky pad
+    """prewarm() compiles the small shape and latches the sticky pad
     floor; later tiny submits reuse the floor shape."""
     rng = random.Random(8)
     table, fids = _random_table(rng, 60)
     fids[table.add("a/b")] = "a/b"  # guarantee the decide batch has matches
     m = PartitionedMatcher(table)
-    m.prewarm((1, 8))
+    m.prewarm()
     assert m._pad_floor == 8
     m.match(["a/b"])  # decide fused on a real-match batch
     assert m._fused is True

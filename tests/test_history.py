@@ -21,6 +21,7 @@ Tiers:
 import asyncio
 import json
 import os
+import re
 
 from rmqtt_tpu.broker.context import BrokerConfig, ServerContext
 from rmqtt_tpu.broker.history import (
@@ -325,10 +326,14 @@ def test_forced_anomaly_end_to_end():
             assert row["history.collect_ms"] >= 80.0
             await asyncio.sleep(0.05)  # let the hook task run
 
-            assert hist.anomalies, "no anomaly recorded"
-            a = hist.anomalies[-1]
-            assert a["series"] == "history.collect_ms"
-            assert a["value"] >= 80.0 and a["factor"] > 1.0
+            # the FORCED anomaly: a slow collect_once under load (5 ms
+            # against a ~1 ms baseline) may have fired one of its own first
+            forced = [x for x in hist.anomalies
+                      if x["series"] == "history.collect_ms"
+                      and x["value"] >= 80.0]
+            assert forced, f"no forced anomaly recorded: {hist.anomalies}"
+            a = forced[-1]
+            assert a["factor"] > 1.0
             # the correlated dump rode the annotation by reference
             assert any(d["plane"] == "device"
                        and d["reason"] == "test-retrace-storm"
@@ -338,23 +343,25 @@ def test_forced_anomaly_end_to_end():
                        for op in b.ctx.telemetry.slow_ops)
             # SERVER_ANOMALY hook payload
             assert fired, "SERVER_ANOMALY hook did not fire"
-            series, value, arow = fired[0]
-            assert series == "history.collect_ms" and value >= 80.0
-            assert arow["series"] == "history.collect_ms"
+            hooked = [f for f in fired
+                      if f[0] == "history.collect_ms" and f[1] >= 80.0]
+            assert hooked, f"the forced anomaly did not reach the hook: {fired}"
+            assert hooked[-1][2]["series"] == "history.collect_ms"
             # counters: stats gauge + the per-series scrape family
             assert b.ctx.stats().to_json()["history_anomalies"] >= 1
             status, body = await http_get(api.bound_port,
                                           "/metrics/prometheus")
             text = body.decode()
             assert "# TYPE rmqtt_history_anomalies_total counter" in text
-            assert ('rmqtt_history_anomalies_total{node="1",'
-                    'series="history.collect_ms"} 1') in text
+            m = re.search(r'rmqtt_history_anomalies_total\{node="1",'
+                          r'series="history\.collect_ms"\} (\d+)', text)
+            assert m and int(m.group(1)) >= 1, text
             assert "rmqtt_history_samples_recorded_total" in text
             # anomalies ride the query body
             status, body = await http_get(api.bound_port, "/api/v1/history")
             snap = json.loads(body)
-            assert snap["anomalies"] and (
-                snap["anomalies"][-1]["series"] == "history.collect_ms")
+            assert any(x["series"] == "history.collect_ms"
+                       and x["value"] >= 80.0 for x in snap["anomalies"])
             # ops_doctor renders the step + its correlated dump
             import importlib.util
             import pathlib

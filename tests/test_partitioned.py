@@ -112,9 +112,11 @@ def test_partitioned_overflow_rerun():
     # use '+' to concentrate matches instead:
     table2 = PartitionedTable()
     fids2 = [table2.add("a/+/#") for _ in range(300)]
-    m = PartitionedMatcher(table2, max_words=4)
+    m = PartitionedMatcher(table2)
     (row,) = m.match(["a/b/c"])
-    assert len(row) == 300  # auto-widened despite max_words=4
+    assert len(row) == 300
+    # a lone topic's shape starts at 256 slots: the budget regrew, sticky
+    assert max(m._budgets.values()) >= 512
 
 
 def test_deep_filter_and_topic():
@@ -132,12 +134,16 @@ def test_deep_filter_and_topic():
 
 def test_jit_signature_stability_under_churn():
     """Table growth/churn must not thrash XLA compiles: device-array chunk
-    counts are pow2-bucketed (floor 64) and NC/B/max_words are pow2-bucketed,
-    so a steady add/remove workload pins a handful of jit signatures."""
+    counts are pow2-bucketed (floor 64) and NC/B/slot budgets are
+    pow2-bucketed, so a steady add/remove workload pins a handful of jit
+    signatures of the programs the matcher serves with."""
     import random
 
     from rmqtt_tpu.core.topic import filter_valid
-    from rmqtt_tpu.ops.partitioned import _match_partitioned
+    from rmqtt_tpu.ops.partitioned import _match_fused, _match_fused_grouped
+
+    def programs():
+        return _match_fused._cache_size() + _match_fused_grouped._cache_size()
 
     rng = random.Random(7)
     table = PartitionedTable()
@@ -158,7 +164,8 @@ def test_jit_signature_stability_under_churn():
     add_some(200)
     topics = ["/".join(rng.choice(words[:5]) for _ in range(rng.randint(1, 5))) for _ in range(32)]
     matcher.match(topics)
-    base = _match_partitioned._cache_size()
+    assert matcher._fused is True  # the first batch verified the pipeline
+    base = programs()
     # churn: interleave adds/removes with matches across many rounds
     for round_ in range(30):
         add_some(40)
@@ -168,9 +175,10 @@ def test_jit_signature_stability_under_churn():
         matcher.match(
             ["/".join(rng.choice(words[:5]) for _ in range(rng.randint(1, 5))) for _ in range(32)]
         )
-    grown = _match_partitioned._cache_size() - base
+    assert matcher.fused_batches >= 30
+    grown = programs() - base
     # buckets are sticky + pow2, so signatures grow log-bounded with table
-    # size (the workload grows the table ~7x => a few nc/max_words steps),
+    # size (the workload grows the table ~7x => a few nc/budget steps),
     # never per-round (30 rounds must NOT mean ~30 compiles)
     assert grown <= 4, f"churn thrashed XLA compiles: {grown} new signatures"
 
@@ -411,11 +419,9 @@ def test_native_encode_survives_nul_in_filter_levels():
     assert nat[3][0].any() and not nat[3][1].any()
 
 
-def test_pallas_kernel_interpret_matches_lax():
-    """The Pallas inner-loop kernel (interpret mode on CPU) must produce
-    bit-identical packed words / final matches vs the lax scan path, across
-    full add/remove/match workloads."""
-    import os
+def test_default_matcher_matches_oracle_through_churn():
+    """The default matcher (lax scan, fused tail) must produce exactly
+    ``match_filter``'s matches across a full add/remove/rematch workload."""
     import random
 
     from rmqtt_tpu.core.topic import filter_valid, match_filter
@@ -431,81 +437,39 @@ def test_pallas_kernel_interpret_matches_lax():
         f = "/".join(levels)
         if filter_valid(f):
             fids[table.add(f)] = f
-    prior = os.environ.get("RMQTT_PALLAS")
-    os.environ["RMQTT_PALLAS"] = "1"
-    try:
-        m = PartitionedMatcher(table)
-        topics = [
-            "/".join(rng.choice(["a", "b", "c", "x", ""]) for _ in range(rng.randint(1, 5)))
-            for _ in range(64)
-        ] + ["$sys/a"]
-        got = m.match(topics)
-        assert m._pallas is True, "pallas kernel did not pass its self-check"
-        for topic, row in zip(topics, got):
-            expect = sorted(fid for fid, f in fids.items() if match_filter(f, topic))
-            assert sorted(row.tolist()) == expect, topic
-        # churn then rematch through the same (pallas) matcher
-        for fid in list(fids)[:150]:
-            table.remove(fid)
-            del fids[fid]
-        got = m.match(topics[:16])
-        for topic, row in zip(topics[:16], got):
-            expect = sorted(fid for fid, f in fids.items() if match_filter(f, topic))
-            assert sorted(row.tolist()) == expect, topic
-    finally:
-        if prior is None:
-            del os.environ["RMQTT_PALLAS"]
-        else:
-            os.environ["RMQTT_PALLAS"] = prior
+    m = PartitionedMatcher(table)
+    topics = [
+        "/".join(rng.choice(["a", "b", "c", "x", ""]) for _ in range(rng.randint(1, 5)))
+        for _ in range(64)
+    ] + ["$sys/a"]
+    got = m.match(topics)
+    for topic, row in zip(topics, got):
+        expect = sorted(fid for fid, f in fids.items() if match_filter(f, topic))
+        assert sorted(row.tolist()) == expect, topic
+    # churn then rematch through the same matcher
+    for fid in list(fids)[:150]:
+        table.remove(fid)
+        del fids[fid]
+    got = m.match(topics[:16])
+    assert m.fused_batches >= 1  # served on the device, not by the reference
+    for topic, row in zip(topics[:16], got):
+        expect = sorted(fid for fid, f in fids.items() if match_filter(f, topic))
+        assert sorted(row.tolist()) == expect, topic
 
-
-def test_native_decode_matches_numpy():
-    """rt_match_decode (C++) vs the numpy decode oracle on random compact
-    words — byte-for-byte identical per-topic sorted fid lists."""
-    import numpy as np
-
-    from rmqtt_tpu import runtime as rt
-    from rmqtt_tpu.ops.partitioned import (
-        CHUNK,
-        WORDS_PER_CHUNK,
-        _native_decode,
-        _numpy_decode,
-    )
-
-    if rt.load() is None:
-        import pytest
-
-        pytest.skip("native runtime unavailable")
-    rng = np.random.default_rng(13)
-    b, k, nc, nchunks = 64, 8, 4, 16
-    wi = rng.integers(0, nc * WORDS_PER_CHUNK, size=(b, k)).astype(np.int32)
-    # sparse random words, some rows empty
-    wb = (rng.integers(0, 1 << 32, size=(b, k), dtype=np.uint32)
-          * (rng.random((b, k)) < 0.3)).astype(np.uint32)
-    chunk_ids = rng.integers(0, nchunks, size=(b, nc)).astype(np.int32)
-    fid_map = rng.integers(0, 1 << 31, size=nchunks * CHUNK).astype(np.int64)
-    got = _native_decode(wi, wb, chunk_ids, b, fid_map)
-    assert got is not None
-    want = _numpy_decode(wi, wb, chunk_ids, b, fid_map)
-    assert len(got) == len(want) == b
-    for g, w in zip(got, want):
-        assert g.tolist() == w.tolist()
-
-
-def test_global_vs_topk_compaction_parity():
-    """The batch-global compaction (default) and the per-topic top_k path
-    must produce identical routing results."""
+def test_global_compaction_matches_oracle():
+    """The batch-global compaction with the host decode (the fused
+    pipeline's reference and fallback) must produce the oracle's routing
+    results."""
     table, fids, rng = build_random(47, 2000)
     topics = [
         "/".join(rng.choice(["a", "b", "c", "d", "", "$m"]) for _ in range(rng.randint(1, 6)))
         for _ in range(96)
     ]
-    mg = PartitionedMatcher(table, compact="global")
-    mk = PartitionedMatcher(table, compact="topk")
+    mg = PartitionedMatcher(table)
+    mg._fused = False  # what a failed verify (or RMQTT_FUSED=0) leaves
     got_g = mg.match(topics)
-    got_k = mk.match(topics)
-    for topic, g, k in zip(topics, got_g, got_k):
-        assert g.tolist() == k.tolist(), topic
+    assert mg.fused_batches == 0
+    for topic, g in zip(topics, got_g):
         expect = sorted(fid for fid, f in fids.items() if match_filter(f, topic))
         assert g.tolist() == expect, topic
 
@@ -515,8 +479,8 @@ def test_global_budget_regrow():
     results — total is computed from the untruncated mask on device."""
     table = PartitionedTable()
     expect = sorted(table.add("a/+/#") for _ in range(200))
-    m = PartitionedMatcher(table, compact="global")
-    m.match(["a/0/0", "a/0/1"])  # settle pallas (first batch pads to BT)
+    m = PartitionedMatcher(table)
+    m.match(["a/0/0", "a/0/1"])  # the fused verify consumes the first batch
     m.match(["a/0/0", "a/0/1"])  # settle the steady 2-topic bucket
     bucket = min(m._budgets)  # the smallest bucket = the 2-topic one
     m._budgets[bucket] = 4  # force overflow: 200 matches span many words
@@ -606,7 +570,7 @@ def test_grouped_upload_dedup_parity():
     through the grouped candidate upload and routes identically to distinct
     topics; the no-dedup gate keeps unique batches on the plain path."""
     table, fids, rng = build_random(53, 1500)
-    m = PartitionedMatcher(table, compact="global")
+    m = PartitionedMatcher(table)
     hot = ["a/b/c", "a/b", "x/y/z"]
     topics = [hot[i % 3] for i in range(64)]  # U=3 << B
     rows = m.match(topics)
@@ -621,27 +585,48 @@ def test_grouped_upload_dedup_parity():
     assert m._group_inputs(uniq_groups, fake_cand) is None
 
 
-def test_pallas_decision_latches_off_small_batches_on_cpu(monkeypatch):
-    """ADVICE r2: (a) the process-wide race flag exists at module scope so
-    the decide path cannot NameError on a real TPU; (b) a CPU-platform
-    process latches _pallas=False on its FIRST small batch, so small-batch
-    workloads stop paying BT padding without ever seeing a >=1024 batch."""
-    import rmqtt_tpu.ops.partitioned as P
+def test_prewarm_compiles_one_program_and_latches_the_floor():
+    """The padding rule in one sentence: a batch pads to its pow2 bucket,
+    and to the sticky floor if that is larger. A fresh matcher's 1-topic
+    submit has batch dimension 1; ``prewarm`` compiles exactly one match
+    program (a lone topic padded to the floor) and afterwards the 1-topic
+    submit takes that program."""
+    from rmqtt_tpu.broker.devprof import DEVPROF
+    from rmqtt_tpu.ops.partitioned import PREWARM_FLOOR
 
-    assert hasattr(P, "_PALLAS_RACED")  # module-scope init (was a NameError)
-    monkeypatch.delenv("RMQTT_PALLAS", raising=False)
+    def batch_dim(h):
+        assert h[0] == "f", h[0]  # fused handles carry the batch inside
+        return h[3][5].shape[0]   # the rerun arguments
+
     table = PartitionedTable()
-    fids = {}
-    for f in ("a/b", "a/+", "x/#"):
-        fids[table.add(f)] = f
+    fid = table.add("a/b")
     m = PartitionedMatcher(table)
-    rows = m.match(["a/b"])
-    assert sorted(fids[i] for i in rows[0].tolist()) == ["a/+", "a/b"]
-    assert m._pallas is False  # latched without a >=1024 batch
-    # with pallas ruled out, a 1-topic submit no longer pads to the BT grid
+    m._fused = True  # skip the first-use verify: count the served program
+    assert m._pad_floor == 1
     h = m.match_submit(["a/b"])
-    chunk_ids = h[3][5] if h[0] == "f" else h[2]  # fused handles carry the
-    assert chunk_ids.shape[0] == 1                # batch inside rerun args
+    assert batch_dim(h) == 1
+    assert m.match_complete(h)[0].tolist() == [fid]
+
+    was = DEVPROF.enabled
+    DEVPROF.reset()
+    DEVPROF.enabled = True
+    def traced():
+        return {k: v["traces"]
+                for k, v in DEVPROF.snapshot()["compile"]["kernels"].items()
+                if v["traces"]}
+
+    try:
+        m.prewarm()
+        # one topic of eight rows: the deduplicated-candidate form
+        assert traced() == {"match_fused_grouped": 1}
+        assert m._pad_floor == PREWARM_FLOOR
+        h = m.match_submit(["a/b"])
+        assert batch_dim(h) == PREWARM_FLOOR
+        assert m.match_complete(h)[0].tolist() == [fid]
+        assert traced() == {"match_fused_grouped": 1}  # the warmed program
+    finally:
+        DEVPROF.enabled = was
+        DEVPROF.reset()
 
 
 def test_nc_split_dispatch_parity():

@@ -131,10 +131,9 @@ def test_broker_with_mesh_router():
     asyncio.run(asyncio.wait_for(run(), 120))
 
 
-def test_sharded_global_vs_topk_and_regrow():
-    """Sharded per-device global compaction == sharded topk == oracle, and
-    a forced per-shard budget overflow regrows and still returns exact
-    results."""
+def test_sharded_global_compaction_and_regrow():
+    """Sharded per-device global compaction == oracle, and a forced
+    per-shard budget overflow regrows and still returns exact results."""
     import random
 
     from rmqtt_tpu.core.topic import filter_valid, match_filter
@@ -157,14 +156,11 @@ def test_sharded_global_vs_topk_and_regrow():
         "/".join(rng.choice(["a", "b", "x", ""]) for _ in range(rng.randint(1, 5)))
         for _ in range(64)
     ]
-    mg = ShardedPartitionedMatcher(table, mesh, compact="global")
-    mk = ShardedPartitionedMatcher(table, mesh, compact="topk")
+    mg = ShardedPartitionedMatcher(table, mesh)
     got_g = mg.match(topics)
-    got_k = mk.match(topics)
-    for topic, g, k in zip(topics, got_g, got_k):
+    for topic, g in zip(topics, got_g):
         expect = sorted(fid for fid, f in fids.items() if match_filter(f, topic))
         assert g.tolist() == expect, topic
-        assert k.tolist() == expect, topic
     # force a per-shard overflow and re-match: sticky regrow, same results
     for key in list(mg._budgets):
         mg._budgets[key] = 2

@@ -198,11 +198,6 @@ def test_bench_trend_parses_all_artifact_generations(tmp_path):
     assert none == []
     text = bt.render(rows, regressions, 10.0)
     assert "REGRESSIONS" in text and "cfg1_exact_1k" in text
-    # smoke over the REAL accumulated artifacts (whatever their state)
-    real = bt.load_rounds(os.path.join(os.path.dirname(__file__), "..",
-                                       "BENCH_r*.json"))
-    assert len(real) >= 3
-    assert any(r["configs"] for r in real)
 
 
 def test_full_scrape_grammar_all_planes(tmp_path):
@@ -306,3 +301,33 @@ def test_roofline_peaks_are_keyed_by_device_kind():
     assert m["device_kind"] == "TPU v5 lite" and m["hbm_gbps"] == 819.0
     with pytest.raises(ValueError):
         model_table(t, [1], "TPU v99")
+
+
+# ------------------------------------------------- environment switches
+#: every literal ``RMQTT_*`` variable the package reads. A new one has to be
+#: argued for here (and named in README); ``RMQTT_<SECTION>__<KEY>`` is
+#: conf.py's generic TOML override, built from a prefix, and is not one
+ENV_SWITCHES = {
+    "RMQTT_DELTA_UPLOADS", "RMQTT_DEVICE_PROFILE", "RMQTT_DEVPROF_DIR",
+    "RMQTT_EGRESS_COALESCE", "RMQTT_FAILPOINTS", "RMQTT_FETCH_TIMEOUT",
+    "RMQTT_FUSED", "RMQTT_HOSTPROF_DIR", "RMQTT_HOST_PROFILE",
+    "RMQTT_HYBRID_ADAPT", "RMQTT_HYBRID_MAX", "RMQTT_KEEPALIVE_WHEEL",
+    "RMQTT_PACKED", "RMQTT_PAD_FLOOR", "RMQTT_PROBE_EVERY", "RMQTT_SEG_BYTES",
+}
+
+
+def test_environment_switches_are_the_pinned_list_and_documented():
+    import pathlib
+
+    root = pathlib.Path(__file__).parent.parent
+    read = set()
+    for path in (root / "rmqtt_tpu").rglob("*.py"):
+        read |= set(re.findall(
+            r'(?:environ\.get\(|environ\[|getenv\()\s*"(RMQTT_[A-Z_]+)"',
+            path.read_text()))
+    assert read == ENV_SWITCHES, (
+        f"read but not pinned: {sorted(read - ENV_SWITCHES)}; "
+        f"pinned but not read: {sorted(ENV_SWITCHES - read)}")
+    readme = (root / "README.md").read_text()
+    missing = [v for v in sorted(ENV_SWITCHES) if f"`{v}" not in readme]
+    assert not missing, f"switches README does not name: {missing}"
