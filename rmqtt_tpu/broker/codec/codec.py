@@ -41,6 +41,7 @@ from rmqtt_tpu.broker.codec.primitives import (
     encode_varint,
 )
 from rmqtt_tpu.broker.codec.props import decode_properties, encode_properties
+from rmqtt_tpu.runtime import CODEC_STRIDE
 
 _PROTO_NAMES = {b"MQIsdp": pk.V31, b"MQTT": None}  # None → level byte decides
 
@@ -63,6 +64,10 @@ def _native_lib():
             _native = False
     return _native or None
 
+
+# the acks whose whole body is the packet id, by first byte (flags as the
+# spec fixes them; any other first byte goes through ``_decode``)
+_ID_ONLY = {0x40: Puback, 0x50: Pubrec, 0x62: Pubrel, 0x70: Pubcomp}
 
 _SCAN_ERRORS = {
     1: "malformed remaining length",
@@ -151,19 +156,12 @@ class MqttCodec:
         v5 = self.version == pk.V5
         while True:
             buf = bytes(self._buf)
-            rows, consumed, err, hit_cap = rt.codec_scan(lib, buf, v5, self.max_inbound_size)
+            meta, n, consumed, err, hit_cap = rt.codec_scan(
+                lib, buf, v5, self.max_inbound_size)
             if consumed:
                 del self._buf[:consumed]
-            for m in rows:
-                first = m[0]
-                try:
-                    if first >> 4 == pk.TYPE_PUBLISH:
-                        out.append(self._build_publish(buf, m, v5))
-                    else:
-                        out.append(self._decode(first, buf[m[1] : m[1] + m[2]]))
-                except ProtocolError as e:
-                    self.pending_error = e
-                    return
+            if not self._build(buf, meta, 0, n, out):
+                return
             if err:
                 self.pending_error = ProtocolError(
                     _SCAN_ERRORS.get(err, f"scan error {err}"),
@@ -173,24 +171,61 @@ class MqttCodec:
             if not hit_cap:
                 return
 
-    def _build_publish(self, buf: bytes, m, v5: bool) -> Publish:
-        first = m[0]
-        qos = (first >> 1) & 0x3
+    def build(self, buf: bytes, meta, row0: int, nrows: int) -> List[Packet]:
+        """The packets of frames a native scan has framed already: records
+        ``row0`` to ``row0 + nrows`` of ``meta``, a flat list of
+        ``rt_codec_scan`` records (``CODEC_STRIDE`` ints a frame) whose
+        offsets index ``buf`` (the ingress thread's chunks,
+        runtime/ingress.cc). ``feed``'s contract: a frame that does not
+        decode sets ``pending_error``; the packets before it are returned,
+        and with none before it the error raises."""
+        if self.pending_error is not None:
+            raise self.pending_error
+        out: List[Packet] = []
+        if not self._build(buf, meta, row0, nrows, out) and not out:
+            raise self.pending_error
+        return out
+
+    def _build(self, buf: bytes, meta, row0: int, nrows: int,
+               out: List[Packet]) -> bool:
+        """→ False where a frame did not decode (``pending_error`` set)."""
+        v5 = self.version == pk.V5
+        lo = row0 * CODEC_STRIDE
+        for o in range(lo, lo + nrows * CODEC_STRIDE, CODEC_STRIDE):
+            first = meta[o]
+            try:
+                if first >> 4 == pk.TYPE_PUBLISH:
+                    out.append(self._build_publish(buf, meta, o, v5))
+                elif meta[o + 2] == 2 and first in _ID_ONLY:
+                    i = meta[o + 1]
+                    out.append(_ID_ONLY[first]((buf[i] << 8) | buf[i + 1], 0, {}))
+                else:
+                    i = meta[o + 1]
+                    out.append(self._decode(first, buf[i : i + meta[o + 2]]))
+            except ProtocolError as e:
+                self.pending_error = e
+                return False
+        return True
+
+    def _build_publish(self, buf: bytes, m, o: int, v5: bool) -> Publish:
+        """``m[o:o + 10]`` is the frame's record (runtime/codec.cc)."""
+        first = m[o]
         try:
-            topic = buf[m[3] : m[3] + m[4]].decode("utf-8")
+            topic = buf[m[o + 3] : m[o + 3] + m[o + 4]].decode("utf-8")
         except UnicodeDecodeError as e:
             raise ProtocolError(f"invalid utf8: {e}") from e
         props = {}
-        if v5 and m[7] > 1:  # a single byte is the zero-length varint
-            props = decode_properties(Reader(buf[m[6] : m[6] + m[7]]))
+        if v5 and m[o + 7] > 1:  # a single byte is the zero-length varint
+            props = decode_properties(Reader(buf[m[o + 6] : m[o + 6] + m[o + 7]]))
+        pid = m[o + 5]
         # positional: ~350ns/pkt cheaper than kwargs on the hot path
         return Publish(
             topic,
-            buf[m[8] : m[8] + m[9]],
-            qos,
+            buf[m[o + 8] : m[o + 8] + m[o + 9]],
+            (first >> 1) & 0x3,
             bool(first & 0x1),
             bool(first & 0x8),
-            m[5] if m[5] >= 0 else None,
+            pid if pid >= 0 else None,
             props,
         )
 

@@ -532,6 +532,12 @@ class ServerContext:
             from rmqtt_tpu.broker.egress import EgressHub
 
             self.egress_hub = EgressHub(self.telemetry)
+        # the socket reads of plain-TCP sessions, done by the runtime
+        # library's ingress thread (broker/ingress.py; started by the first
+        # session it can serve; none where the library did not load)
+        from rmqtt_tpu.broker.ingress import IngressHub
+
+        self.ingress_hub = IngressHub(self.metrics, self.telemetry)
         self.keepalive_wheel = None
         if (self.cfg.keepalive_wheel
                 and os.environ.get("RMQTT_KEEPALIVE_WHEEL", "") != "0"):
@@ -747,6 +753,7 @@ class ServerContext:
             self.durability.start()
         if self.keepalive_wheel is not None:
             self.keepalive_wheel.start()
+        self.ingress_hub.start()
         if self._store_sweep_task is None:
             self._store_sweep_task = asyncio.get_running_loop().create_task(
                 self._store_sweep_loop(), name="store-sweep")
@@ -771,6 +778,7 @@ class ServerContext:
             await self.keepalive_wheel.stop()
         if self.egress_hub is not None:
             self.egress_hub.close()
+        self.ingress_hub.close()
         await self.autotune.stop()
         await self.slo.stop()
         await self.overload.stop()
@@ -879,6 +887,15 @@ class ServerContext:
         if self.egress_hub is not None:
             (s.egress_thread_busy_ms_total, s.egress_thread_sends,
              s.egress_thread_jobs) = self.egress_hub.thread_stats()
+        # read chunks handed to sessions, those the native ingress thread
+        # read (broker/ingress.py), times its 64 KB bound stopped a
+        # connection; the thread's own clock
+        s.net_ingress_reads = self.metrics.get("net.ingress_reads")
+        s.net_ingress_offloop_reads = self.metrics.get(
+            "net.ingress_offloop_reads")
+        s.net_ingress_paused = self.metrics.get("net.ingress_paused")
+        (s.ingress_thread_busy_ms_total, s.ingress_thread_recvs,
+         s.ingress_thread_jobs) = self.ingress_hub.thread_stats()
         wheel = self.keepalive_wheel
         if wheel is not None:
             s.net_wheel_sessions = wheel.sessions

@@ -876,6 +876,8 @@ const KEYS=["connections","sessions","subscriptions","subscriptions_shared",
  "net_egress_coalesced","net_egress_drains",
  "net_egress_offloop_flushes","net_egress_offloop_partial",
  "egress_thread_busy_ms_total","egress_thread_sends","egress_thread_jobs",
+ "net_ingress_reads","net_ingress_offloop_reads","net_ingress_paused",
+ "ingress_thread_busy_ms_total","ingress_thread_recvs","ingress_thread_jobs",
  "net_wheel_sessions","net_wheel_timeouts",
  "routing_failover_state",
  "routing_failovers","routing_switchbacks","routing_failover_host_routed",

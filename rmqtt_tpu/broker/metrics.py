@@ -221,6 +221,17 @@ class Stats:
         self.egress_thread_busy_ms_total = 0.0
         self.egress_thread_sends = 0
         self.egress_thread_jobs = 0
+        # off-loop socket reads (broker/ingress.py): reads = read chunks
+        # handed to sessions (both paths), offloop_reads = those the native
+        # ingress thread read, paused = times its 64 KB bound stopped a
+        # connection, ingress_thread_* = that thread's busy clock, recvs,
+        # jobs (epoll rounds that posted)
+        self.net_ingress_reads = 0
+        self.net_ingress_offloop_reads = 0
+        self.net_ingress_paused = 0
+        self.ingress_thread_busy_ms_total = 0.0
+        self.ingress_thread_recvs = 0
+        self.ingress_thread_jobs = 0
         self.net_wheel_sessions = 0
         self.net_wheel_timeouts = 0
         # telemetry-history gauges (broker/history.py), filled by

@@ -51,6 +51,7 @@ from rmqtt_tpu.core.topic import (
     topic_valid,
 )
 from rmqtt_tpu.router.base import Id, SubscriptionOptions
+from rmqtt_tpu.runtime import INGRESS_DATA, INGRESS_RAW
 
 
 @dataclass
@@ -539,6 +540,9 @@ class SessionState:
                 high_water=getattr(ctx, "egress_high_water", 64 * 1024),
                 telemetry=ctx.telemetry,
                 hub=getattr(ctx, "egress_hub", None))
+        # the connection whose reads the native ingress thread does
+        # (broker/ingress.py), from _read_loop's take-over to the close
+        self._ingress = None
 
     # ------------------------------------------------------------------ io
     async def send(self, packet) -> None:
@@ -638,6 +642,9 @@ class SessionState:
                 # what the native thread is still writing (flush waits)
                 self._egress.flush()
                 self._egress.close()
+            if self._ingress is not None:
+                # out of the ingress thread's hands before the socket goes
+                self._ingress.detach()
             try:
                 self.writer.close()
             except Exception:
@@ -662,36 +669,136 @@ class SessionState:
             # the pipelined CONNECT burst ended in a malformed frame (even
             # with no valid packets between CONNECT and the bad frame):
             # any valid packets above were processed first, then close
-            self.ctx.metrics.inc("protocol.errors")
-            await self._disconnect_with(self.codec.pending_error.reason_code)
+            await self._protocol_error(self.codec.pending_error.reason_code)
             return
+        hub = getattr(self.ctx, "ingress_hub", None)
+        if hub is None or not hub.eligible(self):
+            await self._read_transport()
+        # a connection's first chunk (as a rule its SUBSCRIBE, or all a
+        # short-lived client ever sends) keeps the transport's path: the
+        # take-over costs a handful of system calls and every later read a
+        # second thread's wake-up, which the connect phase should not wait
+        # for and a connection that says one thing never earns back
+        elif (await self._read_transport(first_only=True)
+                and await self._read_transport(held_only=True)
+                and await self._read_offloop(hub)):
+            await self._read_transport()
+
+    async def _read_transport(self, first_only: bool = False,
+                              held_only: bool = False) -> bool:
+        """Serve the chunks of the asyncio transport's StreamReader: until
+        the connection ends (→ False); or — ``first_only`` — one chunk
+        (→ True after it); or — ``held_only``, the transport paused for the
+        ingress thread's take-over — while the reader holds bytes it had
+        read already (→ True once it is empty)."""
+        reader = self.reader
+        metrics = self.ctx.metrics
+        if held_only:
+            transport = self.writer.transport
+            transport.pause_reading()
+        served = 0
         while True:
-            data = await self.reader.read(65536)
+            if (first_only and served) or (held_only and not reader._buffer):
+                return True
+            served += 1
+            data = await reader.read(65536)
+            if held_only:
+                # a read that drains the reader may resume the transport:
+                # paused again before anything can suspend
+                transport.pause_reading()
             if not data:
-                return
+                return False
             self._last_packet = time.monotonic()
-            tok = (self._st_decode.begin(len(data))
-                   if self.ctx.telemetry.enabled else 0)
+            metrics.inc("net.ingress_reads")
             try:
-                packets = self.codec.feed(data)
+                packets = self._decode_chunk(data, len(data))
             except ProtocolViolation as e:
-                if tok:
-                    self._st_decode.end(tok)
-                self.ctx.metrics.inc("protocol.errors")
-                # v5: name the violation before closing (DISCONNECT 0x95
-                # packet-too-large / 0x81 malformed; disconnect.rs reasons)
-                await self._disconnect_with(e.reason_code)
-                return
-            if tok:
-                self._st_decode.end(tok)
+                await self._protocol_error(e.reason_code)
+                return False
             for p in packets:
                 await self._handle(p)
             if self.codec.pending_error is not None:
-                # a later frame in the chunk was malformed; valid packets
-                # above were processed first
-                self.ctx.metrics.inc("protocol.errors")
-                await self._disconnect_with(self.codec.pending_error.reason_code)
-                return
+                await self._protocol_error(self.codec.pending_error.reason_code)
+                return False
+
+    def _decode_chunk(self, data: bytes, size: int, meta=None, row0: int = 0,
+                      nrows: int = 0) -> list:
+        """One read chunk's packets, under the ``ingress.decode`` stage:
+        ``data`` through ``codec.feed``, or (``meta``) the ingress thread's
+        bytes, ``size`` of them whole frames, built from their scan
+        records. ``feed``'s contract: a frame that does not decode raises
+        if no packet precedes it, else is left as ``codec.pending_error``
+        for after the packets."""
+        tok = (self._st_decode.begin(size)
+               if self.ctx.telemetry.enabled else 0)
+        try:
+            if meta is None:
+                return self.codec.feed(data)
+            return self.codec.build(data, meta, row0, nrows)
+        finally:
+            if tok:
+                self._st_decode.end(tok)
+
+    async def _protocol_error(self, reason_code: int) -> None:
+        """A frame did not decode (the valid packets before it have been
+        handled). v5: name the violation before closing (DISCONNECT 0x95
+        packet-too-large / 0x81 malformed; disconnect.rs reasons)."""
+        self.ctx.metrics.inc("protocol.errors")
+        await self._disconnect_with(reason_code)
+
+    async def _read_offloop(self, hub) -> bool:
+        """Give the socket's reads to the native ingress thread
+        (broker/ingress.py; the transport is paused and its StreamReader
+        empty) and serve what it posts until the connection ends (→ False:
+        the read loop is done). → True where nothing could be registered:
+        the reads stay the transport's."""
+        transport = self.writer.transport
+        conn = None
+        if not (self.reader.at_eof() or transport.is_closing()):
+            # the codec's buffer holds at most the head of a frame now
+            conn = hub.attach(self, bytes(self.codec._buf))
+        if conn is None:
+            transport.resume_reading()
+            return True
+        self.codec._buf.clear()
+        self._ingress = conn
+        inbox = conn.inbox
+        while True:
+            if not inbox:
+                if conn.lost:
+                    # asyncio closed the connection under us (a write
+                    # failed, the writer was closed): what reader.read()
+                    # would have told the old loop
+                    return False
+                await conn.wait()
+                continue
+            chunks, meta, blob = inbox.popleft()
+            i = inbox.popleft()
+            flags, size = chunks[i + 1], chunks[i + 4]
+            if not flags & INGRESS_DATA:
+                # EOF, or recv's error: what reader.read() tells the other
+                # loop with b"" or a ConnectionError. run() closes the
+                # writer; no turn is spent on letting the transport see it
+                # too, while a publish to this client would still find the
+                # session connected
+                return False
+            try:
+                if flags & INGRESS_RAW:
+                    # the scan refused a frame: codec.feed judges the bytes
+                    off = chunks[i + 3]
+                    packets = self._decode_chunk(blob[off:off + size], size)
+                else:
+                    packets = self._decode_chunk(
+                        blob, size, meta, chunks[i + 5], chunks[i + 6])
+            except ProtocolViolation as e:
+                await self._protocol_error(e.reason_code)
+                return False
+            for p in packets:
+                await self._handle(p)
+            if self.codec.pending_error is not None:
+                await self._protocol_error(self.codec.pending_error.reason_code)
+                return False
+            conn.ack(size)
 
     async def _deliver_loop(self) -> None:
         s = self.s

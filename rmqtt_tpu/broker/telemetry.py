@@ -92,7 +92,8 @@ UNITS: Dict[str, str] = {"routing.batch_size": "count"}
 # the served path's busy stages, entry point down (see ``Stage``); the
 # section each one brackets is named at its call site
 SERVED_STAGES = (
-    "ingress.decode",        # codec.feed(data) per read chunk (session.py)
+    "ingress.collect",       # IngressHub._on_ready: one turn's chunks (ingress.py)
+    "ingress.decode",        # codec.feed / codec.build per read chunk (session.py)
     "ingress.publish",       # _publish_inner up to registry.forwards
     "routing.plan",          # RoutingService._plan(batch)
     "routing.match.side",    # AdaptiveHybrid._side_match (host trie mirror)

@@ -59,7 +59,8 @@ def load() -> Optional[ctypes.CDLL]:
         return _lib
     if _build_failed:
         return None
-    srcs = [_RUNTIME_DIR / n for n in ("topics.cc", "encode.cc", "codec.cc", "egress.cc")]
+    srcs = [_RUNTIME_DIR / n for n in (
+        "topics.cc", "encode.cc", "codec.cc", "egress.cc", "ingress.cc")]
     if not _LIB_PATH.exists() or any(
         s.exists() and s.stat().st_mtime > _LIB_PATH.stat().st_mtime for s in srcs
     ):
@@ -133,6 +134,8 @@ def load() -> Optional[ctypes.CDLL]:
     lib.rt_topic_validate.restype = ctypes.c_int
     if hasattr(lib, "rt_egress_new"):  # absent in stale .so builds
         _egress_protos(lib)
+    if hasattr(lib, "rt_ingress_new"):  # absent in stale .so builds
+        _ingress_protos(lib)
     _lib = lib
     return lib
 
@@ -157,12 +160,37 @@ def _egress_protos(lib) -> None:
     lib.rt_egress_stats.restype = None
 
 
+_I64P = ctypes.POINTER(ctypes.c_int64)
+
+
+def _ingress_protos(lib) -> None:
+    lib.rt_ingress_new.restype = ctypes.c_void_p
+    lib.rt_ingress_free.argtypes = [ctypes.c_void_p]
+    lib.rt_ingress_free.restype = None
+    lib.rt_ingress_eventfd.argtypes = [ctypes.c_void_p]
+    lib.rt_ingress_eventfd.restype = ctypes.c_int32
+    lib.rt_ingress_add.argtypes = [
+        ctypes.c_void_p, ctypes.c_int64, ctypes.c_int32, ctypes.c_int32,
+        ctypes.c_int64, ctypes.c_char_p, ctypes.c_int64]
+    lib.rt_ingress_add.restype = ctypes.c_int32
+    lib.rt_ingress_remove.argtypes = [ctypes.c_void_p, ctypes.c_int64]
+    lib.rt_ingress_remove.restype = None
+    lib.rt_ingress_collect.argtypes = [
+        ctypes.c_void_p, ctypes.c_int64, _I64P, _I64P,
+        ctypes.POINTER(_I64P), ctypes.POINTER(_I64P),
+        ctypes.POINTER(ctypes.c_void_p), _I64P]
+    lib.rt_ingress_collect.restype = ctypes.c_int64
+    lib.rt_ingress_stats.argtypes = [ctypes.c_void_p, _I64P]
+    lib.rt_ingress_stats.restype = None
+
+
 CODEC_STRIDE = 10  # int64 slots per frame record (runtime/codec.cc)
 _SCAN_CAP = 8192  # frames per scan call; feed loops on over-full buffers
 
 
 def codec_scan(lib, buf: bytes, is_v5: bool, max_size: int):
-    """→ (rows list [n][stride], consumed, err, hit_cap)."""
+    """→ (records, n, consumed, err, hit_cap): the ``n`` frames' records
+    as one flat list of ``CODEC_STRIDE`` ints a frame."""
     cap = min(len(buf) // 2 + 1, _SCAN_CAP)
     meta = np.empty((cap, CODEC_STRIDE), dtype=np.int64)
     consumed = ctypes.c_int64(0)
@@ -172,7 +200,7 @@ def codec_scan(lib, buf: bytes, is_v5: bool, max_size: int):
         meta.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)), cap,
         ctypes.byref(consumed), ctypes.byref(err),
     )
-    return meta[:n].tolist(), consumed.value, err.value, n == cap
+    return meta[:n].ravel().tolist(), n, consumed.value, err.value, n == cap
 
 
 def codec_encode_publish(lib, topic: bytes, payload: bytes, props: bytes,
@@ -335,6 +363,97 @@ class EgressThread:
         out = (ctypes.c_int64 * 3)()
         self._stats(self._ptr, out)
         return out[0], out[1], out[2]
+
+
+INGRESS_CHUNK = 7  # int64 slots per chunk record (runtime/ingress.cc)
+# chunk flags (runtime/ingress.cc)
+INGRESS_FRAMES, INGRESS_RAW, INGRESS_EOF, INGRESS_ERR, INGRESS_PAUSED = (
+    1, 2, 4, 8, 16)
+INGRESS_DATA = INGRESS_FRAMES | INGRESS_RAW  # a chunk that carries bytes
+
+
+class IngressThread:
+    """ctypes wrapper over the library's reading thread (runtime/ingress.cc):
+    one epoll over every registered connection's socket, a non-blocking
+    ``recv`` and the frame scan on readable, without ever taking the GIL.
+    Every method is the event loop's to call.
+
+    ``collect`` and ``stats`` are a swap and a few loads under a mutex the
+    thread holds only while it appends, so they go through a ``PyDLL``
+    handle and keep the GIL (as ``EgressThread`` does); ``add`` makes one
+    ``epoll_ctl``, ``remove`` can wait for a read in flight and ``close``
+    joins the thread: those release it."""
+
+    def __init__(self) -> None:
+        lib = load()
+        if lib is None or not hasattr(lib, "rt_ingress_new"):
+            raise RuntimeError("native runtime without ingress.cc")
+        self._lib = lib
+        held = ctypes.PyDLL(str(_LIB_PATH))  # the same mapping, GIL kept
+        _ingress_protos(held)
+        self._collect = held.rt_ingress_collect
+        self._stats = held.rt_ingress_stats
+        ptr = lib.rt_ingress_new()
+        if not ptr:
+            raise RuntimeError("rt_ingress_new failed (epoll / eventfd / thread)")
+        self._ptr = ctypes.c_void_p(ptr)
+        self.eventfd = int(lib.rt_ingress_eventfd(self._ptr))
+        self._chunks, self._meta = _I64P(), _I64P()
+        self._bytes = ctypes.c_void_p()
+        self._counts = (ctypes.c_int64 * 3)()
+        self._out = (ctypes.byref(self._chunks), ctypes.byref(self._meta),
+                     ctypes.byref(self._bytes), self._counts)
+
+    def close(self) -> None:
+        """Joins the thread and closes its epoll and eventfd; the
+        registered sockets stay the caller's."""
+        ptr, self._ptr = self._ptr, None
+        if ptr:
+            self._lib.rt_ingress_free(ptr)
+
+    __del__ = close
+
+    def add(self, conn_id: int, fd: int, is_v5: bool, max_size: int,
+            head: bytes = b"") -> None:
+        """The thread reads ``fd`` from now on, as connection ``conn_id``
+        (> 0, never reused). ``head``: bytes already read that are not a
+        whole frame yet. ``fd`` stays open until ``remove`` has returned."""
+        rc = self._lib.rt_ingress_add(self._ptr, conn_id, fd, 1 if is_v5 else 0,
+                                      max_size, head, len(head))
+        if rc:
+            raise OSError(-rc, os.strerror(-rc))
+
+    def remove(self, conn_id: int) -> None:
+        """Once this returns the thread is out of any read of the
+        connection's fd, for good."""
+        self._lib.rt_ingress_remove(self._ptr, conn_id)
+
+    def collect(self, acks: dict) -> Tuple[List[int], List[int], bytes]:
+        """Hand in ``acks`` (connection id → bytes consumed since the last
+        call) and take what the thread has posted: → (chunks, meta, blob),
+        two flat int lists of ``INGRESS_CHUNK`` and ``CODEC_STRIDE`` slots a
+        row (runtime/ingress.cc ``rt_ingress_collect``) and the bytes the
+        rows' offsets index."""
+        n = len(acks)
+        if n:
+            arr = ctypes.c_int64 * n
+            ids, sizes = arr(*acks), arr(*acks.values())
+        else:
+            ids = sizes = None
+        n = self._collect(self._ptr, n, ids, sizes, *self._out)
+        if not n:
+            return [], [], b""
+        _, frames, size = self._counts
+        return (self._chunks[:INGRESS_CHUNK * n],
+                self._meta[:CODEC_STRIDE * frames],
+                ctypes.string_at(self._bytes, size))
+
+    def stats(self) -> Tuple[int, int, int, int]:
+        """→ (busy ns, recvs, jobs, times the bound stopped a connection)
+        of the thread since it started."""
+        out = (ctypes.c_int64 * 4)()
+        self._stats(self._ptr, out)
+        return out[0], out[1], out[2], out[3]
 
 
 def _i32p(a: np.ndarray):

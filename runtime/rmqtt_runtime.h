@@ -58,4 +58,18 @@ int64_t rt_egress_collect(void* eg, int64_t* out, int64_t cap);
 int32_t rt_egress_wait(void* eg, int64_t ticket, int32_t timeout_ms);
 void rt_egress_stats(void* eg, int64_t* out);
 
+// ingress.cc — off-loop socket reads + frame scan (the library's second thread)
+void* rt_ingress_new();
+void rt_ingress_free(void* in);
+int32_t rt_ingress_eventfd(void* in);
+int32_t rt_ingress_add(void* in, int64_t id, int32_t fd, int32_t is_v5,
+                       int64_t max_size, const uint8_t* head,
+                       int64_t head_len);
+void rt_ingress_remove(void* in, int64_t id);
+int64_t rt_ingress_collect(void* in, int64_t n_acks, const int64_t* ack_ids,
+                           const int64_t* ack_bytes, const int64_t** chunks,
+                           const int64_t** meta, const uint8_t** bytes,
+                           int64_t* counts);
+void rt_ingress_stats(void* in, int64_t* out);
+
 }  // extern "C"

@@ -1,12 +1,13 @@
 // Sanitizer test driver for the native runtime (topics.cc, encode.cc,
-// codec.cc, egress.cc). Built with -fsanitize=address,undefined by `make
+// codec.cc, egress.cc, ingress.cc). Built with -fsanitize=address,undefined by `make
 // sancheck` and with -fsanitize=thread by `make tsancheck` (both run from
 // tests/test_native.py): exercises every C ABI entry point with normal,
 // boundary, and malformed inputs so leaks, overflows, UB and races are
 // caught even though the Python test suite runs against the unsanitized
-// library. Only egress.cc has a thread of its own (test_egress plays the
-// event loop against it); for the rest thread safety is external by
-// contract, so TSan has nothing to see there.
+// library. egress.cc and ingress.cc each have a thread of their own
+// (test_egress and test_ingress play the event loop against them); for the
+// rest thread safety is external by contract, so TSan has nothing to see
+// there.
 
 #include <fcntl.h>
 #include <poll.h>
@@ -19,7 +20,9 @@
 #include <cstdint>
 #include <cstdio>
 #include <cstring>
+#include <map>
 #include <string>
+#include <thread>
 #include <vector>
 
 #include "rmqtt_runtime.h"
@@ -378,8 +381,274 @@ static void test_egress() {
   rt_egress_free(nullptr);
 }
 
+// ---- ingress.cc: the loop's side, as broker/ingress.py plays it
+struct Chunk {
+  int64_t flags, err, nframes;
+  std::string bytes;               // the chunk's own bytes
+  std::vector<int64_t> meta;       // rows, offsets rebased to `bytes`
+};
+
+// One collect (after waiting for the eventfd, at most `wait_ms`) with the
+// given acks; appends every chunk to its connection's list.
+static int64_t collect_in(void* in, std::map<int64_t, std::vector<Chunk>>& got,
+                          int wait_ms,
+                          const std::vector<int64_t>& ack_ids = {},
+                          const std::vector<int64_t>& ack_bytes = {}) {
+  pollfd p{rt_ingress_eventfd(in), POLLIN, 0};
+  assert(poll(&p, 1, wait_ms) >= 0);
+  const int64_t *chunks, *meta;
+  const uint8_t* bytes;
+  int64_t counts[3];
+  const int64_t n = rt_ingress_collect(
+      in, static_cast<int64_t>(ack_ids.size()), ack_ids.data(),
+      ack_bytes.data(), &chunks, &meta, &bytes, counts);
+  assert(n == counts[0]);
+  for (int64_t i = 0; i < n; i++) {
+    const int64_t* c = chunks + 7 * i;
+    Chunk ch{c[1], c[2], c[6], std::string(), {}};
+    assert(c[3] >= 0 && c[3] + c[4] <= counts[2]);
+    ch.bytes.assign(reinterpret_cast<const char*>(bytes) + c[3],
+                    static_cast<size_t>(c[4]));
+    assert(c[5] + c[6] <= counts[1]);
+    for (int64_t f = 0; f < c[6]; f++) {
+      const int64_t* m = meta + 10 * (c[5] + f);
+      int64_t r[10];
+      std::memcpy(r, m, sizeof r);
+      r[1] -= c[3];
+      if ((m[0] >> 4) == 3) {
+        r[3] -= c[3];
+        if (m[6] >= 0) r[6] -= c[3];
+        r[8] -= c[3];
+      }
+      assert(r[1] >= 0 && r[1] + r[2] <= c[4]);  // the body lies in the chunk
+      ch.meta.insert(ch.meta.end(), r, r + 10);
+    }
+    got[c[0]].push_back(std::move(ch));
+  }
+  return n;
+}
+
+static void write_all(int fd, const std::string& s) {
+  size_t off = 0;
+  while (off < s.size()) {
+    ssize_t w = write(fd, s.data() + off, s.size() - off);
+    assert(w > 0);
+    off += static_cast<size_t>(w);
+  }
+}
+
+// A v3 QoS1 PUBLISH frame of topic "t/<i>" and the given payload.
+static std::string publish_frame(int i, int pid, const std::string& payload) {
+  const std::string topic = "t/" + std::to_string(i);
+  uint8_t out[512];
+  const int64_t n = rt_codec_encode_publish(
+      reinterpret_cast<const uint8_t*>(topic.data()),
+      static_cast<int64_t>(topic.size()),
+      reinterpret_cast<const uint8_t*>(payload.data()),
+      static_cast<int64_t>(payload.size()), nullptr, 0, 1, 0, 0, pid, out, 512);
+  assert(n > 0);
+  return std::string(reinterpret_cast<char*>(out), static_cast<size_t>(n));
+}
+
+static std::string all_bytes(const std::vector<Chunk>& v) {
+  std::string s;
+  for (const Chunk& c : v) s += c.bytes;
+  return s;
+}
+
+static void test_ingress() {
+  void* in = rt_ingress_new();
+  assert(in);
+  assert(rt_ingress_eventfd(in) >= 0);
+  std::map<int64_t, std::vector<Chunk>> got;
+  // 1) 16 connections, 10 rounds of a PUBLISH and a PUBACK each: every byte
+  // comes out once, per connection in order, framed and pre-parsed
+  constexpr int N = 16, ROUNDS = 10;
+  int sv[N][2];
+  for (auto& p : sv) make_pair(p);
+  for (int i = 0; i < N; i++)
+    assert(rt_ingress_add(in, i + 1, sv[i][0], 0, 1 << 20, nullptr, 0) == 0);
+  std::vector<std::string> sent(N);
+  const std::string puback = {0x40, 0x02, 0x00, 0x07};
+  for (int r = 0; r < ROUNDS; r++) {
+    for (int i = 0; i < N; i++) {
+      const std::string d =
+          publish_frame(i, r + 1, "p" + std::to_string(r)) + puback;
+      write_all(sv[i][1], d);
+      sent[i] += d;
+    }
+    std::vector<int64_t> ids, bytes;
+    for (auto& [id, v] : got) {  // acknowledge what the last rounds brought
+      ids.push_back(id);
+      bytes.push_back(0);
+    }
+    collect_in(in, got, 5000, ids, bytes);
+  }
+  for (int tries = 0; tries < 200; tries++) {
+    size_t have = 0;
+    for (int i = 0; i < N; i++) have += all_bytes(got[i + 1]).size();
+    size_t want = 0;
+    for (auto& s : sent) want += s.size();
+    if (have == want) break;
+    collect_in(in, got, 100);
+  }
+  for (int i = 0; i < N; i++) {
+    assert(all_bytes(got[i + 1]) == sent[i]);
+    int64_t frames = 0;
+    for (const Chunk& c : got[i + 1]) {
+      assert(c.flags == 1 && c.err == 0);
+      for (int64_t f = 0; f < c.nframes; f++) {
+        const int64_t* m = c.meta.data() + 10 * f;
+        if (m[0] == 0x32) {  // the PUBLISH: topic, id and payload spans
+          const std::string topic = "t/" + std::to_string(i);
+          assert(c.bytes.substr(static_cast<size_t>(m[3]),
+                                static_cast<size_t>(m[4])) == topic);
+          assert(m[5] >= 1 && m[5] <= ROUNDS && m[6] == -1);
+          assert(c.bytes.substr(static_cast<size_t>(m[8]),
+                                static_cast<size_t>(m[9])) ==
+                 "p" + std::to_string(m[5] - 1));
+        } else {
+          assert(m[0] == 0x40 && m[2] == 2);
+        }
+      }
+      frames += c.nframes;
+    }
+    assert(frames == 2 * ROUNDS);
+  }
+  // 2) a head the caller had read already, then a 30 KB frame in pieces:
+  // nothing is posted until the frame is whole, then it comes in one chunk
+  int big[2];
+  make_pair(big);
+  std::string frame = {static_cast<char>(0x82)};  // SUBSCRIBE, 30000-byte body
+  frame += static_cast<char>(0x80 | (30000 & 0x7F));
+  frame += static_cast<char>(0x80 | ((30000 >> 7) & 0x7F));
+  frame += static_cast<char>(30000 >> 14);
+  frame += std::string(30000, 's');
+  assert(rt_ingress_add(in, 100, big[0], 1, 1 << 20,
+                        reinterpret_cast<const uint8_t*>(frame.data()), 3) == 0);
+  for (size_t off = 3; off < frame.size();) {
+    const size_t n = std::min<size_t>(7001, frame.size() - off);
+    write_all(big[1], frame.substr(off, n));
+    off += n;
+    collect_in(in, got, off < frame.size() ? 30 : 5000);
+    if (off < frame.size()) assert(got.count(100) == 0);
+  }
+  while (got.count(100) == 0) collect_in(in, got, 100);
+  assert(got[100].size() == 1 && got[100][0].bytes == frame);
+  assert(got[100][0].nframes == 1 && got[100][0].meta[0] == 0x82 &&
+         got[100][0].meta[1] == 4 && got[100][0].meta[2] == 30000);
+  // 3) good frames, then one the scan refuses: the good ones come framed,
+  // the rest raw, and everything later raw too (oversize, then a CONNECT)
+  int bad[2], over[2], conn[2];
+  make_pair(bad), make_pair(over), make_pair(conn);
+  assert(rt_ingress_add(in, 101, bad[0], 0, 1 << 20, nullptr, 0) == 0);
+  assert(rt_ingress_add(in, 102, over[0], 0, 64, nullptr, 0) == 0);
+  assert(rt_ingress_add(in, 103, conn[0], 0, 1 << 20, nullptr, 0) == 0);
+  const std::string malformed = {0x30, static_cast<char>(0xFF),
+                                 static_cast<char>(0xFF), static_cast<char>(0xFF),
+                                 static_cast<char>(0xFF), 0x01};
+  write_all(bad[1], puback + malformed);
+  const std::string oversize = {0x30, 0x41};  // body of 65 > 64
+  write_all(over[1], puback + puback + oversize);
+  const std::string connect = {0x10, 0x02, 0x00, 0x00};
+  write_all(conn[1], puback + connect);
+  while (all_bytes(got[101]).size() < 10 || all_bytes(got[102]).size() < 10 ||
+         all_bytes(got[103]).size() < 8)
+    collect_in(in, got, 100);
+  for (int64_t id : {101, 102, 103}) {
+    assert(got[id].size() == 2);
+    assert(got[id][0].flags == 1 && got[id][0].nframes == (id == 102 ? 2 : 1));
+    assert(got[id][1].flags == 2 && got[id][1].nframes == 0);
+  }
+  assert(got[101][1].bytes == malformed && got[102][1].bytes == oversize &&
+         got[103][1].bytes == connect);
+  write_all(bad[1], puback);
+  while (got[101].size() < 3) collect_in(in, got, 100);
+  assert(got[101][2].flags == 2 && got[101][2].bytes == puback);
+  // 4) EOF in the middle of a frame, and a reset: posted, not handled
+  int eof[2], rst[2];
+  make_pair(eof), make_pair(rst);
+  assert(rt_ingress_add(in, 104, eof[0], 0, 1 << 20, nullptr, 0) == 0);
+  assert(rt_ingress_add(in, 105, rst[0], 0, 1 << 20, nullptr, 0) == 0);
+  write_all(eof[1], puback + std::string("\x30\x10half", 6));
+  close(eof[1]);
+  assert(write(rst[0], "unread", 6) == 6);  // the peer closes over it: a reset
+  close(rst[1]);
+  while (got[104].empty() || got[104].back().flags != 4 || got[105].empty())
+    collect_in(in, got, 100);
+  assert(all_bytes(got[104]) == puback);
+  assert(got[105].size() == 1 && got[105][0].flags == 8 &&
+         got[105][0].err == ECONNRESET);
+  // 5) the bound: a flood nobody acknowledges stops being read at 64 KB
+  // (+ one read); an acknowledgement sets it going again
+  int flood[2];
+  make_pair(flood);
+  assert(fcntl(flood[1], F_SETFL, O_NONBLOCK) == 0);
+  assert(rt_ingress_add(in, 106, flood[0], 0, 1 << 20, nullptr, 0) == 0);
+  std::string burst;
+  for (int k = 0; k < 256; k++) burst += puback;  // 1 KB of whole frames
+  size_t wrote = 0;
+  auto pump = [&] {  // write until the socket buffer is full
+    for (;;) {
+      ssize_t w = write(flood[1], burst.data(), burst.size());
+      if (w < 0) {
+        assert(errno == EAGAIN || errno == EWOULDBLOCK);
+        return;
+      }
+      wrote += static_cast<size_t>(w);
+    }
+  };
+  int64_t st[4];
+  size_t seen = 0;
+  for (int rounds = 0; rounds < 100; rounds++) {
+    pump();
+    collect_in(in, got, 50);
+    const size_t now = all_bytes(got[106]).size();
+    if (now == seen && now >= 64 * 1024) break;
+    seen = now;
+  }
+  assert(seen >= 64 * 1024 && seen < 64 * 1024 + 2 * 64 * 1024);
+  assert(wrote > seen);  // the rest waits in the kernel: TCP backpressure
+  bool paused = false;
+  for (const Chunk& c : got[106]) paused |= (c.flags & 16) != 0;
+  assert(paused);
+  rt_ingress_stats(in, st);
+  assert(st[3] >= 1);
+  collect_in(in, got, 50);
+  assert(all_bytes(got[106]).size() == seen);  // still stopped
+  collect_in(in, got, 50, {106}, {static_cast<int64_t>(seen)});
+  while (all_bytes(got[106]).size() == seen) collect_in(in, got, 100);
+  // 6) remove while the peer floods: afterwards the fd is ours to close,
+  // and a later collect may still bring what was posted before
+  std::thread flooder([&] {
+    for (int k = 0; k < 200; k++) {
+      if (send(sv[0][1], puback.data(), puback.size(), MSG_NOSIGNAL) < 0) return;
+    }
+  });
+  rt_ingress_remove(in, 1);
+  close(sv[0][0]);
+  flooder.join();
+  rt_ingress_remove(in, 1);     // twice: nothing to do
+  rt_ingress_remove(in, 4242);  // never added
+  collect_in(in, got, 10);
+  rt_ingress_stats(in, st);
+  assert(st[0] > 0 && st[1] >= N && st[2] >= 1);  // rounds may coalesce
+  // an fd that cannot be polled is refused at once
+  assert(rt_ingress_add(in, 107, sv[0][0], 0, 1 << 20, nullptr, 0) == -EBADF);
+  // 7) free with connections still registered: their fds stay ours
+  rt_ingress_free(in);
+  write_all(sv[1][1], puback);
+  assert(read_n(sv[1][0], 4) == puback);
+  close(sv[0][1]);
+  for (int i = 1; i < N; i++) close(sv[i][0]), close(sv[i][1]);
+  for (int* p : {big, bad, over, conn, flood}) close(p[0]), close(p[1]);
+  close(eof[0]), close(rst[0]);
+  rt_ingress_free(nullptr);
+}
+
 int main() {
   test_egress();
+  test_ingress();
   test_trie();
   test_encoder();
   test_match_decode_routes();

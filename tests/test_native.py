@@ -196,8 +196,9 @@ def test_native_topic_validate_matches_python():
 def test_runtime_sanitizers(target, needs):
     """The sanitizer passes over every native C ABI entry point (runtime/
     test_runtime.cc): ASan+UBSan (`make sancheck`: leaks, overflows, UB)
-    and, since egress.cc gave the library a thread of its own, TSan (`make
-    tsancheck`: races between the event loop's calls and that thread).
+    and, since egress.cc and ingress.cc gave the library threads of its
+    own, TSan (`make tsancheck`: races between the event loop's calls and
+    the egress and the ingress thread).
     They fail the suite even though Python links the unsanitized .so."""
     import shutil
     import subprocess
