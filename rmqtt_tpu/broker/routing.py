@@ -68,6 +68,7 @@ class RoutingService:
         self._rec_miss = self.tele.recorder("publish.cache_miss")
         self._rec_qwait = self.tele.recorder("routing.queue_wait")
         # busy-clock stages of the dispatch (telemetry.Stage)
+        self._st_run = self.tele.stage("ingress.run")
         self._st_plan = self.tele.stage("routing.plan")
         self._st_resolve = self.tele.stage("routing.resolve")
         self.max_batch = max_batch
@@ -329,6 +330,77 @@ class RoutingService:
             if trace is not None:
                 trace.add("publish.cache_miss", t0, dur, topic)
         return res, False
+
+    async def matches_run(self, msgs: list, traces: list) -> list:
+        """``matches_for_fanout`` for a run: the publishes one connection
+        had pipelined (``msgs``: their messages in publish order, matched
+        by ``from_id`` and ``topic``; ``traces`` beside them), offered to
+        the batcher in one synchronous pass — the ``ingress.run`` stage —
+        so they are neighbours in one dispatch, and awaited together.
+        → ``[(relations, cache_hit)]`` in the same order.
+
+        The run suspends at least once, as ``matches_for_fanout`` does per
+        publish (see there: the yield is load-bearing); its futures
+        resolve with the dispatch, so as a rule it suspends once, and the
+        caller yields between its fan-outs where one has met a crowded
+        deliver queue (``SessionState._run_forward``). If one is rejected
+        the rest are abandoned and the error raised."""
+        t0 = time.perf_counter_ns() if self.tele.enabled else 0
+        tok = self._st_run.begin(len(msgs)) if t0 else 0
+        loop = asyncio.get_running_loop()
+        q = self._q
+        out: list = []
+        parked = False
+        try:
+            for msg, trace in zip(msgs, traces):
+                from_id, topic = msg.from_id, msg.topic
+                entry = self._cache_lookup(topic)
+                if entry is not None:
+                    out.append((self.router.collapse(
+                        self.cache.derive(entry, from_id)), True))
+                    if t0:
+                        dur = time.perf_counter_ns() - t0
+                        self._rec_hit(dur, topic, trace)
+                        if trace is not None:
+                            trace.add("publish.cache_hit", t0, dur, topic)
+                    continue
+                fut = loop.create_future()
+                out.append(fut)
+                parked = True
+                # t0 doubles as the enqueue timestamp (queue-wait histogram)
+                item = (from_id, topic, fut, False, t0, trace)
+                try:
+                    q.put_nowait(item)
+                except asyncio.QueueFull:
+                    if tok:  # the section may not cross a suspension
+                        self._st_run.end(tok)
+                        tok = 0
+                    await q.put(item)
+            if tok:
+                self._st_run.end(tok)
+            if not parked:
+                await asyncio.sleep(0)
+                return out
+            timed = t0 and self.cache is not None  # see matches_for_fanout
+            for k, fut in enumerate(out):
+                if fut.__class__ is tuple:
+                    continue
+                out[k] = (await fut, False)
+                if timed:
+                    topic, trace = msgs[k].topic, traces[k]
+                    dur = time.perf_counter_ns() - t0
+                    self._rec_miss(dur, topic, trace)
+                    if trace is not None:
+                        trace.add("publish.cache_miss", t0, dur, topic)
+        except BaseException:
+            for fut in out:
+                if fut.__class__ is not tuple:
+                    # abandoned: a queued one is skipped at resolve time, a
+                    # rejected one's exception counts as retrieved
+                    if not fut.cancel() and not fut.cancelled():
+                        fut.exception()
+            raise
+        return out
 
     async def matches_raw(self, from_id: Optional[Id], topic: str):
         """Un-collapsed variant for cluster-global shared-group choice."""

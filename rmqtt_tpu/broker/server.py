@@ -43,6 +43,16 @@ log = logging.getLogger("rmqtt_tpu.broker")
 
 _UNSET = object()  # sentinel: _on_connection called as the raw listener callback
 
+#: listen(2) backlog of every listener (asyncio's own is 100). A fleet that
+#: reconnects opens hundreds of connections at once while the loop may be
+#: seconds from its next accept (a SUBSCRIBE burst): past the backlog the
+#: kernel drops the handshake's last ACK or falls back to SYN cookies, the
+#: client believes it is connected, and its CONNECT is answered with a reset
+#: much later (TcpExtListenOverflows; 4,096 subscribers connecting 512 at a
+#: time lost one connection in one run of six: PERF.md §6, PR 33). asyncio
+#: accepts up to this many a loop turn.
+LISTEN_BACKLOG = 1024
+
 
 def _build_ssl_context(cert: str, key, client_ca: str = ""):
     """Server-side TLS context; with ``client_ca`` set, mutual TLS
@@ -137,7 +147,11 @@ class MqttBroker:
             # plugin refuses to coexist (one owner of session durability).
             await self.ctx.durability.recover()
         cfg = self.ctx.cfg
-        rp = {"reuse_port": True} if cfg.reuse_port else {}
+        # every listener: SO_REUSEPORT where asked for, and a listen queue
+        # that holds a fleet's simultaneous connects (LISTEN_BACKLOG)
+        rp = {"backlog": LISTEN_BACKLOG}
+        if cfg.reuse_port:
+            rp["reuse_port"] = True
         self._server = await asyncio.start_server(
             self._on_connection, cfg.host, cfg.port, **rp
         )
@@ -320,9 +334,13 @@ class MqttBroker:
                     if peer is None:
                         return
             try:
-                got = await asyncio.wait_for(
-                    self._read_connect(reader, codec), timeout=ctx.cfg.max_handshake_delay
-                )
+                try:
+                    got = await asyncio.wait_for(
+                        self._read_connect(reader, codec),
+                        timeout=ctx.cfg.max_handshake_delay
+                    )
+                except asyncio.TimeoutError:
+                    got = await self._read_connect_late(reader, codec)
             except (asyncio.TimeoutError, ProtocolViolation, ConnectionError):
                 ctx.metrics.inc("handshake.failures")
                 writer.close()
@@ -368,6 +386,24 @@ class MqttBroker:
         if got is None or not isinstance(got[0], pk.Connect):
             return None
         return got
+
+    async def _read_connect_late(self, reader, codec):
+        """``max_handshake_delay`` has passed without a CONNECT. The deadline
+        is for a client that does not send; where its bytes are in the
+        reader all the same, they came in time and it was this loop that
+        was late (one turn of it can outlast the deadline while a fleet's
+        SUBSCRIBE burst is served): they are read after all — at once, the
+        buffer holds them. → what ``_read_connect`` gives, or raises the
+        TimeoutError a silent or half-sent CONNECT has earned."""
+        if not getattr(reader, "_buffer", None):
+            raise asyncio.TimeoutError
+        packets = codec.feed(await reader.read(65536))
+        if not packets:
+            raise asyncio.TimeoutError
+        self.ctx.metrics.inc("handshake.late_reads")
+        if not isinstance(packets[0], pk.Connect):
+            return None
+        return packets[0], packets[1:]
 
     async def _handshake(self, connect: pk.Connect, reader, writer, codec, peer,
                          early: Optional[list] = None):

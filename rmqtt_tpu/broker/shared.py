@@ -37,8 +37,16 @@ class SessionRegistry:
         self._st_fanout = ctx.telemetry.stage("fanout.enqueue")
         # the deliver-queue backpressure counters (Session._enqueue_crowded)
         # exist from the start: a reader tells "never" from "no such counter"
-        for name in ("fanout.enqueues", "fanout.held", "deliver.queue_over_half"):
+        for name in ("fanout.enqueues", "fanout.held", "deliver.queue_over_half",
+                     "deliver.cold_enqueues", "ingress.runs",
+                     "ingress.run_publishes"):
             ctx.metrics.inc(name, 0)
+        # a session may serve a connection's pipelined publishes as a run
+        # (session.py ``_publish_run``: ``RoutingService.matches_run``, then
+        # ``fanout`` each) only where ``forwards`` is this class's own: a
+        # registry that overrides it (cluster modes, the fabric) keeps one
+        # publish at a time
+        self.run_forwards = type(self).forwards is SessionRegistry.forwards
 
     # ------------------------------------------------------------- fencing
     @property
@@ -222,7 +230,21 @@ class SessionRegistry:
         # (broker/tracing.py); fan-out hands it to each DeliverItem so the
         # per-subscriber deliver loops can stamp their spans
         trace = CURRENT_TRACE.get() if self.ctx.telemetry.enabled else None
-        # p2p short-circuit (shared.rs:743-769)
+        if msg.target_clientid is not None:
+            return self.fanout(msg, None, False, trace)
+        # routed through the epoch-versioned match cache when the topic is
+        # hot: the collapsed map comes straight from the cached expansion
+        # (shared-group choice still per publish) and never enters the
+        # batcher; the QoS0 wire_cache below then reuses encode work WITHIN
+        # the fan-out, so a hot topic pays neither match nor re-encode
+        relmap, cache_hit = await self.ctx.routing.matches_for_fanout(
+            msg.from_id, msg.topic)
+        return self.fanout(msg, relmap, cache_hit, trace)
+
+    def fanout(self, msg: Message, relmap, cache_hit: bool, trace) -> int:
+        """The synchronous half of ``forwards``: enqueue ``msg`` for every
+        subscriber of ``relmap``; → how many. A p2p message goes to its
+        target alone, whatever was matched (shared.rs:743-769)."""
         if msg.target_clientid is not None:
             target = self._sessions.get(msg.target_clientid)
             if target is None:
@@ -233,13 +255,6 @@ class SessionRegistry:
             )
             self._mark_forwarded(msg, msg.target_clientid)
             return 1
-        # routed through the epoch-versioned match cache when the topic is
-        # hot: the collapsed map comes straight from the cached expansion
-        # (shared-group choice still per publish) and never enters the
-        # batcher; the QoS0 wire_cache below then reuses encode work WITHIN
-        # the fan-out, so a hot topic pays neither match nor re-encode
-        relmap, cache_hit = await self.ctx.routing.matches_for_fanout(
-            msg.from_id, msg.topic)
         if self.ctx.routing.cache is not None:
             # only meaningful with the cache on — counting misses while
             # disabled would read as a malfunctioning cache (0% hit rate)

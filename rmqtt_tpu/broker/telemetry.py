@@ -95,6 +95,7 @@ SERVED_STAGES = (
     "ingress.collect",       # IngressHub._on_ready: one turn's chunks (ingress.py)
     "ingress.decode",        # codec.feed / codec.build per read chunk (session.py)
     "ingress.publish",       # _publish_inner up to registry.forwards
+    "ingress.run",           # RoutingService.matches_run: a run's topics offered
     "routing.plan",          # RoutingService._plan(batch)
     "routing.match.side",    # AdaptiveHybrid._side_match (host trie mirror)
     "routing.match.device",  # device submit half + complete half (hybrid)

@@ -131,7 +131,7 @@ class _CompactState:
 
     __slots__ = ("arrays", "fid_of_row", "row_of_fid", "cap_chunks", "nchunks",
                  "excl_chunks", "excl_free", "shared_chunks_of",
-                 "shared_rows_of", "shared_free", "open_shared")
+                 "shared_rows_of", "shared_free", "open_shared", "spread")
 
 
 def _build_compact_state(
@@ -190,6 +190,7 @@ def _build_compact_state(
     st.shared_rows_of = {}
     st.shared_free = {}
     st.open_shared = []
+    st.spread = 0
     pos = CHUNK
     for key in keys_sorted:
         k = len(by_key[key])
@@ -202,6 +203,7 @@ def _build_compact_state(
             for r in krows:
                 occ[r // CHUNK] = occ.get(r // CHUNK, 0) + 1
             st.shared_chunks_of[key] = occ
+            st.spread += len(occ) > 1
         else:
             st.excl_chunks[key] = list(range(first_chunk, last_chunk + 1))
         pos += k
@@ -296,6 +298,9 @@ class PartitionedTable:
         # partition key → exclusive chunk ids / shared chunk ids it occupies
         self._excl_chunks: Dict[Tuple, List[int]] = {}
         self._shared_chunks_of: Dict[Tuple, Dict[int, int]] = {}  # cid → row count
+        # how many partitions occupy MORE than one shared chunk: kept where
+        # an occupancy gains or loses a chunk, read by _layout_is_tight
+        self._spread = 0
         # free row slots inside partition-exclusive chunks
         self._excl_free: Dict[Tuple, List[int]] = {}
         # shared-chunk pool: cid → free row slots; _open_shared lists chunk
@@ -529,7 +534,8 @@ class PartitionedTable:
                 dst = slots.pop(0)
                 self._move_row(src, dst)
             shared_rows.clear()
-            self._shared_chunks_of.pop(key, None)
+            if len(self._shared_chunks_of.pop(key, ())) > 1:
+                self._spread -= 1
             self._excl_free[key] = slots[1:][::-1]
             return slots[0]
         # 2) small partition: take a slot in a shared chunk, preferring
@@ -557,7 +563,13 @@ class PartitionedTable:
                 self._open_shared.append(cid)
                 row = base
         shared_rows.append(row)
-        occ[row // CHUNK] = occ.get(row // CHUNK, 0) + 1
+        cid = row // CHUNK
+        if cid in occ:
+            occ[cid] += 1
+        else:
+            occ[cid] = 1
+            if len(occ) == 2:
+                self._spread += 1
         return row
 
     def _free_shared_slot(self, row: int) -> None:
@@ -738,6 +750,8 @@ class PartitionedTable:
             occ[cid] -= 1
             if occ[cid] == 0:
                 del occ[cid]
+                if len(occ) == 1:
+                    self._spread -= 1
             self._shared_rows_of[key].remove(row)
             self._free_shared_slot(row)
         else:
@@ -780,6 +794,9 @@ class PartitionedTable:
         with self._mu:
             if self._compacting:
                 return False
+            if self._layout_is_tight():
+                self.dirty_ops = 0  # nothing to rebuild: the churn is spent
+                return False
             self._compacting = True
         try:
             th = threading.Thread(
@@ -795,6 +812,21 @@ class PartitionedTable:
             _LOG.warning("background compaction thread failed to start: %s", e)
             return False
         return True
+
+    def _layout_is_tight(self) -> bool:
+        """Could a rebuild tighten nothing? So where no partition has chunks
+        of its own, every partition sits in ONE shared chunk, and the
+        chunks are full bar the last: a topic's candidate set cannot
+        shrink and no chunk can be saved. A bulk load of small partitions
+        packs that way by itself — 1,000,000 two-level exact filters are
+        1,000,000 one-row partitions in 7,813 full chunks — and its rebuild
+        (17 s of Python per million partitions, then a re-upload of the
+        whole table) would land in the first minute of traffic for
+        nothing. Three compares: it is asked on the dispatch path, under
+        ``self._mu`` (the caller holds it)."""
+        if self._excl_chunks or (self.nchunks - 2) * CHUNK >= self.size:
+            return False  # nchunks counts the reserved empty chunk 0
+        return not self._spread
 
     def _compact_bg(self) -> None:
         try:
@@ -875,6 +907,7 @@ class PartitionedTable:
         self._excl_chunks = state.excl_chunks
         self._excl_free = state.excl_free
         self._shared_chunks_of = state.shared_chunks_of
+        self._spread = state.spread
         self._shared_rows_of = state.shared_rows_of
         self._shared_free = state.shared_free
         self._open_shared = state.open_shared

@@ -482,6 +482,84 @@ def test_handshake_executor_gate():
     asyncio.run(asyncio.wait_for(run(), 30))
 
 
+@pytest.mark.parametrize("sent", ["whole", "half", "nothing"])
+def test_a_connect_that_came_in_time_survives_a_late_loop(sent, monkeypatch):
+    """``max_handshake_delay`` is for a client that does not send. A CONNECT
+    that reached the socket in time, while one turn of this loop outlasted
+    the deadline (a fleet's SUBSCRIBE burst: 4,096 packets of 244 filters),
+    is served late, not refused; half a CONNECT, or none, still times out."""
+    import time
+
+    from rmqtt_tpu.broker.codec import MqttCodec
+
+    real = MqttBroker._read_connect
+
+    async def _read_connect(self, reader, codec):
+        time.sleep(0.45)        # the loop is busy elsewhere, past the deadline
+        await asyncio.sleep(0)  # ... and only then does this task get its turn
+        return await real(self, reader, codec)
+
+    monkeypatch.setattr(MqttBroker, "_read_connect", _read_connect)
+
+    async def run():
+        b = MqttBroker(ServerContext(BrokerConfig(port=0, max_handshake_delay=0.3)))
+        await b.start()
+        try:
+            reader, writer = await asyncio.open_connection("127.0.0.1", b.port)
+            codec = MqttCodec(pk.V311)
+            frame = codec.encode(pk.Connect(client_id="late-loop", protocol=pk.V311))
+            writer.write({"whole": frame + codec.encode(pk.Pingreq()),
+                          "half": frame[:5], "nothing": b""}[sent])
+            data = await asyncio.wait_for(reader.read(64), 5)
+            if sent == "whole":
+                got = codec.feed(data)
+                assert isinstance(got[0], pk.Connack) and got[0].reason_code == 0
+                if len(got) < 2:  # the pipelined PINGREQ was kept, and answered
+                    got += codec.feed(await asyncio.wait_for(reader.read(64), 5))
+                assert isinstance(got[1], pk.Pingresp)
+                assert b.ctx.metrics.get("handshake.late_reads") == 1
+                assert b.ctx.metrics.get("handshake.failures") == 0
+            else:
+                assert data == b""  # closed: the client was the late one
+                assert b.ctx.metrics.get("handshake.failures") == 1
+            writer.close()
+        finally:
+            await b.stop()
+
+    asyncio.run(asyncio.wait_for(run(), 30))
+
+
+def test_every_listener_asks_for_the_listen_backlog(monkeypatch):
+    """A fleet's simultaneous connects must fit the listen queue
+    (``server.LISTEN_BACKLOG``; asyncio's default is 100, past which the
+    kernel drops handshakes or falls back to SYN cookies)."""
+    from rmqtt_tpu.broker import server
+
+    asked = []
+    real = asyncio.start_server
+
+    async def start_server(*a, **k):
+        asked.append(k.get("backlog"))
+        return await real(*a, **k)
+
+    monkeypatch.setattr(asyncio, "start_server", start_server)
+
+    async def run():
+        b = MqttBroker(ServerContext(BrokerConfig(port=0, ws_port=0)))
+        await b.start()
+        try:
+            # 300 connects at once, the loop held up behind them
+            conns = await asyncio.gather(*(
+                asyncio.open_connection("127.0.0.1", b.port) for _ in range(300)))
+            for _r, w in conns:
+                w.close()
+        finally:
+            await b.stop()
+
+    asyncio.run(asyncio.wait_for(run(), 30))
+    assert asked == [server.LISTEN_BACKLOG] * 2 and server.LISTEN_BACKLOG >= 1024
+
+
 def test_handshake_rate_gate():
     """max_handshake_rate: connects beyond the configured handshakes/sec are
     refused before any bytes are read (node.rs:212-239 busy detection)."""

@@ -93,6 +93,21 @@ def test_fused_step_compiles(spec, b):
     assert c.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+@pytest.mark.parametrize("b", [128, 256, 512, 1024])
+def test_fused_step_compiles_on_legacy_int32_tiles(spec, b):
+    """``_match_fused`` as the ``b1_1m_exact`` table calls it (benchmark cell
+    ``b1_1m_exact.pub40``): 1,000,000 two-level exact filters are 7,814
+    chunks padded to 8,192, and a level with 1M distinct tokens is past what
+    a packed level can name, so the tiles are the legacy field-major layout
+    with int32 tokens (``layout=None``) and the topic tokens ship as int32."""
+    c = pm._match_fused.lower(
+        spec((UP_CHUNKS, LEVELS + 3, pm.CHUNK), jnp.int32),
+        spec((UP_CHUNKS, pm.CHUNK), jnp.int32), spec((b, LEVELS), jnp.int32),
+        spec((b,), jnp.int16), spec((b,), jnp.bool_), spec((b, NC), jnp.uint16),
+        budget=_budget(b), layout=None).compile()
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 def test_fused_grouped_and_split_compile(spec):
     """The deduplicated-candidate form and the NC-split form of the same
     batch: [U, NC] distinct rows + inverse; two NC tiers in one program."""

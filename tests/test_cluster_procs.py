@@ -594,8 +594,19 @@ def test_partition_duplicate_session_fence_heal(tmp_path):
         ack = await owner.subscribe("fence/#", qos=1)
         assert ack.reason_codes[0] < 0x80
         pub2 = await TestClient.connect(mports[1], "fence-pub2")
-        await pub2.publish("fence/warm", b"w", qos=1)
-        p = await owner.recv(timeout=10.0)
+        # the nodes have only just opened their listeners: on a loaded host
+        # node 2 may not have reached node 1 yet, and a forward it cannot
+        # make is not owed to anyone. So warm up as a device would: publish
+        # until one crosses
+        deadline = asyncio.get_running_loop().time() + 30.0
+        while True:
+            await pub2.publish("fence/warm", b"w", qos=1)
+            try:
+                p = await owner.recv(timeout=2.0)
+                break
+            except asyncio.TimeoutError:
+                assert asyncio.get_running_loop().time() < deadline, (
+                    "no publish ever crossed from node 2 to node 1")
         assert p.payload == b"w"
         # ---- partition: every cluster frame on both nodes is cut
         for i in (1, 2):

@@ -71,15 +71,24 @@ def _check_path(b, path, least: int = 1) -> None:
 
 @pytest.fixture
 def handled(monkeypatch):
-    """Every packet ``_handle`` is given, per client id, in order."""
+    """Every packet a session serves, per client id, in order: what
+    ``_handle`` is given, and the PUBLISHes ``_publish_run`` takes when a
+    chunk holds several in a row."""
     seen = {}
     real = SessionState._handle
+    real_run = SessionState._publish_run
 
     async def _handle(self, p):
         seen.setdefault(self.s.client_id, []).append(p)
         await real(self, p)
 
+    async def _publish_run(self, packets, i):
+        j = await real_run(self, packets, i)
+        seen.setdefault(self.s.client_id, []).extend(packets[i:j])
+        return j
+
     monkeypatch.setattr(SessionState, "_handle", _handle)
+    monkeypatch.setattr(SessionState, "_publish_run", _publish_run)
     return seen
 
 
@@ -394,7 +403,8 @@ def test_broker_stop_with_connections_attached(path):
 
 # -------------------------------------------------------------- the bound
 def test_flood_into_a_stuck_session_is_bounded(monkeypatch):
-    """A publisher floods a session whose ``_handle`` does not return: the
+    """A publisher floods a session that does not get past its first
+    PUBLISH (a lone one's ``_handle``, a run's ``_publish_run``): the
     thread stops reading its socket near 64 KB, the kernel's buffers fill
     and the sender blocks (TCP backpressure); released, every frame is
     handled, in order."""
@@ -402,6 +412,7 @@ def test_flood_into_a_stuck_session_is_bounded(monkeypatch):
     gate = asyncio.Event()
     seen = []
     real = SessionState._handle
+    real_run = SessionState._publish_run
 
     async def _handle(self, p):
         if self.s.client_id == "flooder" and isinstance(p, pk.Publish):
@@ -409,7 +420,23 @@ def test_flood_into_a_stuck_session_is_bounded(monkeypatch):
             seen.append(p.payload)
         await real(self, p)
 
+    async def _publish_run(self, packets, i):
+        await gate.wait()
+        j = await real_run(self, packets, i)
+        seen.extend(p.payload for p in packets[i:j])
+        return j
+
+    in_hand = []  # bytes of the chunks the stuck session has decoded
+    real_decode = SessionState._decode_chunk
+
+    def _decode_chunk(self, data, size, *a, **k):
+        if self.s.client_id == "flooder":
+            in_hand.append(size)
+        return real_decode(self, data, size, *a, **k)
+
     monkeypatch.setattr(SessionState, "_handle", _handle)
+    monkeypatch.setattr(SessionState, "_publish_run", _publish_run)
+    monkeypatch.setattr(SessionState, "_decode_chunk", _decode_chunk)
 
     async def run():
         loop = asyncio.get_running_loop()
@@ -439,7 +466,11 @@ def test_flood_into_a_stuck_session_is_bounded(monkeypatch):
                 await asyncio.sleep(0.01)
         assert stalled == 30 and off < len(blob), "the sender never blocked"
         conn = next(iter(b.ctx.ingress_hub._conns.values()))
-        held = sum(b[0][i + 4] for b, i in zip(*[iter(conn.inbox)] * 2))
+        # what the thread posted and the session has not handled: the chunks
+        # in its hands (those that queued behind one are served with it) and
+        # the inbox
+        held = sum(in_hand[1:]) + sum(
+            b[0][i + 4] for b, i in zip(*[iter(conn.inbox)] * 2))
         assert 0 < held < 3 * 64 * 1024, held  # 64 KB, one read, the chunk in hand
         assert _m(b, "net.ingress_paused") >= 1
         assert b.ctx.ingress_hub._thread.stats()[3] >= 1

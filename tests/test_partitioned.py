@@ -719,3 +719,85 @@ def test_segmented_table_parity():
     for t, g in zip(topics[:64], got2):
         expect = sorted(fid for fid, f in fids.items() if match_filter(f, t))
         assert sorted(g.tolist()) == expect, t
+
+
+@pytest.mark.parametrize("shape,tight", [
+    ("one_row_partitions", True),    # b1_1m_exact's table, small
+    ("one_partition_in_two_chunks", False),
+    ("a_partition_with_chunks_of_its_own", False),
+    ("holes_after_removals", False),
+])
+def test_a_tight_bulk_load_is_not_rebuilt(shape, tight):
+    """A rebuild that could tighten nothing is skipped (``_layout_is_tight``):
+    a bulk load of one-row partitions packs full shared chunks by itself, and
+    its compaction would only land in the first minute of traffic. Any
+    partition with chunks of its own, spread over two shared chunks, or holes
+    in the packing keeps the rebuild."""
+    t = PartitionedTable()
+    fids = [t.add(f"iot/{n}") for n in range(3000)]  # 3,000 one-row partitions
+    if shape == "one_partition_in_two_chunks":
+        # the last shared chunk has 56 slots left: 80 rows of one partition
+        # take them and open another
+        for k in range(80):
+            t.add(f"fleet/site/x/{k}")
+    elif shape == "a_partition_with_chunks_of_its_own":
+        for k in range(200):
+            t.add(f"fleet/site/x/{k}")
+    elif shape == "holes_after_removals":
+        for fid in fids[:400]:
+            t.remove(fid)
+    assert t.needs_compact()
+    with t._mu:
+        assert t._layout_is_tight() is tight
+    t.compact_async = True
+    started = t.maybe_compact_async()
+    assert started is (not tight)
+    if tight:
+        assert t.dirty_ops == 0 and not t.needs_compact() and t.compactions == 0
+    else:
+        t._compact_thread.join(30)
+        assert t.compactions == 1
+    m = PartitionedMatcher(t)
+    rows = m.match(["iot/2999", "iot/17" if shape != "holes_after_removals" else "iot/17x",
+                    "fleet/site/x/3", "iot/none"])
+    assert len(rows[0]) == 1 and len(rows[3]) == 0
+    assert len(rows[2]) == (0 if shape in ("one_row_partitions", "holes_after_removals") else 1)
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3, 4])
+def test_the_spread_count_follows_the_occupancy_map_under_churn(seed):
+    """``_layout_is_tight`` reads ``_spread`` instead of walking the partition
+    map (1M entries under ``b1_1m_exact``, on the dispatch path): the count
+    of partitions in more than one shared chunk has to equal a recount after
+    adds, removals, migrations to chunks of their own and compactions."""
+    import random
+
+    rng = random.Random(seed)
+    t = PartitionedTable()
+    live = []
+
+    def recount():
+        return sum(len(occ) > 1 for occ in t._shared_chunks_of.values())
+
+    seen = 0
+    for phase in range(6):
+        for step in range(1500):
+            if step % 100 == 0:
+                seen = max(seen, t._spread)
+                assert t._spread == recount(), (phase, step)
+            if live and rng.random() < 0.4:
+                t.remove(live.pop(rng.randrange(len(live))))
+            else:
+                # 10 partitions that grow to a few hundred rows (they spread
+                # over shared chunks, then migrate) among one-row partitions
+                part = rng.randrange(10) if rng.random() < 0.6 else rng.randrange(10**6)
+                live.append(t.add(f"fleet/site/p{part}/{rng.randrange(10**9)}"))
+        assert t._spread == recount(), phase
+        if phase % 2:
+            t._compact()
+            assert t._spread == recount(), ("compacted", phase)
+    # the churn did spread partitions over shared chunks, and moved some out
+    assert seen > 0 and t._excl_chunks
+    for fid in live:
+        t.remove(fid)
+    assert t._spread == recount() == 0
