@@ -17,6 +17,7 @@ from typing import Any, Dict, List, Optional
 from rmqtt_tpu.broker.acl import AclEngine
 from rmqtt_tpu.broker.delayed import DelayedSender
 from rmqtt_tpu.broker.fitter import Fitter, FitterConfig
+from rmqtt_tpu.broker.gcpolicy import GCPOLICY
 from rmqtt_tpu.broker.hooks import HookRegistry
 from rmqtt_tpu.broker.metrics import Metrics, Stats
 from rmqtt_tpu.broker.retain import RetainStore
@@ -644,6 +645,7 @@ class ServerContext:
 
         self._host_dispatch_probe = _host_dispatch_probe
         self._hostprof_started = False
+        self._gcpolicy_armed = False
         HOSTPROF.configure(
             enabled=self.cfg.host_profile,
             block_ms=self.cfg.host_block_ms,
@@ -749,6 +751,11 @@ class ServerContext:
         if HOSTPROF.enabled and not self._hostprof_started:
             HOSTPROF.start()
             self._hostprof_started = True
+        # the collector's policy (broker/gcpolicy.py): refcounted like the
+        # profiler, but no option turns it off
+        if not self._gcpolicy_armed:
+            GCPOLICY.arm()
+            self._gcpolicy_armed = True
         if self.durability is not None:
             self.durability.start()
         if self.keepalive_wheel is not None:
@@ -806,6 +813,9 @@ class ServerContext:
             HOSTPROF.configure(telemetry=None)
         if HOSTPROF.dispatch_probe is self._host_dispatch_probe:
             HOSTPROF.configure(dispatch_probe=None)
+        if self._gcpolicy_armed:
+            self._gcpolicy_armed = False
+            GCPOLICY.disarm()
 
     def stats(self) -> Stats:
         s = Stats()
@@ -926,6 +936,10 @@ class ServerContext:
 
             s.host_open_fds = _fd_count()
             s.host_threads = _threading.active_count()
+        # the collector's policy (broker/gcpolicy.py): how often it engaged
+        # and what the full passes cost; there with host_profile off too
+        for k, v in GCPOLICY.stats_block().items():
+            setattr(s, k, v)
         hbm = getattr(self.router, "device_hbm", None)
         if callable(hbm):
             try:

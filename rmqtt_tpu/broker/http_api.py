@@ -461,9 +461,14 @@ class HttpApi:
             # host-plane profiler (broker/hostprof.py): event-loop lag,
             # GC pause forensics, blocking-call incidents (frame stacks),
             # process rollups. Shape-stable with the profiler disabled.
+            from rmqtt_tpu.broker.gcpolicy import GCPOLICY
             from rmqtt_tpu.broker.hostprof import HOSTPROF
 
-            return 200, {"node": ctx.node_id, **HOSTPROF.snapshot()}, J
+            snap = HOSTPROF.snapshot()
+            # the collector's policy (broker/gcpolicy.py) beside the
+            # profiler's per-generation forensics
+            snap["gc"]["policy"] = GCPOLICY.snapshot()
+            return 200, {"node": ctx.node_id, **snap}, J
         if path == "/api/v1/history/sum":
             # cluster-wide telemetry timeline (broker/history.py): node
             # timelines align on step buckets (counters sum, quantile/rate
@@ -872,6 +877,8 @@ const KEYS=["connections","sessions","subscriptions","subscriptions_shared",
  "device_hbm_modeled_mb",
  "host_loop_laggy_ticks","host_lag_storms","host_blocked_calls",
  "host_gc_pauses","host_gc_pause_ms_total","host_open_fds","host_threads",
+ "host_gc_freezes","host_gc_thaws","host_gc_frozen_objects",
+ "host_gc_full_pauses","host_gc_full_pause_ms_total",
  "net_egress_frames","net_egress_flushes","net_egress_bytes",
  "net_egress_coalesced","net_egress_drains",
  "net_egress_offloop_flushes","net_egress_offloop_partial",

@@ -142,6 +142,16 @@ class Stats:
         self.host_gc_pause_ms_total = 0.0
         self.host_open_fds = 0
         self.host_threads = 0
+        # the collector's policy (broker/gcpolicy.py), filled by
+        # ServerContext.stats() whether host_profile is on or not: heaps
+        # frozen after a long full pass, budgeted thaws, the permanent
+        # generation as last counted, and the generation-2 passes alone
+        # (thaws included) with their stopped time
+        self.host_gc_freezes = 0
+        self.host_gc_thaws = 0
+        self.host_gc_frozen_objects = 0
+        self.host_gc_full_pauses = 0
+        self.host_gc_full_pause_ms_total = 0.0
         # device-plane failover gauges (broker/failover.py), overwritten
         # from RoutingService.stats(); zeros for routers without a host
         # fallback. state is 0=device (healthy) 1=host fallback 2=probing
