@@ -105,6 +105,18 @@ class Broker:
         (self.ctl / "mem.req").touch()
         return self._await("mem.json", 60.0)["memory_peak_bytes"]
 
+    def compiling(self) -> bool:
+        """Is a compile worker of the program alive? ``ops/hybrid.py`` has a
+        match program it has not met compiled on a thread of its own
+        (``rmqtt-compile``: started with the first such batch, gone when its
+        list is empty) while the host mirror answers, and tracing shares the
+        GIL with the event loop: a window that holds such a compile reads
+        another broker. The counters say only that a compile has *ended*
+        (``compile.traces``); that one is under way, only the thread does."""
+        (self.ctl / "threads.req").touch()
+        names = self._await("threads.json", 60.0)["names"]
+        return any("compile" in n for n in names)
+
     def stop(self) -> None:
         if self.proc.poll() is None:
             self.proc.send_signal(signal.SIGTERM)

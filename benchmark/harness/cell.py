@@ -16,6 +16,11 @@ The comparison is exact: each limit is 0. A pair counts once, in the
 comparison and in the rate alike: a subscriber that holds two matching
 filters, or that is sent a QoS1 delivery again, has received the publish
 once, and its latency is that of the first copy.
+
+A publish belongs to the window by its **send** instant, and its latencies
+are taken from its **due** instant. In a closed loop the two are one. In an
+open loop (``fleet.py``) a publish that fell due while its connection's
+window was full was sent later, and its latency counts that wait.
 """
 
 from __future__ import annotations
@@ -37,9 +42,13 @@ from harness.reference import Reference
 
 WARMUP_MIN_S = 10.0      # traffic before the window, at the least
 WARMUP_CAP_S = 60.0
+COMPILE_CAP_S = 480.0    # so long a compile worker is waited for: a checkout's first run compiles
 PROBE_PERIODS = 2        # device batches in a row that must compile nothing
 NO_DEVICE_GIVE_UP_S = 15.0  # so long without a device batch: none is coming
 SETTLE_LIMIT_S = 60.0    # a delivery that comes within a minute is late, not lost
+GO_LEAD_S = 0.5          # an open mix's schedule starts this long after "go" is told
+LOADGEN_WALL_PCT = 90.0  # a publisher process over this share of a core kept no schedule
+LATE_TAIL_SHARE = 0.5    # the generator's lateness at p99 over the PUBACK's p99: the tail is the fleet's
 TRACE_SLICE_S = 60.0    # of the window's middle; the whole window where it is shorter
 
 
@@ -60,6 +69,8 @@ class Records:
         self.P = pub_procs
         self.topics = [[] for _ in range(pub_procs)]
         self.t_send = [[] for _ in range(pub_procs)]
+        self.t_due = [[] for _ in range(pub_procs)]
+        self.waited = [[] for _ in range(pub_procs)]
         self.t_ack = [np.zeros(0) for _ in range(pub_procs)]
         self.ids, self.times, self.subs = [], [], []
         self.cpu = {}
@@ -83,6 +94,8 @@ class Records:
         for k, r in enumerate(fleet.ask(fleet.pubs, "drain")):
             self.topics[k] += r["topics"]
             self.t_send[k].append(np.frombuffer(r["t_send"]))
+            self.t_due[k].append(np.frombuffer(r["t_due"]))
+            self.waited[k].append(np.frombuffer(r["waited"], dtype=np.int8))
             self.t_ack[k] = np.frombuffer(r["t_ack"])
             self.cpu[f"pub{k}"] = np.frombuffer(r["cpu"]).reshape(-1, 2)
             self.inflight += r["inflight"]
@@ -91,17 +104,22 @@ class Records:
         return new
 
     def table(self):
-        """Publishes by id (``record * P + process``): send instant and
-        PUBACK instant; nan where no publish has the id."""
+        """Publishes by id (``record * P + process``): send, PUBACK and due
+        instants, and whether the publish queued on a full window; nan (0)
+        where no publish has the id."""
         P = self.P
-        sends = [np.concatenate(s) if s else np.zeros(0) for s in self.t_send]
+        cat = lambda parts: np.concatenate(parts) if parts else np.zeros(0)  # noqa: E731
+        sends = [cat(s) for s in self.t_send]
         size = max(len(s) for s in sends) * P
-        send, ack = np.full(size, np.nan), np.full(size, np.nan)
+        send, ack, due = (np.full(size, np.nan) for _ in range(3))
+        waited = np.zeros(size, np.int8)
         for k in range(P):
             n = len(sends[k])
             send[k::P][:n] = sends[k]
             ack[k::P][:n] = self.t_ack[k][:n]
-        return send, ack
+            due[k::P][:n] = cat(self.t_due[k])
+            waited[k::P][:n] = cat(self.waited[k])
+        return send, ack, due, waited
 
     def topic_of(self, ident: int) -> str:
         return self.topics[ident % self.P][ident // self.P]
@@ -123,7 +141,7 @@ def compare(rec: Records, ref: Reference, subscribers: int,
     """The window's publishes against the plain reference. → the numbers
     compared (each with the limit 0), ``attempted`` and the arrays the
     end-to-end metrics are taken from."""
-    send, ack = rec.table()
+    send, ack, due, _waited = rec.table()
     in_window = (send >= t0) & (send < t1)
     W = np.flatnonzero(in_window)
     ids = np.concatenate(rec.ids) if rec.ids else np.zeros(0, np.int64)
@@ -158,8 +176,8 @@ def compare(rec: Records, ref: Reference, subscribers: int,
                          np.argsort(-by_sub)[:4] if by_sub[i]],
         "pairs": int(got.size),
         "copies": int(hit.sum()),  # PUBLISH packets: pairs + further copies
-        "deliver_ms": (when[first] - send[got // subscribers]) * 1e3,
-        "puback_ms": (ack[W] - send[W]) * 1e3,
+        "deliver_ms": (when[first] - due[got // subscribers]) * 1e3,
+        "puback_ms": (ack[W] - due[W]) * 1e3,
         # pairs by the 5 s slice their publish was sent in: shows stalls
         "pairs_by_5s": np.bincount(
             ((send[got // subscribers] - t0) // 5.0).astype(np.int64)).tolist(),
@@ -179,6 +197,69 @@ def end_to_end(cmp: dict, seconds: float, setup_s: float) -> dict:
     }
 
 
+def open_loop(rec: Records, cmp: dict, n_due: int, t0: float, t1: float) -> dict:
+    """What an open mix's window offered and what of it left: publishes
+    **due** in the window (``n_due``, counted from the schedule itself),
+    **sent** in it (by send instant, as the comparison counts them), their
+    ratio, the share of the due that met a full window (queued and sent
+    later, or never sent: still queued at the close), and the generator's
+    own lateness (send minus due of the sent publishes that had a free slot)."""
+    send, _ack, due, waited = rec.table()
+    due_in = (due >= t0) & (due < t1)
+    never_sent = n_due - int(due_in.sum())
+    met_full = int(waited[due_in].sum()) + never_sent
+    had_slot = (send >= t0) & (send < t1) & (waited == 0)
+    late = (send[had_slot] - due[had_slot]) * 1e3
+    out = {
+        "due": n_due, "sent": cmp["publishes"], "never_sent": never_sent,
+        "sent_of_due_pct": 100.0 * cmp["publishes"] / n_due if n_due else None,
+        "window_full_share_pct": 100.0 * met_full / n_due if n_due else None,
+        "late_p50_ms": pct(late, 50), "late_p99_ms": pct(late, 99),
+        "late_max_ms": float(late.max()) if late.size else float("nan"),
+    }
+    return {k: None if v != v else v for k, v in out.items()}  # nan: nothing to read
+
+
+def require_schedule_kept(cell: str, traffic: dict, loadgen: dict,
+                          offered: dict, puback_p99_ms: float) -> None:
+    """An open mix is an instrument only while the fleet, not the broker,
+    keeps the schedule. Two rules, each failing in words. A publisher
+    process burned over ``LOADGEN_WALL_PCT`` of a core across the window:
+    the generator was the wall. Or the generator's own lateness (publishes
+    that had a free slot and still left after they fell due) reached, at its
+    99th percentile, ``LATE_TAIL_SHARE`` of the PUBACK's 99th percentile:
+    every latency is taken from the due instant, so the tail that would be
+    reported is then the fleet's own. A broker that cannot keep up is NOT
+    such a run: the windows fill, the publisher processes idle, a publish
+    that queued on a full window is not late by the generator's doing, and
+    the falling rate is the reading. A closed mix has no schedule to keep
+    and is not held to this."""
+    if traffic["loop"] != "open":
+        return
+    sent = (f"the fleet sent its publishes {offered['late_p50_ms']} / "
+            f"{offered['late_p99_ms']} / {offered['late_max_ms']} ms (p50 / p99 / max) "
+            f"after they fell due, {offered['sent']} sent of {offered['due']} due")
+    name, share = max(((k, v) for k, v in loadgen.items() if k.startswith("pub")),
+                      key=lambda kv: kv[1], default=(None, 0.0))
+    if share > LOADGEN_WALL_PCT:
+        spec.fail(
+            f"cell {cell!r}: publisher process {name} of the load generator used "
+            f"{share:.1f} % of a core across the window (over {LOADGEN_WALL_PCT:.0f} %) "
+            f"and {sent}: the generator, not the broker, was the wall, so the "
+            "schedule of this open mix was not the one offered. This is not a "
+            "measurement of the broker, and no result is printed.")
+    late = offered["late_p99_ms"]
+    if late is not None and late >= LATE_TAIL_SHARE * puback_p99_ms:
+        spec.fail(
+            f"cell {cell!r}: {sent}, and the 99th percentile of that lateness is "
+            f"{100 * late / puback_p99_ms:.0f} % of the PUBACK's ({puback_p99_ms:.1f} ms; "
+            f"the line is {100 * LATE_TAIL_SHARE:.0f} %): every latency counts "
+            "from the due instant, so the tail this run would report is the load "
+            "generator's own lateness (publisher processes at "
+            f"{share:.1f} % of a core at the most), not the broker's. This is "
+            "not a measurement of the broker, and no result is printed.")
+
+
 # ------------------------------------------------------------------ warm-up
 def _offered(d: dict) -> tuple:
     """→ (large batches the hybrid has routed, batches the device has
@@ -189,16 +270,42 @@ def _offered(d: dict) -> tuple:
     return be.get("hybrid_large_batches", 0), be["hybrid_served"]["device"][0]
 
 
+def _probes(d: dict):
+    """→ how often the hybrid has shown a large batch to the path it does not
+    choose (``hybrid_probes``, both paths summed); None on a program from
+    before PR 27, which does not count them."""
+    probes = d["backend"].get("hybrid_probes")
+    return None if probes is None else sum(probes.values())
+
+
 def warm_up(b, go, cell: str) -> dict:
     """The cell's own traffic, started by ``go()``, until the shapes it uses
-    are compiled, as far as traffic can tell: at least ``WARMUP_MIN_S``, the
+    are compiled, as far as traffic can tell: at least ``WARMUP_MIN_S``, no
+    compile worker of the program is at work (``Broker.compiling``), the
     hybrid has timed both of its paths (``hybrid_choice`` is set: two device
-    batches have come back), and the last ``PROBE_PERIODS`` batches the
-    device served brought no new program (``compile.traces`` did not rise
-    with them). The hybrid shows its slower path one large batch in 64
+    batches have come back) and has probed once since ``go`` (below), and
+    the last ``PROBE_PERIODS`` batches the device served brought no new
+    program (``compile.traces`` did not rise with them). The hybrid shows
+    its slower path one large batch in 64
     (``ops/hybrid.py``), so a shape that the device has not met yet can
-    still turn up later; the cap bounds the wait and a cap that is hit is
-    printed.
+    still turn up later; the cap (``WARMUP_CAP_S`` past the last compile
+    worker's end) bounds the wait and a cap that is hit is printed.
+
+    A program that the device has not met is compiled off the routing path,
+    one after another, seconds each where the persistent cache holds it and
+    ten to thirty where it does not (a checkout's first run: ~110 s for the
+    seven programs of the 1M table), and while one is traced the event loop
+    waits for the GIL. So the warm-up never ends while the worker lives (up
+    to ``COMPILE_CAP_S``), and the wait for a device batch
+    (``NO_DEVICE_GIVE_UP_S``) counts from the worker's end: the first batch
+    the device serves stands behind the first compile.
+
+    Where the host mirror wins, the device is shown only the probes, so the
+    everyday batch shape (65 to 128 topics) may first reach it with the first
+    probe, ~18 s after ``go`` on the 1M table, and is compiled then: the
+    warm-up neither settles nor gives the device up before the hybrid's
+    first probe since ``go`` (``hybrid_probes``), where large batches form at
+    all and the program counts its probes.
 
     The validity rule (PERF.md §4): where the warm-up would return and the
     hybrid was never offered a batch since ``go`` (no batch over
@@ -210,17 +317,25 @@ def warm_up(b, go, cell: str) -> dict:
     stats0 = b.get("/api/v1/stats")[0]["stats"]
     traces = d["compile"]["traces"]
     large0, dev0 = _offered(d)
+    probes0 = _probes(d)
     dev = dev0
     clean = 0  # device batches in a row that compiled nothing
     t_go = time.perf_counter()
     go()
-    last_dev = t_go  # when the device last served a batch
+    last_dev = t_go  # when the device last served a batch, or a compile worker last lived
+    compiled_s = 0.0  # seconds since go at which no compile worker was left
     while True:
         time.sleep(0.5)
         now = time.perf_counter()
+        compiling = b.compiling()
+        if compiling:
+            last_dev, compiled_s = now, now - t_go
         d = b.get("/api/v1/device")
         be = d["backend"]
         large, served = _offered(d)
+        probed = probes0 is None or large == large0 or _probes(d) > probes0
+        if not probed:
+            last_dev = now
         if d["compile"]["traces"] != traces:
             traces, clean = d["compile"]["traces"], 0
             dev = served
@@ -230,15 +345,18 @@ def warm_up(b, go, cell: str) -> dict:
             dev = served
             last_dev = now
         elapsed = now - t_go
-        settled = be["hybrid_choice"] is not None and clean >= PROBE_PERIODS
+        settled = (be["hybrid_choice"] is not None and clean >= PROBE_PERIODS
+                   and probed)
         # where batches large enough to reach the device are rare there is
         # nothing to wait for: the device's counters stand still
         no_device = now - last_dev >= NO_DEVICE_GIVE_UP_S
-        done = elapsed >= WARMUP_MIN_S and (settled or no_device)
-        if done or elapsed >= WARMUP_CAP_S:
+        done = elapsed >= WARMUP_MIN_S and (settled or no_device) and not compiling
+        # the cap counts from the compile worker's end; the worker has its own
+        if done or elapsed >= COMPILE_CAP_S or (
+                not compiling and elapsed - compiled_s >= WARMUP_CAP_S):
             break
-    warm = {"seconds": elapsed, "cap_hit": not done,
-            "large_batches": large - large0,
+    warm = {"seconds": elapsed, "cap_hit": not done, "compiling_until_s": compiled_s,
+            "probed": probed, "large_batches": large - large0,
             "device_batches": dev - dev0, "clean_device_batches": clean,
             "compile_traces": traces, "hybrid_choice": be["hybrid_choice"]}
     if large == large0 and dev == dev0:
@@ -333,7 +451,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, t_start: float,
     if cpu:
         tiny = spec.load_json(spec.BENCH_DIR / "harness" / "rehearsal.json")
         config = dict(config, **tiny["config"])
-        traffic = dict(traffic, **tiny["traffic"])
+        traffic = dict(traffic, **tiny["traffic"], **(
+            tiny["open_traffic"] if traffic["loop"] == "open" else {}))
     env = dict(os.environ)
     env.pop("BENCH_RUN", None)
     if cpu:
@@ -369,7 +488,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, t_start: float,
                       f"{config['subscriptions']} in the configuration")
         load_s = max(r["seconds"] for r in loaded)
         prepared = fleet.ask(fleet.pubs, "prepare")
-        warm = warm_up(b, lambda: fleet.ask(fleet.pubs, "go"), name)
+        warm = warm_up(b, lambda: fleet.go(GO_LEAD_S), name)
         say(phase="setup", broker_up_s=up_s, load_s=load_s,
             subscriptions=resident, load_per_s=n_subs / load_s,
             publishers=sum(r["connections"] for r in prepared), warm_up=warm,
@@ -377,7 +496,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, t_start: float,
                 "platform", "device_kind", "device_count", "matcher",
                 "words_producer", "host_mirror", "hybrid_max")})
         if warm["cap_hit"]:
-            say(phase="warning", what=f"warm-up hit its cap of {WARMUP_CAP_S:.0f}s")
+            say(phase="warning", what=f"warm-up hit its cap ({WARMUP_CAP_S:.0f} s past the "
+                f"last compile worker, {COMPILE_CAP_S:.0f} s in all)")
 
         # ---- the window
         before = b.snapshot()
@@ -404,6 +524,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, t_start: float,
         settle_s = time.perf_counter() - t1
         peak = b.memory_peak_bytes()
         final = b.snapshot()  # also: the broker outlived the run
+        n_due = fleet.due_between(t0, t1) if traffic["loop"] == "open" else None
         fleet.close()
         fleet = None
         b.stop()
@@ -425,6 +546,14 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, t_start: float,
         compiles = (after["device"]["compile"]["traces"]
                     - before["device"]["compile"]["traces"])
         e2e = end_to_end(cmp, seconds, setup_s)
+        offered = None
+        if traffic["loop"] == "open":
+            offered = open_loop(rec, cmp, n_due, t0, t1)
+            say(phase="open_loop", rate_publishes_per_s=traffic["rate_publishes_per_s"],
+                arrival=traffic["arrival"], burst_size=traffic["burst_size"],
+                inflight=traffic["inflight"], **offered)
+        if not cpu:  # a rehearsal's few bursts on a shared CPU measure no schedule
+            require_schedule_kept(name, traffic, loadgen, offered, e2e["puback_p99_ms"])
         say(phase="window", seconds=seconds, publishes=cmp["publishes"],
             publishes_per_s=cmp["publishes"] / seconds,
             pairs_expected=cmp["attempted"], pairs=cmp["pairs"],
@@ -457,7 +586,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, t_start: float,
             run = {"before": before, "after": after, "trace": traced,
                    "loadgen_cpu_busy_pct": loadgen,
                    "deliver_ms": cmp["deliver_ms"], "puback_ms": cmp["puback_ms"],
-                   "config": config,
+                   "open_loop": offered, "config": config,
                    "device": device, "seconds": seconds}
             for m in cell["per_layer"]:
                 value = spec.load_reader(m["name"]).read(run)

@@ -1,10 +1,20 @@
 """The load generator: publisher and subscriber connections in child processes.
 
 One general generator reads a traffic mix's parameters (``traffic/*.json``).
-The loop is closed: every publisher connection keeps ``inflight`` QoS1
+``loop: closed``: every publisher connection keeps ``inflight`` QoS1
 publishes outstanding and sends the next when a PUBACK comes (MQTT's own
-flow control; the broker sets the rate). ``spec.py`` refuses ``loop: open``
-until a cell brings the open loop with a run on the chip.
+flow control; the broker sets the rate).
+
+``loop: open``: publishes fall **due** on the mix's schedule (``schedule``:
+bursts that share one instant in every publisher process), at
+``rate_publishes_per_s`` over all connections, and the seed draws their
+topics. MQTT's flow control stays: a connection keeps at most ``inflight``
+publishes unacknowledged. A publish that falls due while its connection's window is
+full queues on the connection, first in first out, and leaves when a PUBACK
+frees a slot. Each record keeps the due instant beside the send instant,
+and every latency is taken from the due one, so it counts the wait. Under
+the broker's capacity that is a plain open loop; over it every window is
+full and the offered rate only decides how long the due queues grow.
 
 Each process runs one asyncio loop on raw-socket protocols (``mqtt.py``) and
 records into flat arrays; nothing here touches JAX or the program's code.
@@ -24,6 +34,7 @@ import math
 import multiprocessing as mp
 import time
 from array import array
+from collections import deque
 
 from harness import generators, mqtt
 
@@ -206,18 +217,57 @@ class _Subscribers(_Child):
                 "lost": sum(c.lost.done() for c in self.conns)}
 
 
+def schedule(traffic: dict, proc: int, procs: int, conns: int):
+    """An open mix's due publishes of one publisher process, without end:
+    ``(seconds after the start instant, connection index)``, the instants
+    never falling. A mix gives the same schedule in every run and for every
+    seed (the seed draws the topics): every ``burst_size / rate`` seconds
+    ``burst_size`` publishes fall due at one instant; process k takes the
+    k-th of ``procs`` even shares, on distinct connections, rotating so that
+    all are used alike."""
+    size, rate = traffic["burst_size"], traffic["rate_publishes_per_s"]
+    share = size * (proc + 1) // procs - size * proc // procs
+    if share > conns:
+        raise RuntimeError(f"publisher process {proc}: {share} publishes of a "
+                           f"burst on {conns} connections")
+    for k in itertools.count():
+        t = k * size / rate
+        for j in range(share):
+            yield t, (k * share + j) % conns
+
+
+def due_between(traffic: dict, pub_conns: list, start: float,
+                t0: float, t1: float) -> int:
+    """An open mix's publishes that fell due in [t0, t1) where the schedule
+    started at ``start``, by the schedule itself: sent, queued on a full
+    window, or never reached. ``pub_conns``: connections of each process."""
+    n = 0
+    for k, conns in enumerate(pub_conns):
+        for off, _c in schedule(traffic, k, len(pub_conns), conns):
+            if start + off >= t1:
+                break
+            n += start + off >= t0
+    return n
+
+
 class _Publishers(_Child):
     def __init__(self, pipe, a, stop_at) -> None:
         super().__init__(pipe)
         self.a, self.stop_at = a, stop_at
         self.topics = []          # topic of each publish, by record index
         self.t_send = array("d")  # the instant the publish left
+        self.t_due = array("d")   # the instant it fell due (closed loop: t_send)
+        self.waited = array("b")  # 1: it met a full window and queued
         self.t_ack = array("d")   # PUBACK instant; nan = none (yet)
         self.inflight = 0
         self.sent = 0
         self.pending = {}         # protocol object → {packet id: record}, oldest first
         self.pid = {}             # protocol object → last packet id
         self.out_of_order = 0     # PUBACKs that passed an older publish's
+        self.open = a["traffic"]["loop"] == "open"
+        self.window = a["traffic"]["inflight"]
+        self.queue = {}           # open loop: protocol object → due instants waiting
+        self.refill = self._next_queued if self.open else self.send
 
     def on_packets(self, c, packets, now) -> None:
         for typ, _flags, body in packets:
@@ -232,20 +282,22 @@ class _Publishers(_Child):
                 if rec is not None:
                     self.t_ack[rec] = now
                     self.inflight -= 1
-                    self.send(c)
+                    self.refill(c)
             elif typ == mqtt.CONNACK:
                 c.resolve("connack", body[1])
 
-    def send(self, c) -> None:
+    def send(self, c, t_due=None, waited=0) -> bool:
         """One QoS1 publish, unless the window has closed."""
         now = time.perf_counter()
         if now >= self.stop_at.value:
-            return
+            return False
         a = self.a
         rec = len(self.topics)
         topic = next(self.stream)
         self.topics.append(topic)
         self.t_send.append(now)
+        self.t_due.append(now if t_due is None else t_due)
+        self.waited.append(waited)
         self.t_ack.append(math.nan)
         pid = self.pid[c] = self.pid[c] % 65535 + 1
         self.pending[c][pid] = rec
@@ -253,6 +305,39 @@ class _Publishers(_Child):
         # the publish id rides the payload; ids of different processes differ
         ident = rec * a["procs"] + a["proc"]
         c.tr.write(mqtt.publish(topic, str(ident).encode(), 1, pid))
+        return True
+
+    # ---- the open loop
+    def _next_queued(self, c) -> None:
+        """A PUBACK freed a slot: the connection's oldest waiting publish."""
+        q = self.queue[c]
+        if q and self.send(c, q[0], 1):
+            q.popleft()
+
+    def _on_due(self) -> None:
+        """Every publish due by now leaves, or queues on its connection
+        where the window is full. One timer a process, for the next due
+        instant. Past ``stop_at`` nothing is sent and the schedule ends;
+        what is still queued then was never attempted (the harness counts
+        what fell due from the schedule itself)."""
+        now = time.perf_counter()
+        stop = self.stop_at.value
+        t, k = self.next_due
+        if now >= stop:
+            return
+        while t <= now:
+            c = self.conns[k]
+            q = self.queue[c]
+            if q or len(self.pending[c]) >= self.window or not self.send(c, t):
+                q.append(t)
+            off, k = next(self.coming)
+            t = self.start + off
+        self.next_due = t, k
+        self._arm(min(t, stop))
+
+    def _arm(self, when: float) -> None:
+        asyncio.get_running_loop().call_later(
+            max(0.0, when - time.perf_counter()), self._on_due)
 
     async def cmd_prepare(self) -> dict:
         """Draw this process's topic stream ahead of the window (so that the
@@ -267,21 +352,34 @@ class _Publishers(_Child):
             a["port"], (f"pub-{i}" for i in range(a["lo"], a["hi"])),
             self.on_packets)
         for c in self.conns:
-            self.pending[c], self.pid[c] = {}, 0
+            self.pending[c], self.pid[c], self.queue[c] = {}, 0, deque()
         return {"connections": len(self.conns), "pregen": len(ready),
                 "seconds": time.perf_counter() - t0}
 
-    async def cmd_go(self) -> dict:
-        for c in self.conns:
-            for _ in range(self.a["traffic"]["inflight"]):
-                self.send(c)
+    async def cmd_go(self, start: float) -> dict:
+        """Closed loop: fill every window now. Open loop: the schedule runs
+        from ``start``, an instant of the hosts' one clock that every
+        publisher process is given alike."""
+        if not self.open:
+            for c in self.conns:
+                for _ in range(self.window):
+                    self.send(c)
+            return {}
+        a = self.a
+        self.start = start
+        self.coming = schedule(a["traffic"], a["proc"], a["procs"], len(self.conns))
+        off, k = next(self.coming)
+        self.next_due = start + off, k
+        self._arm(start + off)
         return {}
 
     async def cmd_drain(self) -> dict:
-        """Records since the last drain; PUBACK instants of ALL records (an
-        ack may come after its record was handed over)."""
+        """Records since the last drain, and the PUBACK instants of ALL
+        records (an ack may come after its record was handed over)."""
         lo, self.sent = self.sent, len(self.topics)
         return {"topics": self.topics[lo:], "t_send": self.t_send[lo:].tobytes(),
+                "t_due": self.t_due[lo:].tobytes(),
+                "waited": self.waited[lo:].tobytes(),
                 "t_ack": self.t_ack.tobytes(), "inflight": self.inflight,
                 "out_of_order": self.out_of_order, "cpu": self.cpu.tobytes(),
                 "lost": sum(c.lost.done() for c in self.conns)}
@@ -325,6 +423,8 @@ class Fleet:
             self.subs.append(self._start(ctx, _subscriber_main, (a,)))
         procs = traffic["publisher_procs"]
         pregen = int(traffic["pregen_publishes_per_s"] * (seconds + 30) / procs)
+        self.traffic, self.start = traffic, None
+        self.pub_conns = [hi - lo for lo, hi in _split(traffic["publishers"], procs)]
         for k, (lo, hi) in enumerate(_split(traffic["publishers"], procs)):
             a = dict(base, lo=lo, hi=hi, proc=k, procs=procs, pregen=pregen,
                      traffic=traffic)
@@ -362,6 +462,16 @@ class Fleet:
     def ask(cls, procs, *cmd) -> list:
         cls.tell(procs, *cmd)
         return cls.gather(procs, cmd[0])
+
+    def go(self, lead_s: float) -> None:
+        """Start the publishers: a closed loop at once; an open mix's
+        schedule ``lead_s`` from now, at one instant of the hosts' one clock
+        that every publisher process is given alike."""
+        self.start = time.perf_counter() + lead_s
+        self.ask(self.pubs, "go", self.start)
+
+    def due_between(self, t0: float, t1: float) -> int:
+        return due_between(self.traffic, self.pub_conns, self.start, t0, t1)
 
     def close(self) -> None:
         for p, pipe in self.subs + self.pubs:

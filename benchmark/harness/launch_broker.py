@@ -10,7 +10,11 @@ control directory (the harness stays off JAX while the broker lives):
   is written; it goes → the trace is stopped and ``trace.done`` written
   ({dir, start, stop}: the instants between which the profiler was on);
 - ``mem.req`` appears → ``mem.json`` ({memory_peak_bytes}: the largest
-  ``peak_bytes_in_use`` over the local devices, as JAX reports it).
+  ``peak_bytes_in_use`` over the local devices, as JAX reports it);
+- ``threads.req`` appears → ``threads.json`` ({names}: the names of the
+  process's live threads. The program compiles a match program it has not
+  met on a worker thread of its own, off the routing path, and no counter
+  says that one is at work: its name does, ``Broker.compiling``).
 
 The broker module itself runs unchanged, as ``__main__``, on the main thread.
 """
@@ -61,6 +65,10 @@ def _control(ctl: Path) -> None:
                      for d in jax.local_devices()]
             (ctl / "mem.req").unlink()
             _write(ctl / "mem.json", {"memory_peak_bytes": int(max(peaks))})
+        if (ctl / "threads.req").exists():
+            (ctl / "threads.req").unlink()
+            _write(ctl / "threads.json",
+                   {"names": [t.name for t in threading.enumerate()]})
 
 
 def main() -> None:

@@ -10,7 +10,10 @@
    benchmark imports the program);
 3. the MQTT splitter finds the same packets however the stream is cut;
 4. the trace reduction gives, on the recorded fixture, the numbers written
-   beside it.
+   beside it;
+5. an open mix: the schedule of a mix repeats, a burst is one instant in
+   every publisher process, and the ``open_loop`` line (due, sent, never
+   sent, full-window share, lateness) reads what hand-made records say.
 """
 
 from __future__ import annotations
@@ -143,8 +146,57 @@ def trace_fixture() -> dict:
     return {"busy_s": got["busy_s"], "modules": len(got["modules"])}
 
 
+def open_loop_lines() -> dict:
+    """Four publishes of one process, window [10, 12): the first was due
+    before it and sent inside it after a wait on a full window, the second
+    due and sent inside it 100 ms late with a free slot, the third due
+    inside and sent after the close, and a fourth (due at 11.5 by the
+    schedule) still queued at the close and never sent."""
+    import types
+
+    import numpy as np
+
+    from harness import cell, fleet
+
+    t = spec.load_traffic("fleet_sat", {  # no cell runs an open mix yet: one made here
+        "loop": "open", "rate_publishes_per_s": 3200, "arrival": "burst",
+        "burst_size": 256, "inflight": 16})
+    heads = [[list(itertools.islice(fleet.schedule(t, k, 2, 256), 600))
+              for k in (0, 1)] for _ in (0, 1)]
+    check(heads[0] == heads[1], "the schedule of a mix does not repeat")
+    check({x for x, _c in heads[0][0][:128]} == {x for x, _c in heads[0][1][:128]}
+          == {0.0}, "a burst is not one instant in both processes")
+    f8 = lambda *v: np.array(v, np.float64).tobytes()  # noqa: E731
+    replies = {"subs": [{"ids": np.array([0, 1], np.int64).tobytes(),
+                         "times": f8(10.8, 10.7), "cpu": f8(), "lost": 0,
+                         "subs": np.zeros(2, np.dtype("l")).tobytes()}],
+               "pubs": [{"topics": ["a"] * 3, "t_send": f8(10.4, 10.6, 12.1),
+                         "t_due": f8(9.9, 10.5, 11.9), "t_ack": f8(10.7, 10.65, 12.2),
+                         "waited": np.array([1, 0, 1], np.int8).tobytes(),
+                         "inflight": 0,
+                         "out_of_order": 0, "cpu": f8(), "lost": 0}]}
+    made = types.SimpleNamespace(subs="subs", pubs="pubs",
+                                 ask=lambda procs, _cmd: replies[procs])
+    ref = types.SimpleNamespace(expected=lambda topics: (
+        np.ones(len(topics), np.int64), np.zeros(len(topics), np.int64)))
+    rec = cell.Records(1)
+    rec.drain(made)
+    cmp = cell.compare(rec, ref, 1, 10.0, 12.0)
+    check(not any(cmp["checks"].values()) and cmp["publishes"] == 2,
+          "window membership is not by send instant")
+    check(np.allclose(cmp["deliver_ms"], [900.0, 200.0]), "deliver_ms is not from the due instant")
+    line = cell.open_loop(rec, cmp, 3, 10.0, 12.0)  # the schedule had three due in it
+    want = {"due": 3, "sent": 2, "never_sent": 1,
+            "sent_of_due_pct": 200 / 3, "window_full_share_pct": 200 / 3,
+            "late_p50_ms": 100.0, "late_p99_ms": 100.0, "late_max_ms": 100.0}
+    for k, v in want.items():
+        check(abs(line[k] - v) < 1e-6, f"open_loop line: {k} reads {line[k]}, not {v}")
+    return {k: line[k] for k in ("due", "sent", "never_sent")}
+
+
 def main() -> int:
     out = {"names": files_and_names(), "trie_matches_compared": trie_agrees(),
-           "mqtt_packets": mqtt_splits(), "trace_fixture": trace_fixture()}
+           "mqtt_packets": mqtt_splits(), "trace_fixture": trace_fixture(),
+           "open_loop": open_loop_lines()}
     print(json.dumps({"selftest": "ok", **out}))
     return 0

@@ -22,14 +22,15 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
 # every key a mix has, and what it means; null where a key does not apply
 TRAFFIC_KEYS = {
-    "loop": "closed (open is in the format, and refused until a cell brings it)",
+    "loop": "closed (the broker sets the rate) | open (the mix offers one)",
     "publishers": "publisher connections",
     "subscribers": "subscriber connections; filter i belongs to subscriber i % subscribers",
-    "inflight": "closed loop: QoS1 publishes each connection keeps outstanding",
+    "inflight": "the connection's window: QoS1 publishes it has unacknowledged, "
+                "always (closed loop) or at the most (open loop)",
     "rate_publishes_per_s": "open loop: publishes due per second over all connections",
-    "arrival": "open loop: poisson | burst",
+    "arrival": "open loop: burst, the only arrival a cell asks for yet",
     "burst_size": "open loop, burst: publishes due at one instant",
-    "qos1_share": "open loop: share of publishes sent at QoS1 (closed loop is all QoS1)",
+    "qos1_share": "share of publishes sent at QoS1; 1.0, the only one a cell asks for yet",
     "subscribe_qos": "QoS the subscribers ask for",
     "filters_per_subscribe": "filters in one SUBSCRIBE packet of the table load",
     "publisher_procs": "load-generator processes holding the publishers",
@@ -62,16 +63,34 @@ def load_traffic(name: str, overrides: dict) -> dict:
     if unknown or missing:
         fail(f"traffic {name!r}: unknown keys {sorted(unknown)}, "
              f"missing keys {sorted(missing)}")
-    if t["loop"] == "closed":
-        if not t["inflight"] or t["inflight"] < 1:
-            fail(f"traffic {name!r}: a closed loop needs inflight >= 1")
-        if t["qos1_share"] != 1.0:
-            fail(f"traffic {name!r}: a closed loop publishes at QoS1 alone")
-    elif t["loop"] == "open":
-        fail(f"traffic {name!r}: the generator has no open loop yet; the PR "
-             "that adds the first open-loop cell brings it, with its chip run")
-    else:
+    if t["loop"] not in ("closed", "open"):
         fail(f"traffic {name!r}: loop must be closed or open")
+    if not t["inflight"] or t["inflight"] < 1:
+        fail(f"traffic {name!r}: a connection's window needs inflight >= 1")
+    if t["qos1_share"] != 1.0:
+        fail(f"traffic {name!r}: qos1_share must be 1.0: a QoS0 publish "
+             "carries no guarantee that the comparison could hold exactly, "
+             "and no cell asks for one yet")
+    rate, arrival, burst = (t[k] for k in (
+        "rate_publishes_per_s", "arrival", "burst_size"))
+    if t["loop"] == "closed":
+        if any(v is not None for v in (rate, arrival, burst)):
+            fail(f"traffic {name!r}: a closed loop offers no rate: "
+                 "rate_publishes_per_s, arrival and burst_size must be null")
+    elif not rate or rate <= 0:
+        fail(f"traffic {name!r}: an open loop needs rate_publishes_per_s > 0")
+    elif arrival != "burst":
+        fail(f"traffic {name!r}: an open loop's arrival must be burst: no cell "
+             f"asks for another yet (it names {arrival!r}), and the PR that "
+             "adds the first such cell brings the generator's branch with its "
+             "chip run")
+    else:
+        if not burst or burst < 1:
+            fail(f"traffic {name!r}: arrival burst needs burst_size >= 1")
+        if t["publishers"] < burst:
+            fail(f"traffic {name!r}: a burst of {burst} needs as many "
+                 f"publishers (no connection gets two publishes of one burst); "
+                 f"the mix has {t['publishers']}")
     if t["link"] != "loopback":
         fail(f"traffic {name!r}: only the loopback link exists here")
     return t

@@ -8,7 +8,11 @@ a cell, and the harness says so itself instead of printing it* (PERF.md §4):
   batch over ``hybrid_max`` ends the run in words; every shape the three
   cells' warm-ups take (settled early, give-up, cap) returns what PR 30's
   ``warm_up`` returned on the same script (kept here as literals), field for
-  field and to the same second, with as many GETs;
+  field and to the same second, with as many GETs; and since PR 35 a
+  warm-up does not end while a compile worker of the program lives (a warm
+  cache, the compile PR 35's check met, a checkout's first run, a shape met
+  late, a worker that never ends) nor, where large batches form, before
+  the hybrid's first probe;
 - ``cell.require_device_time``: a traced span with no operation on the device
   fails in words;
 - ``acks_out_of_order``: the publisher child counts a PUBACK that passes an
@@ -56,8 +60,10 @@ class Clock:
 
 class ScriptedBroker:
     """``get()`` answers from ``script(t)``, t = seconds since ``go``: →
-    {large, dev, traces, choice, dispatches, items}; ``large`` None plays a
-    program from before PR 27 (no ``hybrid_large_batches``)."""
+    {large, dev, traces, choice, dispatches, items, compiling}; ``large`` None
+    plays a program from before PR 27 (no ``hybrid_large_batches``);
+    ``compiling`` is what the control thread says of a compile worker;
+    ``probes`` None plays a program that does not count its probes."""
 
     def __init__(self, clock: Clock, script) -> None:
         self.clock, self.script, self.t_go = clock, script, None
@@ -65,6 +71,9 @@ class ScriptedBroker:
 
     def go(self) -> None:
         self.t_go = self.clock.now
+
+    def compiling(self) -> bool:
+        return self.script(self.clock.now - self.t_go)["compiling"]
 
     def get(self, path: str):
         self.gets.append(path)
@@ -76,18 +85,24 @@ class ScriptedBroker:
               "hybrid_choice": s["choice"], "hybrid_max": 64}
         if s["large"] is not None:
             be["hybrid_large_batches"] = s["large"]
+        if s["probes"] is not None:
+            be["hybrid_probes"] = {"side": 0, "device": s["probes"]}
         return {"compile": {"traces": s["traces"]}, "backend": be}
 
 
 def script(large=lambda t: int(20 * t), dev=lambda t: 0, traces=lambda t: 4,
-           choice=lambda t: None, dispatches=lambda t: int(100 * t)):
+           choice=lambda t: None, dispatches=lambda t: int(100 * t),
+           compiling=lambda t: False, probes=lambda t: None):
     return lambda t: {"large": large(t), "dev": dev(t), "traces": traces(t),
                       "choice": choice(t), "dispatches": dispatches(t),
-                      "items": dispatches(t) * 150}
+                      "items": dispatches(t) * 150, "compiling": compiling(t),
+                      "probes": probes(t)}
 
 
-def _warm(seconds, cap_hit, large, dev, clean, traces, choice) -> dict:
-    return {"seconds": seconds, "cap_hit": cap_hit, "large_batches": large,
+def _warm(seconds, cap_hit, large, dev, clean, traces, choice, compiled=0.0,
+          probed=True) -> dict:
+    return {"seconds": seconds, "cap_hit": cap_hit, "compiling_until_s": compiled,
+            "probed": probed, "large_batches": large,
             "device_batches": dev, "clean_device_batches": clean,
             "compile_traces": traces, "hybrid_choice": choice}
 
@@ -103,10 +118,55 @@ SHAPES = {
                              traces=lambda t: 5 if t >= 1 else 4,
                              choice=lambda t: "side" if t >= 2 else None),
                       _warm(10.0, False, 200, 3, 2, 5, "side"), 21, 1),
-    # cfg3 on a slow day: hundreds of large batches, the device's first batch
-    # comes at 16 s behind an off-path compile: the give-up branch, as today
+    # hundreds of large batches, the device's first batch comes at 16 s and
+    # no compile worker was seen: the give-up branch
     "first_device_batch_at_16s": (script(dev=lambda t: int(t >= 16)),
                                   _warm(15.0, False, 300, 0, 0, 4, None), 31, 1),
+    # cfg3 as PR 35's check met it: that first batch stands behind an off-path
+    # compile of 16 s. The worker is waited for, and the give-up clock counts
+    # from its end: the window no longer opens on a compile
+    "first_device_batch_behind_a_compile_of_16s": (
+        script(compiling=lambda t: t < 16, dev=lambda t: int(t >= 16) + int(t >= 30),
+               traces=lambda t: 4 + int(t >= 16),
+               choice=lambda t: "side" if t >= 16 else None),
+        _warm(45.0, False, 900, 2, 1, 5, "side", compiled=15.5), 91, 1),
+    # a warm compile cache: the worker is done in 5 s, inside the least length
+    "compile_worker_5s_then_settled": (
+        script(compiling=lambda t: t < 5, dev=lambda t: max(0, min(int(t - 5), 3)),
+               traces=lambda t: 4 + min(int(t), 5),
+               choice=lambda t: "side" if t >= 7 else None),
+        _warm(10.0, False, 200, 3, 3, 9, "side", compiled=4.5), 21, 1),
+    # a checkout's first run: 110 s of compiles, far past WARMUP_CAP_S, and no
+    # cap is hit; 15 s without a device batch after the worker's end
+    "cold_checkout_110s": (
+        script(compiling=lambda t: t < 110, dev=lambda t: int(t >= 13),
+               traces=lambda t: 4 + min(int(t / 13), 8),
+               choice=lambda t: "side" if t >= 13 else None),
+        _warm(124.5, False, 2490, 1, 0, 12, "side", compiled=109.5), 250, 1),
+    # settled at 2 s, and a new shape is met at 8 s: its compile is waited for
+    "new_shape_met_late": (
+        script(compiling=lambda t: t < 4 or 8 <= t < 14, dev=lambda t: min(int(t), 2),
+               choice=lambda t: "side"),
+        _warm(14.0, False, 280, 2, 2, 4, "side", compiled=13.5), 29, 1),
+    # cfg3 where the mirror wins: settled at 3 s on the start's wide batches,
+    # and the hybrid's first probe (its 64th large batch, at 18.3 s) brings the
+    # everyday shape to the device, which is compiled then, not in the window
+    "first_probe_brings_the_everyday_shape": (
+        script(large=lambda t: int(3.5 * t), probes=lambda t: int(3.5 * t) // 64,
+               dev=lambda t: min(int(t), 3) + int(t >= 19.5) + int(t >= 25) + int(t >= 31),
+               compiling=lambda t: t < 2 or 18.5 <= t < 19.5,
+               traces=lambda t: 4 + int(t >= 2) + int(t >= 19.5),
+               choice=lambda t: "side" if t >= 2 else None),
+        _warm(31.0, False, 108, 6, 2, 6, "side", compiled=19.0), 63, 1),
+    # large batches so rare that no probe comes: the cap, and it is printed
+    "no_probe_before_the_cap": (
+        script(large=lambda t: int(0.5 * t), probes=lambda t: 0,
+               dev=lambda t: min(int(t), 3), choice=lambda t: "side"),
+        _warm(60.0, True, 30, 3, 3, 4, "side", probed=False), 121, 1),
+    # a worker that never ends: the compile's own cap, and it is printed
+    "compile_worker_never_ends": (
+        script(compiling=lambda t: True),
+        _warm(480.0, True, 9600, 0, 0, 4, None, compiled=480.0), 961, 1),
     # device batches that stop early: given up 15 s after the last one
     "give_up_after_the_last": (script(dev=lambda t: min(int(t), 1),
                                       choice=lambda t: "side"),
@@ -146,10 +206,13 @@ def test_warm_up_returns_what_it_returned(shape, clock):
     assert len(b.gets) == device_gets + stats_gets
 
 
-@pytest.mark.parametrize("large", [lambda t: 0, lambda t: None, lambda t: 37],
-                         ids=["none_formed", "no_such_count", "none_since_go"])
-def test_traffic_that_never_offers_the_device_a_batch_fails_in_words(large, clock):
-    b = ScriptedBroker(clock, script(large=large, dispatches=lambda t: int(150 * t)))
+@pytest.mark.parametrize("large, probes", [
+    (lambda t: 0, lambda t: None), (lambda t: None, lambda t: None),
+    (lambda t: 37, lambda t: None), (lambda t: 37, lambda t: 0)],
+    ids=["none_formed", "no_such_count", "none_since_go", "none_since_go_probes_counted"])
+def test_traffic_that_never_offers_the_device_a_batch_fails_in_words(large, probes, clock):
+    b = ScriptedBroker(clock, script(large=large, probes=probes,
+                                     dispatches=lambda t: int(150 * t)))
     with pytest.raises(SystemExit) as e:
         cell.warm_up(b, b.go, "cfg2_100k_plus.pub40")
     words = str(e.value)
@@ -200,7 +263,7 @@ class _Conn:
 def _publishers(inflight: int):
     """A publisher child with one connection and ``inflight`` publishes out,
     made inside a running loop as its process makes it."""
-    a = {"procs": 1, "proc": 0, "traffic": {"inflight": inflight}}
+    a = {"procs": 1, "proc": 0, "traffic": {"loop": "closed", "inflight": inflight}}
     p = fleet._Publishers(None, a, types.SimpleNamespace(value=math.inf))
     c = _Conn()
     p.conns, p.pending[c], p.pid[c] = [c], {}, 0
@@ -218,7 +281,7 @@ def _puback(pid: int):
 def test_publisher_counts_acks_that_pass_an_older_publish(acks, want):
     async def main():
         p, c = _publishers(4)
-        await p.cmd_go()
+        await p.cmd_go(0.0)
         assert list(p.pending[c]) == [1, 2, 3, 4]
         for pid in acks:  # one PUBACK a read, as the wrong order would come
             p.on_packets(c, [_puback(pid)], 5.0)
@@ -235,7 +298,8 @@ class _DrainedFleet:
     def __init__(self, out_of_order) -> None:
         f8 = lambda *v: np.array(v, np.float64).tobytes()  # noqa: E731
         self.replies = {"subs": [], "pubs": [
-            {"topics": ["a/b"], "t_send": f8(10.0), "t_ack": f8(10.2), "inflight": 0,
+            {"topics": ["a/b"], "t_send": f8(10.0), "t_due": f8(10.0), "waited": bytes(1),
+             "t_ack": f8(10.2), "inflight": 0,
              "out_of_order": n, "cpu": f8(), "lost": 0} for n in out_of_order]}
 
     def ask(self, procs, _cmd):
