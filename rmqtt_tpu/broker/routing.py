@@ -69,6 +69,7 @@ class RoutingService:
         self._rec_qwait = self.tele.recorder("routing.queue_wait")
         # busy-clock stages of the dispatch (telemetry.Stage)
         self._st_run = self.tele.stage("ingress.run")
+        self._st_hit = self.tele.stage("routing.cache_hit")
         self._st_plan = self.tele.stage("routing.plan")
         self._st_resolve = self.tele.stage("routing.resolve")
         self.max_batch = max_batch
@@ -309,8 +310,13 @@ class RoutingService:
         trace = CURRENT_TRACE.get() if t0 else None
         entry = self._cache_lookup(topic)
         if entry is not None:
-            await asyncio.sleep(0)
+            # routing.cache_hit: the lookup (from t0), derive and collapse,
+            # ended before the yield
+            tok = self._st_hit.begin_at(t0) if t0 else 0
             out = self.router.collapse(self.cache.derive(entry, from_id))
+            if tok:
+                self._st_hit.end(tok)
+            await asyncio.sleep(0)
             if t0:
                 dur = time.perf_counter_ns() - t0
                 self._rec_hit(dur, topic, trace)
@@ -337,7 +343,9 @@ class RoutingService:
         by ``from_id`` and ``topic``; ``traces`` beside them), offered to
         the batcher in one synchronous pass — the ``ingress.run`` stage —
         so they are neighbours in one dispatch, and awaited together.
-        → ``[(relations, cache_hit)]`` in the same order.
+        → ``[(relations, cache_hit)]`` in the same order. A hit's lookup,
+        derive and collapse are the ``routing.cache_hit`` stage's, nested
+        in ``ingress.run`` and left out of it.
 
         The run suspends at least once, as ``matches_for_fanout`` does per
         publish (see there: the yield is load-bearing); its futures
@@ -351,15 +359,20 @@ class RoutingService:
         q = self._q
         out: list = []
         parked = False
+        hit_ns = 0  # the run's cache hits: routing.cache_hit's, not ingress.run's
         try:
             for msg, trace in zip(msgs, traces):
                 from_id, topic = msg.from_id, msg.topic
+                t_hit = time.perf_counter_ns() if t0 else 0
                 entry = self._cache_lookup(topic)
                 if entry is not None:
+                    htok = self._st_hit.begin_at(t_hit) if t0 else 0
                     out.append((self.router.collapse(
                         self.cache.derive(entry, from_id)), True))
                     if t0:
-                        dur = time.perf_counter_ns() - t0
+                        took = self._st_hit.end(htok)
+                        hit_ns += took
+                        dur = t_hit + took - t0
                         self._rec_hit(dur, topic, trace)
                         if trace is not None:
                             trace.add("publish.cache_hit", t0, dur, topic)
@@ -373,11 +386,11 @@ class RoutingService:
                     q.put_nowait(item)
                 except asyncio.QueueFull:
                     if tok:  # the section may not cross a suspension
-                        self._st_run.end(tok)
+                        self._st_run.end(tok, hit_ns)
                         tok = 0
                     await q.put(item)
             if tok:
-                self._st_run.end(tok)
+                self._st_run.end(tok, hit_ns)
             if not parked:
                 await asyncio.sleep(0)
                 return out

@@ -96,6 +96,7 @@ SERVED_STAGES = (
     "ingress.decode",        # codec.feed / codec.build per read chunk (session.py)
     "ingress.publish",       # _publish_inner up to registry.forwards
     "ingress.run",           # RoutingService.matches_run: a run's topics offered
+    "routing.cache_hit",     # a match-cache hit: lookup, derive, collapse (routing.py)
     "routing.plan",          # RoutingService._plan(batch)
     "routing.match.side",    # AdaptiveHybrid._side_match (host trie mirror)
     "routing.match.device",  # device submit half + complete half (hybrid)
@@ -346,6 +347,11 @@ class Stage:
     closes exactly what ``begin`` opened even if the session starts or
     stops in between, with nothing allocated and no second lookup.
 
+    Stages nest only where the outer one is told: a section opened inside
+    another is its own stage's, and the outer ``end(tok, inner)`` leaves out
+    the ``inner`` ns its nested sections took (their spans nest too, and
+    ``host_spans`` names an instant by the innermost).
+
     Callers guard on ``Telemetry.enabled`` exactly like the histogram
     stages: disabled, no boundary reads a clock or builds an object."""
 
@@ -390,16 +396,18 @@ class Stage:
         """Stop the clock without counting; → the section's ns."""
         return self._close(tok, 0)
 
-    def end(self, tok: int) -> int:
-        """Stop the clock and count one pass; → the section's ns."""
-        return self._close(tok, 1)
+    def end(self, tok: int, inner: int = 0) -> int:
+        """Stop the clock and count one pass; → the section's ns. ``inner``:
+        the ns of another stage's sections nested inside this one, which
+        that stage owns and this one does not count."""
+        return self._close(tok, 1, inner)
 
-    def _close(self, tok: int, n: int) -> int:
+    def _close(self, tok: int, n: int, inner: int = 0) -> int:
         now = time.perf_counter_ns()
         if tok < 0:
             PROFILER.close()
             tok = -tok
-        dt = now - tok
+        dt = now - tok - inner
         lock = self._lock
         if lock is None:
             self.count += n

@@ -116,6 +116,33 @@ def test_stage_counts_one_pass_and_laps_without_counting():
     assert st.count == 1 and st.busy_ns >= busy
 
 
+def test_a_nested_stage_owns_its_time_and_the_outer_leaves_it_out(annotation):
+    """``routing.cache_hit`` inside ``ingress.run`` (a run's hits): the outer
+    ``end(tok, inner)`` counts its section less the inner sections, and the
+    two spans nest (the inner is closed first)."""
+    annotation.enabled = True
+    T.PROFILER.poll()
+    outer, inner = T.Stage("ingress.run"), T.Stage("routing.cache_hit")
+    tok = outer.begin(4)
+    t0 = time.perf_counter_ns()
+    while time.perf_counter_ns() - t0 < 2_000_000:  # 2 ms before the hit
+        pass
+    itok = inner.begin_at(time.perf_counter_ns())
+    assert annotation.open_now == 2
+    while time.perf_counter_ns() - t0 < 5_000_000:  # the hit: 3 ms more
+        pass
+    took = inner.end(itok)
+    assert annotation.open_now == 1
+    net = outer.end(tok, took)
+    assert annotation.open_now == 0
+    assert (outer.count, inner.count) == (1, 1)
+    assert outer.busy_ns == net and inner.busy_ns == took >= 3_000_000
+    # the outer holds the 2 ms before the hit and its wall less the hit
+    assert 2_000_000 <= net and net + took <= time.perf_counter_ns() + tok
+    assert [name for name, _kw in annotation.built] == [
+        "rmqtt/ingress.run", "rmqtt/routing.cache_hit"]
+
+
 def test_by_thread_stage_keeps_executor_time_apart():
     import threading
 
