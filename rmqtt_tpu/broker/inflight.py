@@ -60,9 +60,11 @@ class OutInflight:
         self._next_pid = 1
         # event-driven credit: a 10ms sleep-poll in the deliver loop capped
         # per-session QoS1/2 delivery at ~max_inflight/10ms (measured 1.6K
-        # msg/s at the default window of 16)
-        self._credit_ev = asyncio.Event()
-        self._credit_ev.set()
+        # msg/s at the default window of 16). The deliver loop parks on a
+        # future of its own (wait_credit) that a freed slot resolves — or,
+        # while the read task holds it (claim), that the read task hands
+        # back (release) after spending the credit itself
+        self._credit_waiter: Optional[asyncio.Future] = None
         # event-driven retry wake: an idle session's retry loop must BLOCK
         # until something is actually in flight — a 20s sleep-poll per
         # session is ~12.5K timer wakeups/s at 250K held connections, which
@@ -74,7 +76,35 @@ class OutInflight:
         return len(self._entries) < self.max_inflight
 
     async def wait_credit(self) -> None:
-        await self._credit_ev.wait()
+        """Park until a slot is free (the deliver loop's wait: one waiter)."""
+        if self.has_credit():
+            return
+        self._credit_waiter = w = asyncio.get_running_loop().create_future()
+        try:
+            await w
+        finally:
+            if self._credit_waiter is w:
+                self._credit_waiter = None
+
+    def claim(self) -> Optional[asyncio.Future]:
+        """Take the parked deliver loop's wait (None where it is not
+        parked): from here no freed slot wakes the loop, and the holder
+        alone sends until it hands the wait back with ``release``."""
+        w = self._credit_waiter
+        if w is None or w.done():
+            return None
+        self._credit_waiter = None
+        return w
+
+    def release(self, w: asyncio.Future) -> None:
+        """Hand a claimed wait back: resolved where a slot is free, else
+        parked again (a wait cancelled meanwhile is left as it is)."""
+        if w.done():
+            return
+        if self.has_credit():
+            w.set_result(None)
+        else:
+            self._credit_waiter = w
 
     async def wait_nonempty(self) -> None:
         """Block until the window holds at least one entry."""
@@ -82,10 +112,9 @@ class OutInflight:
             await self._nonempty_ev.wait()
 
     def _update_credit(self) -> None:
-        if self.has_credit():
-            self._credit_ev.set()
-        else:
-            self._credit_ev.clear()
+        w = self._credit_waiter
+        if w is not None and self.has_credit() and not w.done():
+            w.set_result(None)
         if self._entries:
             self._nonempty_ev.set()
         else:

@@ -530,6 +530,10 @@ class SessionState:
         self._hold: Optional[Hold] = None
         self._held_acks: deque = deque()
         self._held_task: Optional[asyncio.Task] = None
+        # the parked deliver loop's credit wait, taken by this read chunk's
+        # first PUBACK / PUBCOMP while work is queued (_handle), given back
+        # by the refill that spends the credit the chunk freed (_refill)
+        self._claimed: Optional[asyncio.Future] = None
         # runs of pipelined publishes (_publish_run): where the registry's
         # forwards is a match and then a synchronous fan-out (the single
         # node's; a cluster's or the fabric's keeps one publish at a time)
@@ -677,7 +681,7 @@ class SessionState:
     async def _read_loop(self) -> None:
         early, self.early_packets = self.early_packets, []
         for p in early:
-            await self._handle(p)
+            await self._serve([p])
         if self.codec.pending_error is not None:
             # the pipelined CONNECT burst ended in a malformed frame (even
             # with no valid packets between CONNECT and the bad frame):
@@ -728,10 +732,7 @@ class SessionState:
             except ProtocolViolation as e:
                 await self._protocol_error(e.reason_code)
                 return False
-            if len(packets) == 1:
-                await self._handle(packets[0])
-            else:
-                await self._handle_all(packets)
+            await self._serve(packets)
             if self.codec.pending_error is not None:
                 await self._protocol_error(self.codec.pending_error.reason_code)
                 return False
@@ -827,10 +828,7 @@ class SessionState:
                     await self._protocol_error(e.reason_code)
                     return False
                 violation = e  # of a later chunk: after the packets before it
-            if len(packets) == 1:
-                await self._handle(packets[0])
-            else:
-                await self._handle_all(packets)
+            await self._serve(packets)
             if violation is not None:
                 await self._protocol_error(violation.reason_code)
                 return False
@@ -1045,16 +1043,29 @@ class SessionState:
             await asyncio.sleep(max(0.05, timeout - idle))
 
     # ------------------------------------------------------------- dispatch
+    async def _serve(self, packets: list) -> None:
+        """One read chunk's packets, then the refill its acks claimed."""
+        if len(packets) == 1:
+            await self._handle(packets[0])
+        else:
+            await self._handle_all(packets)
+        if self._claimed is not None:
+            await self._refill()
+
     async def _handle_all(self, packets: list) -> None:
         """A read chunk of two or more packets, in order. A PUBLISH with
         another right behind it was pipelined by the client: the two, and
         the PUBLISHes that follow them, are served as a run
-        (``_publish_run``); every other packet as it always was."""
+        (``_publish_run``); every other packet as it always was. Credit
+        the acks before a packet of another kind freed is spent before
+        that packet is served (it may suspend)."""
         i, n = 0, len(packets)
         runs = self._runs
         while i < n:
             p = packets[i]
             i += 1
+            if self._claimed is not None and not isinstance(p, (pk.Puback, pk.Pubcomp)):
+                await self._refill()
             if (runs and i < n and isinstance(p, pk.Publish)
                     and isinstance(packets[i], pk.Publish)):
                 i = await self._publish_run(packets, i - 1)
@@ -1069,6 +1080,11 @@ class SessionState:
             # ack.in: the window release (and what rides it), up to the
             # acked hook — a plugin's hook may suspend
             tok = self._st_ack_in.begin() if self.ctx.telemetry.enabled else 0
+            if self._claimed is None and s.deliver_queue:
+                # the deliver loop is parked on a full window with work
+                # queued: it sleeps on, and the refill after this chunk's
+                # acks spends what they free (_refill)
+                self._claimed = s.out_inflight.claim()
             e = s.out_inflight.ack(p.packet_id)
             if e is not None:
                 self._record_ack_rtt(e)
@@ -1117,6 +1133,33 @@ class SessionState:
         elif isinstance(p, pk.Connect):
             # second CONNECT is a protocol error (MQTT-3.1.0-2)
             self._closing.set()
+
+    async def _refill(self) -> None:
+        """Spend the window credit this read chunk's PUBACKs / PUBCOMPs
+        freed, here in the read task, with the deliver loop's own steps
+        (``_deliver``, in queue order): the frames join the egress job of
+        the turn the acks were read in, where waking the loop would cost
+        it a loop turn more. The loop's credit wait stays claimed until
+        the end, so one task sends at a time even where a hook or a drain
+        suspends here; it is given back resolved where a slot is left free
+        (the queue ran dry), else parked again — the loop never wakes to
+        a full window."""
+        s = self.s
+        q, fl = s.deliver_queue, s.out_inflight
+        w, self._claimed = self._claimed, None
+        n = 0
+        try:
+            while q and fl.has_credit():
+                await q.throttle()
+                item = q.pop()
+                if item is None:
+                    break
+                n += 1
+                await self._deliver(item)
+        finally:
+            fl.release(w)
+            if n:
+                self.ctx.metrics.inc("deliver.ack_refills", n)
 
     def _record_ack_rtt(self, e: OutEntry) -> None:
         """QoS1/2 ack round trip: last (re)delivery → PUBACK/PUBCOMP. Uses

@@ -39,7 +39,7 @@ class SessionRegistry:
         # exist from the start: a reader tells "never" from "no such counter"
         for name in ("fanout.enqueues", "fanout.held", "deliver.queue_over_half",
                      "deliver.cold_enqueues", "ingress.runs",
-                     "ingress.run_publishes"):
+                     "ingress.run_publishes", "deliver.ack_refills"):
             ctx.metrics.inc(name, 0)
         # a session may serve a connection's pipelined publishes as a run
         # (session.py ``_publish_run``: ``RoutingService.matches_run``, then
