@@ -818,6 +818,21 @@ class ServerContext:
             GCPOLICY.disarm()
 
     def stats(self) -> Stats:
+        """The gauge snapshot, timed as the ``stats.snapshot`` stage (the
+        history sampler's tick, every API scrape): the hot-key fold it makes
+        is ``hotkeys.fold``'s, and left out of it."""
+        tele = self.telemetry
+        if not tele.enabled:
+            return self._stats()
+        st, fold = tele.stage("stats.snapshot"), tele.stage("hotkeys.fold")
+        folded = fold.busy_ns
+        tok = st.begin()
+        try:
+            return self._stats()
+        finally:
+            st.end(tok, fold.busy_ns - folded)
+
+    def _stats(self) -> Stats:
         s = Stats()
         s.connections = self.registry.connected_count()
         s.sessions = self.registry.session_count()

@@ -12,8 +12,10 @@ each) that was five or six stalls of 1.5 s in a 51 s window, on a 100K-row
 one (0.6M objects) thirty-odd of 180 ms: the largest single item on the
 event loop's thread, and the p99 of every latency a client sees.
 
-The policy owns one entry of ``gc.callbacks`` and two rules, both applied
-where a full pass ends, on whichever thread collected:
+The policy owns the program's one entry of ``gc.callbacks`` (which also
+times every pass for the host profiler, and makes it a span in a profiler
+session) and two rules, both applied where a full pass ends, on whichever
+thread collected:
 
 *Freeze.* A full pass that took longer than ``LONG_PASS_S`` is followed at
 once by ``gc.freeze()``: what just survived moves to the permanent generation,
@@ -53,6 +55,8 @@ import threading
 import time
 from typing import Callable, Optional
 
+from rmqtt_tpu.broker.telemetry import PROFILER
+
 #: A full pass longer than this is the last one over its heap. 20 ms is four
 #: switch intervals of the interpreter and a fifth of the median delivery in
 #: the saturated cells (~100 ms): a stall that a p99 can feel, where a pass of
@@ -75,16 +79,28 @@ THAW_BUDGET = 0.005
 class GcPolicy:
     """Freezes the heap that survives a long full pass; thaws it on a
     wall-clock budget. ``clock`` and ``collector`` are seams for the tests'
-    scripted clock and collector; the process has one instance, ``GCPOLICY``."""
+    scripted clock and collector; the process has one instance, ``GCPOLICY``.
+
+    Its ``gc.callbacks`` entry is the process's only one of this program: it
+    also times every pass for the host profiler (``watch``), and while a
+    profiler session is on it makes each pass a span, ``rmqtt/gc.young``
+    (generations 0 and 1) or ``rmqtt/gc.full`` (generation 2, the freeze and
+    its count included), on the thread that collected."""
 
     def __init__(self, clock: Callable[[], float] = time.perf_counter,
                  collector=gc) -> None:
         self._clock = clock
         self._gc = collector
-        # arm / disarm against a pass that ends on another thread meanwhile
+        # arm / disarm / watch against a pass that ends on another thread
         self._lock = threading.Lock()
         self._arms = 0
-        self._t0: Optional[float] = None  # start of the full pass under way
+        self._installed = False
+        #: the host profiler's pause sink while it is started:
+        #: ``(generation, seconds, info)`` at the end of every pass; it never
+        #: blocks
+        self.pauses: Optional[Callable[[int, float, dict], None]] = None
+        self._t0: Optional[float] = None  # start of the pass under way
+        self._span = None                 # its annotation, while one is open
         self._frozen = False              # the policy froze, and has not thawed
         self._due = 0.0                   # earliest instant of the next thaw
         # counters: cumulative over the process, like HOSTPROF's
@@ -106,40 +122,72 @@ class GcPolicy:
         """Count one broker in; the first installs the callback."""
         with self._lock:
             self._arms += 1
-            if self._arms == 1:
-                self._gc.callbacks.append(self._on_gc)
+            self._install()
 
     def disarm(self) -> None:
-        """Count one broker out; the last removes the callback and unfreezes
-        what the policy froze."""
+        """Count one broker out; the last removes the callback (unless the
+        host profiler still watches) and unfreezes what the policy froze."""
         with self._lock:
             if self._arms == 0:
                 return
             self._arms -= 1
             if self._arms:
                 return
-            try:
-                self._gc.callbacks.remove(self._on_gc)
-            except ValueError:
-                pass
+            self._install()
             if self._frozen:
                 self._gc.unfreeze()
             self._frozen = False
             self.frozen_objects = 0
 
+    def watch(self, pauses: Callable[[int, float, dict], None]) -> None:
+        """Hand every pass's length to ``pauses`` from now on."""
+        with self._lock:
+            self.pauses = pauses
+            self._install()
+
+    def unwatch(self) -> None:
+        with self._lock:
+            self.pauses = None
+            self._install()
+
+    def _install(self) -> None:
+        """The callback is in ``callbacks`` while a broker is armed or the
+        host profiler watches; the caller holds the lock."""
+        want = bool(self._arms) or self.pauses is not None
+        if want and not self._installed:
+            self._gc.callbacks.append(self._on_gc)
+        elif self._installed and not want:
+            try:
+                self._gc.callbacks.remove(self._on_gc)
+            except ValueError:
+                pass
+        self._installed = want
+
     # ------------------------------------------------------------- callback
     def _on_gc(self, phase: str, info: dict) -> None:
         """``gc.callbacks`` entry; runs on whichever thread collected, and
-        never blocks (a collection can start inside ``arm`` itself)."""
-        if info["generation"] != 2:
-            return
-        now = self._clock()
+        never blocks or raises (a collection can start inside ``arm``
+        itself, or inside a span's own open). One clock read at ``start``
+        and one at ``stop``, and none for a young pass nobody times."""
+        gen = info["generation"]
         if phase == "start":
-            self._t0 = now
+            if PROFILER.on:
+                self._open_span(gen)
+            if gen == 2 or self.pauses is not None:
+                self._t0 = self._clock()
             return
         t0, self._t0 = self._t0, None
-        if t0 is None:
-            return
+        if t0 is not None:
+            now = self._clock()
+            pauses = self.pauses
+            if pauses is not None:
+                pauses(gen, now - t0, info)
+            if gen == 2:
+                self._full_pass(t0, now)
+        if self._span is not None:
+            self._close_span()
+
+    def _full_pass(self, t0: float, now: float) -> None:
         took = now - t0
         self.full_pauses += 1
         self.full_pause_s += took
@@ -165,6 +213,25 @@ class GcPolicy:
                     self._due = end + (end - t0) / THAW_BUDGET
         finally:
             self._lock.release()
+
+    def _open_span(self, gen: int) -> None:
+        # kept off PROFILER's per-thread stack of open sections: a pass can
+        # start inside a stage's own open, between its annotation's
+        # construction and its push
+        try:
+            span = PROFILER.annotation(
+                "rmqtt/gc.full" if gen == 2 else "rmqtt/gc.young")
+            span.__enter__()
+            self._span = span
+        except Exception:
+            self._span = None
+
+    def _close_span(self) -> None:
+        span, self._span = self._span, None
+        try:
+            span.__exit__(None, None, None)
+        except Exception:
+            pass
 
     # -------------------------------------------------------------- surfaces
     def stats_block(self) -> dict:

@@ -19,7 +19,7 @@ dominate p99; this module makes them attributable:
     analogue of devprof's retrace storm): counted, annotated on the
     slow-op ring (``host.lag_storm``) and auto-dumped.
 
-``GC forensics`` (``gc.callbacks``)
+``GC forensics`` (the collector policy's ``gc.callbacks`` entry)
     Pause duration histograms per generation, objects collected /
     uncollectable, and — the forensic the flat counters can't give —
     *gc-during-dispatch correlation*: a pause at/over ``gc_slow_ms``
@@ -73,7 +73,8 @@ import traceback
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional
 
-from rmqtt_tpu.broker.telemetry import Histogram, prom_sanitize
+from rmqtt_tpu.broker.gcpolicy import GCPOLICY
+from rmqtt_tpu.broker.telemetry import NBUCKETS, Histogram, prom_sanitize
 
 _LOG = logging.getLogger("rmqtt_tpu.hostprof")
 
@@ -203,9 +204,8 @@ class HostProfiler:
         self._laggy_ts: deque = deque()
         self._last_storm_mono = -1e18
         self._last_tick_mono = 0.0
-        # gc accounting
-        self._gc_t0: Dict[int, int] = {}
-        # GC samples awaiting the lock (see _gc_cb: it must never block)
+        # gc accounting; samples awaiting the lock (see _gc_cb: it must
+        # never block)
         self._gc_pending: deque = deque()
         self.gc_hist: Dict[int, Histogram] = {g: Histogram() for g in _GENS}
         self.gc_pauses: Dict[int, int] = {g: 0 for g in _GENS}
@@ -276,7 +276,7 @@ class HostProfiler:
         self._last_tick_mono = time.monotonic()
         self._task = loop.create_task(self._sample_loop(), name="hostprof")
         if not self._gc_installed:
-            gc.callbacks.append(self._gc_cb)
+            GCPOLICY.watch(self._gc_cb)
             self._gc_installed = True
         # each watchdog owns its OWN stop event: a stop() immediately
         # followed by a start() (broker restart in one process) must not
@@ -296,10 +296,7 @@ class HostProfiler:
             return
         self._stop_evt.set()
         if self._gc_installed:
-            try:
-                gc.callbacks.remove(self._gc_cb)
-            except ValueError:
-                pass
+            GCPOLICY.unwatch()
             self._gc_installed = False
         task, self._task = self._task, None
         if task is not None:
@@ -397,18 +394,13 @@ class HostProfiler:
             r.rss_mb = rss
 
     # ------------------------------------------------------------- GC seam
-    def _gc_cb(self, phase: str, info: dict) -> None:
-        """gc.callbacks hook: pause duration per generation + collected
-        totals; slow pauses land on the slow-op ring with the in-dispatch
-        correlation. Runs on whichever thread triggered collection."""
-        gen = info.get("generation", 2)
-        if phase == "start":
-            self._gc_t0[gen] = time.perf_counter_ns()
-            return
-        t0 = self._gc_t0.pop(gen, None)
-        if t0 is None:
-            return
-        dur_ns = time.perf_counter_ns() - t0
+    def _gc_cb(self, gen: int, took_s: float, info: dict) -> None:
+        """One pass of the collector, as the process's one ``gc.callbacks``
+        entry (``broker/gcpolicy.py``) timed it: pause duration per
+        generation + collected totals; slow pauses land on the slow-op ring
+        with the in-dispatch correlation. Runs on whichever thread
+        triggered collection."""
+        dur_ns = int(took_s * 1e9)
         collected = int(info.get("collected", 0) or 0)
         uncollectable = int(info.get("uncollectable", 0) or 0)
         # NEVER block here. A collection starts wherever an allocation
@@ -574,6 +566,15 @@ class HostProfiler:
         return out
 
     # ------------------------------------------------------------- surfaces
+    def bucket_stats(self) -> Dict[str, int]:
+        """The lag sampler's histogram as 40 cumulative bucket counts
+        ``hist_host_loop_lag_b<i>``, flat and monotone like telemetry's
+        ``bucket_stats``: two snapshots of ``/api/v1/stats`` give the lag of
+        the ticks between them, where ``host_loop_lag_p99_ms`` is since
+        start. Same keys, zeros, when disabled."""
+        counts = self.lag_hist.counts if self.enabled else [0] * NBUCKETS
+        return {f"hist_host_loop_lag_b{i:02d}": c for i, c in enumerate(counts)}
+
     def snapshot(self) -> dict:
         """The `/api/v1/host` body: shape-stable whether enabled or not."""
         with self._lock:

@@ -60,6 +60,7 @@ import zlib
 from typing import Any, Dict, List, Optional, Tuple
 
 from rmqtt_tpu.broker.hooks import HookType
+from rmqtt_tpu.broker.telemetry import NULL_TELEMETRY
 from rmqtt_tpu.utils.failpoints import FAILPOINTS
 
 log = logging.getLogger("rmqtt_tpu.hotkeys")
@@ -346,20 +347,30 @@ class HotkeysService:
         self._pend_disp: List[str] = []
         self._pend_sub: List[str] = []
         self._pend_drop: List[str] = []
+        # the fold's busy clock (broker/telemetry.py Stage), and the stages
+        # whose open section a seam that folds its full buffer runs in:
+        # they leave the fold out
+        tele = getattr(ctx, "telemetry", None) or NULL_TELEMETRY
+        self._tele = tele
+        self._st_fold = tele.stage("hotkeys.fold")
+        self._in_publish = tele.stage("ingress.publish")
+        self._in_plan = tele.stage("routing.plan")
 
     # ------------------------------------------------------------ hot seams
     # Each seam is one method call behind one `enabled` check at the call
     # site, and the body is ONE list append — the crc32/dict sketch work
     # runs in drain(), amortized per DISTINCT buffered key (zipf-skewed
     # traffic collapses thousands of events into tens of offers). Every
-    # query and the rotator tick drain first, so answers stay exact.
+    # query and the rotator tick drain first, so answers stay exact. A seam
+    # whose buffer fills folds it there (``_spill``), inside its caller's
+    # stage section, which leaves the fold out.
 
     def on_publish(self, topic: str, client_id: str, nbytes: int) -> None:
         """Session publish ingress: topic by count AND bytes, publisher."""
         buf = self._pend_pub
         buf.append((topic, client_id, nbytes))
         if len(buf) >= _PENDING_MAX:
-            self.drain()
+            self._spill(self._in_publish)  # inside ingress.publish
 
     def on_dispatch(self, topic: str) -> None:
         """RoutingService dispatch: attribute automaton work to the
@@ -376,7 +387,7 @@ class HotkeysService:
         buf = self._pend_disp
         buf.extend(t for _f, t in items)
         if len(buf) >= _PENDING_MAX:
-            self.drain()
+            self._spill(self._in_plan)  # inside routing.plan
 
     def on_deliver(self, client_id: str) -> None:
         """Delivery send: the subscriber actually receiving bytes."""
@@ -393,10 +404,33 @@ class HotkeysService:
         if len(buf) >= _PENDING_MAX:
             self.drain()
 
-    def drain(self) -> None:
+    def _spill(self, host) -> None:
+        """Fold a full buffer now, inside the open section of the ``host``
+        stage, which leaves the fold out (its ``end`` never sees it)."""
+        folded = self.drain()
+        if folded:
+            host.leave_out(folded)
+
+    def drain(self) -> int:
         """Fold the buffered seam events into the sketches, aggregating
         per distinct key first so the hash work scales with key
-        cardinality, not event volume."""
+        cardinality, not event volume. Timed as the ``hotkeys.fold`` stage
+        where anything was buffered; → its ns (0 with telemetry off)."""
+        if not (self._pend_pub or self._pend_disp or self._pend_sub
+                or self._pend_drop):
+            return 0
+        if not self._tele.enabled:
+            self._fold()
+            return 0
+        st = self._st_fold
+        tok = st.begin()
+        try:
+            self._fold()
+        finally:
+            folded = st.end(tok)
+        return folded
+
+    def _fold(self) -> None:
         sp = self.spaces
         pubs, self._pend_pub = self._pend_pub, []
         if pubs:

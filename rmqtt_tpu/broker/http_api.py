@@ -47,8 +47,12 @@ def sysinfo() -> dict:
 def stats_body(ctx) -> dict:
     """The ``"stats"`` object of ``/api/v1/stats`` (and a peer's reply to
     STATS_GET): the gauge surface plus the window histograms' cumulative
-    bucket counts (``Telemetry.bucket_stats``)."""
-    return {**ctx.stats().to_json(), **ctx.telemetry.bucket_stats()}
+    bucket counts (``Telemetry.bucket_stats``) and the loop lag's
+    (``HostProfiler.bucket_stats``)."""
+    from rmqtt_tpu.broker.hostprof import HOSTPROF
+
+    return {**ctx.stats().to_json(), **ctx.telemetry.bucket_stats(),
+            **HOSTPROF.bucket_stats()}
 
 
 def client_info(s) -> dict:

@@ -51,7 +51,10 @@ slow-op ring
     on (``PROFILER.poll``, asked once per loop turn / routing dispatch) the
     same section is also a ``jax.profiler.TraceAnnotation("rmqtt/<stage>")``
     so it lands on the host plane of the trace that holds the device's
-    operations, on the thread that ran it.
+    operations, on the thread that ran it. The same session puts every
+    callback of the event loop (``handle_spans``) and every pass of the
+    cyclic collector (``broker/gcpolicy.py``) under a span of its own, so
+    that the loop thread's time between stages has names too.
 
 Stage names are pre-registered (``STAGES``, ``SERVED_STAGES``) so every
 surface — JSON endpoints, Prometheus, $SYS, the dashboard — is
@@ -60,6 +63,7 @@ shape-stable whether or not traffic (or telemetry itself) has happened yet.
 
 from __future__ import annotations
 
+import asyncio
 import re
 import selectors
 import sys
@@ -105,6 +109,7 @@ SERVED_STAGES = (
     "matcher.fetch",
     "matcher.decode",
     "matcher.compile",       # a jit seam call whose shape key was never seen
+    "matcher.compile_offpath",  # ops/hybrid.py's worker: a batch run to compile
     "routing.expand",        # XlaRouter._expand (relations expansion)
     "routing.resolve",       # RoutingService._resolve (futures, cache fill)
     "fanout.enqueue",        # SessionRegistry.forwards' _deliver_local loop
@@ -113,6 +118,8 @@ SERVED_STAGES = (
     "egress.flush",          # a transport write, or a turn's hand-off (egress.py)
     "ack.in",                # subscriber PUBACK/PUBREC/PUBCOMP → window release
     "ack.out",               # publisher's PUBACK/PUBREC encode + send
+    "stats.snapshot",        # ServerContext.stats(): the 5 s tick, a scrape
+    "hotkeys.fold",          # HotkeysService.drain(): buffered events folded
 )
 # these run on the loop thread for some batches and on an executor thread
 # for others: the loop thread is the wall and an executor thread's wall
@@ -263,12 +270,13 @@ class _Profiler:
     cached ``on``. jax is never imported from here: a broker on the trie
     router does not load it, and no session can be on without it."""
 
-    __slots__ = ("on", "annotation", "loop_tid", "_tls")
+    __slots__ = ("on", "annotation", "loop_tid", "handles", "_tls")
 
     def __init__(self) -> None:
         self.on = False
         self.annotation = None  # jax.profiler.TraceAnnotation, once jax is loaded
         self.loop_tid = threading.get_ident()  # re-bound by Telemetry.bind_loop
+        self.handles = False  # the loop's callbacks are spans (handle_spans)
         self._tls = threading.local()  # per thread: open annotations, batch seq
 
     def poll(self) -> bool:
@@ -312,6 +320,44 @@ class _Profiler:
 #: process-global like the profiler session it mirrors
 PROFILER = _Profiler()
 
+_STOCK_RUN = asyncio.events.Handle._run
+# span name by qualname: a Task's coroutine, any other callback
+_TASK_SPANS: Dict[str, str] = {}
+_CB_SPANS: Dict[str, str] = {}
+
+
+def _traced_run(self) -> None:
+    """``Handle._run`` while a session is on: the callback is a span, on the
+    loop thread, that the stages it runs nest in — ``rmqtt/loop.task/<the
+    coroutine's __qualname__>`` for a Task's step or wake-up,
+    ``rmqtt/loop.cb/<the callback's __qualname__>`` for anything else."""
+    cb = self._callback
+    owner = getattr(cb, "__self__", None)
+    if isinstance(owner, asyncio.Task):
+        coro = owner.get_coro()
+        q = getattr(coro, "__qualname__", None) or type(coro).__qualname__
+        name = _TASK_SPANS.get(q)
+        if name is None:
+            name = _TASK_SPANS[q] = "rmqtt/loop.task/" + q
+    else:
+        q = getattr(cb, "__qualname__", None) or type(cb).__qualname__
+        name = _CB_SPANS.get(q)
+        if name is None:
+            name = _CB_SPANS[q] = "rmqtt/loop.cb/" + q
+    ann = PROFILER.annotation(name)
+    ann.__enter__()
+    try:
+        _STOCK_RUN(self)
+    finally:
+        ann.__exit__(None, None, None)
+
+
+def handle_spans(on: bool) -> None:
+    """Put the span-making ``Handle._run`` in (``on``) or the stock one back:
+    process-wide, like the profiler session it follows."""
+    asyncio.events.Handle._run = _traced_run if on else _STOCK_RUN
+    PROFILER.handles = on
+
 
 class IdleSpanSelector(selectors.DefaultSelector):
     """The event loop's selector (``server.py`` hands it to
@@ -320,11 +366,18 @@ class IdleSpanSelector(selectors.DefaultSelector):
     clock as the stages: ``rmqtt/loop.idle`` where it may block (the loop
     has nothing queued and waits for the network), ``rmqtt/loop.poll``
     where it may not (work is queued: the span is the system call and
-    re-taking the GIL after it). Spans only, no counter."""
+    re-taking the GIL after it). Spans only, no counter. The poll also
+    puts the loop's callbacks under spans of their own when it first sees
+    a session on, and takes them out when it first sees it off
+    (``handle_spans``): with no session the stock ``Handle._run`` runs."""
 
     def select(self, timeout=None):
         if not PROFILER.poll():
+            if PROFILER.handles:
+                handle_spans(False)
             return super().select(timeout)
+        if not PROFILER.handles:
+            handle_spans(True)
         poll = timeout is not None and timeout <= 0
         with PROFILER.annotation("rmqtt/loop.poll" if poll else "rmqtt/loop.idle"):
             return super().select(timeout)
@@ -401,6 +454,12 @@ class Stage:
         the ns of another stage's sections nested inside this one, which
         that stage owns and this one does not count."""
         return self._close(tok, 1, inner)
+
+    def leave_out(self, inner: int) -> None:
+        """``end``'s ``inner`` for a section this stage has open on the loop
+        thread, from code nested in it that the section's ``end`` never
+        sees (a hot-key seam that folds its full buffer)."""
+        self.busy_ns -= inner
 
     def _close(self, tok: int, n: int, inner: int = 0) -> int:
         now = time.perf_counter_ns()

@@ -99,16 +99,23 @@ class AdaptiveHybrid:
         self._to_compile: dict = {}
         self._compiler: Optional[threading.Thread] = None
         # busy-clock stages (broker/telemetry.py Stage), wired by the
-        # router; the match entry points take ``staged`` from their caller
-        self._st_side = self._st_dev = None
+        # router; the match entry points take ``staged`` from their caller,
+        # the compile worker times itself where its stage is wired
+        self._st_side = self._st_dev = self._st_offpath = None
         # EMA state is touched from both the submit and the completion
         # executor threads (RoutingService pipelining); the GIL keeps it
         # memory-safe but probe cadence / rate attribution would skew —
         # RLock because _bump_device nests into _bump
         self._lock = threading.RLock()
 
-    def use_stages(self, side, device) -> None:
+    def use_stages(self, side, device, offpath=None) -> None:
         self._st_side, self._st_dev = side, device
+        self._st_offpath = offpath
+
+    @property
+    def compiles_under_way(self) -> int:
+        """1 while the compile worker lives (it has a batch to compile)."""
+        return int(self._compiler is not None)
 
     # ------------------------------------------------------------- internals
     def _bump(self, key: str, rate: float) -> None:
@@ -234,6 +241,8 @@ class AdaptiveHybrid:
                     self._compiler = None
                     return
                 sig, topics = next(iter(self._to_compile.items()))
+            st = self._st_offpath
+            tok = st.begin(len(topics)) if st is not None else 0
             try:
                 # the whole round trip: what the submit half needs and what
                 # the complete half regrows both compile here. The result
@@ -245,6 +254,9 @@ class AdaptiveHybrid:
                 _LOG.exception("compiling %s off the routing path failed; "
                                "the next batch of its shape tries again",
                                sig[0])
+            finally:
+                if tok:
+                    st.end(tok)
             with self._lock:
                 del self._to_compile[sig]
 

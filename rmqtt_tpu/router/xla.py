@@ -219,11 +219,12 @@ class XlaRouter(Router):
     def use_telemetry(self, tele) -> None:
         """Wired by ServerContext beside ``router.telemetry``: the busy
         stages of the match path (broker/telemetry.py ``Stage``) — the
-        relations expansion here, the hybrid's two backends, the device
-        matcher's four sections."""
+        relations expansion here, the hybrid's two backends and its compile
+        worker, the device matcher's four sections."""
         self._st_expand = tele.stage("routing.expand")
-        self._hybrid.use_stages(tele.stage("routing.match.side"),
-                                tele.stage("routing.match.device"))
+        self._hybrid.use_stages(
+            tele.stage("routing.match.side"), tele.stage("routing.match.device"),
+            tele.stage("matcher.compile_offpath") if tele.enabled else None)
         use = getattr(self.matcher, "use_telemetry", None)
         if use is not None:
             use(tele)
@@ -456,6 +457,9 @@ class XlaRouter(Router):
             # still being compiled off the routing path (ops/hybrid.py)
             "hybrid_large_batches": self._hybrid.large_batches,
             "hybrid_compiling_side": list(self._hybrid.compiling_side),
+            # 1 while a program is compiled off the routing path (the
+            # worker thread ``rmqtt-compile`` lives), else 0
+            "compiles_under_way": self._hybrid.compiles_under_way,
             "compile_cache": compile_cache_stats(),
         }
 

@@ -195,3 +195,22 @@ def test_a_shape_reaches_its_slot_budget_in_one_step_and_the_next_starts_there(r
     assert m._budget_for(256, 32) == 4096 and m._budget_for(128, 32) == 512
     # a lone topic padded to the floor says little: neither scaled nor learned
     assert m._regrown(300, 1, 8) == 512 and m._slots_per_topic == 16
+
+
+@pytest.mark.timeout(180)
+def test_the_workers_compile_is_its_own_stage_and_gauge(routed):
+    """``matcher.compile_offpath`` times the worker's run on ``rmqtt-compile``;
+    ``compiles_under_way`` on ``/api/v1/device`` is 1 while the worker lives;
+    ``matcher.compile`` keeps timing only a compile on the routing path."""
+    router, hybrid, tele, _ref = routed
+    st = tele.stage("matcher.compile_offpath")
+    assert router.device_info()["compiles_under_way"] == 0
+    h = hybrid.match_submit(_topics(100, 5, [40, 41]), True)
+    assert h[0] == "sync"
+    assert router.device_info()["compiles_under_way"] == 1  # it sleeps SLOW_S
+    _wait_compiled(hybrid)
+    assert router.device_info()["compiles_under_way"] == 0
+    assert st.count == 1 and st.busy_ns >= SLOW_S * 1e9
+    flat = tele.stage_stats()
+    assert flat["stage_matcher_compile_offpath_count"] == 1
+    assert tele.stage("matcher.compile").count == 0

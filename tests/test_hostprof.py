@@ -213,12 +213,11 @@ def test_gc_callback_never_blocks_on_the_profiler_lock(prof):
     deadlocked a broker under a 1M-subscription load. The queued sample is
     folded by the next callback that finds the lock free."""
     with prof._lock:  # what auto_dump/snapshot hold while they allocate
-        prof._gc_cb("start", {"generation": 0})
-        prof._gc_cb("stop", {"generation": 0, "collected": 3})
+        prof._gc_cb(0, 0.001, {"generation": 0, "collected": 3})
     assert prof.gc_pauses[0] == 0 and len(prof._gc_pending) == 1
-    prof._gc_cb("start", {"generation": 0})
-    prof._gc_cb("stop", {"generation": 0, "collected": 4})
+    prof._gc_cb(0, 0.002, {"generation": 0, "collected": 4})
     assert prof.gc_pauses[0] == 2 and prof.gc_collected[0] == 7
+    assert prof.gc_pause_ns[0] == 3_000_000
     assert not prof._gc_pending
 
 
