@@ -14,8 +14,8 @@ event loop's thread, and the p99 of every latency a client sees.
 
 The policy owns the program's one entry of ``gc.callbacks`` (which also
 times every pass for the host profiler, and makes it a span in a profiler
-session) and two rules, both applied where a full pass ends, on whichever
-thread collected:
+session) and three rules, all applied where a pass ends, on whichever thread
+collected:
 
 *Freeze.* A full pass that took longer than ``LONG_PASS_S`` is followed at
 once by ``gc.freeze()``: what just survived moves to the permanent generation,
@@ -41,11 +41,39 @@ wall clock to full walks of its long-lived heap, whatever the heap's size. The
 clock of the rule is the collector itself, so a process that allocates
 nothing walks nothing.
 
-Neither constant is an option: both are about what the code can measure (a
-pass's length), not about a deployment. The policy is process-global like
-the collector, and armed per broker with a reference count like
-``HOSTPROF``, so the last ``disarm`` leaves the process as it was found:
-callback removed, heap unfrozen.
+*The young threshold, on the young passes' cost.* CPython starts a
+generation-0 pass when the net count of new tracked objects passes
+``threshold0`` (700). A broker under load makes a hundred such passes a
+second, and a publish lives one publish→PUBACK round trip (~100 ms): every
+object of every publish in flight is walked by ten young passes and promoted
+while it is still alive, walked again in generation 1, and pushed on into
+generation 2, though none of it is cyclic garbage: it dies by reference count
+a few passes later. So the policy adapts ``threshold0`` to what young passes
+(generations 0 and 1) are seen to cost. At the end of each generation-1 pass
+that closes a whole generation-1 period at the present value (at least
+``threshold1`` generation-0 passes), the rule reads the young passes'
+seconds since the period began over the wall seconds. Above
+``YOUNG_BUDGET`` ``threshold0`` doubles, however long the passes are: the
+cost falls only once passes come further apart than a publish lives, and on
+the way there (twice or four times 700 under 640 publishes in flight) the
+passes are both long and frequent, so a rule that stopped at a long pass
+would stay where it started. Where the young passes are within that budget
+but those longer than ``LONG_PASS_S`` took more than ``THAW_BUDGET`` of the
+wall clock, the share a p99 can carry, it halves, never below the value
+found at the first ``arm``. (While a table loads everything survives, so
+the passes stay dear at any threshold and it rises through the load; once
+the load is over a pass walks what is alive in the young generations, not
+``threshold0`` objects.) ``threshold1`` and ``threshold2`` stay as they
+are: fewer, later generation-0 passes promote fewer live objects, and that
+alone makes the older generations' passes rarer. A process whose young
+passes are cheap (an idle broker, a tool that allocates little) never sees
+the threshold move.
+
+No constant is an option: each is about what the code can measure (a pass's
+length, its share of the wall clock), not about a deployment. The policy is
+process-global like the collector, and armed per broker with a reference
+count like ``HOSTPROF``, so the last ``disarm`` leaves the process as it was
+found: callback removed, heap unfrozen, thresholds as they were.
 """
 
 from __future__ import annotations
@@ -75,11 +103,23 @@ LONG_PASS_S = 0.020
 #: walks' worth of time.
 THAW_BUDGET = 0.005
 
+#: Share of wall clock the young passes (generations 0 and 1) may take before
+#: the generation-0 threshold is doubled. At the interpreter's 700 a broker
+#: saturated by the benchmark's fleets spent 9–13 % of its wall clock there
+#: (the host of a TPU v5e), walking and promoting the objects of publishes
+#: still in flight; at 1 % what is left is under the noise of any rate, and
+#: a process whose young passes are cheap never moves. What bounds the other
+#: side is the thaw's budget: young passes longer than ``LONG_PASS_S`` may
+#: take ``THAW_BUDGET`` of the wall clock, as the walks of the whole heap do,
+#: and a threshold whose long passes take more is halved.
+YOUNG_BUDGET = 0.01
+
 
 class GcPolicy:
     """Freezes the heap that survives a long full pass; thaws it on a
-    wall-clock budget. ``clock`` and ``collector`` are seams for the tests'
-    scripted clock and collector; the process has one instance, ``GCPOLICY``.
+    wall-clock budget; sizes the generation-0 threshold by the young passes'
+    cost. ``clock`` and ``collector`` are seams for the tests' scripted clock
+    and collector; the process has one instance, ``GCPOLICY``.
 
     Its ``gc.callbacks`` entry is the process's only one of this program: it
     also times every pass for the host profiler (``watch``), and while a
@@ -116,17 +156,38 @@ class GcPolicy:
         #: has just been paid for, inside the same budget, and never when a
         #: surface is served)
         self.frozen_objects = 0
+        # the young rule: the thresholds found at the first arm, threshold0
+        # as the rule last left it, and the period under way at that value
+        # (young seconds, those of passes over LONG_PASS_S, its
+        # generation-0 passes, when it began)
+        self._found: Optional[tuple] = None
+        self._young = 0
+        self._young_s = 0.0
+        self._long_s = 0.0
+        self._young_n = 0
+        self._since: Optional[float] = None
+        #: raises and lowers of threshold0, cumulative over the process
+        self.threshold_changes = 0
+        #: the young passes' share of the wall clock over the last whole
+        #: period the rule read
+        self.young_share = 0.0
 
     # ------------------------------------------------------------ lifecycle
     def arm(self) -> None:
-        """Count one broker in; the first installs the callback."""
+        """Count one broker in; the first installs the callback and notes
+        the collector's thresholds."""
         with self._lock:
             self._arms += 1
             self._install()
+            if self._arms == 1:
+                self._found = tuple(self._gc.get_threshold())
+                self._young = self._found[0]
+                self._period(None)
 
     def disarm(self) -> None:
         """Count one broker out; the last removes the callback (unless the
-        host profiler still watches) and unfreezes what the policy froze."""
+        host profiler still watches), unfreezes what the policy froze and
+        puts back the thresholds it found if the rule moved them."""
         with self._lock:
             if self._arms == 0:
                 return
@@ -138,6 +199,9 @@ class GcPolicy:
                 self._gc.unfreeze()
             self._frozen = False
             self.frozen_objects = 0
+            if self._found is not None and self._young != self._found[0]:
+                self._gc.set_threshold(*self._found)
+            self._found = None
 
     def watch(self, pauses: Callable[[int, float, dict], None]) -> None:
         """Hand every pass's length to ``pauses`` from now on."""
@@ -168,12 +232,13 @@ class GcPolicy:
         """``gc.callbacks`` entry; runs on whichever thread collected, and
         never blocks or raises (a collection can start inside ``arm``
         itself, or inside a span's own open). One clock read at ``start``
-        and one at ``stop``, and none for a young pass nobody times."""
+        and one at ``stop``, and none for a young pass while no broker is
+        armed and no profiler watches."""
         gen = info["generation"]
         if phase == "start":
             if PROFILER.on:
                 self._open_span(gen)
-            if gen == 2 or self.pauses is not None:
+            if gen == 2 or self._arms or self.pauses is not None:
                 self._t0 = self._clock()
             return
         t0, self._t0 = self._t0, None
@@ -184,6 +249,8 @@ class GcPolicy:
                 pauses(gen, now - t0, info)
             if gen == 2:
                 self._full_pass(t0, now)
+            else:
+                self._young_pass(gen, t0, now)
         if self._span is not None:
             self._close_span()
 
@@ -214,6 +281,54 @@ class GcPolicy:
         finally:
             self._lock.release()
 
+    def _young_pass(self, gen: int, t0: float, now: float) -> None:
+        """The young rule (module docstring). ``gc.set_threshold`` runs no
+        Python code; the lock is only tried, as in ``_full_pass``."""
+        if not self._lock.acquire(blocking=False):
+            return
+        try:
+            if not self._arms or self._found is None:
+                return
+            took = now - t0
+            if self._since is None:
+                self._since = t0
+            self._young_s += took
+            if took > LONG_PASS_S:
+                self._long_s += took
+            if gen == 0:
+                self._young_n += 1
+                return
+            # a generation-1 pass: the end of a period, if it was whole
+            if self._young_n < self._found[1] or now <= self._since:
+                return
+            wall = now - self._since
+            self.young_share = self._young_s / wall
+            floor = self._found[0]
+            if self.young_share > YOUNG_BUDGET and floor > 0:
+                self._set_young(2 * self._young, now)
+            elif self._long_s / wall > THAW_BUDGET and self._young > floor:
+                self._set_young(max(floor, self._young // 2), now)
+            else:
+                self._period(now)
+        finally:
+            self._lock.release()
+
+    def _set_young(self, threshold0: int, now: float) -> None:
+        """``threshold0`` to ``threshold0``, the other two as they are; a new
+        period begins. The caller holds the lock."""
+        self._period(now)
+        _t0, t1, t2 = self._gc.get_threshold()
+        try:
+            self._gc.set_threshold(threshold0, t1, t2)
+        except OverflowError:  # past a C int: it stays where it is
+            return
+        self._young = threshold0
+        self.threshold_changes += 1
+
+    def _period(self, now: Optional[float]) -> None:
+        self._young_s, self._long_s, self._young_n = 0.0, 0.0, 0
+        self._since = now
+
     def _open_span(self, gen: int) -> None:
         # kept off PROFILER's per-thread stack of open sections: a pass can
         # start inside a stage's own open, between its annotation's
@@ -235,24 +350,28 @@ class GcPolicy:
 
     # -------------------------------------------------------------- surfaces
     def stats_block(self) -> dict:
-        """The five gauges of ``/api/v1/stats``."""
+        """The seven gauges of ``/api/v1/stats``."""
         return {
             "host_gc_freezes": self.freezes,
             "host_gc_thaws": self.thaws,
             "host_gc_frozen_objects": self.frozen_objects,
             "host_gc_full_pauses": self.full_pauses,
             "host_gc_full_pause_ms_total": round(self.full_pause_s * 1e3, 3),
+            "host_gc_young_threshold": self._gc.get_threshold()[0],
+            "host_gc_threshold_changes": self.threshold_changes,
         }
 
     def snapshot(self) -> dict:
-        """What ``/api/v1/host`` shows under ``gc``: the same five, and when
-        the next thaw may come."""
+        """What ``/api/v1/host`` shows under ``gc``: the same seven, when
+        the next thaw may come, and the young share the rule last read."""
         out = {k[len("host_gc_"):]: v for k, v in self.stats_block().items()}
         out["next_thaw_in_s"] = (
             round(max(0.0, self._due - self._clock()), 3)
             if self._frozen else None)
+        out["young_share"] = round(self.young_share, 5)
         out["long_pass_ms"] = LONG_PASS_S * 1e3
         out["thaw_budget"] = THAW_BUDGET
+        out["young_budget"] = YOUNG_BUDGET
         return out
 
 

@@ -883,6 +883,7 @@ const KEYS=["connections","sessions","subscriptions","subscriptions_shared",
  "host_gc_pauses","host_gc_pause_ms_total","host_open_fds","host_threads",
  "host_gc_freezes","host_gc_thaws","host_gc_frozen_objects",
  "host_gc_full_pauses","host_gc_full_pause_ms_total",
+ "host_gc_young_threshold","host_gc_threshold_changes",
  "net_egress_frames","net_egress_flushes","net_egress_bytes",
  "net_egress_coalesced","net_egress_drains",
  "net_egress_offloop_flushes","net_egress_offloop_partial",

@@ -146,12 +146,15 @@ class Stats:
         # ServerContext.stats() whether host_profile is on or not: heaps
         # frozen after a long full pass, budgeted thaws, the permanent
         # generation as last counted, and the generation-2 passes alone
-        # (thaws included) with their stopped time
+        # (thaws included) with their stopped time; the generation-0
+        # threshold as it stands and how often the young rule moved it
         self.host_gc_freezes = 0
         self.host_gc_thaws = 0
         self.host_gc_frozen_objects = 0
         self.host_gc_full_pauses = 0
         self.host_gc_full_pause_ms_total = 0.0
+        self.host_gc_young_threshold = 0
+        self.host_gc_threshold_changes = 0
         # device-plane failover gauges (broker/failover.py), overwritten
         # from RoutingService.stats(); zeros for routers without a host
         # fallback. state is 0=device (healthy) 1=host fallback 2=probing

@@ -1,14 +1,16 @@
 """The collector's policy (``rmqtt_tpu/broker/gcpolicy.py``): a full pass that
 was long is the last one over that heap; the heap is thawed on a wall-clock
-budget.
+budget; the generation-0 threshold follows the young passes' cost.
 
-- the rule on a scripted clock, a scripted collector and scripted callback
-  infos: which pass is followed by a freeze, which by nothing, and when a
-  thaw is due;
-- the rule on a real heap, each case in a process of its own so that the
-  pytest process stays thawed: what a freeze takes off the next pass (by
-  counts), that a frozen cycle is reclaimed by the next due thaw and never
-  before, that the last disarm leaves the process as it was found;
+- the rules on a scripted clock, a scripted collector and scripted callback
+  infos: which pass is followed by a freeze, which by nothing, when a thaw is
+  due, and when the young threshold doubles, halves or stays;
+- the rules on a real heap, each case in a process of its own so that the
+  pytest process stays thawed and keeps its thresholds: what a freeze takes
+  off the next pass (by counts), that a frozen cycle is reclaimed by the next
+  due thaw and never before, that a raised young threshold makes the older
+  generations' passes rarer and still reclaims cycles, that the last disarm
+  leaves the process as it was found;
 - an in-process broker under 20,000 subscriptions: the counters on
   ``/api/v1/stats`` and ``/api/v1/host``, and deliveries to exactly the
   oracle's client sets once its heap is frozen.
@@ -17,6 +19,7 @@ budget.
 import asyncio
 import gc
 import json
+import math
 import subprocess
 import sys
 import textwrap
@@ -25,7 +28,8 @@ from pathlib import Path
 import pytest
 
 from rmqtt_tpu.broker import gcpolicy
-from rmqtt_tpu.broker.gcpolicy import LONG_PASS_S, THAW_BUDGET, GcPolicy
+from rmqtt_tpu.broker.gcpolicy import (LONG_PASS_S, THAW_BUDGET, YOUNG_BUDGET,
+                                       GcPolicy)
 
 ROOT = Path(__file__).resolve().parent.parent
 
@@ -46,6 +50,8 @@ class Collector:
         self.callbacks = []
         self.calls = []
         self.frozen = 0
+        self.threshold = (700, 10, 10)
+        self.thresholds_set = []
 
     def freeze(self) -> None:
         self.calls.append("freeze")
@@ -58,6 +64,13 @@ class Collector:
     def get_freeze_count(self) -> int:
         self.calls.append("count")
         return self.frozen
+
+    def get_threshold(self) -> tuple:
+        return self.threshold
+
+    def set_threshold(self, *threshold) -> None:
+        self.thresholds_set.append(threshold)
+        self.threshold = threshold
 
 
 @pytest.fixture
@@ -78,12 +91,158 @@ def a_pass(pol: GcPolicy, clock: Clock, generation: int, took: float) -> None:
                         "uncollectable": 0})
 
 
+def a_period(pol: GcPolicy, clock: Clock, took: float, gap: float,
+             young0: int = 10) -> None:
+    """``young0`` generation-0 passes and the generation-1 pass that ends
+    them, each ``took`` long and ``gap`` after the last."""
+    for _ in range(young0):
+        clock.t += gap
+        a_pass(pol, clock, 0, took)
+    clock.t += gap
+    a_pass(pol, clock, 1, took)
+
+
+OVER = dict(took=0.001, gap=0.009)      # young passes: 10 % of the wall clock
+UNDER = dict(took=0.0001, gap=0.0999)   # 0.1 %
+
+
 @pytest.mark.parametrize("generation", [0, 1])
 def test_a_young_pass_does_nothing_and_counts_nothing(rig, generation):
+    # however long, at the threshold found: nothing to lower, nothing frozen
+    # and no full pause counted
     pol, clock, coll = rig
-    a_pass(pol, clock, generation, 5.0)  # however long
-    assert coll.calls == []
+    a_pass(pol, clock, generation, 5.0)
+    assert coll.calls == [] and coll.thresholds_set == []
     assert pol.full_pauses == 0 and pol.full_pause_s == 0.0
+    assert pol.stats_block()["host_gc_young_threshold"] == 700
+    assert pol.threshold_changes == 0
+
+
+def test_young_passes_under_the_share_leave_the_threshold_alone(rig):
+    pol, clock, coll = rig
+    for _ in range(20):
+        a_period(pol, clock, **UNDER)
+    assert coll.thresholds_set == [] and pol.threshold_changes == 0
+    assert pol.young_share == pytest.approx(0.001, rel=0.05)
+    assert pol.snapshot()["young_share"] == pytest.approx(0.001, rel=0.05)
+
+
+def test_young_passes_over_the_share_double_it_after_a_whole_period(rig):
+    pol, clock, coll = rig
+    # nine generation-0 passes are not a generation-1 period of ten: the
+    # generation-1 pass that ends them decides nothing, and the period goes on
+    a_period(pol, clock, young0=9, **OVER)
+    assert coll.thresholds_set == []
+    a_period(pol, clock, young0=1, **OVER)
+    assert coll.thresholds_set == [(1400, 10, 10)]
+    assert pol.young_share == pytest.approx(0.1, rel=0.15)
+    # a new period begins at the change: a generation-1 pass straight after
+    # it is not a whole one
+    clock.t += 0.009
+    a_pass(pol, clock, 1, 0.001)
+    assert coll.thresholds_set == [(1400, 10, 10)]
+    a_period(pol, clock, **OVER)
+    assert coll.thresholds_set == [(1400, 10, 10), (2800, 10, 10)]
+    assert pol.stats_block()["host_gc_young_threshold"] == 2800
+    assert pol.stats_block()["host_gc_threshold_changes"] == 2
+    a_period(pol, clock, **UNDER)  # cheap again: it stays
+    a_period(pol, clock, **UNDER)
+    assert coll.threshold == (2800, 10, 10) and pol.threshold_changes == 2
+
+
+def test_the_other_two_thresholds_stay_as_they_are(rig):
+    pol, clock, coll = rig
+    coll.threshold = (700, 10, 25)  # set by somebody else after the arm
+    a_period(pol, clock, **OVER)
+    assert coll.thresholds_set == [(1400, 10, 25)]
+
+
+def a_stalling_period(pol: GcPolicy, clock: Clock) -> None:
+    """A whole period cheap in sum (young passes 0.65 % of the wall clock)
+    whose two passes over ``LONG_PASS_S`` take 0.63 % of it, over
+    ``THAW_BUDGET``."""
+    for i in range(10):
+        clock.t += 0.6
+        a_pass(pol, clock, 0, LONG_PASS_S * 1.05 if i < 2 else 0.0001)
+    clock.t += 0.6
+    a_pass(pol, clock, 1, 0.0001)
+
+
+def test_long_young_passes_over_the_thaw_budget_halve_it_never_below_found(rig):
+    pol, clock, coll = rig
+    a_period(pol, clock, **OVER)
+    a_period(pol, clock, **OVER)
+    assert coll.threshold[0] == 2800
+    for want in (1400, 700):
+        a_stalling_period(pol, clock)
+        assert coll.threshold[0] == want
+        assert pol.young_share == pytest.approx(0.0065, rel=0.1)
+    a_stalling_period(pol, clock)
+    assert len(coll.thresholds_set) == 4  # at the found value: not below it
+    assert pol.threshold_changes == 4 and coll.threshold == (700, 10, 10)
+    assert coll.calls == []  # and nothing is frozen for a young pass
+
+
+def test_long_young_passes_within_the_thaw_budget_leave_it_alone(rig):
+    pol, clock, coll = rig
+    a_period(pol, clock, **OVER)
+    for _ in range(3):  # one pass over LONG_PASS_S in 11 s: 0.19 %
+        for i in range(10):
+            clock.t += 1.0
+            a_pass(pol, clock, 0, LONG_PASS_S * 1.05 if i == 0 else 0.0001)
+        clock.t += 1.0
+        a_pass(pol, clock, 1, 0.0001)
+    assert coll.thresholds_set == [(1400, 10, 10)]
+
+
+def test_long_young_passes_do_not_stop_a_raise_while_the_share_is_over_budget(rig):
+    # on the way from 700 to where passes come further apart than a publish
+    # lives, the passes are long and frequent: the rule goes on, and a long
+    # pass does nothing before its period ends
+    pol, clock, coll = rig
+    clock.t += 0.05
+    a_pass(pol, clock, 0, LONG_PASS_S * 3)
+    assert coll.thresholds_set == []
+    for want in (1400, 2800, 5600):
+        a_period(pol, clock, took=LONG_PASS_S * 3, gap=0.05)
+        assert coll.threshold[0] == want
+    assert pol.young_share == pytest.approx(0.545, rel=0.05)
+
+
+def test_the_last_disarm_restores_the_found_thresholds(rig):
+    pol, clock, coll = rig
+    pol.arm()  # a second broker
+    a_period(pol, clock, **OVER)
+    a_period(pol, clock, **OVER)
+    pol.disarm()
+    assert coll.threshold == (2800, 10, 10)  # one broker is still armed
+    pol.disarm()
+    assert coll.threshold == (700, 10, 10)
+    assert coll.thresholds_set[-1] == (700, 10, 10)
+    # disarmed: a young pass times nothing and moves nothing
+    for _ in range(3):
+        a_period(pol, clock, **OVER)
+    assert coll.threshold == (700, 10, 10) and len(coll.thresholds_set) == 3
+    # armed again: the thresholds found are read anew
+    coll.threshold = (1000, 10, 10)
+    pol.arm()
+    a_period(pol, clock, **OVER)
+    assert coll.threshold == (2000, 10, 10)
+    a_stalling_period(pol, clock)
+    a_stalling_period(pol, clock)
+    assert coll.threshold == (1000, 10, 10)
+
+
+def test_a_young_pass_that_ends_while_a_broker_arms_or_stops_moves_nothing(rig):
+    pol, clock, coll = rig
+    with pol._lock:
+        a_period(pol, clock, **OVER)
+    a_period(pol, clock, **OVER)
+    assert coll.thresholds_set == [(1400, 10, 10)]
+    with pol._lock:
+        a_stalling_period(pol, clock)
+    assert coll.thresholds_set == [(1400, 10, 10)]
+    assert pol.threshold_changes == 1
 
 
 def test_a_short_full_pass_is_counted_and_nothing_else(rig):
@@ -104,11 +263,15 @@ def test_a_long_full_pass_is_the_last_one_over_that_heap(rig):
     assert pol.stats_block() == {
         "host_gc_freezes": 1, "host_gc_thaws": 0,
         "host_gc_frozen_objects": 123_456, "host_gc_full_pauses": 1,
-        "host_gc_full_pause_ms_total": 500.0}
+        "host_gc_full_pause_ms_total": 500.0,
+        "host_gc_young_threshold": 700, "host_gc_threshold_changes": 0}
     snap = pol.snapshot()
     assert snap["next_thaw_in_s"] == pytest.approx(0.5 / THAW_BUDGET)
     assert snap["long_pass_ms"] == LONG_PASS_S * 1e3
     assert snap["thaw_budget"] == THAW_BUDGET
+    assert snap["young_threshold"] == 700 and snap["threshold_changes"] == 0
+    assert snap["young_share"] == 0.0
+    assert snap["young_budget"] == YOUNG_BUDGET
 
 
 def test_the_count_is_paid_from_the_same_budget(rig):
@@ -315,6 +478,84 @@ def test_a_frozen_cycle_is_reclaimed_by_the_next_due_thaw_and_never_before():
     assert out["heap_alive"] == 150_000
 
 
+def test_a_raised_young_threshold_makes_older_passes_rarer_and_still_reclaims():
+    # a churn of short-lived containers over a fixed in-flight set, and a
+    # little cyclic garbage a step; the clock reads every pass as 1 ms, and
+    # the body adds idle time between steps: 10 s a step, and the young
+    # passes are nothing beside the wall clock; none, and they are half of it
+    out = _in_a_process_of_its_own("""
+        import collections
+
+        class Ticks:
+            def __init__(self): self.t = 0.0
+            def __call__(self):
+                self.t += 0.001
+                return self.t
+
+        class Cyc:
+            made = died = 0
+            def __init__(self):
+                Cyc.made += 1
+                self.me = self
+            def __del__(self):
+                Cyc.died += 1
+
+        clock = Ticks()
+        inflight = collections.deque()
+
+        def churn(steps, idle):
+            for i in range(steps):
+                inflight.append([[i], [i], [i], [i]])
+                if len(inflight) > 2000:
+                    inflight.popleft()
+                for _ in range(4):
+                    Cyc()
+                clock.t += idle
+
+        def passes(steps, idle):
+            a = [g["collections"] for g in gc.get_stats()]
+            churn(steps, idle)
+            return [y - x for x, y in
+                    zip(a, [g["collections"] for g in gc.get_stats()])]
+
+        found = gc.get_threshold()
+        out["found"] = list(found)
+        pol = GcPolicy(clock=clock)
+        pol.arm()
+        out["before"] = passes(150_000, 10.0)       # cheap: the threshold stays
+        out["held"] = gc.get_threshold()[0]
+        steps = 0
+        while gc.get_threshold()[0] < 16 * found[0] and steps < 400_000:
+            churn(1_000, 0.0)                       # dear: it doubles
+            steps += 1_000
+        out["raised"] = gc.get_threshold()[0]
+        out["changes"] = pol.threshold_changes
+        out["gauge"] = pol.stats_block()["host_gc_young_threshold"]
+        made, died = Cyc.made, Cyc.died
+        out["after"] = passes(150_000, 10.0)
+        out["still"] = gc.get_threshold()[0]
+        out["made"], out["reclaimed"] = Cyc.made - made, Cyc.died - died
+        out["left"] = Cyc.made - Cyc.died
+        out["freezes"] = pol.freezes
+        pol.disarm()
+        out["after_disarm"] = list(gc.get_threshold())
+    """)
+    found = out["found"][0]
+    assert out["held"] == found and out["freezes"] == 0
+    assert out["raised"] >= 16 * found and out["still"] == out["raised"]
+    assert out["changes"] >= 4 and out["gauge"] == out["raised"]
+    b, a = out["before"], out["after"]
+    assert b[0] >= 300 and b[1] >= 25 and b[2] >= 2, b
+    # each generation's passes fall, the older ones with the youngest
+    assert a[0] * 8 <= b[0] and a[1] * 8 <= b[1] and a[2] < b[2], (b, a)
+    # the cycles of the raised stretch are still reclaimed with no explicit
+    # collection: all but what the last young pass had not reached yet
+    assert out["made"] == 600_000
+    assert out["made"] - out["reclaimed"] <= out["raised"], out
+    assert out["left"] <= out["raised"], out
+    assert out["after_disarm"] == out["found"]
+
+
 def test_arm_twice_disarm_twice_leaves_the_process_as_it_was_found():
     out = _in_a_process_of_its_own("""
         before = list(gc.callbacks)
@@ -400,11 +641,87 @@ def test_full_passes_that_end_on_many_threads_keep_the_policy_whole():
     assert out["frozen_after_disarm"] == 0 and out["entries_after_disarm"] == 0
 
 
+def test_young_passes_that_end_on_many_threads_keep_the_threshold_whole():
+    # young passes end on whichever thread allocated, raising and halving
+    # threshold0 while a second broker comes and goes: the clock is dear
+    # (every reading 1 ms after the last: young passes are half the wall
+    # clock) or, every other 50 ms, cheap with stalls (5 s before a pass, and
+    # one pass in ten 0.4 s long). Whatever interleaves, the threshold is the
+    # found value times a power of two, nothing raises, and the last disarm
+    # puts the found thresholds back
+    out = _in_a_process_of_its_own("""
+        import itertools, os, sys, threading, time
+
+        class Modal:
+            def __init__(self):
+                self.t, self.n, self.cheap = 0.0, itertools.count(1), False
+            def __call__(self):
+                n = next(self.n)
+                if not self.cheap:
+                    self.t += 0.001
+                elif n % 2:
+                    self.t += 5.0
+                else:
+                    self.t += 0.4 if n % 20 == 0 else 0.001
+                return self.t
+
+        found = gc.get_threshold()
+        clock = Modal()
+        pol = GcPolicy(clock=clock)
+        pol.arm()
+        stop = time.monotonic() + 2.0
+        errors, seen = [], set()
+        def worker():
+            try:
+                while time.monotonic() < stop:
+                    junk = [[i] for i in range(300)]
+                    del junk
+                    for _ in range(10):
+                        gc.collect(0)
+                    gc.collect(1)
+                    seen.add(gc.get_threshold()[0])
+            except Exception as e:
+                errors.append(repr(e))
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [threading.Thread(target=worker)
+                       for _ in range(2 * (os.cpu_count() or 4))]
+            for t in threads:
+                t.start()
+            flip = time.monotonic()
+            while time.monotonic() < stop:    # a second broker comes and goes
+                pol.arm()
+                time.sleep(0.001)
+                pol.disarm()
+                if time.monotonic() > flip:
+                    clock.cheap, flip = not clock.cheap, flip + 0.05
+            for t in threads:
+                t.join(timeout=30)
+            out["alive"] = sum(t.is_alive() for t in threads)
+        finally:
+            sys.setswitchinterval(old)
+        out["errors"], out["seen"] = errors, sorted(seen)
+        out["changes"], out["arms"] = pol.threshold_changes, pol._arms
+        out["found"] = list(found)
+        pol.disarm()
+        out["after_disarm"] = list(gc.get_threshold())
+    """)
+    assert out["alive"] == 0 and out["errors"] == [] and out["arms"] == 1
+    found = out["found"][0]
+    ratios = [v / found for v in out["seen"]]
+    assert all(r >= 1 and r == 2 ** round(math.log2(r)) for r in ratios), ratios
+    # raises alone could make one change per value seen: it also came down
+    assert max(ratios) >= 2 and out["changes"] > len(out["seen"])
+    assert out["after_disarm"] == out["found"]
+
+
 # ------------------------------------------------------- through the broker
 @pytest.fixture
 def thawed():
     """The pytest process is left as it was found, whatever the case did."""
     callbacks, found = list(gc.callbacks), gc.get_freeze_count()
+    thresholds = gc.get_threshold()
     yield
     while gcpolicy.GCPOLICY._arms:
         gcpolicy.GCPOLICY.disarm()
@@ -412,6 +729,7 @@ def thawed():
     # with the rest, until its next collection: nothing of the broker's stays)
     assert gc.get_freeze_count() <= found < 1000
     assert list(gc.callbacks) == callbacks
+    assert gc.get_threshold() == thresholds
 
 
 def test_a_loaded_broker_freezes_its_heap_and_still_delivers_to_the_oracle(
@@ -441,6 +759,8 @@ def test_a_loaded_broker_freezes_its_heap_and_still_delivers_to_the_oracle(
             else:
                 out.append(f"site/{k % 50}/dev{k}/temp")
         return out
+
+    found = gc.get_threshold()
 
     async def run():
         b = MqttBroker(ServerContext(BrokerConfig(port=0)))
@@ -475,6 +795,14 @@ def test_a_loaded_broker_freezes_its_heap_and_still_delivers_to_the_oracle(
             assert policy["frozen_objects"] == stats["host_gc_frozen_objects"]
             assert policy["next_thaw_in_s"] is not None
             assert policy["thaw_budget"] == THAW_BUDGET
+            # the young rule's two counters, on both surfaces; whether the
+            # threshold moved is this process's young passes' business
+            assert stats["host_gc_young_threshold"] >= found[0]
+            assert policy["young_threshold"] >= found[0]
+            assert (policy["threshold_changes"]
+                    >= stats["host_gc_threshold_changes"] >= 0)
+            assert 0.0 <= policy["young_share"] < 1.0
+            assert policy["young_budget"] == YOUNG_BUDGET
 
             # a sample of topics, each to exactly the oracle's clients
             pub = await TestClient.connect(b.port, "pub")
@@ -509,7 +837,8 @@ def test_a_loaded_broker_freezes_its_heap_and_still_delivers_to_the_oracle(
                     pass
             await api.stop()
             await b.stop()
-        # the last stop left the process unfrozen
+        # the last stop left the process unfrozen, its thresholds as found
         assert gc.get_freeze_count() == 0
+        assert gc.get_threshold() == found
 
     asyncio.run(run())

@@ -2,7 +2,7 @@
 
 - the collector's one ``gc.callbacks`` entry (``broker/gcpolicy.py``): both
   sets of counters from one clock pair a pass, no clock read for a young pass
-  nobody times, and while a profiler session is on one balanced
+  nobody times (no broker armed, no profiler watching), and while a profiler session is on one balanced
   ``rmqtt/gc.young`` / ``rmqtt/gc.full`` span a pass — none without one, and
   still balanced where a collection starts inside a stage's own open;
 - the loop's callbacks (``broker/telemetry.py`` ``handle_spans``): the span
@@ -91,6 +91,12 @@ class Collector:
         self.calls.append("count")
         return 7
 
+    def get_threshold(self):
+        return (700, 10, 10)
+
+    def set_threshold(self, *threshold):
+        self.calls.append("threshold")
+
 
 def _pass(pol, clock, gen, took):
     pol._on_gc("start", {"generation": gen})
@@ -124,12 +130,15 @@ def test_one_callback_times_every_pass_for_both_sets_of_counters(annotation):
 def test_a_young_pass_nobody_times_reads_no_clock_and_builds_nothing(annotation):
     clock, coll = Clock(), Collector()
     pol = GcPolicy(clock=clock, collector=coll)
-    pol.arm()
-    for gen in (0, 1):
+    for gen in (0, 1):  # no broker armed, no profiler watching
         _pass(pol, clock, gen, 1.0)
     assert clock.reads == 0 and annotation.built == []
-    _pass(pol, clock, 2, 0.001)  # a full pass is the policy's: one clock pair
+    pol.arm()
+    _pass(pol, clock, 0, 0.001)  # armed, the young rule times it: one pair
     assert clock.reads == 2 and annotation.built == []
+    _pass(pol, clock, 2, 0.001)  # a full pass is the policy's: one clock pair
+    assert clock.reads == 4 and annotation.built == []
+    assert coll.calls == []
     pol.disarm()
 
 
