@@ -12,6 +12,8 @@ the plain trie (``trie.py``, in a process of its own) says of the same
 filters and topics, every QoS1 publish of the window against its PUBACK, and
 every PUBACK of the run against the order its connection's publishes left in
 ([MQTT-4.6.0-2]; it bites where a mix keeps more than one publish in flight).
+Where the table holds ``$share`` groups, each group a publish matches is due
+one delivery, to one of its members ([MQTT 5.0 §4.8.2]; ``reference.py``).
 The comparison is exact: each limit is 0. A pair counts once, in the
 comparison and in the rate alike: a subscriber that holds two matching
 filters, or that is sent a QoS1 delivery again, has received the publish
@@ -140,7 +142,8 @@ def compare(rec: Records, ref: Reference, subscribers: int,
             t0: float, t1: float) -> dict:
     """The window's publishes against the plain reference. → the numbers
     compared (each with the limit 0), ``attempted`` and the arrays the
-    end-to-end metrics are taken from."""
+    end-to-end metrics are taken from. A reference that answers without
+    groups (two arrays) is a table without ``$share`` rows."""
     send, ack, due, _waited = rec.table()
     in_window = (send >= t0) & (send < t1)
     W = np.flatnonzero(in_window)
@@ -156,20 +159,27 @@ def compare(rec: Records, ref: Reference, subscribers: int,
     order = np.lexsort((when, keys))
     got, first = np.unique(keys[order], return_index=True)
     first = order[first]
-    counts, flat = ref.expected([rec.topic_of(int(i)) for i in W])
+    counts, flat, *groups = ref.expected([rec.topic_of(int(i)) for i in W])
     want = np.repeat(W, counts) * subscribers + flat  # unique by construction
     missing = np.setdiff1d(want, got, assume_unique=True)
     by_sub = np.bincount(missing % subscribers, minlength=1)
+    if groups:
+        shared = shared_groups(groups[0], W, want, got, subscribers)
+    else:
+        shared = {"unexpected": int(np.setdiff1d(got, want, assume_unique=True).size),
+                  "missed": 0, "extra": 0, "groups": 0}
     return {
         "checks": {
             "missing_pairs": int(missing.size),
-            "unexpected_pairs": int(np.setdiff1d(got, want, assume_unique=True).size),
+            "unexpected_pairs": shared["unexpected"],
             "unacked_qos1": int(np.isnan(ack[W]).sum()),
             "acks_out_of_order": int(rec.out_of_order),
             "unknown_publish_ids": int((~known).sum()),
             "connections_lost": int(rec.lost),
+            "shared_missed": shared["missed"],
+            "shared_extra": shared["extra"],
         },
-        "attempted": int(want.size),
+        "attempted": int(want.size) + shared["groups"],
         "publishes": int(W.size),
         # who went without: the four subscribers that miss most, [index, pairs]
         "missing_most": [[int(i), int(by_sub[i])] for i in
@@ -182,6 +192,62 @@ def compare(rec: Records, ref: Reference, subscribers: int,
         "pairs_by_5s": np.bincount(
             ((send[got // subscribers] - t0) // 5.0).astype(np.int64)).tolist(),
     }
+
+
+def shared_groups(groups: tuple, W: np.ndarray, want: np.ndarray, got: np.ndarray,
+                  subscribers: int) -> dict:
+    """The window's publishes against the ``$share`` groups they match
+    (``Reference.expected``'s third item), one member of each receiving.
+    Pairs are ``publish * subscribers + subscriber``; ``want`` are the plain
+    ones, ``got`` those received (sorted, unique). For each publish, with R
+    its receivers, N its plain subscribers and G1..Gk its groups:
+
+    - ``unexpected``: |R \\ (N ∪ G1 ∪ … ∪ Gk)|;
+    - ``missed``: the (publish, group) pairs no member of which is in R;
+    - ``extra``: the receivers in some group and not in N, less the largest
+      matching of them to the groups (a receiver to a group it is a member
+      of, a group to one receiver at most): Σ max(0, |R ∩ Gj \\ N| − 1)
+      where the groups share no member. A member that is also in N is never
+      extra: the records cannot say through which subscription it came;
+    - ``groups``: the (publish, group) pairs, each one delivery due."""
+    gcounts, gids, offsets, members = groups
+    sizes = offsets[gids + 1] - offsets[gids]
+    pair = np.repeat(np.arange(gids.size), sizes)  # the (publish, group) of each member row
+    start = np.repeat(offsets[gids] - (np.cumsum(sizes) - sizes), sizes)
+    key = np.repeat(W, gcounts)[pair] * subscribers + members[start + np.arange(pair.size)]
+    received = np.isin(key, got)
+    extra_rows = received & ~np.isin(key, want)
+    keys, where = key[extra_rows], pair[extra_rows]
+    # a receiver in two of its publish's groups: that publish is matched whole
+    uniq, n = np.unique(keys, return_counts=True)
+    tangled = np.isin(keys // subscribers, uniq[n > 1] // subscribers)
+    extra = int((~tangled).sum()) - np.unique(where[~tangled]).size
+    for pub in np.unique(keys[tangled] // subscribers):
+        mine = tangled & (keys // subscribers == pub)
+        edges = {}
+        for k, g in zip(keys[mine].tolist(), where[mine].tolist()):
+            edges.setdefault(k, []).append(g)
+        extra += len(edges) - _matching(edges)
+    return {"unexpected": int(np.setdiff1d(got, np.union1d(want, key)).size),
+            "missed": int(gids.size - np.unique(pair[received]).size),
+            "extra": extra, "groups": int(gids.size)}
+
+
+def _matching(edges: dict) -> int:
+    """Size of a largest matching of receivers to groups (``edges``:
+    receiver → its groups), by augmenting paths."""
+    owner = {}  # group → the receiver matched to it
+
+    def augment(r, seen: set) -> bool:
+        for g in edges[r]:
+            if g not in seen:
+                seen.add(g)
+                if g not in owner or augment(owner[g], seen):
+                    owner[g] = r
+                    return True
+        return False
+
+    return sum(augment(r, set()) for r in edges)
 
 
 def end_to_end(cmp: dict, seconds: float, setup_s: float) -> dict:
@@ -432,8 +498,8 @@ def settle(rec: Records, fleet: Fleet, ref: Reference, subscribers: int,
             break
         time.sleep(0.25)
     cmp = compare(rec, ref, subscribers, t0, t1)
-    while (cmp["checks"]["missing_pairs"] or cmp["checks"]["unacked_qos1"]) \
-            and time.perf_counter() < t1 + SETTLE_LIMIT_S:
+    while (cmp["checks"]["missing_pairs"] or cmp["checks"]["shared_missed"]
+           or cmp["checks"]["unacked_qos1"]) and time.perf_counter() < t1 + SETTLE_LIMIT_S:
         time.sleep(1.0)
         rec.drain(fleet)
         cmp = compare(rec, ref, subscribers, t0, t1)
@@ -481,6 +547,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, t_start: float,
                       "(runtime/librmqtt_runtime.so did not build)")
         fleet.gather(fleet.subs, "prepare")
         loaded = fleet.ask(fleet.subs, "load")
+        ref.built()  # a table the comparison cannot judge ends here, in words
         resident = b.get("/api/v1/stats")[0]["stats"]["subscriptions"]
         n_subs = sum(r["subscribed"] for r in loaded)
         if not resident == n_subs == config["subscriptions"]:

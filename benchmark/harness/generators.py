@@ -9,6 +9,22 @@ A generator is built as ``G(seed, config)`` and gives ``filters()`` (the
 whole table, sorted, the same in every process that asks) and
 ``topic_stream(stream_seed)``: an endless iterator of publish topics.
 
+Row i of the sorted table belongs to subscriber ``i % subscribers``, for
+the fleet that SUBSCRIBEs it and for the reference alike. A row may be a
+shared subscription, ``$share/{ShareName}/{filter}`` (MQTT 5.0 §4.8.2):
+equal rows are members of one group, and one member of each group whose
+filter matches a publish receives it; the members are the owners of the
+rows' sorted positions, so k equal rows, which sort next to each other,
+are k consecutive subscribers. No subscriber may hold two rows on one
+filter (``x`` and ``$share/g/x``, or ``$share/g/x`` and ``$share/h/x``):
+a broker keeps one subscription of an owner a filter, and the reference
+refuses such a table in words (``reference.py``).
+
+A test may give a generator of its own: the file that the environment
+variable ``BENCHMARK_TEST_GENERATOR`` names is imported too, by every
+process of the run. No cell's run sets it, so no configuration can name
+such a generator.
+
 ``mixed_tree`` and ``single_plus`` are copies of ``bench.gen_mixed`` /
 ``bench._tree_topic`` / ``bench.gen_single_plus`` as the accepted tree has
 them (PERF.md, Open questions, lists the originals).
@@ -17,10 +33,14 @@ them (PERF.md, Open questions, lists the originals).
 from __future__ import annotations
 
 import importlib
+import importlib.util
+import os
 import random
+import sys
 from pathlib import Path
 
 REGISTRY: dict = {}
+TEST_GENERATOR = "BENCHMARK_TEST_GENERATOR"
 
 
 def register(name: str):
@@ -38,6 +58,11 @@ def load(name: str):
         for f in sorted(more.glob("*.py")):
             if f.stem != "__init__":
                 importlib.import_module(f"harness.generators_more.{f.stem}")
+    test_file = os.environ.get(TEST_GENERATOR)
+    if test_file and TEST_GENERATOR not in sys.modules:
+        found = importlib.util.spec_from_file_location(TEST_GENERATOR, test_file)
+        sys.modules[TEST_GENERATOR] = module = importlib.util.module_from_spec(found)
+        found.loader.exec_module(module)
     try:
         return REGISTRY[name]
     except KeyError:

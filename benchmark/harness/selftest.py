@@ -13,7 +13,10 @@
    beside it;
 5. an open mix: the schedule of a mix repeats, a burst is one instant in
    every publisher process, and the ``open_loop`` line (due, sent, never
-   sent, full-window share, lateness) reads what hand-made records say.
+   sent, full-window share, lateness) reads what hand-made records say;
+6. ``$share`` groups: on hand-made records, one member of each group a
+   publish matches reads every check 0, and every member of each reads
+   ``shared_extra`` above 0 and every other check 0.
 """
 
 from __future__ import annotations
@@ -194,9 +197,48 @@ def open_loop_lines() -> dict:
     return {k: line[k] for k in ("due", "sent", "never_sent")}
 
 
+def shared_lines() -> dict:
+    """Four subscribers: ``a/#`` plain at 0, the group ``$share/g/a/+`` of 1
+    and 2, the group ``$share/h/a/b`` of 2 and 3; one publish ``a/b`` and one
+    ``a/c``, both sent and acked inside the window [10, 12)."""
+    import types
+
+    import numpy as np
+
+    from harness import cell
+    from harness.reference import Table
+
+    table = Table(["a/#", "$share/g/a/+", "$share/g/a/+", "$share/h/a/b",
+                   "x/0", "x/1", "$share/h/a/b", "x/3"], 4)
+    f8 = lambda *v: np.array(v, np.float64).tobytes()  # noqa: E731
+
+    def checks(received: list) -> dict:
+        pubs = [{"topics": ["a/b", "a/c"], "t_send": f8(10.1, 10.2), "t_due": f8(10.1, 10.2),
+                 "t_ack": f8(10.3, 10.4), "waited": bytes(2), "inflight": 0,
+                 "out_of_order": 0, "cpu": f8(), "lost": 0}]
+        subs = [{"ids": np.array([p for p, _s in received], np.int64).tobytes(),
+                 "times": f8(*[10.5] * len(received)), "cpu": f8(), "lost": 0,
+                 "subs": np.array([s for _p, s in received], np.dtype("l")).tobytes()}]
+        made = types.SimpleNamespace(subs="subs", pubs="pubs",
+                                     ask=lambda procs, _cmd: {"subs": subs, "pubs": pubs}[procs])
+        rec = cell.Records(1)
+        rec.drain(made)
+        cmp = cell.compare(rec, table, 4, 10.0, 12.0)
+        check(cmp["attempted"] == 5, f"expected_pairs reads {cmp['attempted']}, not 2 + 3 groups")
+        return cmp["checks"]
+
+    sound = checks([(0, 0), (0, 1), (0, 3), (1, 0), (1, 2)])
+    check(not any(sound.values()), f"one member of each group: {sound}")
+    every = checks([(0, 0), (0, 1), (0, 2), (0, 3), (1, 0), (1, 1), (1, 2)])
+    check(every["shared_extra"] == 2 and not any(v for k, v in every.items()
+                                                 if k != "shared_extra"),
+          f"every member of each group: {every}")
+    return {"shared_extra": every["shared_extra"]}
+
+
 def main() -> int:
     out = {"names": files_and_names(), "trie_matches_compared": trie_agrees(),
            "mqtt_packets": mqtt_splits(), "trace_fixture": trace_fixture(),
-           "open_loop": open_loop_lines()}
+           "open_loop": open_loop_lines(), "shared": shared_lines()}
     print(json.dumps({"selftest": "ok", **out}))
     return 0

@@ -1,6 +1,6 @@
 """The broker broken on purpose, for the tests here.
 
-    BENCHMARK_FAULT=drop|alter|reorder python faulty_broker.py <control dir> <broker args>
+    BENCHMARK_FAULT=drop|alter|reorder|share_all|share_none python faulty_broker.py <control dir> <broker args>
 
 Started in place of ``harness/launch_broker.py``. ``drop`` and ``alter``
 spoil every ``EVERY``-th non-empty match row where it is produced, in
@@ -21,6 +21,16 @@ connection's next one has left, so the younger publish is acked first
 ([MQTT-4.6.0-2] broken; every publish still delivered and acked). A kept
 PUBACK that no other follows leaves by itself ``LONE_S`` later, in order:
 the fault shows only where a fleet keeps more than one publish in flight.
+
+``share_all`` and ``share_none`` break the choice of ``$share`` groups in
+``Router.collapse``, where every routing path (a cache hit, a batch, a lone
+match) collapses a match's groups to one member each ([MQTT 5.0 §4.8.2]):
+
+- ``share_all``: every member of a matched group is kept;
+- ``share_none``: the groups are dropped, only the plain subscribers are
+  sent the publish.
+
+Both are silent on a table without groups.
 """
 
 import os
@@ -64,9 +74,25 @@ def install_reorder() -> None:
     SessionState._send_in_stage = _send_in_stage
 
 
+def install_share(fault: str) -> None:
+    from rmqtt_tpu.router.base import Router, SubRelation
+
+    def collapse(self, raw):
+        out, shared = raw
+        if fault == "share_all":
+            for (_group, tf), candidates in shared.items():
+                for sid, opts, _online in candidates:
+                    out.setdefault(sid.node_id, []).append(SubRelation(tf, sid, opts))
+        return out
+
+    Router.collapse = collapse
+
+
 def install(fault: str) -> None:
     if fault == "reorder":
         return install_reorder()
+    if fault in ("share_all", "share_none"):
+        return install_share(fault)
     from rmqtt_tpu.router.xla import XlaRouter
 
     orig = XlaRouter._expand
@@ -89,7 +115,7 @@ def install(fault: str) -> None:
 
 if __name__ == "__main__":
     fault = os.environ["BENCHMARK_FAULT"]
-    if fault not in ("drop", "alter", "reorder"):
+    if fault not in ("drop", "alter", "reorder", "share_all", "share_none"):
         raise SystemExit(f"unknown BENCHMARK_FAULT {fault!r}")
     install(fault)
     import launch_broker

@@ -19,14 +19,19 @@ a cell, and the harness says so itself instead of printing it* (PERF.md §4):
   older publish's ([MQTT-4.6.0-2]), the harness sums the count and holds it
   to 0;
 - the two readers that now print what they know: ``breakdown.idle_gaps`` by
-  host span, ``expand.busy_pct`` 0 where the stage is there and made no pass.
+  host span, ``expand.busy_pct`` 0 where the stage is there and made no pass;
+- ``$share`` groups ([MQTT 5.0 §4.8.2]): the reference's groups, the table it
+  refuses in words, and ``compare``'s ``shared_missed`` / ``shared_extra`` on
+  hand-built records, against a count made publish by publish.
 
 Run with ``python -m pytest benchmark/tests`` from the checkout's root.
 """
 
 import asyncio
+import itertools
 import math
 import os
+import random
 import sys
 import types
 from pathlib import Path
@@ -38,7 +43,7 @@ BENCH = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(BENCH))
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-from harness import cell, fleet, mqtt, spec  # noqa: E402
+from harness import cell, fleet, mqtt, reference, spec  # noqa: E402
 
 FIXTURES = BENCH / "fixtures"
 CELL = "cfg2_100k_plus.fleet_sat"
@@ -317,7 +322,7 @@ def test_harness_sums_the_count_and_holds_it_to_zero():
         assert checks["acks_out_of_order"] == sum(counts)
         assert list(checks) == ["missing_pairs", "unexpected_pairs", "unacked_qos1",
                                 "acks_out_of_order", "unknown_publish_ids",
-                                "connections_lost"]
+                                "connections_lost", "shared_missed", "shared_extra"]
 
 
 # ---------------------------------- two readers that print what they know
@@ -354,3 +359,149 @@ def test_expand_busy_pct_reads_zero_where_the_stage_made_no_pass(stats0, stats1,
 
     if want == 0.0:
         assert busy_pct(_stage_run(stats0, stats1), ("routing.expand",)) is None
+
+
+# ------------------------------------------------------------ $share groups
+def _table(subscribers: int, held: dict) -> reference.Table:
+    """A table whose row i belongs to subscriber ``i % subscribers``, laid out
+    so that subscriber o holds the rows ``held[o]`` (the rest: filters no
+    topic of these tests matches)."""
+    rounds = max(map(len, held.values()))
+    rows = [(held.get(o, [])[r:r + 1] or [f"z/{o}/{r}"])[0]
+            for r in range(rounds) for o in range(subscribers)]
+    return reference.Table(rows, subscribers)
+
+
+def _window(topics: list, received: list) -> cell.Records:
+    """Records of one publisher process whose publishes ``topics`` were all
+    sent inside the window [9, 11) and acked, and of the deliveries
+    ``received``: (publish, subscriber), each once."""
+    f8 = lambda *v: np.array(v, np.float64).tobytes()  # noqa: E731
+    n = len(topics)
+    pubs = [{"topics": topics, "t_send": f8(*[10.0] * n), "t_due": f8(*[10.0] * n),
+             "t_ack": f8(*[10.1] * n), "waited": bytes(n), "inflight": 0,
+             "out_of_order": 0, "cpu": f8(), "lost": 0}]
+    subs = [{"ids": np.array([p for p, _s in received], np.int64).tobytes(),
+             "times": f8(*[10.05] * len(received)), "cpu": f8(), "lost": 0,
+             "subs": np.array([s for _p, s in received], np.dtype("l")).tobytes()}]
+    made = types.SimpleNamespace(subs="subs", pubs="pubs",
+                                 ask=lambda procs, _cmd: {"subs": subs, "pubs": pubs}[procs])
+    rec = cell.Records(1)
+    rec.drain(made)
+    return rec
+
+
+SHARED = {
+    # owner → rows; topic
+    "disjoint": ({0: ["$share/g/t/+"], 1: ["$share/g/t/+"],
+                  2: ["$share/h/t/x"], 3: ["$share/h/t/x"]}, "t/x"),
+    # one member in both groups: only a matching counts it right
+    "overlap": ({0: ["$share/g/t/+"], 1: ["$share/g/t/+", "$share/g/t/#"],
+                 2: ["$share/g/t/#"]}, "t/x"),
+    "member_also_plain": ({0: ["t/#", "$share/g/t/+"], 1: ["$share/g/t/+"],
+                           2: ["$share/g/t/+"]}, "t/x"),
+    "one_share_two_filters": ({0: ["$share/g/t/+"], 1: ["$share/g/t/+"],
+                               2: ["$share/g/t/#"], 3: ["$share/g/t/#"]}, "t/x"),
+    "one_filter_two_groups": ({0: ["$share/g/t/x"], 1: ["$share/g/t/x"],
+                               2: ["$share/h/t/x"], 3: ["$share/h/t/x"]}, "t/x"),
+    "dollar_topic": ({0: ["$share/g/#"], 1: ["$share/g/#"], 2: ["$SYS/#"]}, "$SYS/x"),
+    "groups_only": ({0: ["$share/g/t/x"], 1: ["$share/g/t/x"]}, "t/x"),
+}
+
+
+@pytest.mark.parametrize("case, received, want", [
+    # want: missing, unexpected, shared_missed, shared_extra, attempted
+    ("disjoint", [0, 2], (0, 0, 0, 0, 2)),
+    ("disjoint", [0, 1, 2, 3], (0, 0, 0, 2, 2)),
+    ("disjoint", [1], (0, 0, 1, 0, 2)),
+    ("overlap", [1], (0, 0, 0, 0, 2)),
+    ("overlap", [0, 2], (0, 0, 0, 0, 2)),
+    ("overlap", [0, 1, 2], (0, 0, 0, 1, 2)),  # Σ max(0, |R ∩ Gj| − 1) would say 2
+    ("member_also_plain", [0], (0, 0, 0, 0, 2)),
+    ("member_also_plain", [0, 1], (0, 0, 0, 0, 2)),
+    ("member_also_plain", [0, 1, 2], (0, 0, 0, 1, 2)),
+    ("member_also_plain", [1], (1, 0, 0, 0, 2)),
+    ("one_share_two_filters", [1, 3], (0, 0, 0, 0, 2)),
+    ("one_share_two_filters", [0, 1], (0, 0, 1, 1, 2)),
+    ("one_filter_two_groups", [0, 3], (0, 0, 0, 0, 2)),
+    ("one_filter_two_groups", [0, 1, 2, 3], (0, 0, 0, 2, 2)),
+    ("dollar_topic", [2], (0, 0, 0, 0, 1)),
+    ("dollar_topic", [0, 2], (0, 1, 0, 0, 1)),
+    ("groups_only", [1], (0, 0, 0, 0, 1)),
+    ("groups_only", [], (0, 0, 1, 0, 1)),
+    ("groups_only", [0, 1, 3], (0, 1, 0, 1, 1)),
+])
+def test_shared_groups_are_held_to_one_member(case, received, want):
+    held, topic = SHARED[case]
+    rec = _window([topic], [(0, s) for s in received])
+    cmp = cell.compare(rec, _table(4, held), 4, 9.0, 11.0)
+    c = cmp["checks"]
+    assert (c["missing_pairs"], c["unexpected_pairs"], c["shared_missed"],
+            c["shared_extra"], cmp["attempted"]) == want
+    assert not (c["unacked_qos1"] or c["unknown_publish_ids"] or c["connections_lost"])
+
+
+def test_the_reference_names_each_group_and_its_members():
+    t = _table(4, SHARED["one_share_two_filters"][0] | {0: ["$share/g/t/+", "a/#"]})
+    counts, flat, (gcounts, gids, offsets, members) = t.expected(["t/x", "t", "a/b", "$SYS/t"])
+    assert counts.tolist() == [0, 0, 1, 0] and flat.tolist() == [0]
+    assert gcounts.tolist() == [2, 1, 0, 0]  # t/+ and t/#; t/# matches its parent t
+    groups = [sorted(members[offsets[g]:offsets[g + 1]].tolist()) for g in gids]
+    assert groups == [[0, 1], [2, 3], [2, 3]]
+    # a table without $share rows answers as before: two arrays
+    assert len(_table(4, {0: ["t/#"], 1: ["t/x"]}).expected(["t/x"])) == 2
+
+
+@pytest.mark.parametrize("held, words", [
+    ({1: ["t/x", "$share/g/t/x"]}, "subscriber 1 holds the rows 't/x' and '$share/g/t/x'"),
+    ({2: ["$share/g/t/x", "$share/h/t/x"]},
+     "subscriber 2 holds the rows '$share/g/t/x' and '$share/h/t/x', both on the filter 't/x'"),
+    ({0: ["$share/g"]}, "'$share/g' is a $share filter without a ShareName or a filter"),
+    ({0: ["$share//t"]}, "'$share//t' is a $share filter without a ShareName or a filter"),
+], ids=["plain_and_shared", "two_groups", "no_filter", "no_share_name"])
+def test_a_table_the_comparison_cannot_judge_is_refused_in_words(held, words):
+    with pytest.raises(reference.Refused) as e:
+        _table(4, held)
+    assert words in str(e.value)
+
+
+def _by_publish(table, topics, received):
+    """``compare``'s shared numbers counted publish by publish, the matching
+    by trying every assignment of receivers to groups."""
+    counts, flat, (gcounts, gids, offsets, members) = table.expected(topics)
+    out = {"unexpected": 0, "missed": 0, "extra": 0}
+    at = gat = 0
+    for k in range(len(topics)):
+        N = set(flat[at:at + counts[k]].tolist())
+        G = [set(members[offsets[g]:offsets[g + 1]].tolist())
+             for g in gids[gat:gat + gcounts[k]]]
+        at, gat = at + counts[k], gat + gcounts[k]
+        R = {s for p, s in received if p == k}
+        out["unexpected"] += len(R - N - set().union(*G))
+        out["missed"] += sum(not (R & g) for g in G)
+        extra = sorted((R - N) & set().union(*G))
+        best = max((sum(1 for r, g in zip(perm, G) if r is not None and r in g)
+                    for perm in itertools.permutations(extra + [None] * len(G), len(G))),
+                   default=0)
+        out["extra"] += len(extra) - best
+    return out
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_compare_agrees_with_a_count_made_publish_by_publish(seed):
+    rng = random.Random(seed)
+    S = 6
+    inner = ["t/+", "t/#", "t/x", "+/x", "#", "u/x"]
+    held = {}
+    for o in range(S):  # each owner: a few rows, never two on one filter
+        for f in rng.sample(inner, rng.randint(1, 3)):
+            held.setdefault(o, []).append(
+                f if rng.random() < 0.3 else f"$share/{rng.choice('gh')}/{f}")
+    table = _table(S, held)
+    topics = [rng.choice(["t/x", "t/y", "u/x", "t", "$SYS/x"]) for _ in range(40)]
+    received = sorted({(k, rng.randrange(S)) for k in range(len(topics))
+                       for _ in range(rng.randint(0, S))})
+    cmp = cell.compare(_window(topics, received), table, S, 9.0, 11.0)
+    got = {k: cmp["checks"][f"shared_{k}"] for k in ("missed", "extra")}
+    got["unexpected"] = cmp["checks"]["unexpected_pairs"]
+    assert got == _by_publish(table, topics, received)
