@@ -411,6 +411,7 @@ class ServerContext:
             cache_capacity=self.cfg.route_cache_capacity,
             cache_shared_bypass=self.cfg.route_cache_shared_bypass,
             telemetry=self.telemetry,
+            metrics=self.metrics,
         )
         self.retain = RetainStore(
             enable=self.cfg.retain_enable,

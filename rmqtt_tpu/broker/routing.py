@@ -33,6 +33,7 @@ import time
 from typing import List, Optional, Tuple
 
 from rmqtt_tpu.broker.failover import _swallow_abandoned
+from rmqtt_tpu.broker.metrics import Metrics
 from rmqtt_tpu.broker.telemetry import NULL_TELEMETRY, PROFILER, Telemetry
 from rmqtt_tpu.broker.tracing import CURRENT_TRACE
 from rmqtt_tpu.router.base import Id, Router, SubRelationsMap
@@ -56,6 +57,7 @@ class RoutingService:
         cache_capacity: int = 8192,
         cache_shared_bypass: bool = False,
         telemetry: Optional[Telemetry] = None,
+        metrics: Optional[Metrics] = None,
     ) -> None:
         self.router = router
         # latency telemetry (broker/telemetry.py): stage histograms for
@@ -72,6 +74,13 @@ class RoutingService:
         self._st_hit = self.tele.stage("routing.cache_hit")
         self._st_plan = self.tele.stage("routing.plan")
         self._st_resolve = self.tele.stage("routing.resolve")
+        self._st_collapse = self.tele.stage("routing.collapse")
+        # the $share group choice's counters (``_collapse``) exist from the
+        # start: a reader tells "never" from "no such counter"
+        self.metrics = metrics if metrics is not None else Metrics()
+        for name in ("shared.publishes", "shared.groups", "shared.candidates",
+                     "shared.no_member"):
+            self.metrics.inc(name, 0)
         self.max_batch = max_batch
         self.linger = linger_ms / 1000.0
         self._q: asyncio.Queue = asyncio.Queue(maxsize=max_queue)
@@ -313,7 +322,7 @@ class RoutingService:
             # routing.cache_hit: the lookup (from t0), derive and collapse,
             # ended before the yield
             tok = self._st_hit.begin_at(t0) if t0 else 0
-            out = self.router.collapse(self.cache.derive(entry, from_id))
+            out = self._collapse(self.cache.derive(entry, from_id))
             if tok:
                 self._st_hit.end(tok)
             await asyncio.sleep(0)
@@ -367,7 +376,7 @@ class RoutingService:
                 entry = self._cache_lookup(topic)
                 if entry is not None:
                     htok = self._st_hit.begin_at(t_hit) if t0 else 0
-                    out.append((self.router.collapse(
+                    out.append((self._collapse(
                         self.cache.derive(entry, from_id)), True))
                     if t0:
                         took = self._st_hit.end(htok)
@@ -511,7 +520,7 @@ class RoutingService:
                     fut.set_exception(res)
                     continue
                 try:
-                    fut.set_result(res if raw else self.router.collapse(res))
+                    fut.set_result(res if raw else self._collapse(res))
                 except Exception as e:
                     # a collapse failure (e.g. a shared-sub strategy callback
                     # bug) must reject ITS waiter, not kill the service task
@@ -542,9 +551,43 @@ class RoutingService:
                     else:
                         derived = self.cache.derive(entry, fid)
                     fut.set_result(
-                        derived if raw else self.router.collapse(derived))
+                        derived if raw else self._collapse(derived))
                 except Exception as e:
                     fut.set_exception(e)
+
+    def _collapse(self, raw) -> SubRelationsMap:
+        """``Router.collapse``, the ``$share`` group choice. A relation that
+        holds no group, or is not the ``(relations, groups)`` pair of the
+        ``Router`` contract (a duck-typed router's own result, as the stub
+        routers of ``tests/test_failpoints.py`` return), passes no stage and
+        is counted nowhere. One that holds a group is the
+        ``routing.collapse`` stage, which nests
+        in ``routing.cache_hit`` (a hit) or ``routing.resolve`` (a miss) and
+        is not left out of them: the readers ``routing.cache_busy_pct`` and
+        ``routing.busy_pct`` already include its time, and
+        ``shared.collapse_busy_pct`` reads how much of it is the choice.
+        Counters, from lengths the relation already has: ``shared.publishes``
+        (collapses that met a group), ``shared.groups`` (choices made),
+        ``shared.candidates`` (candidates offered to the strategy) and
+        ``shared.no_member`` (groups given no member)."""
+        if raw.__class__ is not tuple or not raw[1]:
+            return self.router.collapse(raw)
+        out, shared = raw
+        groups = len(shared)
+        candidates = sum(map(len, shared.values()))
+        before = sum(map(len, out.values()))
+        tok = self._st_collapse.begin() if self.tele.enabled else 0
+        relmap = self.router.collapse(raw)
+        if tok:
+            self._st_collapse.end(tok)
+        chosen = sum(map(len, relmap.values())) - before
+        m = self.metrics
+        m.inc("shared.publishes")
+        m.inc("shared.groups", groups)
+        m.inc("shared.candidates", candidates)
+        if chosen < groups:
+            m.inc("shared.no_member", groups - chosen)
+        return relmap
 
     @staticmethod
     def _reject(batch, exc) -> None:

@@ -112,6 +112,7 @@ SERVED_STAGES = (
     "matcher.compile_offpath",  # ops/hybrid.py's worker: a batch run to compile
     "routing.expand",        # XlaRouter._expand (relations expansion)
     "routing.resolve",       # RoutingService._resolve (futures, cache fill)
+    "routing.collapse",      # Router.collapse where a relation holds a $share group
     "fanout.enqueue",        # SessionRegistry.forwards' _deliver_local loop
     "deliver.credit_wait",   # out_inflight.wait_credit(): a WAIT, not busy
     "deliver.send",          # _deliver: props, OutEntry, encode, feed

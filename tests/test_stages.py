@@ -33,13 +33,17 @@ from tests.test_http_plugins import http_get
 # router is the device router (small batches: the host mirror serves,
 # inline on the loop thread). Two chunks come in (the PUBLISH, the
 # subscriber's PUBACK) and two vectored writes go out (the delivery, the
-# publisher's PUBACK); every other boundary is passed once.
+# publisher's PUBACK); every other boundary is passed once, but the group
+# choice: the subscription is a plain one, so ``routing.collapse`` is not
+# passed at all.
 ONE_PUBLISH = {
     "ingress.decode": 2, "ingress.publish": 1, "routing.plan": 1,
     "routing.match.side": 1, "routing.expand": 1, "routing.resolve": 1,
+    "routing.collapse": 0,
     "fanout.enqueue": 1, "deliver.send": 1, "egress.flush": 2,
     "ack.in": 1, "ack.out": 1,
 }
+SHARED = ("shared.publishes", "shared.groups", "shared.candidates", "shared.no_member")
 
 
 def _key(stage: str) -> str:
@@ -127,9 +131,10 @@ def test_a_nested_stage_owns_its_time_and_the_outer_leaves_it_out(annotation):
     t0 = time.perf_counter_ns()
     while time.perf_counter_ns() - t0 < 2_000_000:  # 2 ms before the hit
         pass
-    itok = inner.begin_at(time.perf_counter_ns())
+    t_hit = time.perf_counter_ns()
+    itok = inner.begin_at(t_hit)
     assert annotation.open_now == 2
-    while time.perf_counter_ns() - t0 < 5_000_000:  # the hit: 3 ms more
+    while time.perf_counter_ns() - t_hit < 3_000_000:  # the hit: 3 ms more
         pass
     took = inner.end(itok)
     assert annotation.open_now == 1
@@ -221,18 +226,19 @@ def test_batch_seq_rides_the_thread(annotation):
 
 
 # ------------------------------------------------------------- a live broker
-async def _one_publish(cfg: dict, probe):
+async def _one_publish(cfg: dict, probe, sub_filter: str = "fleet/+/state"):
     """Start a ``--router xla`` broker in this process, connect a QoS1
-    subscriber and a publisher, let the connects and the SUBSCRIBE settle,
-    then → (stats before, stats after) ONE QoS1 publish that the subscriber
-    receives and PUBACKs. ``probe(broker)`` runs just before the publish."""
+    subscriber to ``sub_filter`` and a publisher, let the connects and the
+    SUBSCRIBE settle, then → (stats before, stats after) ONE QoS1 publish
+    that the subscriber receives and PUBACKs. ``probe(broker)`` runs just
+    before the publish."""
     b = MqttBroker(ServerContext(BrokerConfig(port=0, router="xla", **cfg)))
     api = HttpApi(b.ctx, port=0)
     await b.start()
     await api.start()
     try:
         sub = await TestClient.connect(b.port, "stage-sub", version=pk.V311)
-        await sub.subscribe("fleet/+/state", qos=1)
+        await sub.subscribe(sub_filter, qos=1)
         publ = await TestClient.connect(b.port, "stage-pub", version=pk.V311)
         await asyncio.sleep(0.2)
 
@@ -258,9 +264,10 @@ async def _one_publish(cfg: dict, probe):
 
 @pytest.fixture(scope="module")
 def one_publish():
-    built = []
+    built, brokers = [], []
 
-    def probe(_b, _orig=T._Profiler.open):
+    def probe(b, _orig=T._Profiler.open):
+        brokers.append(b)
         T._Profiler.open = lambda *a, **k: built.append(a) or _orig(*a, **k)
 
     orig = T._Profiler.open
@@ -268,25 +275,48 @@ def one_publish():
         before, after = asyncio.run(asyncio.wait_for(_one_publish({}, probe), 60))
     finally:
         T._Profiler.open = orig
-    return before, after, built
+    return before, after, built, brokers[0].ctx.metrics.to_json()
 
 
 @pytest.mark.parametrize("stage", sorted(ONE_PUBLISH))
 def test_one_qos1_publish_passes_each_boundary(one_publish, stage):
-    before, after, _built = one_publish
+    before, after, _built, _metrics = one_publish
     k = _key(stage) + "_count"
     assert after[k] - before[k] == ONE_PUBLISH[stage]
     assert after[_key(stage) + "_busy_ms_total"] >= before[_key(stage) + "_busy_ms_total"]
 
 
 def test_one_publish_waits_are_in_the_window_histograms(one_publish):
-    before, after, built = one_publish
+    before, after, built, _metrics = one_publish
     for hist in ("routing_queue_wait", "deliver_queue_wait", "publish_e2e"):
         keys = [f"hist_{hist}_b{i:02d}" for i in range(T.NBUCKETS)]
         assert sum(after[k] - before[k] for k in keys) == 1, hist
     assert after["stage_deliver_credit_wait_count"] == before["stage_deliver_credit_wait_count"]
     assert after["host_loop_cpu_ms_total"] > before["host_loop_cpu_ms_total"]
     assert built == []  # no profiler session: not one annotation was opened
+
+
+def test_a_groupless_publish_counts_no_group_choice(one_publish):
+    """The counters of the group choice are there from the start, at 0, and
+    a plain subscription leaves them there."""
+    _before, _after, _built, metrics = one_publish
+    assert {name: metrics.get(name) for name in SHARED} == dict.fromkeys(SHARED, 0)
+
+
+def test_a_shared_publish_passes_the_group_choice_once():
+    """One publish to a ``$share`` group of one member: ``routing.collapse``
+    counts the one collapse that met a group, and the counters one group,
+    one candidate, one choice made."""
+    brokers = []
+    before, after = asyncio.run(asyncio.wait_for(_one_publish(
+        {}, brokers.append, sub_filter="$share/g/fleet/+/state"), 60))
+    k = _key("routing.collapse")
+    assert after[k + "_count"] - before[k + "_count"] == 1
+    assert after[k + "_busy_ms_total"] >= before[k + "_busy_ms_total"]
+    metrics = brokers[0].ctx.metrics.to_json()
+    assert {name: metrics[name] for name in SHARED} == {
+        "shared.publishes": 1, "shared.groups": 1, "shared.candidates": 1,
+        "shared.no_member": 0}
 
 
 def test_disabled_reads_no_clock_at_any_boundary(monkeypatch):
