@@ -16,7 +16,9 @@ depends on it):
 - **Space-Saving top-k** (Metwally et al.): at most ``k`` tracked keys;
   a new key evicts the current minimum and inherits its count as its
   per-entry error bound, so every reported count ``c`` with error ``e``
-  brackets the true count in ``[c - e, c]`` and ``e <= N/k``. Two
+  brackets the true count in ``[c - e, c]`` and ``e <= N/k``. The victim
+  comes off a lazy min-heap of ``(count, insertion order)``: O(log k) an
+  eviction, ties going to the earliest inserted key. Two
   summaries merge via the Agarwal et al. mergeable-summaries rule
   (absent keys contribute the donor's floor to both count and error),
   preserving the bracket fleet-wide.
@@ -53,6 +55,7 @@ surface stays shape-stable.
 from __future__ import annotations
 
 import asyncio
+import heapq
 import logging
 import math
 import time
@@ -114,30 +117,66 @@ class SpaceSaving:
     """Bounded top-k with per-entry error: ``counts[key]`` overestimates
     the true count by at most ``errs[key]`` (the evicted minimum the
     entry inherited), and any untracked key's true count is <= the
-    current floor. O(k) on the eviction path only; hits are one dict op."""
+    current floor. A hit is one dict op. The eviction victim is the
+    smallest count, the earliest inserted among equal counts (the key
+    ``min(counts, key=counts.get)`` names: a dict keeps insertion order
+    and an update keeps a key's place). It comes off ``_heap``, one
+    ``(count, seq, key)`` entry per tracked key, pushed at insertion with
+    a rising ``seq``; hits leave it stale, and since counts only rise, an
+    entry at the top whose count is stale is replaced by its live count
+    until the top is current: O(log k) an eviction, the heap never longer
+    than ``k``."""
 
-    __slots__ = ("k", "counts", "errs")
+    __slots__ = ("k", "counts", "errs", "_heap", "_seq")
 
     def __init__(self, k: int) -> None:
         self.k = max(1, int(k))
         self.counts: Dict[str, int] = {}
         self.errs: Dict[str, int] = {}
+        self._heap: List[Tuple[int, int, str]] = []
+        self._seq = 0
 
     def offer(self, key: str, inc: int = 1) -> None:
+        self.offer_all(((key, inc),))
+
+    def offer_all(self, items) -> int:
+        """``offer(key, n)`` for each ``(key, n)`` of ``items``, in order;
+        → the evictions it made."""
         c = self.counts
-        v = c.get(key)
-        if v is not None:
-            c[key] = v + inc
-            return
+        get = c.get
+        insert = self._insert
+        evicted = 0
+        for key, inc in items:
+            v = get(key)
+            if v is None:
+                evicted += insert(key, inc)
+            else:
+                c[key] = v + inc
+        return evicted
+
+    def _insert(self, key: str, inc: int) -> int:
+        """Track an untracked key; → 1 where it evicted the minimum."""
+        c = self.counts
+        heap = self._heap
+        seq = self._seq
+        self._seq = seq + 1
         if len(c) < self.k:
             c[key] = inc
             self.errs[key] = 0
-            return
-        victim = min(c, key=c.get)
-        floor = c.pop(victim)
-        self.errs.pop(victim, None)
+            heapq.heappush(heap, (inc, seq, key))
+            return 0
+        while True:
+            floor, vseq, victim = heap[0]
+            live = c[victim]
+            if live == floor:
+                break
+            heapq.heapreplace(heap, (live, vseq, victim))
+        del c[victim]
+        del self.errs[victim]
         c[key] = floor + inc
         self.errs[key] = floor
+        heapq.heapreplace(heap, (floor + inc, seq, key))
+        return 1
 
     def floor(self) -> int:
         """Upper bound on any UNTRACKED key's count (0 until full)."""
@@ -187,26 +226,26 @@ class CountMin:
     Deterministic crc32-per-row hashing keeps two nodes' sketches
     cell-compatible; merge is element-wise addition."""
 
-    __slots__ = ("width", "depth", "rows")
+    __slots__ = ("width", "depth", "rows", "seeds")
 
     def __init__(self, width: int, depth: int) -> None:
         self.width = max(8, int(width))
         self.depth = max(1, int(depth))
         self.rows: List[List[int]] = [
             [0] * self.width for _ in range(self.depth)]
+        self.seeds: Tuple[int, ...] = tuple(
+            (_SEED_MULT * (r + 1)) & 0xFFFFFFFF for r in range(self.depth))
 
     def add_data(self, data: bytes, inc: int = 1) -> None:
         w = self.width
-        for r, row in enumerate(self.rows):
-            row[zlib.crc32(data, (_SEED_MULT * (r + 1)) & 0xFFFFFFFF) % w] \
-                += inc
+        for row, seed in zip(self.rows, self.seeds):
+            row[zlib.crc32(data, seed) % w] += inc
 
     def query(self, key: str) -> int:
         data = key.encode("utf-8", "surrogatepass")
         w = self.width
-        return min(
-            row[zlib.crc32(data, (_SEED_MULT * (r + 1)) & 0xFFFFFFFF) % w]
-            for r, row in enumerate(self.rows))
+        return min(row[zlib.crc32(data, seed) % w]
+                   for row, seed in zip(self.rows, self.seeds))
 
     def merge(self, other: "CountMin") -> None:
         if other.width != self.width or other.depth != self.depth:
@@ -231,14 +270,35 @@ class _Window:
         self.total = 0
         self.t0 = now
 
-    def offer(self, key: str, inc: int = 1) -> None:
-        self.total += inc
-        self.ss.offer(key, inc)
-        data = key.encode("utf-8", "surrogatepass")
-        if self.cms is not None:
-            self.cms.add_data(data, inc)
-        h = zlib.crc32(data) % _LC_BITS
-        self.bitmap[h >> 3] |= 1 << (h & 7)
+    def fold(self, agg: Dict[str, int], twin: Optional["_Window"] = None,
+             twin_agg: Optional[Dict[str, int]] = None) -> int:
+        """Offer every ``(key, n)`` of ``agg``, in its order, to the
+        Space-Saving, and add it to the total, the CMS rows and the bitmap
+        with one ``encode`` a key. ``twin`` is a window of the same key
+        space without a CMS (topic_bytes beside topics) offered
+        ``twin_agg``, whose keys are among ``agg``'s: it takes its bitmap
+        bits from this pass. → the Space-Saving evictions made."""
+        self.total += sum(agg.values())
+        evicted = self.ss.offer_all(agg.items())
+        if twin is not None:
+            twin.total += sum(twin_agg.values())
+            evicted += twin.ss.offer_all(twin_agg.items())
+            twin_bm = twin.bitmap
+        cms = self.cms
+        rows = () if cms is None else tuple(zip(cms.rows, cms.seeds))
+        w = 0 if cms is None else cms.width
+        bm = self.bitmap
+        crc32 = zlib.crc32
+        for key, inc in agg.items():
+            data = key.encode("utf-8", "surrogatepass")
+            for row, seed in rows:
+                row[crc32(data, seed) % w] += inc
+            h = crc32(data) % _LC_BITS
+            byte, bit = h >> 3, 1 << (h & 7)
+            bm[byte] |= bit
+            if twin is not None and key in twin_agg:
+                twin_bm[byte] |= bit
+        return evicted
 
     def distinct_est(self) -> int:
         zeros = sum(_ZERO_BITS[b] for b in self.bitmap)
@@ -276,9 +336,6 @@ class _Space:
         self.cur = _Window(k, width, depth, now, cms)
         self.prev = _Window(k, width, depth, now, cms)
         self.alerting = False
-
-    def offer(self, key: str, inc: int = 1) -> None:
-        self.cur.offer(key, inc)
 
     def rotate(self, now: float) -> None:
         self.prev = self.cur
@@ -340,6 +397,10 @@ class HotkeysService:
         }
         self.rotations = 0
         self.alerts_total = 0
+        # distinct keys the folds offered, summed over spaces, and the
+        # Space-Saving evictions they made (counted once a fold)
+        self.fold_keys = 0
+        self.evictions = 0
         self.alerts_by_space: Dict[str, int] = {s: 0 for s in ALERT_SPACES}
         self._task: Optional[asyncio.Task] = None
         # pending seam events, folded into the sketches by drain()
@@ -432,6 +493,7 @@ class HotkeysService:
 
     def _fold(self) -> None:
         sp = self.spaces
+        keys = evicted = 0
         pubs, self._pend_pub = self._pend_pub, []
         if pubs:
             tc: Dict[str, int] = {}
@@ -442,15 +504,9 @@ class HotkeysService:
                 if nbytes > 0:
                     tb[topic] = tb.get(topic, 0) + nbytes
                 pc[cid] = pc.get(cid, 0) + 1
-            offer = sp["topics"].offer
-            for key, n in tc.items():
-                offer(key, n)
-            offer = sp["topic_bytes"].offer
-            for key, n in tb.items():
-                offer(key, n)
-            offer = sp["publishers"].offer
-            for key, n in pc.items():
-                offer(key, n)
+            evicted += sp["topics"].cur.fold(tc, sp["topic_bytes"].cur, tb)
+            evicted += sp["publishers"].cur.fold(pc)
+            keys += len(tc) + len(tb) + len(pc)
         disp, self._pend_disp = self._pend_disp, []
         if disp:
             fc: Dict[str, int] = {}
@@ -460,25 +516,24 @@ class HotkeysService:
             for topic, n in fc.items():
                 seg = first_segment(topic)
                 pf[seg] = pf.get(seg, 0) + n
-            offer = sp["prefixes"].offer
-            for key, n in pf.items():
-                offer(key, n)
+            evicted += sp["prefixes"].cur.fold(pf)
+            keys += len(pf)
         subs, self._pend_sub = self._pend_sub, []
         if subs:
             sc: Dict[str, int] = {}
             for cid in subs:
                 sc[cid] = sc.get(cid, 0) + 1
-            offer = sp["subscribers"].offer
-            for key, n in sc.items():
-                offer(key, n)
+            evicted += sp["subscribers"].cur.fold(sc)
+            keys += len(sc)
         drops, self._pend_drop = self._pend_drop, []
         if drops:
             dc: Dict[str, int] = {}
             for key in drops:
                 dc[key] = dc.get(key, 0) + 1
-            offer = sp["drops"].offer
-            for key, n in dc.items():
-                offer(key, n)
+            evicted += sp["drops"].cur.fold(dc)
+            keys += len(dc)
+        self.fold_keys += keys
+        self.evictions += evicted
 
     # ------------------------------------------------------------ lifecycle
     def start(self) -> None:
@@ -670,6 +725,8 @@ class HotkeysService:
             "hotkeys_prefixes_tracked": len(sp["prefixes"].cur.ss.counts),
             "hotkeys_rotations": self.rotations,
             "hotkeys_alerts": self.alerts_total,
+            "hotkeys_fold_keys": self.fold_keys,
+            "hotkeys_evictions": self.evictions,
         }
 
     def history_summary(self) -> Dict[str, float]:

@@ -898,7 +898,8 @@ const KEYS=["connections","sessions","subscriptions","subscriptions_shared",
  "history_recovered_rows",
  "hotkeys_topics_tracked","hotkeys_publishers_tracked",
  "hotkeys_subscribers_tracked","hotkeys_prefixes_tracked",
- "hotkeys_rotations","hotkeys_alerts","rss_mb"];
+ "hotkeys_rotations","hotkeys_alerts","hotkeys_fold_keys",
+ "hotkeys_evictions","rss_mb"];
 // latency cards: stage -> quantiles shown (fed by /api/v1/latency;
 // histogram units are ns, rendered as ms)
 const LAT_STAGES=[["publish.e2e",["p50","p99"]],["routing.match",["p50","p99"]],

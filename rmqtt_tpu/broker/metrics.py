@@ -269,6 +269,8 @@ class Stats:
         self.hotkeys_prefixes_tracked = 0
         self.hotkeys_rotations = 0
         self.hotkeys_alerts = 0
+        self.hotkeys_fold_keys = 0
+        self.hotkeys_evictions = 0
 
     def to_json(self) -> Dict[str, Union[int, float]]:
         """Gauge dict for the admin surfaces. Most gauges are ints; the

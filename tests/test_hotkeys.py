@@ -25,10 +25,12 @@ Tiers:
 """
 
 import asyncio
+import functools
 import importlib.util
 import json
 import pathlib
 import random
+import zlib
 from collections import Counter
 
 import pytest
@@ -187,6 +189,216 @@ def test_cms_shape_mismatch_raises():
         pass
     else:
         raise AssertionError("shape mismatch must raise")
+
+
+# ------------------------------------------ the fold against the scan oracle
+class _ScanSpaceSaving:
+    """Space-Saving as the plane had it before the heap: the victim is
+    found by scanning every tracked key (the oracle of the tests below)."""
+
+    __slots__ = ("k", "counts", "errs")
+
+    def __init__(self, k):
+        self.k = max(1, int(k))
+        self.counts = {}
+        self.errs = {}
+
+    def offer(self, key, inc=1):
+        c = self.counts
+        v = c.get(key)
+        if v is not None:
+            c[key] = v + inc
+            return
+        if len(c) < self.k:
+            c[key] = inc
+            self.errs[key] = 0
+            return
+        victim = min(c, key=c.get)
+        floor = c.pop(victim)
+        self.errs.pop(victim, None)
+        c[key] = floor + inc
+        self.errs[key] = floor
+
+    floor = SpaceSaving.floor
+    entries = SpaceSaving.entries
+
+
+def _scan_offer(win, key, inc):
+    """``_Window.offer`` as it was: one encode and five hashes a space."""
+    win.total += inc
+    win.ss.offer(key, inc)
+    data = key.encode("utf-8", "surrogatepass")
+    if win.cms is not None:
+        w = win.cms.width
+        for r, row in enumerate(win.cms.rows):
+            row[zlib.crc32(data, (0x9E3779B9 * (r + 1)) & 0xFFFFFFFF) % w] \
+                += inc
+    h = zlib.crc32(data) % 4096
+    win.bitmap[h >> 3] |= 1 << (h & 7)
+
+
+class _ScanPlane(HotkeysService):
+    """The plane with the scan-based Space-Saving and the per-space fold
+    it had before the heap and the shared pass."""
+
+    def __init__(self, ctx, cfg):
+        super().__init__(ctx, cfg)
+        for space in self.spaces.values():
+            space.prev.ss = _ScanSpaceSaving(space.k)
+            space.cur.ss = _ScanSpaceSaving(space.k)
+
+    def rotate(self):
+        super().rotate()
+        for space in self.spaces.values():
+            space.cur.ss = _ScanSpaceSaving(space.k)
+
+    def _fold(self):
+        sp = self.spaces
+        pubs, self._pend_pub = self._pend_pub, []
+        tc, tb, pc = {}, {}, {}
+        for topic, cid, nbytes in pubs:
+            tc[topic] = tc.get(topic, 0) + 1
+            if nbytes > 0:
+                tb[topic] = tb.get(topic, 0) + nbytes
+            pc[cid] = pc.get(cid, 0) + 1
+        for name, agg in (("topics", tc), ("topic_bytes", tb),
+                          ("publishers", pc)):
+            for key, n in agg.items():
+                _scan_offer(sp[name].cur, key, n)
+        disp, self._pend_disp = self._pend_disp, []
+        fc = Counter(disp)
+        pf = {}
+        for topic, n in fc.items():
+            seg = first_segment(topic)
+            pf[seg] = pf.get(seg, 0) + n
+        for key, n in pf.items():
+            _scan_offer(sp["prefixes"].cur, key, n)
+        subs, self._pend_sub = self._pend_sub, []
+        for key, n in Counter(subs).items():
+            _scan_offer(sp["subscribers"].cur, key, n)
+        drops, self._pend_drop = self._pend_drop, []
+        for key, n in Counter(drops).items():
+            _scan_offer(sp["drops"].cur, key, n)
+
+
+@functools.lru_cache(maxsize=None)
+def _benchmark_zipf_topics(n):
+    """``n`` publishes of the stream of ``b1_1m_zipf.pub40`` (its generator
+    ``exact_zipf`` over the cell's 1,000,000 exact topics)."""
+    import itertools
+    import sys
+
+    bench = str(pathlib.Path(__file__).resolve().parent.parent / "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from harness import generators
+
+    gen = generators.load("exact_zipf")(2**31 + 42,
+                                        {"subscriptions": 1_000_000})
+    return tuple(itertools.islice(gen.topic_stream(2**33 + 7), n))
+
+
+def _fold_stream(case, rng):
+    """Four folds of ``(topic, publisher, nbytes, subscriber, drop key)``
+    events; the plane rotates after the second."""
+    folds = []
+    for f in range(4):
+        if case == "uniform_1m":
+            ns = [rng.randrange(1_000_000) for _ in range(3000)]
+            ev = [(f"iot/{n}", f"pub-{n % 100}", 7, f"sub-{n % 4096}",
+                   f"queue_full:sub-{n % 300}") for n in ns]
+        elif case == "zipf_099":
+            topics = _benchmark_zipf_topics(12_000)[f * 3000:(f + 1) * 3000]
+            ev = [(t, f"pub-{i % 40}", 7, f"sub-{zlib.crc32(t.encode()) % 4096}",
+                   f"queue_full:{t}") for i, t in enumerate(topics)]
+        elif case == "all_tied":
+            # every key of a fold twice, no key seen in two folds: every
+            # count ties with every other
+            keys = [f"f{f}/k{i}" for i in range(200)]
+            ev = [(t, t, 5, t, t) for t in keys + keys]
+        elif case == "evict_reinsert":
+            # a cycle a little longer than k: every key is evicted and
+            # comes back, with a few hot keys in between
+            keys = [f"c{i % 71}/x" for i in range(700)]
+            keys += [f"hot{i % 3}/x" for i in range(50)]
+            rng.shuffle(keys)
+            ev = [(t, t, 1, t, t) for t in keys]
+        else:  # weighted: repeats, byte counts from 0 up, sizes > 1
+            ns = [int(rng.paretovariate(1.2)) % 500 for _ in range(3000)]
+            ev = [(f"w{n % 37}/t{n}", f"pub-{n % 90}", (n * 13) % 400,
+                   f"sub-{n % 150}", f"drop:{n % 80}") for n in ns]
+        folds.append(ev)
+    return folds
+
+
+def _sketches(hk):
+    out = {}
+    for name, space in hk.spaces.items():
+        for side in ("cur", "prev"):
+            win = getattr(space, side)
+            out[name, side] = (
+                list(win.ss.counts.items()), list(win.ss.errs.items()),
+                win.cms.rows if win.cms is not None else None,
+                bytes(win.bitmap), win.total)
+    return out
+
+
+@pytest.mark.parametrize("k", [8, 64])
+@pytest.mark.parametrize("case", ["uniform_1m", "zipf_099", "all_tied",
+                                  "evict_reinsert", "weighted"])
+def test_fold_matches_scan_oracle(case, k):
+    """The heap-evicting Space-Saving and the one-pass fold give every
+    sketch of the plane exactly what the scan-based code gave on the same
+    events: Space-Saving counts in dict order, errors, CMS rows, bitmaps
+    and totals over four folds and one rotation, and so every view."""
+    rng = random.Random(f"{case}/{k}")
+    new = _ctx(hotkeys_k=k).hotkeys
+    ctx = _ctx(hotkeys_k=k)
+    old = _ScanPlane(ctx, ctx.cfg)
+    assert new.k == old.k == k
+    for f, events in enumerate(_fold_stream(case, rng)):
+        for hk in (new, old):
+            for topic, pub, nbytes, sub, drop in events:
+                hk.on_publish(topic, pub, nbytes)
+                hk.on_dispatch(topic)
+                hk.on_deliver(sub)
+                hk.on_drop("x", drop)
+            hk.drain()
+            if f == 1:
+                hk.rotate()
+        assert _sketches(new) == _sketches(old), f
+        block, oracle = new.stats_block(), old.stats_block()
+        for counter in ("hotkeys_fold_keys", "hotkeys_evictions"):
+            block.pop(counter)
+            oracle.pop(counter)
+        assert block == oracle
+        assert new.snapshot() == old.snapshot()
+        assert new.history_summary() == old.history_summary()
+    assert new.point("topics", events[0][0]) == old.point("topics",
+                                                         events[0][0])
+
+
+@pytest.mark.parametrize("stream", ["distinct", "repeats"])
+def test_fold_counters(stream):
+    """``hotkeys_fold_keys`` sums the distinct keys each fold offered each
+    space; ``hotkeys_evictions`` counts what Space-Saving evicted: n
+    distinct keys into a fresh plane of k = 8 evict n - 8 in every space,
+    repeats of 8 keys evict nothing."""
+    hk = _ctx(hotkeys_k=8).hotkeys
+    n = 500 if stream == "distinct" else 8
+    for rep in range(1 if stream == "distinct" else 20):
+        for i in range(n):
+            hk.on_publish(f"t{i}/x", f"pub-{i}", 3)
+            hk.on_dispatch(f"t{i}/x")
+            hk.on_deliver(f"sub-{i}")
+            hk.on_drop("queue_full", f"sub-{i}")
+    hk.drain()
+    st = hk.stats_block()
+    assert st["hotkeys_fold_keys"] == len(SPACES) * n
+    assert st["hotkeys_evictions"] == len(SPACES) * (n - 8)
+    assert all(len(hk.spaces[s].cur.ss.counts) == 8 for s in SPACES)
+    hk.drain()  # nothing buffered: no fold, nothing counted
+    assert hk.stats_block() == st
 
 
 def test_distinct_estimate_near_truth():
