@@ -861,7 +861,8 @@ const KEYS=["connections","sessions","subscriptions","subscriptions_shared",
  "routing_cache_door_rejects","routing_uploads","routing_delta_uploads",
  "routing_upload_bytes","routing_compactions","routing_compact_ms_total",
  "routing_cand_cache_invalidations","routing_encode_topics",
- "routing_encode_host_resolved","routing_fused_batches",
+ "routing_encode_host_resolved","routing_encode_candidates",
+ "routing_encode_scanned","routing_partitions_split","routing_fused_batches",
  "routing_stage_encode_ms_total","routing_stage_dispatch_ms_total",
  "routing_stage_fetch_ms_total","routing_stage_decode_ms_total",
  "fabric_batches","fabric_items","fabric_bytes_out","fabric_deliver_in",

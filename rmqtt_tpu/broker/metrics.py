@@ -81,6 +81,9 @@ class Stats:
         self.routing_cand_cache_invalidations = 0
         self.routing_encode_topics = 0
         self.routing_encode_host_resolved = 0
+        self.routing_encode_candidates = 0
+        self.routing_encode_scanned = 0
+        self.routing_partitions_split = 0
         self.routing_fused_batches = 0
         # per-stage device dispatch attribution (PR9 stage_timing promoted
         # to the live surface via XlaRouter.device_stats): cumulative ms,

@@ -185,6 +185,9 @@ class RoutingService:
             "routing_cand_cache_invalidations": d.get("cand_cache_invalidations", 0),
             "routing_encode_topics": d.get("encode_topics", 0),
             "routing_encode_host_resolved": d.get("encode_host_resolved", 0),
+            "routing_encode_candidates": d.get("encode_candidates", 0),
+            "routing_encode_scanned": d.get("encode_scanned", 0),
+            "routing_partitions_split": d.get("partitions_split", 0),
             "routing_fused_batches": d.get("fused_batches", 0),
             # per-stage device dispatch attribution (PR9 stage_timing via
             # XlaRouter.device_stats): cumulative ms → _total suffix (summed

@@ -110,6 +110,7 @@ SERVED_STAGES = (
     "matcher.decode",
     "matcher.compile",       # a jit seam call whose shape key was never seen
     "matcher.compile_offpath",  # ops/hybrid.py's worker: a batch run to compile
+    "matcher.partition_split",  # PartitionedTable._split: a partition keyed one level deeper
     "routing.expand",        # XlaRouter._expand (relations expansion)
     "routing.resolve",       # RoutingService._resolve (futures, cache fill)
     "routing.collapse",      # Router.collapse where a relation holds a $share group

@@ -1,21 +1,27 @@
 """Partitioned automaton: trie-style pruning flattened for the TPU.
 
 The dense matcher scans every filter row per topic; the reference's trie
-wins by pruning on the first levels (`/root/reference/rmqtt/src/trie.rs`
-DFS only descends matching branches). This module flattens exactly that
-pruning into static-shaped TPU compute:
+wins by pruning at every level (rmqtt's `src/trie.rs` DFS only descends
+matching branches). This module flattens that pruning into
+static-shaped TPU compute:
 
-Filters are bucketed by their first two levels into *partitions*
-(NOTES.md design):
+Filters are bucketed by their first three levels into *partitions*
+(``partition_key``; NOTES.md design): the bare ``#``, single-level
+filters, ``k0/#``, two-level filters, ``k0/k1/#``, and ``("4", k0, k1,
+k2)`` for everything deeper, every ``k`` a token or ``+``. A partition of
+the deep kind that would outgrow ``SPLIT_ROWS`` is SPLIT by its next level
+(``PartitionedTable._split``), recursively: ``("5", k0, k1, k2, k3)`` and
+so on, one child per token and one for ``+``, while the filters that end
+at the split level or put ``#`` there stay in the split partition itself.
+So keys reach exactly as deep as a subtree needs to discriminate — a
+namespace whose first three levels select nearly nothing (an ISA-95
+Unified Namespace: one enterprise, a few sites and areas) is pruned at its
+line and cell levels, and a table whose first levels already select keeps
+the three-level keys.
 
-- ``("#",)``      — the bare ``#`` filter;
-- ``("1", k0)``   — single-level filters (k0 = token or ``+``);
-- ``("2", k0)``   — ``<k0>/#`` (prefix length 1);
-- ``("3", k0, k1)`` — everything else, k0/k1 ∈ {token, ``+``}.
-
-A publish topic (t0, t1, …) can only match filters in ≤7 partitions:
-``#``, ``t0/#``, ``+/#``, (t0,t1), (t0,+), (+,t1), (+,+) — plus the
-single-level partitions when the topic has one level. Each partition owns
+A publish topic can only match filters in the partitions
+``topic_partitions`` lists: at most 15 three-level ones, plus the token and
+``+`` children of each split partition it descends. Each partition owns
 fixed-size row *chunks* (``CHUNK`` rows) in the flat table, so churn is O(1)
 and the kernel sees a per-topic list of chunk ids: one `lax.scan` step
 gathers a [B, CHUNK] row tile per candidate chunk, applies the same level
@@ -29,12 +35,13 @@ from __future__ import annotations
 
 import bisect
 import functools
+import itertools
 import logging
 import os
 import threading
 import time
 from collections import deque
-from typing import Dict, List, Optional, Sequence, Set, Tuple
+from typing import Collection, Dict, List, Optional, Sequence, Set, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -122,6 +129,17 @@ _LOG = logging.getLogger("rmqtt_tpu.ops")
 CHUNK = 128  # rows per partition chunk (4 packed words)
 WORDS_PER_CHUNK = CHUNK // 32
 
+#: a deep partition (kind "4" and its split children) that holds this many
+#: rows is split by its next level before it takes one more: no partition a
+#: topic consults spans more than a few chunks (``PartitionedTable._split``)
+SPLIT_ROWS = 4 * CHUNK
+
+#: the padded candidate-chunk count (NC) a batch starts from: the kernel
+#: scans NC chunk steps a topic, a power of two grown to the largest
+#: candidate set a batch meets, and a compaction install starts it here
+#: again (``PartitionedTable._install_compact``)
+NC_FLOOR = 32
+
 # partition key kinds
 _K_HASH = ("#",)
 
@@ -130,7 +148,7 @@ class _CompactState:
     """A fully-built compacted physical layout, ready to swap in."""
 
     __slots__ = ("arrays", "fid_of_row", "row_of_fid", "cap_chunks", "nchunks",
-                 "excl_chunks", "excl_free", "shared_chunks_of",
+                 "excl_chunks", "excl_free", "excl_rows", "shared_chunks_of",
                  "shared_rows_of", "shared_free", "open_shared", "spread")
 
 
@@ -186,6 +204,7 @@ def _build_compact_state(
     # re-create the sparse layout the compaction just removed).
     st.excl_chunks = {}
     st.excl_free = {}
+    st.excl_rows = {}
     st.shared_chunks_of = {}
     st.shared_rows_of = {}
     st.shared_free = {}
@@ -206,6 +225,7 @@ def _build_compact_state(
             st.spread += len(occ) > 1
         else:
             st.excl_chunks[key] = list(range(first_chunk, last_chunk + 1))
+            st.excl_rows[key] = k
         pos += k
     st.nchunks = (pos + CHUNK - 1) // CHUNK
     # the tail of the last chunk is unowned free space: future adds for
@@ -218,7 +238,7 @@ def _build_compact_state(
     return st
 
 
-def partition_key(levels: Sequence[str]) -> Tuple:
+def partition_key(levels: Sequence[str], split: Collection = ()) -> Tuple:
     """Partition of a (stripped, validated) filter.
 
     Depth-3 keys: measurement showed the depth-2 wildcard-wildcard bucket
@@ -232,27 +252,48 @@ def partition_key(levels: Sequence[str]) -> Tuple:
     - ``("H3", k0, k1)``    ``k0/k1/#``
     - ``("4", k0, k1, k2)`` three or more levels (k2 = third level)
 
-    with every ``k`` ∈ {token, ``+``}.
+    with every ``k`` ∈ {token, ``+``}. Where a deep key is in ``split`` (the
+    table's split partitions) a filter that goes on past that key's levels
+    with a token or ``+`` belongs to the child keyed by its next level too:
+    ``("5", k0, k1, k2, k3)``, ``("6", k0, .., k4)`` and so on — the kind
+    is one more than the levels the key names. A filter that ends at the
+    split level, or puts ``#`` there, stays in the split key.
     """
     f0 = levels[0]
     if f0 == HASH:
         return _K_HASH
-    k0 = PLUS if f0 == PLUS else f0
     if len(levels) == 1:
-        return ("1", k0)
+        return ("1", f0)
     if levels[1] == HASH:
-        return ("2", k0)
-    k1 = PLUS if levels[1] == PLUS else levels[1]
+        return ("2", f0)
     if len(levels) == 2:
-        return ("2E", k0, k1)
+        return ("2E", f0, levels[1])
     if levels[2] == HASH:
-        return ("H3", k0, k1)
-    k2 = PLUS if levels[2] == PLUS else levels[2]
-    return ("4", k0, k1, k2)
+        return ("H3", f0, levels[1])
+    key = ("4", f0, levels[1], levels[2])
+    d = 3
+    while split and key in split and len(levels) > d and levels[d] != HASH:
+        d += 1
+        key = (str(d + 1),) + key[1:] + (levels[d - 1],)
+    return key
 
 
-def topic_partitions(levels: Sequence[str]) -> List[Tuple]:
-    """Candidate partitions for a publish topic (≤15, most tiny)."""
+def _descend(key: Tuple, levels: Sequence[str], split: Collection,
+             out: List[Tuple]) -> None:
+    """``key``, then — where it is split and the topic goes on past it —
+    its token child's and its ``+`` child's walks, in that order."""
+    out.append(key)
+    d = len(key) - 1  # levels the key names
+    if split and len(levels) > d and key in split:
+        kind = str(d + 2)
+        _descend((kind,) + key[1:] + (levels[d],), levels, split, out)
+        _descend((kind,) + key[1:] + (PLUS,), levels, split, out)
+
+
+def topic_partitions(levels: Sequence[str], split: Collection = ()) -> List[Tuple]:
+    """Candidate partitions for a publish topic: at most 15 where no
+    partition it reaches is split, and two more (the token and ``+``
+    children) for each split partition the topic descends through."""
     t0 = levels[0]
     n = len(levels)
     out: List[Tuple] = [_K_HASH, ("2", t0), ("2", PLUS)]
@@ -269,8 +310,8 @@ def topic_partitions(levels: Sequence[str]) -> List[Tuple]:
         return out
     t2 = levels[2]
     for a, b in pairs:
-        out.append(("4", a, b, t2))
-        out.append(("4", a, b, PLUS))
+        _descend(("4", a, b, t2), levels, split, out)
+        _descend(("4", a, b, PLUS), levels, split, out)
     return out
 
 
@@ -287,7 +328,15 @@ class PartitionedTable:
     accumulates a full chunk's worth of rows it migrates to exclusive
     chunks. Filter ids are therefore STABLE HANDLES decoupled from row
     positions (`fid ↔ row` maps), so migration never breaks the router.
+
+    A deep partition that holds ``split_rows`` rows is SPLIT before it
+    takes one more (``_split``): its rows move, fids kept, to the children
+    its next level keys (``partition_key``), and the split is kept for good.
+    None (``RetainedTable``, whose inverse index names three-level keys)
+    keeps every key at three levels.
     """
+
+    split_rows: Optional[int] = SPLIT_ROWS
 
     def __init__(self, max_levels: int = 8) -> None:
         self.max_levels = max_levels
@@ -303,6 +352,14 @@ class PartitionedTable:
         self._spread = 0
         # free row slots inside partition-exclusive chunks
         self._excl_free: Dict[Tuple, List[int]] = {}
+        # rows a partition holds in its exclusive chunks: what _split tests
+        self._excl_rows: Dict[Tuple, int] = {}
+        # split partitions, in the order they split (the native mirror is
+        # sent the new ones), and the topic levels a candidate set depends
+        # on: max(3, the longest split key's length)
+        self._split_keys: Dict[Tuple, None] = {}
+        self._key_depth = 3
+        self._split_stage = _Stage("matcher.partition_split")
         # shared-chunk pool: cid → free row slots; _open_shared lists chunk
         # ids that still have free slots (O(1) allocation)
         self._shared_free: Dict[int, List[int]] = {}
@@ -380,7 +437,11 @@ class PartitionedTable:
         # Python resolved (all under _encode_py, none under the native path)
         self.encode_topics_total = 0
         self.encode_host_resolved = 0
-        self._nc_cap = 32
+        # candidate chunks of those topics, and chunk steps the kernel runs
+        # for them (the padded NC each)
+        self.encode_candidates_total = 0
+        self.encode_scanned_total = 0
+        self._nc_cap = NC_FLOOR
         # narrow dtypes while ids fit: halves the per-batch host→device
         # upload of ttok/chunk_ids AND the device
         # tiles' gather traffic (pack_device_rows shares _tok_wide, so the
@@ -520,6 +581,7 @@ class PartitionedTable:
         # 1) free slot in one of the partition's exclusive chunks
         free = self._excl_free.get(key)
         if free:
+            self._excl_rows[key] = self._excl_rows.get(key, 0) + 1
             return free.pop()
         shared_rows = self._shared_rows_of.setdefault(key, [])
         excl = self._excl_chunks.get(key)
@@ -529,6 +591,7 @@ class PartitionedTable:
             cid = self._new_chunk()
             base = cid * CHUNK
             self._excl_chunks.setdefault(key, []).append(cid)
+            self._excl_rows[key] = self._excl_rows.get(key, 0) + len(shared_rows) + 1
             slots = list(range(base, base + CHUNK))
             for src in shared_rows:
                 dst = slots.pop(0)
@@ -579,7 +642,12 @@ class PartitionedTable:
             self._open_shared.append(cid)
         slots.append(row)
 
-    def _move_row(self, src: int, dst: int) -> None:
+    def _move_row(self, src: int, dst: int, rekey=None) -> None:
+        """Move one row's data and fid from ``src`` to ``dst`` and free
+        ``src`` into the shared pool. ``rekey`` = (old key, new key,
+        levels) where the move puts the fid in another partition
+        (``_split``): a compaction build in flight then replays it as a
+        remove from the old key and an add to the new one."""
         self.tok[dst] = self.tok[src]
         self.flen[dst] = self.flen[src]
         self.prefix_len[dst] = self.prefix_len[src]
@@ -593,8 +661,11 @@ class PartitionedTable:
             self._txn.append(dst // CHUNK)
             self._undo_pending.append((dst, int(self._fid_of_row[dst])))
             self._undo_pending.append((src, fid))
-            if self._compact_journal is not None:
-                self._compact_journal.append(("m", fid))
+            journal = self._compact_journal
+            if journal is not None and rekey is None:
+                journal.append(("m", fid))
+            elif journal is not None:
+                journal += [("r", fid, rekey[0]), ("a", fid, rekey[1], rekey[2])]
         self._fid_of_row[dst] = fid
         self._row_of_fid[fid] = dst
         self._clear_row(src)
@@ -654,7 +725,7 @@ class PartitionedTable:
 
     def _register_cand(self, levels: Sequence[str], cache_key: Tuple) -> None:
         """Record which partition keys a cached candidate set consulted."""
-        for key in topic_partitions(levels):
+        for key in topic_partitions(levels, self._split_keys):
             self._cand_keys_of.setdefault(key, set()).add(cache_key)
 
     def fid_overlay(self, version: int, epoch: int):
@@ -685,8 +756,13 @@ class PartitionedTable:
             nlev = len(levels)
             if nlev > self.max_levels:
                 self._grow(self._cap_chunks, nlev)
-            key = partition_key(levels)
             self._begin_txn()
+            key = partition_key(levels, self._split_keys)
+            while (self.split_rows is not None
+                   and self._excl_rows.get(key, 0) >= self.split_rows
+                   and len(key) >= 4 and key not in self._split_keys):
+                self._split(key)
+                key = partition_key(levels, self._split_keys)
             row = self._alloc_row(key)
             self._write_row(row, levels)
             fid = self._next_fid
@@ -725,6 +801,55 @@ class PartitionedTable:
         if prefix > self._eff_levels:
             self._eff_levels = prefix
 
+    def _split(self, key: Tuple) -> None:
+        """Split a deep partition by its next level: every row moves, fid
+        kept, to the key ``partition_key`` now gives its filter — the child
+        of its token or ``+`` there, or ``key`` itself for a filter that
+        ends at that level or puts ``#`` there. The partition's exclusive
+        chunks go back to the shared pool as they empty. O(rows of the
+        partition), once a partition: ``add`` stays amortized O(1). Only
+        ``key`` and the keys that took rows are dirtied, for the native
+        mirror and the candidate cache. Caller holds the lock, inside the
+        add's transaction."""
+        st = self._split_stage
+        tok = st.begin()
+        try:
+            key_of = self._key_of_fid
+            rows: List[int] = []
+            for cid in self._excl_chunks.pop(key, ()):
+                # after a compaction a boundary chunk holds a neighbor's
+                # rows too: only this key's move
+                base = cid * CHUNK
+                for off in np.flatnonzero(self._fid_of_row[base:base + CHUNK] >= 0):
+                    row = base + int(off)
+                    if key_of[int(self._fid_of_row[row])] == key:
+                        rows.append(row)
+            for row in self._excl_free.pop(key, ()):
+                self._free_shared_slot(row)
+            self._excl_rows.pop(key, None)
+            self._split_keys[key] = None
+            if len(key) > self._key_depth:
+                # candidate sets now depend on one more topic level: the
+                # cache keys of every entry are too short
+                self._key_depth = len(key)
+                self._cand_cache.clear()
+                self._cand_keys_of.clear()
+            touched = {key}
+            for src in rows:
+                fid = int(self._fid_of_row[src])
+                levels = self._filter_of_fid(fid)
+                new = partition_key(levels, self._split_keys)
+                key_of[fid] = new
+                touched.add(new)
+                dst = self._alloc_row(new)
+                self._move_row(src, dst, (key, new, levels))
+            for k in touched:
+                if self._nenc:
+                    self._parts_dirty.add(k)
+                self._invalidate_cand(k)
+        finally:
+            st.end(tok)
+
     def remove(self, fid: int) -> None:
         with self._mu:
             key = self._key_of_fid.pop(fid, None)
@@ -756,6 +881,7 @@ class PartitionedTable:
             self._free_shared_slot(row)
         else:
             self._excl_free.setdefault(key, []).append(row)
+            self._excl_rows[key] = self._excl_rows.get(key, 0) - 1
 
     def needs_compact(self) -> bool:
         """Churn threshold at which the fragmented layout is worth a
@@ -889,7 +1015,9 @@ class PartitionedTable:
             if op[0] == "a":
                 adds[op[1]] = (op[2], op[3])
             elif op[0] == "r":
-                removed[op[1]] = op[2]
+                # the FIRST removal names the key the built layout holds the
+                # fid under: a split re-adds it under another (r, a, r)
+                removed.setdefault(op[1], op[2])
                 adds.pop(op[1], None)
                 moved.pop(op[1], None)
             else:  # 'm': migrated by a concurrent add — data may be torn
@@ -906,6 +1034,7 @@ class PartitionedTable:
         self.nchunks = state.nchunks
         self._excl_chunks = state.excl_chunks
         self._excl_free = state.excl_free
+        self._excl_rows = state.excl_rows
         self._shared_chunks_of = state.shared_chunks_of
         self._spread = state.spread
         self._shared_rows_of = state.shared_rows_of
@@ -943,6 +1072,12 @@ class PartitionedTable:
         # the epoch bump makes the next native encode resync its partition
         # mirror wholesale
         self._parts_dirty.clear()
+        # the padded NC is the new layout's to set: rows loaded far apart
+        # (a fleet subscribing connection by connection) scatter a partition
+        # over many shared chunks, and the NC they grew would otherwise
+        # outlive the layout that needed it. One new kernel shape a
+        # compaction, where the installed layout needs a smaller NC
+        self._nc_cap = NC_FLOOR
 
     def _filter_of_fid(self, fid: int) -> List[str]:
         """Decode a live fid's filter levels back from the row data."""
@@ -963,7 +1098,7 @@ class PartitionedTable:
         """Candidate chunk ids for a topic prefix (partition-map walk)."""
         chunks: List[int] = []
         seen: set = set()  # partitions share boundary/shared chunks
-        for key in topic_partitions(levels):
+        for key in topic_partitions(levels, self._split_keys):
             for cid in self._excl_chunks.get(key, ()):
                 if cid not in seen:
                     seen.add(cid)
@@ -1039,6 +1174,7 @@ class PartitionedTable:
             self._cand_cache.clear()
             self._cand_keys_of.clear()
         cache = self._cand_cache
+        depth = self._key_depth
         groups = np.full((b,), -1, dtype=np.int32)
         for j, topic in enumerate(topics):
             levels = split_levels(topic) if isinstance(topic, str) else list(topic)
@@ -1052,9 +1188,9 @@ class PartitionedTable:
             tok_rows.append(row)
             # candidate chunks: cached per effective prefix — topics share
             # these heavily (the wildcard partitions are common to all).
-            # The key must cover every level the partition scheme inspects
-            # (1, 2 or 3 depending on topic depth).
-            ckey = tuple(levels[:3]) if len(levels) >= 3 else tuple(levels)
+            # The key must cover every level the partition scheme inspects:
+            # up to 3, and one more below each split partition
+            ckey = tuple(levels[:depth])
             ckey = (len(ckey),) + ckey
             ent = cache.get(ckey)
             if ent is None:
@@ -1072,10 +1208,12 @@ class PartitionedTable:
         if batch:
             ttok[:batch] = np.asarray(tok_rows, dtype=np.int64).astype(ttok.dtype)
         mx = max((len(c) for c in per_topic_chunks), default=1)
-        # sticky pow2 NC (grow-only per table): a light batch after a heavy
-        # one must not flip the kernel signature back and forth
+        # sticky pow2 NC (grow-only between compactions): a light batch
+        # after a heavy one must not flip the kernel signature back and forth
         self._nc_cap = max(self._nc_cap, 1 << (max(1, mx) - 1).bit_length())
         nc = self._nc_cap
+        self.encode_candidates_total += sum(map(len, per_topic_chunks))
+        self.encode_scanned_total += batch * nc
         chunk_ids = np.zeros((b, nc), dtype=self._cand_dtype())  # 0 = empty chunk
         for j, chunks in enumerate(per_topic_chunks):
             chunk_ids[j, : len(chunks)] = chunks
@@ -1085,9 +1223,9 @@ class PartitionedTable:
 
     def _sync_native(self, enc) -> None:
         """Bring the native token and partition mirrors up to date: one call
-        for the new tokens and two or three for the partitions, however
-        much was mutated since the last encode. Caller holds the table
-        lock."""
+        for the new tokens, two or three for the partitions and one for the
+        new splits, however much was mutated since the last encode. Caller
+        holds the table lock."""
         toks = self.tokens._strs
         if enc.tokens_synced < len(toks):
             enc.add_tokens(toks[enc.tokens_synced:], _FIRST_TOK + enc.tokens_synced)
@@ -1108,6 +1246,10 @@ class PartitionedTable:
             if shared:
                 enc.parts_put(shared.keys(), shared.values(), True)
         self._parts_dirty.clear()
+        # splits are for good: only the ones since the last sync go
+        if enc.splits_synced < len(self._split_keys):
+            enc.split_put(list(itertools.islice(self._split_keys, enc.splits_synced, None)))
+            enc.splits_synced = len(self._split_keys)
 
     def _encode_native(
         self, topics: Sequence[str | Sequence[str]], pad_batch_to: Optional[int],
@@ -1142,8 +1284,10 @@ class PartitionedTable:
             )
             nc = max(1, 1 << (max(1, mx) - 1).bit_length())  # pow2 bucket
             if nc > nc_cap:
-                self._nc_cap = nc  # sticky: grows, never shrinks
+                self._nc_cap = nc  # sticky until the next compaction install
                 continue
+            self.encode_candidates_total += int(counts[:batch].sum())
+            self.encode_scanned_total += batch * nc_cap
             # the C ABI fills int32; shrink for upload when ids fit (the
             # narrowing copy is ~0.5ms/16K for half the bytes shipped).
             # tlen clamps like the python path: comparisons are invariant
@@ -1823,8 +1967,10 @@ class PartitionedMatcher:
         self._dev_fid_map: Optional[np.ndarray] = None
 
     def use_telemetry(self, tele) -> None:
-        """Count the four sections into ``tele``'s ``matcher.*`` stages."""
+        """Count the four sections into ``tele``'s ``matcher.*`` stages, and
+        the table's partition splits into ``matcher.partition_split``."""
         self._stages = {k: tele.stage("matcher." + k) for k in _STAGE_KEYS}
+        self.table._split_stage = tele.stage("matcher.partition_split")
 
     def _timed(self) -> bool:
         """Are this call's four sections the routing path's? Not where the

@@ -89,7 +89,11 @@ class RetainedTable(PartitionedTable):
     fids, compact) and abuses the unused ``first_wild`` row flag — always
     False for concrete topics — to carry the row's ``$``-topic bit, so
     ``pack_device_rows`` ships it as flag bit 1 with zero layout changes.
+    Its keys stay at three levels (no split): the inverse index masks
+    exactly those.
     """
+
+    split_rows = None
 
     def __init__(self, max_levels: int = 8) -> None:
         super().__init__(max_levels)

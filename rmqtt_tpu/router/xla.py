@@ -414,6 +414,12 @@ class XlaRouter(Router):
             # per-topic Python resolved (0 while the native encoder serves)
             "encode_topics": getattr(t, "encode_topics_total", 0),
             "encode_host_resolved": getattr(t, "encode_host_resolved", 0),
+            # candidate chunks of those topics, and the chunk steps the
+            # kernel ran for them (the padded NC each); partitions split
+            # one level deeper so far (ops/partitioned.py SPLIT_ROWS)
+            "encode_candidates": getattr(t, "encode_candidates_total", 0),
+            "encode_scanned": getattr(t, "encode_scanned_total", 0),
+            "partitions_split": len(getattr(t, "_split_keys", ())),
             # batches served end-to-end by the fused device pipeline
             # (ops/partitioned.py): nonzero proves host decode is off the
             # per-batch path

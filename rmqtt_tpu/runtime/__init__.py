@@ -99,6 +99,10 @@ def load() -> Optional[ctypes.CDLL]:
         ctypes.c_int32,
     ]
     lib.rt_enc_parts_put.restype = ctypes.c_int64
+    lib.rt_enc_split_put.argtypes = [
+        ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64,
+    ]
+    lib.rt_enc_split_put.restype = ctypes.c_int64
     lib.rt_enc_encode.argtypes = [
         ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int64, ctypes.c_int32,
         ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int32),
@@ -463,10 +467,11 @@ def _i32p(a: np.ndarray):
 class NativeEncoder:
     """ctypes wrapper over the C++ batched topic encoder (runtime/encode.cc).
 
-    Owns the native mirrors of one ``PartitionedTable``'s token dictionary
-    and partition-key → chunk-ids maps; the table pushes new tokens and the
-    keys its mutations touched before each encode, in a fixed number of
-    calls (see partitioned.py ``_sync_native``). No call here is per topic.
+    Owns the native mirrors of one ``PartitionedTable``'s token dictionary,
+    partition-key → chunk-ids maps and split partitions; the table pushes new
+    tokens, the keys its mutations touched and its new splits before each
+    encode, in a fixed number of calls (see partitioned.py
+    ``_sync_native``). No call here is per topic.
     """
 
     def __init__(self) -> None:
@@ -477,6 +482,7 @@ class NativeEncoder:
         self._ptr = ctypes.c_void_p(lib.rt_enc_new())
         self.tokens_synced = 0  # count of TokenDict entries pushed so far
         self.parts_epoch = -1  # table.layout_epoch the partition mirror reflects
+        self.splits_synced = 0  # count of the table's split partitions pushed
 
     def __del__(self) -> None:
         ptr = getattr(self, "_ptr", None)
@@ -512,6 +518,15 @@ class NativeEncoder:
         )
         if rc != n:
             raise RuntimeError(f"native partition mirror refused key #{-rc - 1} of {n}")
+
+    def split_put(self, keys: Collection[Tuple]) -> None:
+        """Mark deep partition keys split (for good): a topic that goes on
+        past one walks its token and ``+`` children too."""
+        n = len(keys)
+        blob = ("/".join(map("/".join, keys)) + "/").encode()
+        rc = self._lib.rt_enc_split_put(self._ptr, blob, len(blob), n)
+        if rc != n:
+            raise RuntimeError(f"native split mirror refused key #{-rc - 1} of {n}")
 
     def encode(
         self,

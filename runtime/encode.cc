@@ -6,13 +6,17 @@
 // natively for the WHOLE batch in one call: split levels, token-dict
 // lookup, $-prefix flag, and the candidate-chunk walk itself — the encoder
 // holds a mirror of the table's partition-key → chunk-ids maps
-// (`_excl_chunks` / `_shared_chunks_of`), derives each topic's <= 15
-// partition keys (`topic_partitions`) and unions their chunks in
+// (`_excl_chunks` / `_shared_chunks_of`) and of its split partitions
+// (`_split_keys`), derives each topic's partition keys (`topic_partitions`:
+// at most 15 three-level ones, and the token and '+' children of every
+// split partition the topic descends through) and unions their chunks in
 // `_candidates_for`'s order. Nothing comes back to Python per topic,
 // whatever share of the batch has a never-seen prefix. The table keeps the
 // mirror in step the way it keeps the tokens: mutations mark the keys they
 // touched, the next encode pushes those keys' chunk lists in one or two
-// rt_enc_parts_put calls (a compaction install resyncs wholesale).
+// rt_enc_parts_put calls and the new splits in one rt_enc_split_put call
+// (a compaction install resyncs the chunk lists wholesale; a split is kept
+// for good).
 //
 // Exposed as a C ABI for ctypes (no pybind11 in this image). Thread safety:
 // external, same contract as topics.cc.
@@ -23,7 +27,9 @@
 #include <algorithm>
 #include <string>
 #include <string_view>
+#include <initializer_list>
 #include <unordered_map>
+#include <unordered_set>
 #include <vector>
 
 namespace {
@@ -46,6 +52,26 @@ struct SvEq {
   bool operator()(std::string_view a, std::string_view b) const noexcept { return a == b; }
 };
 
+inline uint64_t mix(uint64_t h, uint32_t v) {
+  h ^= v;
+  h *= 0x9E3779B97F4A7C15ull;
+  return h ^ (h >> 29);
+}
+// The bytes of int32 words (a deep key, below): mixed a word
+// at a time, which costs less than hashing their bytes.
+struct WordsHash {
+  using is_transparent = void;
+  size_t operator()(std::string_view s) const noexcept {
+    uint64_t h = s.size();
+    for (size_t i = 0; i + sizeof(uint32_t) <= s.size(); i += sizeof(uint32_t)) {
+      uint32_t v;
+      std::memcpy(&v, s.data() + i, sizeof v);
+      h = mix(h, v);
+    }
+    return static_cast<size_t>(h);
+  }
+};
+
 // Heterogeneous unordered lookup (P1690) only ships in libstdc++ from GCC
 // 11; on older toolchains fall back to one reusable thread_local buffer so
 // the hot loop still never allocates per lookup.
@@ -63,30 +89,44 @@ auto sv_find(const Map& m, std::string_view k) {
 }
 #endif
 
-// partitioned.py partition_key kinds. A key is (kind, k0, k1, k2) with
-// every k a token id or kPlusTok (unused slots 0) — the token-id image of
-// the Python tuple ("4", k0, k1, k2) etc. Every literal level of a stored
-// key was interned when its filter row was written, so a topic level the
+// partitioned.py partition_key kinds. A key is its kind, then the token id
+// (or kPlusTok) of each level it names — the token-id image of the Python
+// tuple ("4", "a", "+", "c") and the like. The deep kinds "4", "5", ...
+// name 3, 4, ... levels and are stored as kDeep + levels; every other kind
+// has a fixed number of levels. Every literal level of a stored key was
+// interned when its filter row was written, so a topic level the
 // dictionary does not know (kUnkTok) cannot name any partition.
-enum Kind : int32_t { kHash = 0, k1 = 1, k2 = 2, k2E = 3, kH3 = 4, k4 = 5, kBad = -1 };
+enum Kind : int32_t { kHash = 0, k1 = 1, k2 = 2, k2E = 3, kH3 = 4, kBad = -1 };
+constexpr int32_t kArity[] = {0, 1, 1, 2, 2};  // by Kind
+constexpr int32_t kDeep = 16;                  // deep kind "N" = kDeep + N - 1
+constexpr int32_t kMaxKeyLevels = 4096;        // a parse bound, far past any filter
 
-struct PartKey {
+using Key = std::vector<int32_t>;
+
+// A key of at most three levels — every key of a table that never splits —
+// is held fixed-width (unused levels 0), hashed and compared inline; a
+// deeper one, which only a split makes, by the bytes of its words.
+struct Key3 {
   int32_t kind, a, b, c;
-  bool operator==(const PartKey& o) const noexcept {
+  bool operator==(const Key3& o) const noexcept {
     return kind == o.kind && a == o.a && b == o.b && c == o.c;
   }
 };
-struct PartKeyHash {
-  size_t operator()(const PartKey& k) const noexcept {
+struct Key3Hash {
+  size_t operator()(const Key3& k) const noexcept {
     uint64_t h = static_cast<uint32_t>(k.kind);
-    for (int32_t v : {k.a, k.b, k.c}) {
-      h ^= static_cast<uint32_t>(v);
-      h *= 0x9E3779B97F4A7C15ull;
-      h ^= h >> 29;
-    }
+    for (int32_t v : {k.a, k.b, k.c}) h = mix(h, static_cast<uint32_t>(v));
     return static_cast<size_t>(h);
   }
 };
+constexpr size_t kKey3Words = 4;
+
+Key3 key3(const int32_t* k, size_t n) {
+  return {k[0], n > 1 ? k[1] : 0, n > 2 ? k[2] : 0, n > 3 ? k[3] : 0};
+}
+std::string_view words(const int32_t* k, size_t n) {
+  return {reinterpret_cast<const char*>(k), n * sizeof(int32_t)};
+}
 
 // Next '/'-terminated segment of [*p, end): levels cannot hold a '/', so
 // the token and partition-key streams delimit themselves with it whatever
@@ -101,81 +141,167 @@ bool next_segment(const char** p, const char* end, std::string_view* out) {
   return true;
 }
 
-Kind kind_of(std::string_view s) {
+// The stored kind of a key's first segment, or kBad.
+int32_t kind_of(std::string_view s) {
   if (s == "#") return kHash;
   if (s == "1") return k1;
   if (s == "2") return k2;
   if (s == "2E") return k2E;
   if (s == "H3") return kH3;
-  if (s == "4") return k4;
-  return kBad;
+  if (s.empty() || s.size() > 4 || s[0] == '0') return kBad;
+  int32_t n = 0;
+  for (char ch : s) {
+    if (ch < '0' || ch > '9') return kBad;
+    n = n * 10 + (ch - '0');
+  }
+  return n >= 4 && n - 1 <= kMaxKeyLevels ? kDeep + n - 1 : kBad;
 }
+
+int32_t arity_of(int32_t kind) { return kind >= kDeep ? kind - kDeep : kArity[kind]; }
 
 struct Encoder {
   std::unordered_map<std::string, int32_t, SvHash, SvEq> tokens;
   // partition key -> chunk ids it occupies (exclusive, then shared)
-  std::unordered_map<PartKey, std::vector<int32_t>, PartKeyHash> parts;
+  std::unordered_map<Key3, std::vector<int32_t>, Key3Hash> parts;
+  std::unordered_map<std::string, std::vector<int32_t>, WordsHash, SvEq> deep_parts;
+  // split deep partitions (their words): a topic that goes on past one
+  // descends into its token and '+' children
+  std::unordered_set<std::string, WordsHash, SvEq> splits;
+  // topic levels a candidate row depends on: 3, or one past the longest
+  // split key
+  int32_t key_depth = 3;
   // per-batch memo: a topic's candidate row is a function of its first
-  // <= 3 key tokens and min(levels, 3) — the first topic of the batch with
-  // a given prefix walks, later ones copy its row and share its group id
-  std::unordered_map<PartKey, int32_t, PartKeyHash> seen_prefix;
+  // <= key_depth key tokens and min(levels, key_depth) — the first topic
+  // of the batch with a given prefix walks, later ones copy its row and
+  // share its group id. Keyed {kl, tokens} up to three levels, by the
+  // tokens' words (whose length says kl) past that
+  std::unordered_map<Key3, int32_t, Key3Hash> seen_prefix;
+  std::unordered_map<std::string, int32_t, WordsHash, SvEq> seen_deep;
   // chunk-id dedup stamps (partitions share boundary / shared chunks)
   std::vector<uint32_t> stamp;
   uint32_t stamp_gen = 0;
+  // scratch: the key being walked, a topic's key tokens
+  Key key, kt;
+
+  // Key k[0..n)'s chunk ids, or null.
+  const std::vector<int32_t>* find(const int32_t* k, size_t n) const {
+    if (n <= kKey3Words) {
+      auto it = parts.find(key3(k, n));
+      return it == parts.end() ? nullptr : &it->second;
+    }
+    auto it = sv_find(deep_parts, words(k, n));
+    return it == deep_parts.end() ? nullptr : &it->second;
+  }
+  std::vector<int32_t>& slot(const Key& k) {
+    if (k.size() <= kKey3Words) return parts[key3(k.data(), k.size())];
+    return deep_parts[std::string(words(k.data(), k.size()))];
+  }
+  void erase(const Key& k) {
+    if (k.size() <= kKey3Words)
+      parts.erase(key3(k.data(), k.size()));
+    else
+      deep_parts.erase(std::string(words(k.data(), k.size())));
+  }
 
   int32_t key_token(std::string_view lev) const {
     if (lev == "+") return kPlusTok;
     auto it = sv_find(tokens, lev);
     return it == tokens.end() ? kUnkTok : it->second;
   }
+
+  // Parse one key of a '/'-segmented stream into `out`: its kind, then
+  // as many levels as the kind names. False on an unknown kind or level,
+  // or a stream that ends inside the key.
+  bool parse_key(const char** p, const char* end, Key* out) const {
+    std::string_view seg;
+    int32_t kind;
+    if (!next_segment(p, end, &seg) || (kind = kind_of(seg)) == kBad) return false;
+    out->assign(1, kind);
+    for (int32_t l = 0, n = arity_of(kind); l < n; ++l) {
+      int32_t tok;
+      if (!next_segment(p, end, &seg) || (tok = key_token(seg)) == kUnkTok) return false;
+      out->push_back(tok);
+    }
+    return true;
+  }
 };
 
-// Union `key`'s chunks into one topic's candidate row: first occurrence
-// wins, the TRUE count runs on past nc_cap (the caller grows and retries).
-inline void add_part(Encoder* enc, const PartKey& key, int32_t* out,
-                     int32_t nc_cap, int32_t* count) {
-  if (key.a == kUnkTok || key.b == kUnkTok || key.c == kUnkTok) return;
-  auto it = enc->parts.find(key);
-  if (it == enc->parts.end()) return;
-  for (int32_t cid : it->second) {
-    uint32_t& st = enc->stamp[static_cast<size_t>(cid)];
-    if (st == enc->stamp_gen) continue;
-    st = enc->stamp_gen;
-    if (*count < nc_cap) out[*count] = cid;
-    ++*count;
-  }
-}
+// One topic's candidate walk: partitioned.py topic_partitions +
+// _candidates_for.
+struct Walk {
+  Encoder* enc;
+  const int32_t* t;  // the topic's first min(nlev, key_depth) key tokens
+  int32_t nlev;      // min(levels, key_depth)
+  int32_t* out;
+  int32_t nc_cap;
+  int32_t count = 0;
 
-// partitioned.py topic_partitions + _candidates_for for one topic whose
-// first min(nlev, 3) key tokens are t[0..2].
-int32_t walk_parts(Encoder* enc, const int32_t* t, int32_t nlev, int32_t* out,
-                   int32_t nc_cap) {
-  if (++enc->stamp_gen == 0) {  // wrapped: stale stamps could alias
-    std::fill(enc->stamp.begin(), enc->stamp.end(), 0u);
-    enc->stamp_gen = 1;
+  // Union a key's chunks into the row: first occurrence wins, the TRUE
+  // count runs on past nc_cap (the caller grows and retries).
+  void add(const int32_t* k, size_t n) {
+    for (size_t i = 1; i < n; ++i)
+      if (k[i] == kUnkTok) return;
+    const std::vector<int32_t>* cids = enc->find(k, n);
+    if (!cids) return;
+    for (int32_t cid : *cids) {
+      uint32_t& st = enc->stamp[static_cast<size_t>(cid)];
+      if (st == enc->stamp_gen) continue;
+      st = enc->stamp_gen;
+      if (count < nc_cap) out[count] = cid;
+      ++count;
+    }
   }
-  int32_t c = 0;
-  const int32_t P = kPlusTok;
-  add_part(enc, {kHash, 0, 0, 0}, out, nc_cap, &c);
-  add_part(enc, {k2, t[0], 0, 0}, out, nc_cap, &c);
-  add_part(enc, {k2, P, 0, 0}, out, nc_cap, &c);
-  if (nlev == 1) {
-    add_part(enc, {k1, t[0], 0, 0}, out, nc_cap, &c);
-    add_part(enc, {k1, P, 0, 0}, out, nc_cap, &c);
-    return c;
+  // a key of a fixed kind, its words on the stack
+  void flat(std::initializer_list<int32_t> k) { add(k.begin(), k.size()); }
+  // partitioned.py _descend: the deep key, then — where it is split and
+  // the topic goes on past it — its token child's and '+' child's walks.
+  void descend() {
+    Key& k = enc->key;
+    add(k.data(), k.size());
+    const int32_t d = static_cast<int32_t>(k.size()) - 1;  // levels it names
+    if (nlev <= d || enc->splits.empty() ||
+        sv_find(enc->splits, words(k.data(), k.size())) == enc->splits.end())
+      return;
+    ++k[0];
+    k.push_back(t[d]);
+    descend();
+    k.back() = kPlusTok;
+    descend();
+    k.pop_back();
+    --k[0];
   }
-  const int32_t pairs[4][2] = {{t[0], t[1]}, {t[0], P}, {P, t[1]}, {P, P}};
-  for (const auto& p : pairs) add_part(enc, {kH3, p[0], p[1], 0}, out, nc_cap, &c);
-  if (nlev == 2) {
-    for (const auto& p : pairs) add_part(enc, {k2E, p[0], p[1], 0}, out, nc_cap, &c);
-    return c;
+  void deep(int32_t a, int32_t b, int32_t c) {
+    enc->key.assign({kDeep + 3, a, b, c});
+    descend();
   }
-  for (const auto& p : pairs) {
-    add_part(enc, {k4, p[0], p[1], t[2]}, out, nc_cap, &c);
-    add_part(enc, {k4, p[0], p[1], P}, out, nc_cap, &c);
+
+  int32_t run() {
+    if (++enc->stamp_gen == 0) {  // wrapped: stale stamps could alias
+      std::fill(enc->stamp.begin(), enc->stamp.end(), 0u);
+      enc->stamp_gen = 1;
+    }
+    const int32_t P = kPlusTok;
+    flat({kHash});
+    flat({k2, t[0]});
+    flat({k2, P});
+    if (nlev == 1) {
+      flat({k1, t[0]});
+      flat({k1, P});
+      return count;
+    }
+    const int32_t pairs[4][2] = {{t[0], t[1]}, {t[0], P}, {P, t[1]}, {P, P}};
+    for (const auto& p : pairs) flat({kH3, p[0], p[1]});
+    if (nlev == 2) {
+      for (const auto& p : pairs) flat({k2E, p[0], p[1]});
+      return count;
+    }
+    for (const auto& p : pairs) {
+      deep(p[0], p[1], t[2]);
+      deep(p[0], p[1], P);
+    }
+    return count;
   }
-  return c;
-}
+};
 
 }  // namespace
 
@@ -198,12 +324,16 @@ int64_t rt_enc_add_tokens(void* h, const char* blob, int64_t blob_len,
   return n;
 }
 
-void rt_enc_parts_clear(void* h) { static_cast<Encoder*>(h)->parts.clear(); }
+void rt_enc_parts_clear(void* h) {
+  auto* enc = static_cast<Encoder*>(h);
+  enc->parts.clear();
+  enc->deep_parts.clear();
+}
 
 // Install n partition keys' chunk lists in one call. `keys` is the n keys'
-// segments — the Python tuple's kind, then its 0-3 levels ("4","a","+","c";
-// "2E","","b"; "#") — each followed by '/'; the kind fixes how many levels
-// follow. `counts[i]` chunk ids of key i follow
+// segments — the Python tuple's kind, then its levels ("4","a","+","c";
+// "5","a","+","c","d"; "2E","","b"; "#") — each followed by '/'; the kind
+// fixes how many levels follow. `counts[i]` chunk ids of key i follow
 // one another in `chunks`, in the table's own order. With `append` 0 a
 // key's list is replaced (an empty one erases the key); with 1 the ids
 // are appended — the table pushes a key's exclusive chunks first, then its
@@ -214,31 +344,41 @@ void rt_enc_parts_clear(void* h) { static_cast<Encoder*>(h)->parts.clear(); }
 int64_t rt_enc_parts_put(void* h, const char* keys, int64_t keys_len, int64_t n,
                          const int32_t* counts, const int32_t* chunks,
                          int32_t append) {
-  static constexpr int32_t kArity[] = {0, 1, 1, 2, 2, 3};  // by Kind
   auto* enc = static_cast<Encoder*>(h);
   const char* p = keys;
   const char* const end = keys + keys_len;
-  auto segment = [&](std::string_view* out) { return next_segment(&p, end, out); };
+  Key key;
   for (int64_t i = 0; i < n; ++i) {
-    std::string_view seg;
-    PartKey pk{kBad, 0, 0, 0};
-    if (!segment(&seg) || (pk.kind = kind_of(seg)) == kBad) return -(i + 1);
-    int32_t* lev[3] = {&pk.a, &pk.b, &pk.c};
-    for (int32_t l = 0; l < kArity[pk.kind]; ++l) {
-      if (!segment(&seg) || (*lev[l] = enc->key_token(seg)) == kUnkTok) return -(i + 1);
-    }
+    if (!enc->parse_key(&p, end, &key)) return -(i + 1);
     const int32_t cnt = counts[i];
     if (cnt == 0) {
-      if (!append) enc->parts.erase(pk);
+      if (!append) enc->erase(key);
       continue;
     }
-    auto& v = enc->parts[pk];
+    auto& v = enc->slot(key);
     if (!append) v.clear();
     v.insert(v.end(), chunks, chunks + cnt);
     const int32_t mx = *std::max_element(chunks, chunks + cnt);
     chunks += cnt;
     if (static_cast<size_t>(mx) >= enc->stamp.size())
       enc->stamp.resize(static_cast<size_t>(mx) + 1, 0u);
+  }
+  return p == end ? n : -(n + 1);
+}
+
+// Mark n deep partition keys (segments as rt_enc_parts_put's) split, for
+// good: a topic that goes on past such a key also walks its token and '+'
+// children. Returns n, or -(i+1) when key i does not parse or is not of a
+// deep kind.
+int64_t rt_enc_split_put(void* h, const char* keys, int64_t keys_len, int64_t n) {
+  auto* enc = static_cast<Encoder*>(h);
+  const char* p = keys;
+  const char* const end = keys + keys_len;
+  Key key;
+  for (int64_t i = 0; i < n; ++i) {
+    if (!enc->parse_key(&p, end, &key) || key[0] < kDeep) return -(i + 1);
+    enc->splits.emplace(words(key.data(), key.size()));
+    enc->key_depth = std::max(enc->key_depth, static_cast<int32_t>(key.size()));
   }
   return p == end ? n : -(n + 1);
 }
@@ -257,24 +397,28 @@ int32_t rt_enc_encode(void* h, const char* blob, int64_t n, int32_t max_levels,
   const auto& tokens = enc->tokens;
   auto& seen = enc->seen_prefix;
   seen.clear();
+  enc->seen_deep.clear();
+  const int32_t depth = enc->key_depth;
+  const int32_t looked = std::max(max_levels, depth);
+  Key& kt = enc->kt;  // key tokens of the first <= depth levels
+  kt.assign(static_cast<size_t>(depth), 0);
   int32_t max_count = 0;
   const char* p = blob;
   for (int64_t j = 0; j < n; ++j) {
     const char* topic_start = p;
     int32_t* row = ttok + j * max_levels;
     int32_t nlev = 0;
-    int32_t kt[3] = {0, 0, 0};  // key tokens of the first <= 3 levels
     const char* lev_start = p;
     for (;; ++p) {
       if (*p == '/' || *p == '\0') {
-        if (nlev < max_levels || nlev < 3) {
+        if (nlev < looked) {
           std::string_view lev(lev_start, static_cast<size_t>(p - lev_start));
           auto it = sv_find(tokens, lev);
           const int32_t tok = it == tokens.end() ? kUnkTok : it->second;
           if (nlev < max_levels) row[nlev] = tok;
           // a literal "+" level names the PLUS partitions, as the Python
           // walk's string equality has it (no valid publish carries one)
-          if (nlev < 3) kt[nlev] = lev == "+" ? kPlusTok : tok;
+          if (nlev < depth) kt[nlev] = lev == "+" ? kPlusTok : tok;
         }
         ++nlev;
         if (*p == '\0') break;
@@ -284,21 +428,27 @@ int32_t rt_enc_encode(void* h, const char* blob, int64_t n, int32_t max_levels,
     for (int32_t i = nlev; i < max_levels; ++i) row[i] = kPadTok;
     tlen[j] = nlev;
     tdollar[j] = topic_start[0] == '$' ? 1 : 0;
-    const int32_t kl = nlev < 3 ? nlev : 3;
+    const int32_t kl = nlev < depth ? nlev : depth;
     int32_t* out = cand + j * nc_cap;
-    auto [it, fresh] = seen.try_emplace(PartKey{kl, kt[0], kt[1], kt[2]},
-                                        static_cast<int32_t>(j));
-    int32_t c;
-    if (fresh) {
-      c = walk_parts(enc, kt, kl, out, nc_cap);
+    const auto jj = static_cast<int32_t>(j);
+    int32_t first;  // the batch's first topic with this memo key
+    if (kl <= 3) {
+      first = seen.try_emplace(Key3{kl, kt[0], kl > 1 ? kt[1] : 0, kl > 2 ? kt[2] : 0}, jj)
+                  .first->second;
     } else {
-      c = cand_counts[it->second];
-      std::memcpy(out, cand + static_cast<int64_t>(it->second) * nc_cap,
+      first = enc->seen_deep.try_emplace(std::string(words(kt.data(), kl)), jj).first->second;
+    }
+    int32_t c;
+    if (first == jj) {
+      c = Walk{enc, kt.data(), kl, out, nc_cap}.run();
+    } else {
+      c = cand_counts[first];
+      std::memcpy(out, cand + static_cast<int64_t>(first) * nc_cap,
                   static_cast<size_t>(c < nc_cap ? c : nc_cap) * sizeof(int32_t));
     }
+    group[j] = first;
     for (int32_t i = c; i < nc_cap; ++i) out[i] = 0;
     cand_counts[j] = c;
-    group[j] = it->second;
     if (c > max_count) max_count = c;
     ++p;  // skip '\0'
   }

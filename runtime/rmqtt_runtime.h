@@ -27,6 +27,7 @@ void rt_enc_parts_clear(void* enc);
 int64_t rt_enc_parts_put(void* enc, const char* keys, int64_t keys_len, int64_t n,
                          const int32_t* counts, const int32_t* chunks,
                          int32_t append);
+int64_t rt_enc_split_put(void* enc, const char* keys, int64_t keys_len, int64_t n);
 int32_t rt_enc_encode(void* enc, const char* blob, int64_t n, int32_t max_levels,
                       int32_t* ttok, int32_t* tlen, uint8_t* tdollar, int32_t nc_cap,
                       int32_t* cand, int32_t* cand_counts, int32_t* group);

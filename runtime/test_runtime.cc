@@ -93,8 +93,10 @@ static void test_encoder() {
   put("2/sensor/", {7});
   put("1/+/", {});
   assert(flush(1) == 2);
-  put("9/x/", {1});
+  put("Q/x/", {1});
   assert(flush(0) == -1);  // unknown kind
+  put("0/x/", {1});
+  assert(flush(0) == -1);  // no deep kind names fewer than three levels
   put("1/nosuch/", {1});
   assert(flush(0) == -1);  // unknown level
   put("#/", {7});
@@ -151,7 +153,32 @@ static void test_encoder() {
   mx = rt_enc_encode(e, blob.data(), n, 1, ttok1.data(), tlen.data(), dollar.data(),
                      cap2, cand2.data(), cnt.data(), grp.data());
   assert(mx == 4 && ttok1[0] == 10 && tlen[0] == 5);
-  rt_enc_parts_clear(e);
+  // split deep partitions: sensor/+/b keyed one level deeper (its token
+  // child "c"), +/a/+ too (its '+' child), and that child once more at
+  // level 4 (its token child "d" and its '+' child)
+  assert(rt_enc_add_tokens(e, "c/d/", 4, 14) == 2);
+  put("5/sensor/+/b/c/", {11});
+  put("5/+/a/+/+/", {12});
+  put("6/+/a/+/+/d/", {13});
+  put("6/+/a/+/+/+/", {14});
+  assert(flush(0) == 4);
+  keys = "4/sensor/+/b/4/+/a/+/5/+/a/+/+/";
+  assert(rt_enc_split_put(e, keys.data(), static_cast<int64_t>(keys.size()), 3) == 3);
+  keys = "2E/sensor/a/";  // only a deep kind splits
+  assert(rt_enc_split_put(e, keys.data(), static_cast<int64_t>(keys.size()), 1) == -1);
+  keys.clear();
+  mx = rt_enc_encode(e, blob.data(), n, lvl, ttok.data(), tlen.data(), dollar.data(),
+                     cap2, cand2.data(), cnt.data(), grp.data());
+  // topic 0 sensor/a/b/c/d: # 7 | H3 2 | 4/sensor/+/b 5, its child c 11 |
+  // 4/+/a/+ 9 (5 seen), its '+' child 12, whose children d 13 and '+' 14
+  const int32_t want0c[8] = {7, 2, 5, 11, 9, 12, 13, 14};
+  assert(mx == 8 && cnt[0] == 8 && std::memcmp(&cand2[0], want0c, sizeof want0c) == 0);
+  // topic 4 sensor/a/b/zzz: an unknown level 3 names no child, and four
+  // levels reach no level-5 key
+  const int32_t want4c[5] = {7, 2, 5, 9, 12};
+  assert(cnt[4] == 5 && std::memcmp(&cand2[4 * cap2], want4c, sizeof want4c) == 0);
+  assert(grp[4] == 4);  // levels 4 and 5 are read now: another prefix
+  rt_enc_parts_clear(e);  // chunk lists go; the splits are kept
   mx = rt_enc_encode(e, blob.data(), n, lvl, ttok.data(), tlen.data(), dollar.data(),
                      cap2, cand2.data(), cnt.data(), grp.data());
   assert(mx == 0 && cnt[0] == 0 && cand2[0] == 0);

@@ -636,6 +636,9 @@ def test_nc_split_dispatch_parity():
     import numpy as np
 
     table = PartitionedTable()
+    # three-level keys only: a split would prune the two fat partitions
+    # below, and this case needs their skew
+    table.split_rows = None
     fids = {}
     # skew candidate counts: two fat partitions (several exclusive chunks
     # each) that deep "fat/x/k/..." topics pull together, vs tiny cold ones
